@@ -78,13 +78,6 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    import bench
-
-    bench._honor_cpu_request()
-    bench._backend_watchdog(
-        emit=lambda reason: print(json.dumps({"phase": "backend", "error": reason}), flush=True)
-    )
-
     root = args.root or tempfile.mkdtemp(prefix="hs_sf100_")
     os.makedirs(root, exist_ok=True)
     n_li = int(datagen.LINEITEM_ROWS_SF1 * args.sf)

@@ -12,7 +12,7 @@ Usage:
 One JSON line per query:
     {"query": "q3", "indexed_ms": ..., "plain_ms": ..., "speedup": ...,
      "rows": N, "indexes_used": [...]}
-plus a final markdown table on stderr for RESULTS.md.
+plus a final markdown table on stderr.
 """
 
 from __future__ import annotations
@@ -77,13 +77,6 @@ def main():
     ap.add_argument("--queries", default="")
     ap.add_argument("--keep", action="store_true")
     args = ap.parse_args()
-
-    import bench
-
-    bench._honor_cpu_request()
-    bench._backend_watchdog(
-        emit=lambda reason: print(json.dumps({"query": None, "error": reason}), flush=True)
-    )
 
     from tpch_queries import TPCH_QUERIES  # noqa: E402 (tests/ on path)
 

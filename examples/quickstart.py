@@ -17,13 +17,6 @@ import pyarrow.parquet as pq
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-    # some TPU images pin the platform at interpreter startup; enforce the
-    # env request on the config object so the example runs without a chip
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import hyperspace_tpu as hst
 
 

@@ -29,20 +29,18 @@ import os as _os
 
 # Persistent XLA compilation cache: index builds re-run the same fused sort
 # program per size class across processes; without this every fresh process
-# pays a tens-of-seconds TPU compile. Opt out with HS_JAX_CACHE_DIR="".
-_cache_dir = _os.environ.get(
-    "HS_JAX_CACHE_DIR", _os.path.join(_os.path.expanduser("~"), ".cache", "hyperspace_tpu", "xla")
-)
-if _cache_dir and not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    try:
-        import jax as _jax
+# pays a tens-of-seconds TPU compile. JAX_COMPILATION_CACHE_DIR, where set,
+# places the cache (jax reads it itself; nothing is configured here).
+# Otherwise it lives at one fixed path inside the checkout — the path is part
+# of the cache key, so a directory that moves never hits.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    import jax as _jax
 
-        # respect a cache dir the user already configured programmatically
-        if not _jax.config.jax_compilation_cache_dir:
-            _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-            _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # pragma: no cover - cache is best-effort
-        pass
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))), ".jax_cache"),
+    )
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 from hyperspace_tpu.version import __version__
 from hyperspace_tpu.config import HyperspaceConf, keys

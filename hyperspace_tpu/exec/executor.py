@@ -18,6 +18,7 @@ import pyarrow.dataset as pads
 
 from hyperspace_tpu.exec import batch as B
 from hyperspace_tpu.exec import trace
+from hyperspace_tpu.exec.device import DeviceUnsupported
 from hyperspace_tpu.obs import spans
 from hyperspace_tpu.plan import logical as L
 from hyperspace_tpu.plan.expr import (
@@ -27,6 +28,14 @@ from hyperspace_tpu.plan.expr import (
     as_bool_mask,
     extract_equi_join_keys,
 )
+from hyperspace_tpu.reliability.errors import ReliabilityError
+
+#: The only errors that send a streamed device path back to the materialized
+#: one: the typed "not a device shape" signals (GroupCapacityExceeded is a
+#: DeviceUnsupported) and the reliability taxonomy's lake-IO errors. A
+#: TypeError, an XLA or Mosaic compile error, or a device runtime error
+#: propagates — a correct host answer must never hide a broken device program.
+_STREAM_FALLBACK_ERRORS = (DeviceUnsupported, ReliabilityError)
 
 
 #: synthetic global-row-id column carried by the top-k host fallback
@@ -607,29 +616,26 @@ def aggregate_batch(session, keys, aggs, batch: B.Batch) -> B.Batch:
         and conf.agg_device_grouped_enabled
         and B.num_rows(batch) >= conf.device_exec_min_rows
     ):
+        from hyperspace_tpu.exec import device as D
+
         try:
-            from hyperspace_tpu.exec import device as D
-        except ImportError:
-            D = None
-        if D is not None:
-            try:
-                got = D.device_grouped_aggregate(
-                    session,
-                    batch,
-                    None,
-                    keys,
-                    aggs,
-                    scan_key=None,
-                    max_groups=conf.agg_max_groups,
-                    cap_floor=conf.agg_capacity_floor,
-                    parallel=_maybe_parallel(session, B.num_rows(batch)),
-                )
-                trace.record("agg", "device-grouped-batch")
-                return got
-            except D.GroupCapacityExceeded:
-                trace.fallback("agg", "spill")
-            except D.DeviceUnsupported:
-                trace.fallback("agg", "unsupported")
+            got = D.device_grouped_aggregate(
+                session,
+                batch,
+                None,
+                keys,
+                aggs,
+                scan_key=None,
+                max_groups=conf.agg_max_groups,
+                cap_floor=conf.agg_capacity_floor,
+                parallel=_maybe_parallel(session, B.num_rows(batch)),
+            )
+            trace.record("agg", "device-grouped-batch")
+            return got
+        except D.GroupCapacityExceeded:
+            trace.fallback("agg", "spill")
+        except D.DeviceUnsupported:
+            trace.fallback("agg", "unsupported")
     return host_aggregate(batch, keys, aggs)
 
 
@@ -642,12 +648,9 @@ class Executor:
         native fast path pads its buffers to the device count up front
         (session._note_mesh -> io.set_staging_pad) — otherwise the first
         query's chunks decode with pad=1 and lose the zero-copy device_put
-        handoff. A mesh failure must never kill a host-path query."""
+        handoff. A mesh failure is a failing ``jax.devices()`` and raises."""
         if self.session.conf.io_native_enabled:
-            try:
-                self.session.mesh
-            except Exception:
-                pass
+            self.session.mesh
 
     def execute(
         self,
@@ -743,11 +746,10 @@ class Executor:
                 if isinstance(node, L.Filter) and isinstance(node.child, L.Join):
                     post_filter, node = node.condition, node.child
                 if isinstance(node, L.Join) and self.session.conf.device_execution_enabled:
-                    try:
-                        from hyperspace_tpu.exec import device as D
-                    except ImportError:
-                        D = None
-                    if D is not None and D.join_sides_compatible(node) is not None:
+                    from hyperspace_tpu.exec import device as D
+                    from hyperspace_tpu.exec import join_stream as JS
+
+                    if D.join_sides_compatible(node) is not None:
                         gen = D.stream_bucketed_join(self.session, node)
                         try:
                             first = next(gen)
@@ -770,23 +772,20 @@ class Executor:
                             for chunk in gen:
                                 yield shape(chunk)
                             return
-                    if D is not None:
-                        from hyperspace_tpu.exec import join_stream as JS
-
-                        if JS.broadcast_spec(self.session, node) is not None:
-                            gen = JS.stream_broadcast_join(
-                                self, node, post_filter=post_filter, project=proj
-                            )
-                            try:
-                                first = next(gen)
-                            except StopIteration:
-                                return
-                            except D.DeviceUnsupported:
-                                gen = None
-                            if gen is not None:
-                                yield first
-                                yield from gen
-                                return
+                    if JS.broadcast_spec(self.session, node) is not None:
+                        gen = JS.stream_broadcast_join(
+                            self, node, post_filter=post_filter, project=proj
+                        )
+                        try:
+                            first = next(gen)
+                        except StopIteration:
+                            return
+                        except D.DeviceUnsupported:
+                            gen = None
+                        if gen is not None:
+                            yield first
+                            yield from gen
+                            return
                 chain, leaf = _chain_to_scan(plan)
                 if leaf is not None:
                     files = _leaf_files(leaf)
@@ -869,28 +868,20 @@ class Executor:
                     yield self._exec(sub, wfn)
             return
 
-        try:
-            from hyperspace_tpu.exec import device as D
-        except ImportError:
-            D = None
+        from hyperspace_tpu.exec import device as D
         from hyperspace_tpu.exec.pipeline import ScanPipeline
 
         # H2D staging (stage 2) applies when the chunk will take the device
         # filter path: Filter directly over the scan leaf
         dev_cond = None
         if (
-            D is not None
-            and conf.device_execution_enabled
+            conf.device_execution_enabled
             and chain
             and isinstance(chain[-1], L.Filter)
             and isinstance(leaves[0], (L.FileScan, L.IndexScan))
         ):
             dev_cond = chain[-1].condition
-        staging = (
-            D is not None
-            and (dev_cond is not None or stage_extra)
-            and dynamic_pushdown is None
-        )
+        staging = (dev_cond is not None or stage_extra) and dynamic_pushdown is None
 
         def stage(i, batch):
             if B.num_rows(batch) < conf.device_exec_min_rows:
@@ -1462,9 +1453,7 @@ class Executor:
             return None
         try:
             return self._streaming_topk(sort_plan, k, chain, leaf, groups)
-        except Exception:
-            # the streamed path must never break a query the materialized
-            # path can answer; visible in dispatch traces
+        except _STREAM_FALLBACK_ERRORS:
             trace.record("topk", "stream-fallback")
             return None
 
@@ -1650,12 +1639,10 @@ class Executor:
         falls through to the per-family streaming and materialized paths)
         unless ``hyperspace.exec.fusion.enabled`` is set and the shape fuses."""
         conf = self.session.conf
-        try:
-            from hyperspace_tpu.exec import device as D
-            from hyperspace_tpu.exec import join_stream as JS
-            from hyperspace_tpu.exec import stage_ir
-        except ImportError:
-            return None
+        from hyperspace_tpu.exec import device as D
+        from hyperspace_tpu.exec import join_stream as JS
+        from hyperspace_tpu.exec import stage_ir
+
         if not (
             conf.device_execution_enabled
             and conf.agg_device_grouped_enabled
@@ -1683,9 +1670,7 @@ class Executor:
         except D.DeviceUnsupported:
             trace.fallback("fusion", "join-agg-unsupported")
             return None
-        except Exception:
-            # same discipline as the per-family streamed aggregate: the fused
-            # path must never break a query the materialized path can answer
+        except _STREAM_FALLBACK_ERRORS:
             trace.record("agg", "stream-fallback")
             return None
 
@@ -1723,9 +1708,7 @@ class Executor:
         needed = _chain_needed_columns(chain, plan.aggs, plan.keys)
         try:
             return self._streaming_aggregate(plan, chain, leaf, groups, needed)
-        except Exception:
-            # the streamed path must never break a query the materialized
-            # path can answer; visible in dispatch traces
+        except _STREAM_FALLBACK_ERRORS:
             trace.record("agg", "stream-fallback")
             return None
 
@@ -1850,29 +1833,26 @@ class Executor:
             # the fused predicate and keys are expressed in
             and all(isinstance(nd, (L.Filter, L.Project)) for nd in chain)
         ):
-            try:
-                from hyperspace_tpu.exec import device as D
-            except ImportError:
-                D = None
-            if D is not None:
-                fuse_cond = _chain_pushdown_condition(chain)
-                stage_extra = sorted(
-                    set(plan.keys) | {c for _, _, _, c in plain if c is not None}
-                )
-                stream = D.GroupedAggStream(
-                    self.session,
-                    list(plan.keys),
-                    list(plan.aggs),
-                    max_groups=conf.agg_max_groups,
-                    cap_floor=conf.agg_capacity_floor,
-                    # capacity hint shared across repeated runs of the same
-                    # query shape over the same file set (skips the first
-                    # chunk's right-sizing re-run once cardinality is known)
-                    hint_key=("stream",) + tuple(_leaf_files(leaf)),
-                    # per-stream mode decision (chunk sizes aren't known yet):
-                    # minRows gates the one-shot ops, not stream chunks
-                    parallel=_maybe_parallel(self.session),
-                )
+            from hyperspace_tpu.exec import device as D
+
+            fuse_cond = _chain_pushdown_condition(chain)
+            stage_extra = sorted(
+                set(plan.keys) | {c for _, _, _, c in plain if c is not None}
+            )
+            stream = D.GroupedAggStream(
+                self.session,
+                list(plan.keys),
+                list(plan.aggs),
+                max_groups=conf.agg_max_groups,
+                cap_floor=conf.agg_capacity_floor,
+                # capacity hint shared across repeated runs of the same
+                # query shape over the same file set (skips the first
+                # chunk's right-sizing re-run once cardinality is known)
+                hint_key=("stream",) + tuple(_leaf_files(leaf)),
+                # per-stream mode decision (chunk sizes aren't known yet):
+                # minRows gates the one-shot ops, not stream chunks
+                parallel=_maybe_parallel(self.session),
+            )
 
         # chunks arrive through the prefetch pipeline: chunk k+1 decodes (and
         # stages) while this loop folds chunk k's partials
@@ -2021,10 +2001,8 @@ class Executor:
             return None, None, None, None
         if plan.keys and not conf.agg_device_grouped_enabled:
             return None, None, None, None
-        try:
-            from hyperspace_tpu.exec import device as D
-        except ImportError:
-            return None, None, None, None
+        from hyperspace_tpu.exec import device as D
+
         pruned = getattr(node, "pushdown_predicate", None)
         batch = self._exec(node, with_file_names=False)
         if B.num_rows(batch) < conf.device_exec_min_rows:
@@ -2065,21 +2043,17 @@ class Executor:
         if not with_file_names and self.session.conf.device_execution_enabled:
             # deviceExecution=False is the kill switch back to the pandas
             # merge below — it routes around the whole bucketed-SMJ stack
-            try:
-                from hyperspace_tpu.exec import device as D
-            except ImportError:
-                D = None
-            if D is not None:
-                try:
-                    return D.dispatch_bucketed_join(self.session, plan)
-                except D.DeviceUnsupported:
-                    pass  # next tier: broadcast hash join
-                try:
-                    from hyperspace_tpu.exec import join_stream as JS
+            from hyperspace_tpu.exec import device as D
+            from hyperspace_tpu.exec import join_stream as JS
 
-                    return JS.dispatch_broadcast_join(self, plan)
-                except D.DeviceUnsupported:
-                    trace.fallback("join", "unsupported")
+            try:
+                return D.dispatch_bucketed_join(self.session, plan)
+            except D.DeviceUnsupported:
+                pass  # next tier: broadcast hash join
+            try:
+                return JS.dispatch_broadcast_join(self, plan)
+            except D.DeviceUnsupported:
+                trace.fallback("join", "unsupported")
         trace.record("join", "generic-merge")
 
         pairs = extract_equi_join_keys(plan.condition)

@@ -6,7 +6,9 @@ JVM — SURVEY.md §0 — so this has no reference counterpart). Columns decode
 from an mmap'd file directly into numpy arrays that ``jax.device_put`` can
 ship to HBM with no intermediate pyarrow tables or row pivoting.
 
-The shared library is compiled on demand with g++ (``native/Makefile``); when
+The shared library is compiled on demand with g++ into an artefact named by
+a hash of its sources, so a library built from other sources (or copied in
+from another machine's checkout at another revision) is never loaded; when
 the toolchain or the file's encoding is outside the native dialect
 (compressed/nested/v2-specific shapes), callers fall back to pyarrow via
 ``NativeUnsupported``.
@@ -15,6 +17,8 @@ the toolchain or the file's encoding is outside the native dialect
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -24,8 +28,8 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
-_SO_PATH = os.path.join(_PKG_DIR, "libhs_native.so")
 _SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(_PKG_DIR)), "native")
+_SRC_NAMES = ("hs_native.cc", "thrift_compact.h")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -36,10 +40,24 @@ class NativeUnsupported(Exception):
     """The native decoder cannot handle this file; fall back to pyarrow."""
 
 
-def _build() -> None:
+def _so_path() -> str:
+    """``libhs_native-<source hash>.so`` next to this module."""
+    h = hashlib.sha256()
+    for name in _SRC_NAMES:
+        try:
+            with open(os.path.join(_SRC_DIR, name), "rb") as f:
+                h.update(f.read())
+        except FileNotFoundError:
+            raise NativeUnsupported("native sources not present") from None
+    return os.path.join(_PKG_DIR, f"libhs_native-{h.hexdigest()[:16]}.so")
+
+
+def _build(so_path: str) -> None:
+    """Compile the sources to ``so_path`` (via a temp name + rename, so a
+    concurrent process never loads a half-written library) and drop the
+    artefacts of earlier source revisions."""
     src = os.path.join(_SRC_DIR, "hs_native.cc")
-    if not os.path.exists(src):
-        raise NativeUnsupported("native sources not present")
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     base = [
         os.environ.get("CXX", "g++"),
         "-O3",
@@ -73,7 +91,7 @@ def _build() -> None:
     flags: List[str] = ["-lz", "-lzstd"]
     dropped: List[str] = []
     res = subprocess.run(
-        base + flags + ["-o", _SO_PATH], capture_output=True, text=True, cwd=_SRC_DIR
+        base + flags + ["-o", tmp], capture_output=True, text=True, cwd=_SRC_DIR
     )
     if res.returncode != 0 and _missing(res.stderr, "zstd", "zstd.h"):
         # the dev package (zstd.h + libzstd.so symlink) is absent but the
@@ -82,7 +100,7 @@ def _build() -> None:
         # the codec outright
         compat = [f if f != "-lzstd" else "-l:libzstd.so.1" for f in flags]
         res2 = subprocess.run(
-            base + ["-DHS_ZSTD_COMPAT"] + compat + ["-o", _SO_PATH],
+            base + ["-DHS_ZSTD_COMPAT"] + compat + ["-o", tmp],
             capture_output=True,
             text=True,
             cwd=_SRC_DIR,
@@ -99,7 +117,7 @@ def _build() -> None:
         flags = [f for f in flags if f != f"-l{lib}"] + [define]
         dropped.append(lib)
         res = subprocess.run(
-            base + flags + ["-o", _SO_PATH], capture_output=True, text=True, cwd=_SRC_DIR
+            base + flags + ["-o", tmp], capture_output=True, text=True, cwd=_SRC_DIR
         )
     if res.returncode == 0 and dropped:
         logging.getLogger(__name__).warning(
@@ -108,6 +126,10 @@ def _build() -> None:
         )
     if res.returncode != 0:
         raise NativeUnsupported(f"native build failed: {res.stderr[-2000:]}")
+    os.replace(tmp, so_path)
+    for old in glob.glob(os.path.join(_PKG_DIR, "libhs_native*.so")):
+        if old != so_path:
+            os.remove(old)
 
 
 def _load() -> ctypes.CDLL:
@@ -118,35 +140,17 @@ def _load() -> ctypes.CDLL:
         if _load_failed is not None:
             raise NativeUnsupported(_load_failed)
         try:
-            srcs = [
-                os.path.join(_SRC_DIR, "hs_native.cc"),
-                os.path.join(_SRC_DIR, "thrift_compact.h"),
-            ]
-            if not os.path.exists(_SO_PATH) or any(
-                os.path.exists(s) and os.path.getmtime(s) > os.path.getmtime(_SO_PATH)
-                for s in srcs
-            ):
-                _build()
-            lib = ctypes.CDLL(_SO_PATH)
+            so_path = _so_path()
+            if not os.path.exists(so_path):
+                _build(so_path)
+            lib = ctypes.CDLL(so_path)
         except NativeUnsupported as e:
             _load_failed = str(e)
             raise
         except OSError as e:
             _load_failed = f"cannot load libhs_native: {e}"
             raise NativeUnsupported(_load_failed)
-        try:
-            _wire_symbols(lib)
-        except AttributeError:
-            # stale prebuilt .so missing newer symbols: rebuild once, then
-            # give up via NativeUnsupported (callers fall back) rather than
-            # leaking AttributeError through every native call site
-            try:
-                _build()
-                lib = ctypes.CDLL(_SO_PATH)
-                _wire_symbols(lib)
-            except (NativeUnsupported, OSError, AttributeError) as e:
-                _load_failed = f"libhs_native is stale and rebuild failed: {e}"
-                raise NativeUnsupported(_load_failed)
+        _wire_symbols(lib)
         _lib = lib
         return lib
 

@@ -24,9 +24,7 @@ from hyperspace_tpu.utils.x64 import ensure_x64
 
 
 import jax.numpy as jnp  # noqa: E402
-from hyperspace_tpu.parallel.mesh import get_shard_map  # noqa: E402
-
-shard_map = get_shard_map()
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 

@@ -11,8 +11,9 @@ Two kernels back the build paths (guide: /opt/skills/guides/pallas_guide.md):
   detection in the bucketed index build (the device analogue of counting
   Spark's shuffle partition sizes; ref: HS/index/covering/CoveringIndex.scala:54-69).
 
-Off-TPU (CPU tests, virtual meshes) the kernels run in interpreter mode; the
-numerics are identical.
+On the ``cpu`` platform (tests, virtual meshes) the kernels run in interpreter
+mode with identical numerics; every other platform compiles them through
+Mosaic or fails.
 """
 
 from __future__ import annotations
@@ -34,7 +35,7 @@ _SUBLANES = 8
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------

@@ -37,8 +37,6 @@ from hyperspace_tpu.utils.x64 import ensure_x64
 import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 
-_I64_SIGN = -0x8000000000000000
-
 
 def lex_argsort(keys) -> "jnp.ndarray":
     """Stable argsort by ``keys[0]`` then ``keys[1]`` ... (most-significant
@@ -78,6 +76,67 @@ def bucket_sort_perm(hash_inputs, sort_keys, num_buckets: int):
     return out[-1], out[0]
 
 
+def _f64_hash_halves(khi, klo):
+    """uint32 halves of a float64 ORDER KEY -> halves of the int64 the host
+    ``numeric_hash32`` folds for that value: an integral float (|v| < 2^63)
+    becomes its int64 value, -0.0 becomes +0.0, every NaN the canonical NaN,
+    anything else its raw IEEE-754 bits.
+
+    32-bit integer arithmetic on the bit fields only. The straightforward
+    form (bitcast to float64, compare with ``floor``, convert, bitcast back)
+    does not compile for the TPU: its 64-bit rewriting implements no
+    bitcast-convert between f64 and s64."""
+    u32 = jnp.uint32
+    # invert the order-preserving transform back to the raw bits
+    was_pos = khi >= u32(0x80000000)
+    hi = jnp.where(was_pos, khi ^ u32(0x80000000), ~khi)
+    lo = jnp.where(was_pos, klo, ~klo)
+
+    neg = (hi >> u32(31)) == u32(1)
+    exp = ((hi >> u32(20)) & u32(0x7FF)).astype(jnp.int32)
+    frac_hi = hi & u32(0xFFFFF)
+    is_zero = (exp == 0) & ((frac_hi | lo) == u32(0))
+    is_nan = (exp == 0x7FF) & ((frac_hi | lo) != u32(0))
+
+    # |v| = m * 2^(e - 52) with the 53-bit significand m = (m_hi, lo)
+    e = exp - 1023
+    m_hi = frac_hi | u32(0x100000)
+
+    def sh(x):  # shift amounts are only used where the selecting branch holds
+        return jnp.clip(x, 0, 31).astype(u32)
+
+    # e <= 52: m >> k with k = 52 - e in [0, 52]; integral iff no bit drops
+    k = 52 - e
+    ones = u32(0xFFFFFFFF)
+    lt32_lo = jnp.where(k == 0, lo, (lo >> sh(k)) | (m_hi << sh(32 - k)))
+    lt32_hi = m_hi >> sh(k)
+    lt32_drop = lo & ~(ones << sh(k))
+    ge32_lo = m_hi >> sh(k - 32)
+    ge32_drop = lo | (m_hi & ~(ones << sh(k - 32)))
+    small = k >= 32
+    right_hi = jnp.where(small, u32(0), lt32_hi)
+    right_lo = jnp.where(small, ge32_lo, lt32_lo)
+    right_drop = jnp.where(small, ge32_drop, lt32_drop)
+    # 52 < e <= 62: m << j with j = e - 52 in [1, 10]; always integral
+    j = e - 52
+    left_hi = (m_hi << sh(j)) | (lo >> sh(32 - j))
+    left_lo = lo << sh(j)
+
+    shift_left = e > 52
+    mag_hi = jnp.where(shift_left, left_hi, right_hi)
+    mag_lo = jnp.where(shift_left, left_lo, right_lo)
+    is_int = (e >= 0) & (e <= 62) & (shift_left | (right_drop == u32(0)))
+    # int64(-|v|): two's complement across the halves
+    neg_lo = ~mag_lo + u32(1)
+    neg_hi = ~mag_hi + (mag_lo == u32(0)).astype(u32)
+    int_hi = jnp.where(neg, neg_hi, mag_hi)
+    int_lo = jnp.where(neg, neg_lo, mag_lo)
+
+    out_hi = jnp.where(is_int, int_hi, jnp.where(is_nan, u32(0x7FF80000), hi))
+    out_lo = jnp.where(is_int, int_lo, jnp.where(is_nan, u32(0), lo))
+    return jnp.where(is_zero, u32(0), out_hi), jnp.where(is_zero, u32(0), out_lo)
+
+
 def _device_hash32(kind: str, key):
     """Reconstruct the column's uint32 hash input from its order key —
     bit-exact vs the host ``hashing.numeric_hash32`` on the original values,
@@ -85,19 +144,13 @@ def _device_hash32(kind: str, key):
     as its int64 value; -0.0 as +0.0; NaN canonically): a nullable int64
     column decodes as float64, and the un-normalized bit-pattern hash once
     bucketed it apart from the int64 side of the same join."""
-    v64 = key.astype(jnp.int64)
+    bits = lax.bitcast_convert_type(key.astype(jnp.int64), jnp.uint64)
+    hi = (bits >> jnp.uint64(32)).astype(jnp.uint32)
+    lo = (bits & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
     if kind == "f":
-        # invert the order-preserving transform back to the raw f64 bits
-        raw = jnp.where(v64 < 0, v64 ^ jnp.int64(_I64_SIGN), ~v64)
-        f = lax.bitcast_convert_type(raw, jnp.float64) + 0.0  # -0.0 -> +0.0
-        isint = jnp.isfinite(f) & (jnp.abs(f) < 2.0**63) & (f == jnp.floor(f))
-        int_bits = jnp.where(isint, f, 0).astype(jnp.int64)
-        f_norm = jnp.where(jnp.isnan(f), jnp.float64(jnp.nan), f)
-        bits_i = jnp.where(isint, int_bits, lax.bitcast_convert_type(f_norm, jnp.int64))
-    else:  # i / u / b / M — the key IS the value (or its int64 view)
-        bits_i = v64
-    bits = lax.bitcast_convert_type(bits_i, jnp.uint64)
-    return ((bits ^ (bits >> jnp.uint64(32))) & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+        hi, lo = _f64_hash_halves(hi, lo)
+    # i / u / b / M — the key IS the value (or its int64 view)
+    return hi ^ lo
 
 
 @partial(jax.jit, static_argnames=("num_buckets", "kinds", "interpret"))
@@ -151,9 +204,11 @@ def bucket_sort_build(
       (valid rows occupy positions [0, n_valid)) and int32 rows-per-bucket.
     """
     ensure_x64()
-    interpret = jax.default_backend() != "tpu"
+    from hyperspace_tpu.ops.kernels import _use_interpret
+
     return _build_sorted(
-        tuple(keys), tuple(host_hashes), np.int32(n_valid), num_buckets, tuple(kinds), interpret
+        tuple(keys), tuple(host_hashes), np.int32(n_valid), num_buckets, tuple(kinds),
+        _use_interpret(),
     )
 
 

@@ -15,7 +15,6 @@ from hyperspace_tpu.parallel.hlo_check import (
 from hyperspace_tpu.parallel.mesh import (
     DEFAULT_AXIS,
     device_of_bucket,
-    get_shard_map,
     make_mesh,
     make_mesh_2d,
     mesh_fingerprint,
@@ -31,7 +30,6 @@ __all__ = [
     "assert_shuffle_free",
     "collective_counts",
     "device_of_bucket",
-    "get_shard_map",
     "hlo_text_of",
     "make_mesh",
     "make_mesh_2d",

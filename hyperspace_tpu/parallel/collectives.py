@@ -22,7 +22,7 @@ from functools import partial
 import numpy as np
 
 from hyperspace_tpu.exec import device as D
-from hyperspace_tpu.parallel.mesh import get_shard_map
+from jax import shard_map
 
 
 def sharded_elementwise(mesh, axis, fn):
@@ -31,8 +31,6 @@ def sharded_elementwise(mesh, axis, fn):
     back to the global row order. No collectives — compiled HLO is
     shuffle-free (tests/test_hlo_collectives.py)."""
     from jax.sharding import PartitionSpec as P
-
-    shard_map = get_shard_map()
 
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis))
     def mapped(cols, lits):
@@ -61,7 +59,6 @@ def sharded_topk_chunk_program(mesh, axis, num_keys, cap):
 
     from hyperspace_tpu.ops.sort import _TOPK_SENTINEL, _take_cap
 
-    shard_map = get_shard_map()
     n_dev = mesh.devices.size
 
     @partial(
@@ -69,7 +66,7 @@ def sharded_topk_chunk_program(mesh, axis, num_keys, cap):
         mesh=mesh,
         in_specs=(P(None, axis),),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def program(planes):
         local = lax.sort(
@@ -112,7 +109,6 @@ def sharded_grouped_chunk_program(mesh, axis, pred_fn, key_specs, slot_specs, ca
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    shard_map = get_shard_map()
     n_dev = mesh.devices.size
 
     def program(cols, lits, n_valid, row_base):
@@ -121,7 +117,7 @@ def sharded_grouped_chunk_program(mesh, axis, pred_fn, key_specs, slot_specs, ca
             mesh=mesh,
             in_specs=(P(axis), P(), P(), P()),
             out_specs=(P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         def per_shard(cols_, lits_, n_valid_, row_base_):
             per = next(iter(cols_.values())).shape[0]
@@ -186,7 +182,6 @@ def sharded_fused_grouped_program(mesh, axis, pred_fn, key_specs, slot_specs, ca
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    shard_map = get_shard_map()
     n_dev = mesh.devices.size
 
     def program(state_keys, state_slots, state_fs, state_n, cols, lits, n_valid, row_base):
@@ -195,7 +190,7 @@ def sharded_fused_grouped_program(mesh, axis, pred_fn, key_specs, slot_specs, ca
             mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(axis), P(), P(), P()),
             out_specs=(P(), P(), P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         def per_shard(state_keys_, state_slots_, state_fs_, state_n_, cols_, lits_, n_valid_, row_base_):
             per = next(iter(cols_.values())).shape[0]
@@ -267,7 +262,6 @@ def sharded_fused_topk_program(mesh, axis, num_keys, cap):
 
     from hyperspace_tpu.ops.sort import _TOPK_SENTINEL, _take_cap
 
-    shard_map = get_shard_map()
     n_dev = mesh.devices.size
 
     @partial(
@@ -275,7 +269,7 @@ def sharded_fused_topk_program(mesh, axis, num_keys, cap):
         mesh=mesh,
         in_specs=(P(), P(None, axis)),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def program(state, planes):
         local = lax.sort(

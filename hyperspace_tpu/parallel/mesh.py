@@ -17,18 +17,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 DEFAULT_AXIS = "buckets"
 
 
-def get_shard_map():
-    """jax.shard_map with fallback to the pre-0.8 experimental location."""
-    import jax
-
-    try:
-        return jax.shard_map
-    except AttributeError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 def make_mesh(n_devices: Optional[int] = None, axis: str = DEFAULT_AXIS) -> Mesh:
     """1-D mesh over the first ``n_devices`` local devices (all by default).
 

@@ -16,7 +16,8 @@
 // outside this dialect returns an error and the Python caller falls back to
 // pyarrow.
 //
-// Build: make -C native  (g++ -O3 -shared -fPIC, links -lz -lzstd)
+// Build: on first load, by hyperspace_tpu/native/__init__.py
+// (g++ -O3 -shared -fPIC, links -lz -lzstd)
 
 #include <fcntl.h>
 #ifndef HS_NO_ZLIB

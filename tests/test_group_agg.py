@@ -296,6 +296,41 @@ class TestFallbacks:
         assert_grouped_equal(dev, host)
         assert_grouped_equal(dev2, host)
 
+    def test_device_program_error_in_stream_propagates(self, session, lineitems, monkeypatch):
+        """Only the typed "not a device shape" signals send a streamed device
+        aggregate back to the host. Anything else a device program raises (a
+        TypeError, an XLA or Mosaic compile error, a runtime error) reaches the
+        caller: a correct host answer must not hide a broken device path."""
+        from hyperspace_tpu.exec import device as D
+
+        df = session.read_parquet(lineitems)
+        q = q1_query(df)
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, True)
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_MIN_ROWS, 0)
+        session.conf.set(hst.keys.EXEC_STREAM_AGG_MIN_BYTES, 1)
+        session.conf.set(hst.keys.EXEC_STREAM_CHUNK_BYTES, 1)
+
+        def broken(self, *args, **kwargs):
+            raise TypeError("shard_map() got an unexpected keyword argument")
+
+        monkeypatch.setattr(D.GroupedAggStream, "update", broken)
+        with trace.recording() as events:
+            with pytest.raises(TypeError, match="unexpected keyword"):
+                q.collect()
+        assert ("agg", "stream-fallback") not in events
+
+        def unsupported(self, *args, **kwargs):
+            raise D.DeviceUnsupported("not a device shape")
+
+        monkeypatch.setattr(D.GroupedAggStream, "update", unsupported)
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, False)
+        host = q.collect()
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, True)
+        assert_grouped_equal(
+            q.collect(), host,
+            float_cols=("sum_price", "avg_qty", "avg_price", "avg_disc", "sd_price", "lo"),
+        )
+
     def test_min_rows_gate_counted(self, session, hs, lineitems):
         session.conf.set(hst.keys.NUM_BUCKETS, 4)
         df = session.read_parquet(lineitems)

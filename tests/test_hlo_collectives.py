@@ -113,9 +113,8 @@ class TestCompiledCollectives:
         collective-permute / reduce-scatter anywhere in the compiled program.
         (The final scalar psum is the query's own aggregate — all-reduce — and
         is the ONLY collective present.)"""
-        from hyperspace_tpu.parallel.mesh import get_shard_map
+        from jax import shard_map
 
-        shard_map = get_shard_map()
         nk = N_DEV * 32
         sharding = NamedSharding(mesh, P("buckets"))
 

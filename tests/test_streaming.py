@@ -437,7 +437,20 @@ class TestValueConsistentHashing:
         from hyperspace_tpu.utils.x64 import ensure_x64
 
         ensure_x64()
-        vals = np.array([0.0, -0.0, 1.0, 3.0, -7.0, 3.5, np.nan, 2.0**40], dtype=np.float64)
+        # every branch of the bit-field arithmetic: both shift directions
+        # around 2^32 / 2^52, the 2^63 integral bound, dropped fraction bits,
+        # subnormals, infinities and NaN payloads
+        rng = np.random.default_rng(0)
+        vals = np.concatenate([
+            np.array([0.0, -0.0, 1.0, 3.0, -7.0, 3.5, np.nan, -np.nan, np.inf, -np.inf,
+                      2.0**31, 2.0**32 + 1, -(2.0**32), 4294967296.5, 2.0**40, 2.0**51 + 0.5,
+                      2.0**52 - 0.5, 2.0**52 + 1, 2.0**53 + 2, 2.0**62, -(2.0**62), 2.0**63,
+                      -(2.0**63), 2.0**64, 5e-324, -5e-324, 2.2250738585072014e-308, 1e300]),
+            np.array([0x7FF0000000000001, 0xFFF8000000000001], dtype=np.uint64).view(np.float64),
+            rng.integers(-(2**62), 2**62, 2000).astype(np.float64),
+            rng.integers(-(10**6), 10**6, 2000) + rng.choice([0.0, 0.5, 2.0**-30], 2000),
+            rng.integers(0, 2**64, 4000, dtype=np.uint64).view(np.float64),
+        ])
         keys, kinds, _ = encode_sort_columns([vals])
         got = np.asarray(jax.jit(lambda k: _device_hash32("f", k))(jax.numpy.asarray(keys[0])))
         want = numeric_hash32(vals)

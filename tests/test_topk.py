@@ -64,7 +64,10 @@ def _mk_session(tmp_path, **conf):
 
 def _oracle(data, keys, ascending, n):
     """The semantics contract: pandas stable sort, NULLS LAST both ways."""
-    pdf = pd.DataFrame(dict(data))
+    # dtype pinned per column: pandas 3 would infer its `str` dtype for an
+    # object column and hand missing strings back as NaN, where the product
+    # (and parquet) keep None
+    pdf = pd.DataFrame({c: pd.Series(v, dtype=np.asarray(v).dtype) for c, v in data.items()})
     out = pdf.sort_values(list(keys), ascending=list(ascending), kind="stable", na_position="last")
     return out.head(n)
 
