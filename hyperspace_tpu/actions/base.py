@@ -24,6 +24,7 @@ from hyperspace_tpu.models import states
 from hyperspace_tpu.models.data_manager import IndexDataManager
 from hyperspace_tpu.models.log_entry import IndexLogEntry
 from hyperspace_tpu.models.log_manager import IndexLogManager
+from hyperspace_tpu.obs import spans
 from hyperspace_tpu.telemetry.events import ActionEvent, emit_event
 
 
@@ -131,28 +132,34 @@ class Action:
         latest = self.log_manager.get_latest_id()
         self.base_id = latest if latest is not None else -1
         try:
-            entry = self.transient_log_entry()
-            entry.timestamp = int(time.time() * 1000)
-            if not self.log_manager.write_log(self.base_id + 1, entry):
-                raise ConcurrentModificationException(
-                    f"Another operation is in progress on index {self.index_name!r} "
-                    f"(log id {self.base_id + 1} already exists)."
-                )
+            # stage log-commit: the action's own log work on both sides of
+            # op() — entries built (the final one lists the new data files)
+            # and written
+            with spans.stage("log-commit", "build"):
+                entry = self.transient_log_entry()
+                entry.timestamp = int(time.time() * 1000)
+                if not self.log_manager.write_log(self.base_id + 1, entry):
+                    raise ConcurrentModificationException(
+                        f"Another operation is in progress on index {self.index_name!r} "
+                        f"(log id {self.base_id + 1} already exists)."
+                    )
             self.op()
-            final = self.log_entry()
-            final.state = self.final_state
-            final.timestamp = int(time.time() * 1000)
-            self._enrich_final(final, self.base_id + 2)
-            if not self.log_manager.write_log(self.base_id + 2, final):
-                raise ConcurrentModificationException(
-                    f"Failed to commit final state for index {self.index_name!r}."
-                )
-            # the final entry is committed: the allocated data version is now
-            # referenced, so a failure past this point (e.g. latestStable
-            # write) must NOT delete it — readers fall back to scanning the
-            # log and would find the ACTIVE entry pointing at deleted files
-            self._allocated_version = None
-            self.log_manager.create_latest_stable_log(self.base_id + 2)
+            with spans.stage("log-commit", "build"):
+                final = self.log_entry()
+                final.state = self.final_state
+                final.timestamp = int(time.time() * 1000)
+                self._enrich_final(final, self.base_id + 2)
+                if not self.log_manager.write_log(self.base_id + 2, final):
+                    raise ConcurrentModificationException(
+                        f"Failed to commit final state for index {self.index_name!r}."
+                    )
+                # the final entry is committed: the allocated data version is
+                # now referenced, so a failure past this point (e.g.
+                # latestStable write) must NOT delete it — readers fall back
+                # to scanning the log and would find the ACTIVE entry
+                # pointing at deleted files
+                self._allocated_version = None
+                self.log_manager.create_latest_stable_log(self.base_id + 2)
         except NoChangesException:
             raise
         except Exception as e:

@@ -151,6 +151,10 @@ class ProgramContract:
     a contract says everything it permits. ``forbid`` names which
     :data:`FORBIDDEN_PATTERNS` rules apply (default: all).
 
+    Every family's executable is named after it: compiled text whose
+    ``HloModule`` is not ``jit_hs_<family>`` (:func:`program_name`) violates
+    rule ``program-name``.
+
     ``single_fusion`` asserts the whole-plan-fusion guarantee: the family
     compiles to ONE executable — exactly one ``HloModule`` with exactly one
     ``ENTRY`` computation in the compiled text. (Backends still split an
@@ -164,6 +168,8 @@ class ProgramContract:
     description: str = ""
     single_fusion: bool = False
 
+
+_MODULE_NAME = re.compile(r"^HloModule ([^\s,]+)", re.MULTILINE)
 
 _CONTRACTS: Dict[str, ProgramContract] = {}
 _CONTRACTS_LOCK = threading.Lock()
@@ -191,6 +197,23 @@ def register_contract(
     return c
 
 
+def program_name(family: str) -> str:
+    """The stable function name of a family's device program: its executable
+    is ``jit_<this>`` in the profiler's module line, in HLO dumps and in the
+    compile-cache log."""
+    return "hs_" + family.replace("-", "_")
+
+
+def named(family: str, fn):
+    """``fn`` under its family's :func:`program_name`, for ``jax.jit`` to name
+    the module after. A family that no contract declares is a KeyError: the
+    names and the contracts are one list."""
+    if family not in _CONTRACTS:
+        raise KeyError(f"no contract registered for program family {family!r}")
+    fn.__name__ = fn.__qualname__ = program_name(family)
+    return fn
+
+
 def contract_for(family: str) -> Optional[ProgramContract]:
     with _CONTRACTS_LOCK:
         return _CONTRACTS.get(family)
@@ -214,6 +237,23 @@ def verify_hlo(family: str, hlo_text: str, program: str = "") -> List[Finding]:
         )
     label = program or family
     findings: List[Finding] = []
+    module = _MODULE_NAME.search(hlo_text)
+    if module is not None and module.group(1) != "jit_" + program_name(family):
+        # the profiler, HLO dumps and the compile-cache log know a program by
+        # its module: one that does not carry its family's name is invisible
+        # to every reader that looks for jit_hs_<family>
+        findings.append(
+            Finding(
+                rule="program-name",
+                path=f"hlo:{label}",
+                line=0,
+                message=(
+                    f"{family}: compiled module is {module.group(1)!r}, expected "
+                    f"'jit_{program_name(family)}' (wrap the function in hlo_lint.named())"
+                ),
+                detail={"family": family, "module": module.group(1)},
+            )
+        )
     got = collective_counts(hlo_text)
     for op in COLLECTIVE_OPS:
         lo, hi = contract.collectives.get(op, (0, 0))

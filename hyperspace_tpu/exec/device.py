@@ -539,6 +539,8 @@ def _pad_to_bucket(arr: np.ndarray, m: int, fill) -> np.ndarray:
 import threading as _threading
 import time as _ptime
 
+from hyperspace_tpu.obs import spans as _obs_spans
+
 _COMPILE_SEEN: set = set()
 _COMPILE_SEEN_LOCK = _threading.Lock()
 
@@ -561,40 +563,76 @@ def _note_compile(skeleton: str, sig) -> bool:
 
 
 def _observe_program(family: str, first_seen: bool, t0: float) -> None:
-    """Per-program-family device timing at the program-cache call sites
-    (ROADMAP item 2's fusion baseline): a wall-clock histogram around the
-    jitted call, a cumulative compile-seconds counter on first-seen
-    signatures, and a span annotation on the active trace.
-
-    Timing caveat (documented in observability.md): JAX dispatch is async —
-    on a cached signature the interval covers dispatch plus whatever host
-    sync the call site performs, NOT necessarily full device execution. On
-    a first-seen signature it is dominated by XLA compilation, which is the
-    cost these hooks exist to attribute.
-    """
-    wall = max(0.0, _ptime.perf_counter() - t0)
-    from hyperspace_tpu.obs.metrics import REGISTRY
-
-    REGISTRY.histogram(
-        "hs_device_program_seconds",
-        "wall seconds around device program invocations, by program family",
-        program=family,
-    ).observe(wall)
+    """Note one invocation of a device program family at its program-cache
+    call site: a ``device-program`` event on the active span, and on a
+    first-seen signature the wall seconds since ``t0`` — which XLA
+    compilation dominates — in ``hs_device_compile_seconds_total``. How long
+    the program RAN is the profiler's to say (module ``jit_hs_<family>``);
+    how long the host waited for it is the ``device-wait`` span of
+    :func:`fetch`."""
     if first_seen:
+        from hyperspace_tpu.obs.metrics import REGISTRY
+
         REGISTRY.counter(
             "hs_device_compile_seconds_total",
             "cumulative wall seconds of first-seen (compiling) device "
             "program invocations, by program family",
             program=family,
-        ).inc(wall)
-    from hyperspace_tpu.obs import spans as _obs_spans
-
+        ).inc(max(0.0, _ptime.perf_counter() - t0))
     sp = _obs_spans.current_span()
     if sp is not None:
-        sp.event(
-            "device-program",
-            f"{family}: {wall * 1e3:.2f} ms" + (" (compile)" if first_seen else ""),
-        )
+        sp.event("device-program", family + (" (compile)" if first_seen else ""))
+
+
+# -- the host-device link ----------------------------------------------------
+# (direction, site) -> counter, held here so that a transfer costs a dict
+# read and one add, not a registry lookup
+_LINK_BYTES: dict = {}
+
+
+def link_bytes(direction: str, site: str, nbytes: int) -> None:
+    """Count ``nbytes`` crossing the host-device link at ``site``
+    (``direction`` is ``"h2d"`` or ``"d2h"``): the ``nbytes`` of the array
+    that crosses, padding included."""
+    c = _LINK_BYTES.get((direction, site))
+    if c is None:
+        from hyperspace_tpu.obs.metrics import REGISTRY
+
+        if direction == "h2d":
+            c = REGISTRY.counter(
+                "hs_h2d_bytes_total",
+                "Bytes uploaded host to device (padding included), by call site",
+                site=site,
+            )
+        else:
+            c = REGISTRY.counter(
+                "hs_d2h_bytes_total",
+                "Bytes downloaded device to host (padding included), by call site",
+                site=site,
+            )
+        _LINK_BYTES[(direction, site)] = c
+    c.inc(nbytes)
+
+
+def put(arr, site: str, sharding=None):
+    """``jax.device_put`` with the upload counted under ``site``."""
+    import jax
+
+    dev = jax.device_put(arr) if sharding is None else jax.device_put(arr, sharding)
+    link_bytes("h2d", site, int(arr.nbytes))
+    return dev
+
+
+def fetch(dev, site: str, program: str):
+    """Block on a device result (an array, or a pytree of them) and download
+    it: the wait is a ``device-wait`` span (attr ``program``: the family that
+    produced ``dev``), the bytes count under ``site``."""
+    import jax
+
+    with _obs_spans.span("device-wait", cat="device", program=program):
+        out = jax.device_get(dev)
+    link_bytes("d2h", site, sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(out)))
+    return out
 
 
 # skeleton -> jitted predicate program; the jit object is reused across
@@ -658,14 +696,16 @@ def purge_device_cache_files(paths) -> int:
     return removed
 
 
-def _cached_predicate_jit(skeleton: str, fn):
+def _cached_predicate_jit(skeleton: str, fn, family: str):
+    """The jitted program of ``skeleton``, compiled once; ``family`` (a
+    contract of check/hlo_lint.py) names the executable ``jit_hs_<family>``."""
     import jax
 
     jitted = _PREDICATE_CACHE.get(skeleton)
     if jitted is None:
         while len(_PREDICATE_CACHE) >= _PREDICATE_CACHE_MAX:
             _PREDICATE_CACHE.popitem(last=False)
-        jitted = jax.jit(fn)
+        jitted = jax.jit(_hlo_lint.named(family, fn))
         _PREDICATE_CACHE[skeleton] = jitted
     else:
         _PREDICATE_CACHE.move_to_end(skeleton)
@@ -726,6 +766,16 @@ _hlo_lint.register_contract(
     "bucketed-smj-span",
     collectives={},
     description="bucketed sort-merge join span search: the shuffle-freedom claim itself",
+)
+_hlo_lint.register_contract(
+    "join-pair-totals",
+    collectives={"all-reduce": _ANY, "all-gather": _ANY},
+    description="per-bucket matched-pair counts of a device-materialized join: (nb,) ints out",
+)
+_hlo_lint.register_contract(
+    "join-expand-gather",
+    collectives={"all-gather": _ANY, "all-reduce": _ANY},
+    description="device-materialized inner join: pair expansion + numeric payload gathers, final columns out",
 )
 _hlo_lint.register_contract(
     "fused-stage-agg",
@@ -802,10 +852,10 @@ def _put_encoded(session, mesh, sharding, n_dev, arr):
         remap = np.zeros(cap, dtype=np.int32)
         remap[:k] = rank
         padded = _pad_to_bucket(codes, n_dev, 0)
-        dev_codes = jax.device_put(padded, sharding)
-        dev_remap = jax.device_put(remap, NamedSharding(mesh, P()))
+        dev_codes = put(padded, "filter-cols", sharding)
+        dev_remap = put(remap, "filter-cols", NamedSharding(mesh, P()))
         key = _program_key("dict-expand", mesh)
-        jitted = _cached_predicate_jit(key, _dict_expand_fn)
+        jitted = _cached_predicate_jit(key, _dict_expand_fn, "dict-expand")
         first = _note_compile(key, (padded.shape, remap.shape))
         _hlo_lint.maybe_verify(session.conf, "dict-expand", key, jitted, (dev_codes, dev_remap))
         t0 = _ptime.perf_counter()
@@ -815,7 +865,7 @@ def _put_encoded(session, mesh, sharding, n_dev, arr):
         return dev, ColumnCodec("string", uniques=su), int(padded.nbytes + remap.nbytes)
     enc, codec = encode_column(arr)
     padded = _pad_to_bucket(enc, n_dev, 0 if enc.dtype != np.float64 else np.nan)
-    dev = jax.device_put(padded, sharding)
+    dev = put(padded, "filter-cols", sharding)
     return dev, codec, int(padded.nbytes)
 
 
@@ -879,13 +929,13 @@ def device_filter_mask(session, batch: B.Batch, condition: Expr, scan_key=None, 
         fn = _collectives.sharded_elementwise(mesh, axis, fn)
         parallel.note_op("filter")
     key = _program_key(skeleton, mesh, sharded=parallel is not None)
-    jitted = _cached_predicate_jit(key, fn)
+    jitted = _cached_predicate_jit(key, fn, "fused-filter")
     first = _note_compile(key, tuple(dev_cols[r].shape for r in sorted(dev_cols)))
     _hlo_lint.maybe_verify(session.conf, "fused-filter", key, jitted, (dev_cols, lit_values))
     t0 = _ptime.perf_counter()
     mask = jitted(dev_cols, lit_values)
     _stage_ir.count_dispatch("fused-filter")
-    out = np.asarray(mask)[:n]
+    out = fetch(mask, "filter-mask", "fused-filter")[:n]
     _observe_program("fused-filter", first, t0)
     return out
 
@@ -1005,7 +1055,7 @@ def device_filtered_aggregate(
         if codec.kind == "string":
             raise DeviceUnsupported("string aggregate/predicate columns stay host-side here")
         padded = _pad_to_bucket(arr, n_dev, 0 if arr.dtype != np.float64 else np.nan)
-        dev = jax.device_put(padded, sharding)
+        dev = put(padded, "filter-cols", sharding)
         dev_cols[r] = dev
         codecs[r] = codec
         if ckey is not None:
@@ -1061,13 +1111,13 @@ def device_filtered_aggregate(
         return tuple(outs), tuple(valids)
 
     key = _program_key(skeleton, mesh)
-    jitted = _cached_predicate_jit(key, program)
+    jitted = _cached_predicate_jit(key, program, "fused-agg")
     first = _note_compile(key, tuple(dev_cols[r].shape for r in sorted(dev_cols)))
     _hlo_lint.maybe_verify(session.conf, "fused-agg", key, jitted, (dev_cols, lit_values, np.int64(n)))
     t0 = _ptime.perf_counter()
     outs, valids = jitted(dev_cols, lit_values, np.int64(n))
     _stage_ir.count_dispatch("fused-agg")
-    outs = [np.asarray(o) for o in outs]
+    outs, valids = fetch((outs, valids), "agg-table", "fused-agg")
     valids = [int(v) for v in valids]
     _observe_program("fused-agg", first, t0)
 
@@ -1195,20 +1245,23 @@ def _segment_ids(codes, mask, cap):
     rank-compress into segment ids. Returns (order, sorted-mask, n_groups,
     scatter ids) — scatter ids send masked rows to ``cap``, which
     segment_sum/min/max silently drop (out-of-range scatter)."""
+    import jax
     import jax.numpy as jnp
 
     total = mask.shape[0]
     inv = (~mask).astype(jnp.int32)
-    order = jnp.lexsort(tuple(reversed(codes)) + (inv,))
-    ms = mask[order]
-    ch = jnp.zeros((total - 1,), dtype=bool)
-    for c in codes:
-        cs = c[order]
-        ch = ch | _codes_differ(cs[1:], cs[:-1])
-    ch = ch | (ms[1:] != ms[:-1])
-    seg = jnp.concatenate([jnp.zeros((1,), jnp.int64), jnp.cumsum(ch.astype(jnp.int64))])
-    n_groups = jnp.max(jnp.where(ms, seg, -1)) + 1
-    segs = jnp.where(ms, seg, cap)
+    with jax.named_scope("sort"):
+        order = jnp.lexsort(tuple(reversed(codes)) + (inv,))
+    with jax.named_scope("segment-ids"):
+        ms = mask[order]
+        ch = jnp.zeros((total - 1,), dtype=bool)
+        for c in codes:
+            cs = c[order]
+            ch = ch | _codes_differ(cs[1:], cs[:-1])
+        ch = ch | (ms[1:] != ms[:-1])
+        seg = jnp.concatenate([jnp.zeros((1,), jnp.int64), jnp.cumsum(ch.astype(jnp.int64))])
+        n_groups = jnp.max(jnp.where(ms, seg, -1)) + 1
+        segs = jnp.where(ms, seg, cap)
     return order, ms, n_groups, segs
 
 
@@ -1254,26 +1307,30 @@ def _grouped_chunk_program(pred_fn, key_specs, slot_specs, cap):
     Returns n_groups, per-group first-seen global row index, per-group key
     representatives (gathered from the first-occurrence row, so -0.0/NaN
     payloads follow appearance order like pandas), and the state slots."""
+    import jax
     import jax.numpy as jnp
     from jax import ops as jops
 
     def program(cols, lits, n_valid, row_base):
         total = next(iter(cols.values())).shape[0]
         valid = jnp.arange(total) < n_valid
-        mask = valid if pred_fn is None else (pred_fn(cols, lits) & valid)
-        codes = [_key_code(cols[name], tag) for name, tag in key_specs]
+        with jax.named_scope("filter"):
+            mask = valid if pred_fn is None else (pred_fn(cols, lits) & valid)
+        with jax.named_scope("key-encode"):
+            codes = [_key_code(cols[name], tag) for name, tag in key_specs]
         order, ms, n_groups, segs = _segment_ids(codes, mask, cap)
-        # first original row index per group == appearance order == the
-        # representative row the key values gather from
-        rep = jops.segment_min(
-            jnp.where(ms, order.astype(jnp.int64), jnp.int64(total)),
-            segs, num_segments=cap, indices_are_sorted=True,
-        )
-        repc = jnp.clip(rep, 0, total - 1)
-        fs = jnp.where(rep < total, rep + row_base, _FS_SENTINEL)
-        key_out = tuple(cols[name][repc] for name, _ in key_specs)
-        cols_sorted = {c: cols[c][order] for _, c, _ in slot_specs if c is not None}
-        slot_out = _segment_reduce_slots(cols_sorted, ms, segs, cap, slot_specs)
+        with jax.named_scope("segment-reduce"):
+            # first original row index per group == appearance order == the
+            # representative row the key values gather from
+            rep = jops.segment_min(
+                jnp.where(ms, order.astype(jnp.int64), jnp.int64(total)),
+                segs, num_segments=cap, indices_are_sorted=True,
+            )
+            repc = jnp.clip(rep, 0, total - 1)
+            fs = jnp.where(rep < total, rep + row_base, _FS_SENTINEL)
+            key_out = tuple(cols[name][repc] for name, _ in key_specs)
+            cols_sorted = {c: cols[c][order] for _, c, _ in slot_specs if c is not None}
+            slot_out = _segment_reduce_slots(cols_sorted, ms, segs, cap, slot_specs)
         return n_groups, fs, key_out, slot_out
 
     return program
@@ -1290,37 +1347,39 @@ def _merge_concat_parts(key_specs, slot_specs, cap_out, kcat, slots_cat, fs_cat,
     so a group's minimum concat position is a row from the part where it first
     appeared — the key representatives gathered from it match what a single
     sequential pass would have produced."""
+    import jax
     import jax.numpy as jnp
     from jax import ops as jops
 
     total = mask.shape[0]
-    codes = [_key_code(k, tag) for k, (_, tag) in zip(kcat, key_specs)]
-    order, ms, n_groups, segs = _segment_ids(codes, mask, cap_out)
-    rep = jops.segment_min(
-        jnp.where(ms, order.astype(jnp.int64), jnp.int64(total)),
-        segs, num_segments=cap_out, indices_are_sorted=True,
-    )
-    repc = jnp.clip(rep, 0, total - 1)
-    key_out = tuple(k[repc] for k in kcat)
-    # values fed to the segment ops must follow the SORTED row order that
-    # ``segs`` is defined over (the keys above gather by concat position
-    # instead, so they stay unsorted)
-    fs = jops.segment_min(
-        jnp.where(ms, fs_cat[order], _FS_SENTINEL), segs,
-        num_segments=cap_out, indices_are_sorted=True,
-    )
-    slot_out = []
-    for (kind, _, _), v in zip(slot_specs, slots_cat):
-        v = v[order]
-        if kind in ("cntm", "cnt", "sum", "sumsq"):
-            slot_out.append(jops.segment_sum(jnp.where(ms, v, v.dtype.type(0)), segs, num_segments=cap_out, indices_are_sorted=True))
-        elif kind == "min":
-            big = jnp.iinfo(jnp.int64).max if jnp.issubdtype(v.dtype, jnp.integer) else jnp.inf
-            slot_out.append(jops.segment_min(jnp.where(ms, v, big), segs, num_segments=cap_out, indices_are_sorted=True))
-        else:  # max
-            low = jnp.iinfo(jnp.int64).min if jnp.issubdtype(v.dtype, jnp.integer) else -jnp.inf
-            slot_out.append(jops.segment_max(jnp.where(ms, v, low), segs, num_segments=cap_out, indices_are_sorted=True))
-    return n_groups, fs, key_out, tuple(slot_out)
+    with jax.named_scope("merge"):
+        codes = [_key_code(k, tag) for k, (_, tag) in zip(kcat, key_specs)]
+        order, ms, n_groups, segs = _segment_ids(codes, mask, cap_out)
+        rep = jops.segment_min(
+            jnp.where(ms, order.astype(jnp.int64), jnp.int64(total)),
+            segs, num_segments=cap_out, indices_are_sorted=True,
+        )
+        repc = jnp.clip(rep, 0, total - 1)
+        key_out = tuple(k[repc] for k in kcat)
+        # values fed to the segment ops must follow the SORTED row order that
+        # ``segs`` is defined over (the keys above gather by concat position
+        # instead, so they stay unsorted)
+        fs = jops.segment_min(
+            jnp.where(ms, fs_cat[order], _FS_SENTINEL), segs,
+            num_segments=cap_out, indices_are_sorted=True,
+        )
+        slot_out = []
+        for (kind, _, _), v in zip(slot_specs, slots_cat):
+            v = v[order]
+            if kind in ("cntm", "cnt", "sum", "sumsq"):
+                slot_out.append(jops.segment_sum(jnp.where(ms, v, v.dtype.type(0)), segs, num_segments=cap_out, indices_are_sorted=True))
+            elif kind == "min":
+                big = jnp.iinfo(jnp.int64).max if jnp.issubdtype(v.dtype, jnp.integer) else jnp.inf
+                slot_out.append(jops.segment_min(jnp.where(ms, v, big), segs, num_segments=cap_out, indices_are_sorted=True))
+            else:  # max
+                low = jnp.iinfo(jnp.int64).min if jnp.issubdtype(v.dtype, jnp.integer) else -jnp.inf
+                slot_out.append(jops.segment_max(jnp.where(ms, v, low), segs, num_segments=cap_out, indices_are_sorted=True))
+        return n_groups, fs, key_out, tuple(slot_out)
 
 
 def _grouped_merge_program(key_specs, slot_specs, cap_in, cap_out):
@@ -1442,6 +1501,7 @@ class GroupedAggStream:
         self._slots = None
         self._refs = None
         self._partial = None  # dict(cap, n, fs, keys, slots) — device arrays
+        self._family = "grouped-agg-chunk"  # of the program that last wrote _partial
         self._row_base = 0
         # seed capacity from the last observed cardinality of the same query
         # shape over the same scan: a fresh stream otherwise starts at the
@@ -1503,6 +1563,10 @@ class GroupedAggStream:
         return self._partial is not None
 
     def update(self, batch: B.Batch, condition: Optional[Expr] = None, scan_key=None) -> None:
+        with _obs_spans.span("agg-device-fold", cat="exec"):
+            self._update(batch, condition, scan_key)
+
+    def _update(self, batch: B.Batch, condition: Optional[Expr], scan_key) -> None:
         ensure_x64()
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1585,12 +1649,13 @@ class GroupedAggStream:
                 )
             else:
                 program = _grouped_chunk_program(pred_fn, key_specs, self._slots, cap)
+            family = self._family = "sharded-grouped" if sharded else "grouped-agg-chunk"
             key = _program_key(f"gagg[{cap}]:{base_sk}", mesh, sharded=sharded)
-            jitted = _cached_predicate_jit(key, program)
+            jitted = _cached_predicate_jit(key, program, family)
             first = _note_compile(key, shapes)
             _hlo_lint.maybe_verify(
                 self.session.conf,
-                "sharded-grouped" if sharded else "grouped-agg-chunk",
+                family,
                 key, jitted,
                 (dev_cols, lit_values, np.int64(n), np.int64(self._row_base)),
             )
@@ -1604,11 +1669,9 @@ class GroupedAggStream:
                 n_g_dev, fs, key_out, slot_out = jitted(
                     dev_cols, lit_values, np.int64(n), np.int64(self._row_base)
                 )
-            _stage_ir.count_dispatch("sharded-grouped" if sharded else "grouped-agg-chunk")
-            n_g = int(n_g_dev)
-            _observe_program(
-                "sharded-grouped" if sharded else "grouped-agg-chunk", first, t0
-            )
+            _stage_ir.count_dispatch(family)
+            n_g = int(fetch(n_g_dev, "agg-table", family))
+            _observe_program(family, first, t0)
             if n_g > self.max_groups:
                 exc = GroupCapacityExceeded(
                     f"group cardinality {n_g} exceeds maxGroups {self.max_groups}"
@@ -1630,7 +1693,6 @@ class GroupedAggStream:
             self._partial = new
         else:
             self._merge(new)
-        _stage_ir.note_peak_bytes()
 
     def _ensure_fused_state(self, key_specs, cap):
         """The running partial as (keys, slots, fs, n) device arrays padded
@@ -1679,13 +1741,13 @@ class GroupedAggStream:
             program = _fused_grouped_update_program(
                 pred_fn, key_specs, self._slots, cap
             )
-        family = "fused-stage-agg-sharded" if sharded else "fused-stage-agg"
+        family = self._family = "fused-stage-agg-sharded" if sharded else "fused-stage-agg"
         key = _program_key(
             f"gaggfused[{cap}{'+d' if donate else ''}]:{base_sk}",
             mesh, sharded=sharded,
         )
         jitted = _stage_ir.compile_stage(
-            key, program, donate_argnums=(0, 1, 2) if donate else ()
+            key, program, donate_argnums=(0, 1, 2) if donate else (), family=family
         )
         first = _note_compile(key, shapes + ((cap,),))
         args = (
@@ -1701,16 +1763,15 @@ class GroupedAggStream:
         else:
             n_b_d, n_m_d, n_out_d, fs_out, keys_out, slots_out = jitted(*args)
         _stage_ir.count_dispatch(family)
-        n_b, n_m = int(n_b_d), int(n_m_d)
+        n_b, n_m, n_out = (int(v) for v in fetch((n_b_d, n_m_d, n_out_d), "agg-table", family))
         _observe_program(family, first, t0)
         # the donated state is consumed either way: rebind the partial to the
         # returned (aliased) buffers, which carry the original values on
         # overflow
         self._partial = {
-            "cap": cap, "n": int(n_out_d), "fs": fs_out,
+            "cap": cap, "n": n_out, "fs": fs_out,
             "keys": list(keys_out), "slots": list(slots_out),
         }
-        _stage_ir.note_peak_bytes()
         if n_b > cap or n_m > cap:
             self._cap_hint = max(self._cap_hint, n_b, n_m)
             if state_n == 0:
@@ -1731,7 +1792,7 @@ class GroupedAggStream:
         only the per-group representatives; -1 null stays -1)."""
         import jax
 
-        local = np.asarray(dev_codes)[:n_g]
+        local = fetch(dev_codes, "agg-table", self._family)[:n_g]
         mapping = self._strmaps.setdefault(name, {})
         uniq = self._struniq.setdefault(name, [])
         out = np.full(cap, -1, dtype=np.int64)
@@ -1744,7 +1805,7 @@ class GroupedAggStream:
                 got = mapping[val] = len(uniq)
                 uniq.append(val)
             out[j] = got
-        return jax.device_put(out)
+        return put(out, "agg-table")
 
     def _merge(self, new) -> None:
         import jax
@@ -1772,7 +1833,7 @@ class GroupedAggStream:
         )
         key = _program_key(skeleton, mesh)
         program = _grouped_merge_program(key_specs, self._slots, cap_in, cap_out)
-        jitted = _cached_predicate_jit(key, program)
+        jitted = _cached_predicate_jit(key, program, "grouped-merge")
         first = _note_compile(key, (cap_in, cap_out))
         _hlo_lint.maybe_verify(
             self.session.conf, "grouped-merge", key, jitted,
@@ -1787,7 +1848,7 @@ class GroupedAggStream:
                 a["fs"], b["fs"], np.int64(a["n"]), np.int64(b["n"]),
             )
             _stage_ir.count_dispatch("grouped-merge")
-            n_g = int(n_g_dev)
+            n_g = int(fetch(n_g_dev, "agg-table", "grouped-merge"))
         _observe_program("grouped-merge", first, t0)
         REGISTRY.counter(
             "hs_agg_merge_seconds_total",
@@ -1817,11 +1878,13 @@ class GroupedAggStream:
             raise DeviceUnsupported("no device partial to finalize")
         n = p["n"]
         keys_schema, input_dtypes = self._schema
-        fs = np.asarray(p["fs"])[:n]
-        order = np.argsort(fs, kind="stable")
+        fs, keys, slots = fetch(
+            (p["fs"], tuple(p["keys"]), tuple(p["slots"])), "agg-table", self._family
+        )
+        order = np.argsort(fs[:n], kind="stable")
         key_cols = {}
-        for name, (tag, dtype, unit), dev in zip(self.group_keys, keys_schema, p["keys"]):
-            vals = np.asarray(dev)[:n][order]
+        for name, (tag, dtype, unit), dev in zip(self.group_keys, keys_schema, keys):
+            vals = dev[:n][order]
             if tag == "s":
                 uniq = self._struniq.get(name, [])
                 out = np.full(n, np.nan, dtype=object)
@@ -1836,7 +1899,7 @@ class GroupedAggStream:
                 key_cols[name] = vals.astype(dtype)
             else:
                 key_cols[name] = vals.astype(dtype)
-        slot_cols = [np.asarray(s)[:n][order] for s in p["slots"]]
+        slot_cols = [s[:n][order] for s in slots]
         return n, key_cols, slot_cols
 
     def finalize(self) -> B.Batch:
@@ -1845,6 +1908,10 @@ class GroupedAggStream:
         matched row was NULL, int min/max keep the input dtype, avg/stddev
         from the decomposed states. Rows in first-appearance order, exactly
         like pandas groupby(sort=False)."""
+        with _obs_spans.span("agg-finalize", cat="exec"):
+            return self._finalize()
+
+    def _finalize(self) -> B.Batch:
         from hyperspace_tpu.obs.metrics import REGISTRY
 
         if self._hint_key is not None:
@@ -2525,16 +2592,16 @@ def _bucketed_span_program(mesh, axis: str):
     from jax.sharding import PartitionSpec as P
     from jax import shard_map
 
-    @jax.jit
     def spans(lm, rm):
         @partial(shard_map, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=(P(axis), P(axis)))
         def per_shard(lm_, rm_):
-            lo = jax.vmap(lambda lk, rk: jnp.searchsorted(rk, lk, side="left"))(lm_, rm_)
-            hi = jax.vmap(lambda lk, rk: jnp.searchsorted(rk, lk, side="right"))(lm_, rm_)
+            with jax.named_scope("span-probe"):
+                lo = jax.vmap(lambda lk, rk: jnp.searchsorted(rk, lk, side="left"))(lm_, rm_)
+                hi = jax.vmap(lambda lk, rk: jnp.searchsorted(rk, lk, side="right"))(lm_, rm_)
             return lo, hi
         return per_shard(lm, rm)
 
-    return spans
+    return jax.jit(_hlo_lint.named("bucketed-smj-span", spans))
 
 
 def _join_key_of(batch: B.Batch, key: str) -> np.ndarray:
@@ -2603,6 +2670,55 @@ def _rank_cache_key(lside, rside, lkeys: List[str], rkeys: List[str]):
     return tuple(parts)
 
 
+def _fold_streamed_join(session, plan: L.Join, compat) -> B.Batch:
+    """The host-span-smj-stream tier of :func:`dispatch_bucketed_join`: walk
+    buckets one at a time and fold the chunks into one batch."""
+    lside, rside, lkeys, rkeys = compat
+    # fold chunks incrementally instead of list()-ing the whole
+    # stream: peak memory is O(merged result + one pending run), not
+    # O(result x2), and the generator is closed on any exit so both
+    # sides' bucket readers release mid-stream
+    gen = stream_bucketed_join(session, plan, _compat=compat)
+    merged = None
+    merged_bytes = 0
+    pending: List[B.Batch] = []
+    pending_bytes = 0
+    try:
+        for chunk in gen:
+            pending.append(chunk)
+            pending_bytes += _chunk_nbytes(chunk)
+            # geometric fold: concat once the pending run reaches the
+            # merged size, so total copy work stays O(result) while
+            # at most one merged copy + one run are ever alive
+            if merged is None or pending_bytes >= merged_bytes:
+                batches = ([merged] if merged is not None else []) + pending
+                merged = batches[0] if len(batches) == 1 else B.concat(batches)
+                merged_bytes = _chunk_nbytes(merged)
+                pending, pending_bytes = [], 0
+    finally:
+        gen.close()
+    if pending:
+        batches = ([merged] if merged is not None else []) + pending
+        merged = batches[0] if len(batches) == 1 else B.concat(batches)
+    if merged is None:
+        # an empty streamed result must NOT fall back to the generic
+        # merge — that materializes both multi-GiB sides, the OOM
+        # this path exists to prevent; type the empty batch from the
+        # index footers instead
+        needed = set(plan.output_columns) | {
+            n[:-2] for n in plan.output_columns if n.endswith("#r")
+        }
+        lc = [c for c in lside.output_columns if c in needed or c in lkeys]
+        rc = [c for c in rside.output_columns if c in needed or c in rkeys]
+        hints = _stream_join_dtype_hints(plan, lside, rside, lc, rc)
+        if all(n in hints for n in plan.output_columns):
+            trace.record("join", "host-span-smj-stream")
+            return {n: np.empty(0, dtype=hints[n]) for n in plan.output_columns}
+        raise DeviceUnsupported("streamed join produced no rows")
+    trace.record("join", "host-span-smj-stream")
+    return merged
+
+
 def dispatch_bucketed_join(session, plan: L.Join) -> B.Batch:
     """Single entry point for the bucketed-SMJ paths: one compatibility
     analysis, then device or host spans by the input-rows threshold. Every
@@ -2634,50 +2750,10 @@ def dispatch_bucketed_join(session, plan: L.Join) -> B.Batch:
         except OSError:
             input_bytes = 0
         if input_bytes >= stream_min:
-            # fold chunks incrementally instead of list()-ing the whole
-            # stream: peak memory is O(merged result + one pending run), not
-            # O(result x2), and the generator is closed on any exit so both
-            # sides' bucket readers release mid-stream
-            gen = stream_bucketed_join(session, plan, _compat=compat)
-            merged = None
-            merged_bytes = 0
-            pending: List[B.Batch] = []
-            pending_bytes = 0
-            try:
-                for chunk in gen:
-                    pending.append(chunk)
-                    pending_bytes += _chunk_nbytes(chunk)
-                    # geometric fold: concat once the pending run reaches the
-                    # merged size, so total copy work stays O(result) while
-                    # at most one merged copy + one run are ever alive
-                    if merged is None or pending_bytes >= merged_bytes:
-                        batches = ([merged] if merged is not None else []) + pending
-                        merged = batches[0] if len(batches) == 1 else B.concat(batches)
-                        merged_bytes = _chunk_nbytes(merged)
-                        pending, pending_bytes = [], 0
-            finally:
-                gen.close()
-            if pending:
-                batches = ([merged] if merged is not None else []) + pending
-                merged = batches[0] if len(batches) == 1 else B.concat(batches)
-            if merged is None:
-                # an empty streamed result must NOT fall back to the generic
-                # merge — that materializes both multi-GiB sides, the OOM
-                # this path exists to prevent; type the empty batch from the
-                # index footers instead
-                needed = set(plan.output_columns) | {
-                    n[:-2] for n in plan.output_columns if n.endswith("#r")
-                }
-                lc = [c for c in lside.output_columns if c in needed or c in lkeys]
-                rc = [c for c in rside.output_columns if c in needed or c in rkeys]
-                hints = _stream_join_dtype_hints(plan, lside, rside, lc, rc)
-                if all(n in hints for n in plan.output_columns):
-                    trace.record("join", "host-span-smj-stream")
-                    return {n: np.empty(0, dtype=hints[n]) for n in plan.output_columns}
-                raise DeviceUnsupported("streamed join produced no rows")
-            trace.record("join", "host-span-smj-stream")
-            return merged
-    setup = _bucketed_join_setup(session, plan, compat)
+            with _obs_spans.span("join-host-span-smj-stream", cat="exec"):
+                return _fold_streamed_join(session, plan, compat)
+    with _obs_spans.span("join-bucket-setup", cat="exec"):
+        setup = _bucketed_join_setup(session, plan, compat)
     # the device span program's round trip is EXACTLY computable here: the
     # buckets are already decoded, and the key matrices are rectangles of
     # nb_padded x (widest bucket) int64 — skewed buckets pad every other
@@ -2701,15 +2777,18 @@ def dispatch_bucketed_join(session, plan: L.Join) -> B.Batch:
         total >= session.conf.device_exec_min_rows
         and span_bytes <= session.conf.join_device_span_max_bytes
     ):
-        try:
-            out = device_bucketed_join(session, plan, _compat=compat, _setup=setup)
-            trace.record("join", "device-smj")
-            return out
-        except DeviceUnsupported:
-            pass  # e.g. a decoded batch outside the device language
-    out = host_bucketed_join(session, plan, _compat=compat, _setup=setup)
-    trace.record("join", "host-span-smj")
-    return out
+        with _obs_spans.span("join-device-smj", cat="exec") as tier:
+            try:
+                out = device_bucketed_join(session, plan, _compat=compat, _setup=setup)
+                trace.record("join", "device-smj")
+                return out
+            except DeviceUnsupported:
+                # e.g. a decoded batch outside the device language
+                tier.set(fallback="unsupported")
+    with _obs_spans.span("join-host-span-smj", cat="exec"):
+        out = host_bucketed_join(session, plan, _compat=compat, _setup=setup)
+        trace.record("join", "host-span-smj")
+        return out
 
 
 def _bucketed_join_setup(session, plan: L.Join, compat=None, needed_override=None):
@@ -2984,8 +3063,8 @@ def device_bucketed_join(session, plan: L.Join, _compat=None, _setup=None) -> B.
         lmat, llens = stack_side(lbuckets, lkeys_by_bucket)
         rmat, rlens = stack_side(rbuckets, rkeys_by_bucket)
         sharding = NamedSharding(mesh, P(axis))
-        lmat_dev = jax.device_put(lmat, sharding)
-        rmat_dev = jax.device_put(rmat, sharding)
+        lmat_dev = put(lmat, "join-mats", sharding)
+        rmat_dev = put(rmat, "join-mats", sharding)
         if dev_key is not None:
             _device_cache_put(
                 dev_key, (lmat_dev, rmat_dev, llens, rlens), lmat.nbytes + rmat.nbytes
@@ -3012,8 +3091,7 @@ def device_bucketed_join(session, plan: L.Join, _compat=None, _setup=None) -> B.
         except DeviceUnsupported:
             pass  # e.g. typed-empty output or odd column shapes -> host gather
 
-    lo = np.asarray(lo)
-    hi = np.asarray(hi)
+    lo, hi = fetch((lo, hi), "join-out", "bucketed-smj-span")
 
     def span_of(b: int):
         ll = int(llens[b])
@@ -3120,7 +3198,6 @@ def _expand_gather_program(n_pad: int):
     import jax
     import jax.numpy as jnp
 
-    @jax.jit
     def run(lo, hi, llens, rlens, lcols, rcols, total):
         nb, wl = lo.shape
         # clamp spans to the right side's REAL rows: a left-only bucket's
@@ -3140,11 +3217,12 @@ def _expand_gather_program(n_pad: int):
         i = f % wl
         p = t - g_excl[f]
         j = jnp.clip(lo.reshape(-1)[f] + p, 0, None)
-        louts = tuple(c.reshape(-1)[f] for c in lcols)
-        routs = tuple(c[b, jnp.clip(j, 0, c.shape[1] - 1)] for c in rcols)
+        with jax.named_scope("gather"):
+            louts = tuple(c.reshape(-1)[f] for c in lcols)
+            routs = tuple(c[b, jnp.clip(j, 0, c.shape[1] - 1)] for c in rcols)
         return louts, routs, b, i, j, valid
 
-    return run
+    return jax.jit(_hlo_lint.named("join-expand-gather", run))
 
 
 @lru_cache(maxsize=1)
@@ -3155,7 +3233,6 @@ def _bucket_pair_totals_fn():
     import jax
     import jax.numpy as jnp
 
-    @jax.jit
     def run(lo, hi, ll, rl):
         return jnp.sum(
             jnp.where(
@@ -3166,7 +3243,7 @@ def _bucket_pair_totals_fn():
             axis=1,
         )
 
-    return run
+    return jax.jit(_hlo_lint.named("join-pair-totals", run))
 
 
 def _bucket_pair_totals(lo, hi, ll, rl):
@@ -3209,8 +3286,9 @@ def _device_materialize_inner(
     wl = lo_dev.shape[1]
     llens_np = np.asarray(llens)
     rlens_np = np.asarray(rlens)
-    bucket_totals = np.asarray(
-        _bucket_pair_totals(lo_dev, hi_dev, jnp.asarray(llens_np), jnp.asarray(rlens_np))
+    bucket_totals = fetch(
+        _bucket_pair_totals(lo_dev, hi_dev, jnp.asarray(llens_np), jnp.asarray(rlens_np)),
+        "join-out", "join-pair-totals",
     )
     total = int(bucket_totals.sum())
     out: B.Batch = {}
@@ -3271,10 +3349,10 @@ def _device_materialize_inner(
         wr = bucket_rows(max((B.num_rows(rbuckets[b]) for b in participating), default=1), floor=256)
         lmats = rectangles(lbuckets, l_device, wl)
         rmats = rectangles(rbuckets, r_device, wr)
-        llens_dev = jax.device_put(llens_np)
-        rlens_dev = jax.device_put(rlens_np)
-        lmats_dev = tuple(jax.device_put(lmats[n]) for n in l_device)
-        rmats_dev = tuple(jax.device_put(rmats[n]) for n in r_device)
+        llens_dev = put(llens_np, "join-mats")
+        rlens_dev = put(rlens_np, "join-mats")
+        lmats_dev = tuple(put(lmats[n], "join-mats") for n in l_device)
+        rmats_dev = tuple(put(rmats[n], "join-mats") for n in r_device)
         if mats_key is not None:
             nbytes = sum(m.nbytes for m in (*lmats.values(), *rmats.values()))
             _device_cache_put(
@@ -3292,21 +3370,18 @@ def _device_materialize_inner(
         np.int64(total),
     )
 
-    for name, arr in zip(l_device, louts):
-        v = np.asarray(arr)[:total]
-        dt = dtypes[name]
-        out[name] = v.view(dt) if dt.kind in ("M", "m") else v.astype(dt, copy=False)
-    for name, arr in zip(r_device, routs):
-        v = np.asarray(arr)[:total]
+    louts, routs = fetch((louts, routs), "join-out", "join-expand-gather")
+    for name, arr in zip(l_device + r_device, louts + routs):
+        v = arr[:total]
         dt = dtypes[name]
         out[name] = v.view(dt) if dt.kind in ("M", "m") else v.astype(dt, copy=False)
 
     if host_cols:
         # string/object columns: download the (bucket-ordered) index arrays
         # once and gather host-side, bucket by bucket
-        b_np = np.asarray(b_idx)[:total]
-        i_np = np.asarray(i_idx)[:total]
-        j_np = np.asarray(j_idx)[:total]
+        i_np, j_np = (
+            a[:total] for a in fetch((i_idx, j_idx), "join-out", "join-expand-gather")
+        )
         offsets = np.concatenate([[0], np.cumsum(bucket_totals)])
         for name in host_cols:
             is_left, col = sources[name]

@@ -1054,8 +1054,10 @@ class Executor:
                     child = self._exec(clone, with_file_names)
                 else:
                     child = self._exec(plan.child, with_file_names)
-            mask = self._filter_mask(plan, child, pruned_by=pushed)
-            return B.mask_rows(child, mask)
+            with spans.span("filter-mask", cat="exec"):
+                mask = self._filter_mask(plan, child, pruned_by=pushed)
+            with spans.span("filter-apply", cat="exec"):
+                return B.mask_rows(child, mask)
 
         if isinstance(plan, L.Project):
             # projection pushdown into a directly-scanned source: decode ONLY
@@ -1332,14 +1334,16 @@ class Executor:
             if isinstance(join_node, L.Join):
                 from hyperspace_tpu.exec import device as D
 
-                try:
-                    got = D.aggregate_over_bucketed_join(
-                        self.session, plan, join_node, computes=computes
-                    )
-                    trace.record("agg", "fused-bucketed-join")
-                    return got
-                except D.DeviceUnsupported:
-                    trace.fallback("agg", "join-unsupported")
+                with spans.span("agg-fused-bucketed-join", cat="exec") as tier:
+                    try:
+                        got = D.aggregate_over_bucketed_join(
+                            self.session, plan, join_node, computes=computes
+                        )
+                        trace.record("agg", "fused-bucketed-join")
+                        return got
+                    except D.DeviceUnsupported:
+                        trace.fallback("agg", "join-unsupported")
+                        tier.set(fallback="join-unsupported")
         # streaming check BEFORE the device-scan gate: _try_device_aggregate
         # materializes the whole scan to size its decision, which is exactly
         # what the out-of-core path exists to avoid
@@ -1362,14 +1366,17 @@ class Executor:
                 # the device gate already materialized the scan — reuse it
                 # instead of re-reading parquet on the host fallback
                 if filter_node is not None:
-                    mask = self._filter_mask(filter_node, scan_batch, pruned_by=pruned)
-                    child = B.mask_rows(scan_batch, mask)
+                    with spans.span("filter-mask", cat="exec"):
+                        mask = self._filter_mask(filter_node, scan_batch, pruned_by=pruned)
+                    with spans.span("filter-apply", cat="exec"):
+                        child = B.mask_rows(scan_batch, mask)
                 else:
                     child = scan_batch
 
         if child is None:
             child = self._exec(plan.child, with_file_names)
-        return host_aggregate(child, list(plan.keys), list(plan.aggs))
+        with spans.span("agg-host", cat="exec"):
+            return host_aggregate(child, list(plan.keys), list(plan.aggs))
 
     # -- streamed Limit shapes (execute_stream) -------------------------------
 
@@ -1663,16 +1670,19 @@ class Executor:
         spec = JS.broadcast_spec(self.session, node)
         if spec is None:
             return None
-        try:
-            return stage_ir.stream_join_aggregate(
-                self, node, spec, post_filter, list(plan.keys), list(plan.aggs)
-            )
-        except D.DeviceUnsupported:
-            trace.fallback("fusion", "join-agg-unsupported")
-            return None
-        except _STREAM_FALLBACK_ERRORS:
-            trace.record("agg", "stream-fallback")
-            return None
+        with spans.span("agg-fused-join-agg-stream", cat="exec") as tier:
+            try:
+                return stage_ir.stream_join_aggregate(
+                    self, node, spec, post_filter, list(plan.keys), list(plan.aggs)
+                )
+            except D.DeviceUnsupported:
+                trace.fallback("fusion", "join-agg-unsupported")
+                tier.set(fallback="join-agg-unsupported")
+                return None
+            except _STREAM_FALLBACK_ERRORS:
+                trace.record("agg", "stream-fallback")
+                tier.set(fallback="stream-fallback")
+                return None
 
     def _try_streaming_aggregate(self, plan: L.Aggregate) -> Optional[B.Batch]:
         """Out-of-core aggregate: when the child is a scan chain over more
@@ -1706,11 +1716,13 @@ class Executor:
         if len(groups) < 2:
             return None
         needed = _chain_needed_columns(chain, plan.aggs, plan.keys)
-        try:
-            return self._streaming_aggregate(plan, chain, leaf, groups, needed)
-        except _STREAM_FALLBACK_ERRORS:
-            trace.record("agg", "stream-fallback")
-            return None
+        with spans.span("agg-streamed-partial", cat="exec") as tier:
+            try:
+                return self._streaming_aggregate(plan, chain, leaf, groups, needed)
+            except _STREAM_FALLBACK_ERRORS:
+                trace.record("agg", "stream-fallback")
+                tier.set(fallback="stream-fallback")
+                return None
 
     def _streaming_aggregate(self, plan, chain, leaf, groups, needed) -> B.Batch:
         import pandas as pd
@@ -1728,6 +1740,10 @@ class Executor:
         g_state: Dict[int, Any] = {}       # global plain partials
 
         def fold_chunk(batch):
+            with spans.span("agg-host-combine", cat="exec"):
+                host_fold(batch)
+
+        def host_fold(batch):
             batch = {k: v for k, v in batch.items() if k != INPUT_FILE_NAME}
             n = B.num_rows(batch)
 
@@ -2010,36 +2026,40 @@ class Executor:
             return None, batch, filter_node, pruned
         condition = filter_node.condition if filter_node is not None else None
         scan_key = _pruned_scan_key(_scan_identity(node), pruned)
-        try:
-            if plan.keys:
-                got = D.device_grouped_aggregate(
-                    self.session,
-                    batch,
-                    condition,
-                    list(plan.keys),
-                    list(plan.aggs),
-                    scan_key=scan_key,
-                    max_groups=conf.agg_max_groups,
-                    cap_floor=conf.agg_capacity_floor,
-                    parallel=_maybe_parallel(self.session, B.num_rows(batch)),
-                )
-            else:
-                got = D.device_filtered_aggregate(
-                    self.session, batch, condition, plan.aggs, scan_key=scan_key
-                )
-            return got, batch, filter_node, pruned
-        except D.GroupCapacityExceeded:
-            trace.fallback("agg", "spill")
-            return None, batch, filter_node, pruned
-        except D.DeviceUnsupported:
-            trace.fallback("agg", "unsupported")
-            return None, batch, filter_node, pruned
+        name = "agg-device-grouped-scan" if plan.keys else "agg-device-fused-scan"
+        with spans.span(name, cat="exec") as tier:
+            try:
+                if plan.keys:
+                    got = D.device_grouped_aggregate(
+                        self.session,
+                        batch,
+                        condition,
+                        list(plan.keys),
+                        list(plan.aggs),
+                        scan_key=scan_key,
+                        max_groups=conf.agg_max_groups,
+                        cap_floor=conf.agg_capacity_floor,
+                        parallel=_maybe_parallel(self.session, B.num_rows(batch)),
+                    )
+                else:
+                    got = D.device_filtered_aggregate(
+                        self.session, batch, condition, plan.aggs, scan_key=scan_key
+                    )
+                return got, batch, filter_node, pruned
+            except D.GroupCapacityExceeded:
+                trace.fallback("agg", "spill")
+                tier.set(fallback="spill")
+                return None, batch, filter_node, pruned
+            except D.DeviceUnsupported:
+                trace.fallback("agg", "unsupported")
+                tier.set(fallback="unsupported")
+                return None, batch, filter_node, pruned
 
     def _exec_join(self, plan: L.Join, with_file_names: bool) -> B.Batch:
-        """Generic (non-bucketed) equi-join fallback via a pandas hash merge
-        over slim key frames; see the slim-merge note below."""
-        import pandas as pd
-
+        """Join tiers in order: bucketed SMJ (device or host spans), broadcast
+        hash stream, then the generic host merge. Each tier that runs is a
+        child span named after its dispatch detail."""
+        fallback = None
         if not with_file_names and self.session.conf.device_execution_enabled:
             # deviceExecution=False is the kill switch back to the pandas
             # merge below — it routes around the whole bucketed-SMJ stack
@@ -2050,11 +2070,25 @@ class Executor:
                 return D.dispatch_bucketed_join(self.session, plan)
             except D.DeviceUnsupported:
                 pass  # next tier: broadcast hash join
-            try:
-                return JS.dispatch_broadcast_join(self, plan)
-            except D.DeviceUnsupported:
-                trace.fallback("join", "unsupported")
-        trace.record("join", "generic-merge")
+            with spans.span("join-broadcast-hash-stream", cat="exec") as tier:
+                try:
+                    return JS.dispatch_broadcast_join(self, plan)
+                except D.DeviceUnsupported:
+                    trace.fallback("join", "unsupported")
+                    tier.set(fallback="unsupported")
+                    fallback = "unsupported"
+        # the host tier: its span's own time (children are the two sides'
+        # scans) is the pandas merge and the payload gathers
+        with spans.span("join-generic-merge", cat="exec") as tier:
+            if fallback:
+                tier.set(fallback=fallback)
+            trace.record("join", "generic-merge")
+            return self._generic_merge_join(plan, with_file_names)
+
+    def _generic_merge_join(self, plan: L.Join, with_file_names: bool) -> B.Batch:
+        """Generic (non-bucketed) equi-join via a pandas hash merge over slim
+        key frames; see the slim-merge note below."""
+        import pandas as pd
 
         pairs = extract_equi_join_keys(plan.condition)
         if pairs is None:

@@ -50,6 +50,8 @@ from hyperspace_tpu.exec.device import (
     bucket_rows,
     compile_predicate,
     encode_column,
+    fetch,
+    link_bytes,
     predicate_skeleton,
     stream_bucketed_join,  # noqa: F401  (re-exported: the streaming join surface)
 )
@@ -192,15 +194,16 @@ def _hash_build_program(nkeys: int):
 
     from hyperspace_tpu.ops.hashing import combine_hashes_jnp
 
-    @jax.jit
     def build(planes, n):
-        h = combine_hashes_jnp(list(planes))
+        with jax.named_scope("hash"):
+            h = combine_hashes_jnp(list(planes))
         idx = jnp.arange(h.shape[0], dtype=jnp.int64)
         h = jnp.where(idx < n, h, jnp.uint32(0xFFFFFFFF))
-        order = jnp.argsort(h, stable=True)
+        with jax.named_scope("sort"):
+            order = jnp.argsort(h, stable=True)
         return h[order], order
 
-    return build
+    return jax.jit(_hlo_lint.named("hash-build", build))
 
 
 @lru_cache(maxsize=8)
@@ -214,14 +217,15 @@ def _hash_probe_program(nkeys: int):
 
     from hyperspace_tpu.ops.hashing import combine_hashes_jnp
 
-    @jax.jit
     def probe(table_h, n_build, planes):
-        h = combine_hashes_jnp(list(planes))
-        lo = jnp.searchsorted(table_h, h, side="left").astype(jnp.int64)
-        hi = jnp.searchsorted(table_h, h, side="right").astype(jnp.int64)
+        with jax.named_scope("hash"):
+            h = combine_hashes_jnp(list(planes))
+        with jax.named_scope("span-probe"):
+            lo = jnp.searchsorted(table_h, h, side="left").astype(jnp.int64)
+            hi = jnp.searchsorted(table_h, h, side="right").astype(jnp.int64)
         return jnp.minimum(lo, n_build), jnp.minimum(hi, n_build)
 
-    return probe
+    return jax.jit(_hlo_lint.named("hash-probe", probe))
 
 
 class BuildSide:
@@ -254,6 +258,7 @@ def build_hash_side(session, build_plan: L.LogicalPlan, build_cols: List[str],
     n = B.num_rows(batch)
     planes = tuple(_pad_plane(hash_input_uint32(batch[k]), np.uint32(0)) for k in bkeys)
     prog = _hash_build_program(len(bkeys))
+    link_bytes("h2d", "join-mats", sum(int(p.nbytes) for p in planes))
     table, order = prog(planes, np.int64(n))
     from hyperspace_tpu.exec import stage_ir as _stage_ir
 
@@ -264,7 +269,7 @@ def build_hash_side(session, build_plan: L.LogicalPlan, build_cols: List[str],
         session.conf, "hash-build",
         _program_key(f"hash-build/{sig}", session.mesh), prog, (planes, np.int64(n)),
     )
-    order_host = np.asarray(order)[:n].astype(np.int64)
+    order_host = fetch(order, "join-out", "hash-build")[:n].astype(np.int64)
     nbytes = sum(int(a.nbytes) for a in batch.values())
     nbytes += sum(int(p.nbytes) for p in planes) + int(planes[0].shape[0] * 12)
     return BuildSide(
@@ -322,6 +327,7 @@ def _probe_chunk(session, build: BuildSide, chunk: B.Batch,
         planes.append(hash_input_uint32(arr))
     padded = tuple(_pad_plane(p, np.uint32(0)) for p in planes)
     prog = _hash_probe_program(len(planes))
+    link_bytes("h2d", "join-mats", sum(int(p.nbytes) for p in padded))
     lo_d, hi_d = prog(build.table, np.int64(build.n), padded)
     from hyperspace_tpu.exec import stage_ir as _stage_ir
 
@@ -333,8 +339,7 @@ def _probe_chunk(session, build: BuildSide, chunk: B.Batch,
         _program_key(f"hash-probe/{sig}", session.mesh), prog,
         (build.table, np.int64(build.n), padded),
     )
-    lo = np.asarray(lo_d)[:n]
-    hi = np.asarray(hi_d)[:n]
+    lo, hi = (a[:n] for a in fetch((lo_d, hi_d), "join-out", "hash-probe"))
     counts = hi - lo
     total = int(counts.sum())
     if total == 0:
@@ -447,7 +452,7 @@ def _postjoin_program(cache_key, refs: List[str], ref_on_probe: Dict[str, bool],
                     cols[name] = bcols[name][bidx]
             return fn(cols, lits)
 
-        jitted = jax.jit(prog)
+        jitted = jax.jit(_hlo_lint.named("fused-postjoin", prog))
         _POSTJOIN_CACHE[cache_key] = jitted
     else:
         _POSTJOIN_CACHE.move_to_end(cache_key)
@@ -494,11 +499,15 @@ def _device_postjoin_mask(session, condition, pbatch: B.Batch, build: BuildSide,
         session.conf, "fused-postjoin",
         _program_key(f"fused-postjoin/{hash(sig)}", session.mesh), jitted, args,
     )
+    link_bytes(
+        "h2d", "join-mats",
+        sum(int(a.nbytes) for a in (*pcols.values(), *bcols.values(), pidx_pad, bidx_pad)),
+    )
     mask = jitted(*args)
     from hyperspace_tpu.exec import stage_ir as _stage_ir
 
     _stage_ir.count_dispatch("fused-postjoin")
-    return np.asarray(mask)[:n]
+    return fetch(mask, "join-out", "fused-postjoin")[:n]
 
 
 # --------------------------------------------------------------------------

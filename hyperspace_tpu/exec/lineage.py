@@ -48,6 +48,8 @@ from hyperspace_tpu.exec.device import (
     bucket_rows,
     encode_column,
     ensure_x64,
+    fetch,
+    put,
 )
 
 _hlo_lint.register_contract(
@@ -122,21 +124,21 @@ def lineage_delete_mask(
     else:
         arr, codec = encode_column(col_np)
         padded = _pad_to_bucket(arr, n_dev, 0)
-        dev_col = jax.device_put(padded, row_sharding)
+        dev_col = put(padded, "filter-cols", row_sharding)
         if ckey is not None:
             _device_cache_put(ckey, (dev_col, codec, n), int(padded.nbytes))
 
     m = bucket_rows(int(ids.size), floor=_ID_BUCKET_FLOOR)
     ids_padded = np.full(m, _ID_SENTINEL, dtype=np.int64)
     ids_padded[: ids.size] = ids
-    dev_ids = jax.device_put(ids_padded, replicated)
+    dev_ids = put(ids_padded, "filter-cols", replicated)
     n_ids = jax.device_put(np.int64(ids.size), replicated)
 
     key = _program_key("lineage-antijoin", mesh)
-    jitted = _cached_predicate_jit(key, _antijoin_fn)
+    jitted = _cached_predicate_jit(key, _antijoin_fn, "lineage-antijoin")
     _note_compile(key, (dev_col.shape, dev_ids.shape))
     _hlo_lint.maybe_verify(
         session.conf, "lineage-antijoin", key, jitted, (dev_col, dev_ids, n_ids)
     )
     mask = jitted(dev_col, dev_ids, n_ids)
-    return np.asarray(mask)[:n]
+    return fetch(mask, "filter-mask", "lineage-antijoin")[:n]

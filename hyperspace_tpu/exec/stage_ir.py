@@ -39,6 +39,7 @@ per-family path remains both the default and the fallback.
 
 from __future__ import annotations
 
+import sys
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -46,6 +47,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from hyperspace_tpu.check import hlo_lint as _hlo_lint
+from hyperspace_tpu.obs.metrics import REGISTRY
 
 # --------------------------------------------------------------------------
 # conf gates
@@ -86,29 +88,29 @@ def count_dispatch(program: str) -> None:
     ).inc()
 
 
-def note_peak_bytes() -> int:
-    """Sample total live device-array bytes (``jax.live_arrays``) and fold it
-    into the ``hs_device_peak_bytes`` high-water gauge. Called after fold
-    steps — the moment both the old and new state could coexist, which is
-    exactly the allocation donation exists to avoid."""
-    import jax
+def device_peak_bytes() -> Optional[int]:
+    """The allocator's own high-water mark: the largest ``peak_bytes_in_use``
+    of ``memory_stats()`` over this process's devices. None where the backend
+    keeps no such statistic (the CPU backend), and in a process whose JAX
+    backend is not up yet — reading a gauge must not be what claims the chip."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    from jax._src import xla_bridge
 
-    from hyperspace_tpu.obs.metrics import REGISTRY
+    if not xla_bridge.backends_are_initialized():
+        return None
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in jax.local_devices()]
+    peaks = [p for p in peaks if p is not None]
+    return int(max(peaks)) if peaks else None
 
-    total = 0
-    for a in jax.live_arrays():
-        try:
-            total += int(a.nbytes)
-        except Exception:
-            continue
-    g = REGISTRY.gauge(
-        "hs_device_peak_bytes",
-        "High-water total bytes of live device arrays, sampled after "
-        "streamed fold steps",
-    )
-    if total > g.value:
-        g.set(total)
-    return total
+
+REGISTRY.gauge(
+    "hs_device_peak_bytes",
+    "High-water bytes in use on a device, from the allocator's memory_stats() "
+    "at read time; absent where the backend reports none",
+    fn=device_peak_bytes,
+)
 
 
 # --------------------------------------------------------------------------
@@ -193,13 +195,14 @@ _STAGE_CACHE: "OrderedDict[tuple, object]" = OrderedDict()
 _STAGE_CACHE_MAX = 256
 
 
-def compile_stage(skeleton: str, fn, *, donate_argnums: Tuple[int, ...] = ()):
+def compile_stage(skeleton: str, fn, *, donate_argnums: Tuple[int, ...] = (),
+                  family: Optional[str] = None):
     """``device._cached_predicate_jit`` with a donation vector: one jitted
     stage program per (skeleton, donate_argnums). Donated positional args
     hand their buffers to XLA for output aliasing — callers MUST NOT touch a
     donated argument after the call (the ``donated-buffer-reuse`` lint rule
     enforces this repo-wide) and rebind their state to the returned arrays
-    instead."""
+    instead. ``family`` names the executable ``jit_hs_<family>``."""
     import jax
 
     donate = tuple(int(i) for i in donate_argnums)
@@ -208,6 +211,8 @@ def compile_stage(skeleton: str, fn, *, donate_argnums: Tuple[int, ...] = ()):
     if jitted is None:
         while len(_STAGE_CACHE) >= _STAGE_CACHE_MAX:
             _STAGE_CACHE.popitem(last=False)
+        if family is not None:
+            fn = _hlo_lint.named(family, fn)
         jitted = jax.jit(fn, donate_argnums=donate) if donate else jax.jit(fn)
         _STAGE_CACHE[key] = jitted
     else:
@@ -585,7 +590,7 @@ def _fused_fold_chunk(session, gs, build, chunk, pkeys, bkeys, post_filter,
             if got is None:
                 got = D.encode_column(build.batch[bk])
                 build.enc[bk] = got
-            bkenc.append(jax.device_put(got[0]))
+            bkenc.append(D.put(got[0], "join-mats"))
         bcols = {}
         bcodecs = {}
         for name in needed:
@@ -598,11 +603,11 @@ def _fused_fold_chunk(session, gs, build, chunk, pkeys, bkeys, post_filter,
                 build.enc[col] = got
             if got[1].kind == "string" and col in {c for _, _, c in gs.aggs if c}:
                 raise D.DeviceUnsupported("string aggregate inputs stay host-side")
-            bcols[name] = jax.device_put(got[0])
+            bcols[name] = D.put(got[0], "join-mats")
             bcodecs[name] = got[1]
         border = np.zeros(int(build.table.shape[0]), dtype=np.int64)
         border[: build.n] = build.order
-        state.bdev = (tuple(bkenc), bcols, bcodecs, jax.device_put(border))
+        state.bdev = (tuple(bkenc), bcols, bcodecs, D.put(border, "join-mats"))
     bkenc, bcols, bcodecs, border = state.bdev
 
     # probe-side per-chunk encodings, padded to the sqrt(2) row bucket
@@ -666,15 +671,17 @@ def _fused_fold_chunk(session, gs, build, chunk, pkeys, bkeys, post_filter,
         vmodes, pred_fn, needed, on_probe, gkey_specs, tuple(gs._slots),
         cap, pair_cap,
     )
-    jitted = compile_stage(key, program, donate_argnums=(0, 1, 2) if donate else ())
+    jitted = compile_stage(
+        key, program, donate_argnums=(0, 1, 2) if donate else (), family="fused-stage-join-agg"
+    )
     shapes = (pplanes[0].shape, int(build.table.shape[0]), cap, pair_cap)
     first = D._note_compile(key, shapes)
     args = (
         state_keys, state_slots, state_fs, np.int64(state_n),
         build.table, border, np.int64(build.n), bkenc,
-        tuple(jax.device_put(pl) for pl in pplanes),
-        tuple(jax.device_put(k) for k in pkenc),
-        {k: jax.device_put(v) for k, v in pcols.items()}, bcols,
+        tuple(D.put(pl, "join-mats") for pl in pplanes),
+        tuple(D.put(k, "join-mats") for k in pkenc),
+        {k: D.put(v, "join-mats") for k, v in pcols.items()}, bcols,
         tuple(lits), np.int64(n), np.int64(gs._row_base),
     )
     _hlo_lint.maybe_verify(conf, "fused-stage-join-agg", key, jitted, args)
@@ -682,7 +689,9 @@ def _fused_fold_chunk(session, gs, build, chunk, pkeys, bkeys, post_filter,
     total_d, n_chunk_d, n_m_d, n_kept_d, n_out_d, fs_out, keys_out, slots_out = jitted(*args)
     count_dispatch("fused-stage-join-agg")
     total, n_chunk, n_m, n_kept, n_out = (
-        int(total_d), int(n_chunk_d), int(n_m_d), int(n_kept_d), int(n_out_d)
+        int(v) for v in D.fetch(
+            (total_d, n_chunk_d, n_m_d, n_kept_d, n_out_d), "agg-table", "fused-stage-join-agg"
+        )
     )
     D._observe_program("fused-stage-join-agg", first, t0)
     # the donated state is gone: rebind to the returned (aliased) arrays
@@ -692,7 +701,7 @@ def _fused_fold_chunk(session, gs, build, chunk, pkeys, bkeys, post_filter,
         "cap": cap, "n": n_out, "fs": fs_out,
         "keys": list(keys_out), "slots": list(slots_out),
     }
-    note_peak_bytes()
+    gs._family = "fused-stage-join-agg"
     if total > pair_cap or n_chunk > cap or n_m > cap:
         state.pair_cap = D.bucket_rows(max(total, 1))
         gs._cap_hint = max(gs._cap_hint, n_chunk, n_m)

@@ -190,7 +190,8 @@ class TopKStream:
             merged, cand = self._run_fused(planes + [rid])
         else:
             cand = self._run_chunk(planes + [rid])
-        crid = np.asarray(cand[-1])
+        family = "fused-stage-topk" if use_fused else "topk-chunk"
+        crid = D.fetch(cand[-1], "topk", family)
         valid = crid < _SENT
         add_rid = crid[valid]
         local = (add_rid - base).astype(np.int64)
@@ -204,7 +205,7 @@ class TopKStream:
             )
             self._state = merged
             _merges_total().inc()
-            mrid = np.asarray(merged[-1])
+            mrid = D.fetch(merged[-1], "topk", family)
             merged_rid = mrid[mrid < _SENT]
         elif self._state is None:
             self._state = cand
@@ -237,7 +238,7 @@ class TopKStream:
         mat = np.stack(padded)  # (K+1, P), P a √2 shape bucket
         axis = mesh.axis_names[0]
         sharding = NamedSharding(mesh, P(None, axis))
-        dev = jax.device_put(mat, sharding)
+        dev = D.put(mat, "topk", sharding)
 
         if self.parallel is not None:
             from hyperspace_tpu.parallel import collectives as C
@@ -249,7 +250,7 @@ class TopKStream:
             fn = S.topk_chunk_fn(nk, self.cap)
             family = "topk-chunk"
         key = D._program_key(f"topk[{nk}:{self.cap}]", mesh, sharded=self.parallel is not None)
-        jitted = D._cached_predicate_jit(key, fn)
+        jitted = D._cached_predicate_jit(key, fn, family)
         D._note_compile(key, (mat.shape,))
         _hlo_lint.maybe_verify(self.session.conf, family, key, jitted, (dev,))
         out = jitted(dev)
@@ -277,7 +278,7 @@ class TopKStream:
         padded = [D._pad_to_bucket(r, n_dev, _SENT) for r in mat_rows]
         mat = np.stack(padded)
         axis = mesh.axis_names[0]
-        dev = jax.device_put(mat, NamedSharding(mesh, P(None, axis)))
+        dev = D.put(mat, "topk", NamedSharding(mesh, P(None, axis)))
 
         sharded = self.parallel is not None
         # donation stays off under shard_map (same stance as the grouped
@@ -297,14 +298,13 @@ class TopKStream:
             f"{plan.skeleton()}{'+d' if donate else ''}", mesh, sharded=sharded
         )
         jitted = _stage_ir.compile_stage(
-            key, fn, donate_argnums=(0,) if donate else ()
+            key, fn, donate_argnums=(0,) if donate else (), family=family
         )
         D._note_compile(key, (mat.shape,))
         state = self._state
         _hlo_lint.maybe_verify(self.session.conf, family, key, jitted, (state, dev))
         merged, cand = jitted(state, dev)
         _stage_ir.count_dispatch(family)
-        _stage_ir.note_peak_bytes()
         return merged, cand
 
     def _merge(self, cand, add_pool: B.Batch, add_rid: np.ndarray):
@@ -329,9 +329,9 @@ class TopKStream:
             # rebuild BOTH candidate matrices from raw pooled values over one
             # combined encoding (O(cap) host work) before the device merge
             a, b = self._rebuild_matrices(add_pool, add_rid)
-            a, b = jax.device_put(a), jax.device_put(b)
+            a, b = D.put(a, "topk"), D.put(b, "topk")
         mkey = D._program_key(f"topkmerge[{nk}:{self.cap}]", self.mesh, sharded=False)
-        mjit = D._cached_predicate_jit(mkey, S.topk_merge_fn(nk, self.cap))
+        mjit = D._cached_predicate_jit(mkey, S.topk_merge_fn(nk, self.cap), "topk-merge")
         D._note_compile(mkey, ((nk + 1, self.cap),))
         _hlo_lint.maybe_verify(self.session.conf, "topk-merge", mkey, mjit, (a, b))
         merged = mjit(a, b)
@@ -340,7 +340,7 @@ class TopKStream:
         _stage_ir.count_dispatch("topk-merge")
         self._state = merged
         _merges_total().inc()
-        mrid = np.asarray(merged[-1])
+        mrid = D.fetch(merged[-1], "topk", "topk-merge")
         return mrid[mrid < _SENT], pool_all, rid_all
 
     def _rebuild_matrices(self, add_pool: B.Batch, add_rid: np.ndarray):

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import os
 import re
+import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -39,11 +40,27 @@ from hyperspace_tpu import config as C
 from hyperspace_tpu.indexes import registry
 from hyperspace_tpu.indexes.base import CreateContext, Index, IndexConfig, UpdateMode
 from hyperspace_tpu.models.log_entry import Content, DerivedDataset
+from hyperspace_tpu.obs import spans
+from hyperspace_tpu.obs.metrics import REGISTRY
 from hyperspace_tpu.plan.logical import BucketSpec
 from hyperspace_tpu.plan.resolver import resolve_columns_against_schema
 from hyperspace_tpu.sources import schema as schema_codec
 
 _BUCKET_FILE_RE = re.compile(r"part-(\d+)-")
+
+# what a build counts (stage seconds are spans.stage's, cat "build"; see
+# docs/observability.md "Inside the program")
+_BUILD_ROWS = REGISTRY.counter(
+    "hs_build_rows_total", "Source rows bucketed and sorted by index builds"
+)
+_BUILD_SOURCE_BYTES = REGISTRY.counter(
+    "hs_build_source_bytes_total",
+    "Arrow bytes of the source columns decoded by index builds, keys and payload",
+)
+# thread-seconds inside the write pool, NOT wall: eight workers add up to
+# eight seconds a second; the pool's wall is the take-write stage
+_TAKE_SECONDS = spans.stage_seconds("take", "build")
+_WRITE_SECONDS = spans.stage_seconds("write", "build")
 
 #: Version of the bucket hash function the index's data files were
 #: partitioned with. Bumped whenever ops/hashing changes bucket placement
@@ -220,8 +237,9 @@ class CoveringIndex(Index):
                     return kt, group_payload_fn
 
                 for f in files:
-                    ds_f = relation.arrow_dataset([f])
-                    kt = _project_conform(ds_f, key_res, key_schema)
+                    with spans.stage("decode-keys", "build"):
+                        ds_f = relation.arrow_dataset([f])
+                        kt = _project_conform(ds_f, key_res, key_schema)
                     # emit BEFORE a file that would cross batchRows: groups
                     # stay under the cap (only a single file larger than
                     # batchRows exceeds it, and that group slices evenly),
@@ -384,6 +402,23 @@ def _arrow_field_for(resolved_col, schema: pa.Schema) -> pa.Field:
     return pa.field(resolved_col.normalized_name, field.type)
 
 
+def _take_write(chunk: pa.Table, rows_of_bucket: np.ndarray, out_dir: str, bucket: int) -> str:
+    """One bucket's sorted run: gather its rows, write its file. Runs on the
+    write pool; its two halves are thread-seconds of stages ``take`` and
+    ``write``."""
+    path = os.path.join(out_dir, _bucket_file_name(bucket))
+    t0 = time.perf_counter()
+    rows = chunk.take(pa.array(rows_of_bucket))
+    t1 = time.perf_counter()
+    # uncompressed PLAIN is the index-file dialect: the native decoder
+    # (hyperspace_tpu/native) mmaps these and memcpys column chunks into
+    # device-feedable buffers with zero decompression work
+    pq.write_table(rows, path, use_dictionary=False, compression="NONE")
+    _TAKE_SECONDS.inc(t1 - t0)
+    _WRITE_SECONDS.inc(time.perf_counter() - t1)
+    return path
+
+
 def write_bucketed(
     table: pa.Table,
     bucket_sort_columns: List[str],
@@ -430,15 +465,10 @@ def write_bucketed(
     the exchange fits. Bucket file contents are identical to the single-device
     build's (same rows, same within-bucket order).
     """
-    import time as _time
-
-    import jax
-
+    from hyperspace_tpu.exec import device as D
     from hyperspace_tpu.exec.batch import table_to_batch
     from hyperspace_tpu.ops import encode
     from hyperspace_tpu.ops.sort import bucket_sort_build, padded_size
-
-    timing = os.environ.get("HS_BUILD_TIMING", "") == "1"
 
     os.makedirs(out_dir, exist_ok=True)
     n = table.num_rows
@@ -464,35 +494,37 @@ def write_bucketed(
             mesh = m
             capacity_factor = session.conf.rebucket_capacity_factor
 
+    def _encode_keys(chunk: pa.Table):
+        """Count the chunk and encode its key columns: (rows, key planes,
+        kinds, host hash planes)."""
+        _BUILD_ROWS.inc(chunk.num_rows)
+        _BUILD_SOURCE_BYTES.inc(chunk.nbytes)
+        with spans.stage("encode-keys", "build"):
+            batch = table_to_batch(chunk.select(bucket_sort_columns))
+            keys, kinds, host_hashes = encode.encode_sort_columns(
+                [batch[c] for c in bucket_sort_columns]
+            )
+        return chunk.num_rows, keys, kinds, host_hashes
+
     def _launch(chunk: pa.Table) -> dict:
         """Host encode + device program dispatch + async d2h start. Returns
         the in-flight state; nothing here blocks on the device."""
-        marks = {}
-        t = _time.perf_counter()
-        batch = table_to_batch(chunk.select(bucket_sort_columns))
-        keys, kinds, host_hashes = encode.encode_sort_columns(
-            [batch[c] for c in bucket_sort_columns]
-        )
-        if timing:
-            marks["encode_keys"] = round(_time.perf_counter() - t, 3)
-        t = _time.perf_counter()
-        cn = chunk.num_rows
-        np2 = padded_size(cn)
-        dev_keys = [jax.device_put(np.pad(k, (0, np2 - cn))) for k in keys]
-        dev_hashes = [jax.device_put(np.pad(h, (0, np2 - cn))) for h in host_hashes]
-        perm, counts = bucket_sort_build(dev_keys, dev_hashes, kinds, num_buckets, cn)
-        counts.copy_to_host_async()
-        # the permutation comes back in pieces so bucket writes can start
-        # while later pieces are still in flight (device->host is the narrow
-        # link)
-        n_pieces = min(8, max(1, np2 // (1 << 18)))
-        piece_len = np2 // n_pieces
-        pieces = [perm[i * piece_len : (i + 1) * piece_len] for i in range(n_pieces)]
-        for p in pieces:
-            p.copy_to_host_async()
-        if timing:
-            marks["pad_upload_launch"] = round(_time.perf_counter() - t, 3)
-        return {"chunk": chunk, "np2": np2, "counts": counts, "pieces": pieces, "marks": marks}
+        cn, keys, kinds, host_hashes = _encode_keys(chunk)
+        with spans.stage("h2d-launch", "build"):
+            np2 = padded_size(cn)
+            dev_keys = [D.put(np.pad(k, (0, np2 - cn)), "build-keys") for k in keys]
+            dev_hashes = [D.put(np.pad(h, (0, np2 - cn)), "build-keys") for h in host_hashes]
+            perm, counts = bucket_sort_build(dev_keys, dev_hashes, kinds, num_buckets, cn)
+            counts.copy_to_host_async()
+            # the permutation comes back in pieces so bucket writes can start
+            # while later pieces are still in flight (device->host is the
+            # narrow link)
+            n_pieces = min(8, max(1, np2 // (1 << 18)))
+            piece_len = np2 // n_pieces
+            pieces = [perm[i * piece_len : (i + 1) * piece_len] for i in range(n_pieces)]
+            for p in pieces:
+                p.copy_to_host_async()
+        return {"chunk": chunk, "np2": np2, "counts": counts, "pieces": pieces}
 
     def _prepare_chunk(state: dict, chunk_payload_fn) -> pa.Table:
         """Shared host prep before bucket writes: attach the lazily-decoded
@@ -500,44 +532,29 @@ def write_bucketed(
         single-chunk columns so per-bucket takes don't re-resolve chunk
         offsets (a numpy-gather variant measured equal within noise; arrow
         take keeps string/date columns on one code path)."""
-        chunk, marks = state["chunk"], state["marks"]
-        t = _time.perf_counter()
+        chunk = state["chunk"]
         if chunk_payload_fn is not None:
-            payload = chunk_payload_fn()
+            with spans.stage("decode-payload", "build"):
+                payload = chunk_payload_fn()
             if payload is not None:
+                _BUILD_SOURCE_BYTES.inc(payload.nbytes)
                 for name in payload.column_names:
                     chunk = chunk.append_column(payload.schema.field(name), payload.column(name))
-        if timing:
-            marks["payload_decode"] = round(_time.perf_counter() - t, 3)
-        t = _time.perf_counter()
-        if column_order:
-            chunk = chunk.select(column_order)
-        chunk = chunk.combine_chunks()
-        if timing:
-            marks["combine_chunks"] = round(_time.perf_counter() - t, 3)
+        with spans.stage("combine", "build"):
+            if column_order:
+                chunk = chunk.select(column_order)
+            chunk = chunk.combine_chunks()
         return chunk
 
     def _finish(state: dict, chunk_payload_fn) -> List[str]:
         """Drain the permutation and write the per-bucket sorted parquet
         files; host-heavy, overlapped with the NEXT chunk's device work."""
         np2 = state["np2"]
-        marks = state["marks"]
         chunk = _prepare_chunk(state, chunk_payload_fn)
-        t = _time.perf_counter()
-        counts_np = np.asarray(state["counts"])
+        with spans.stage("d2h-counts", "build"):
+            counts_np = np.asarray(state["counts"])
+            D.link_bytes("d2h", "build-counts", counts_np.nbytes)
         boundaries = np.concatenate([[0], np.cumsum(counts_np)])
-        if timing:
-            marks["counts_wait"] = round(_time.perf_counter() - t, 3)
-        t = _time.perf_counter()
-
-        def _take_write(b: int, lo: int, hi: int) -> str:
-            path = os.path.join(out_dir, _bucket_file_name(b))
-            # uncompressed PLAIN is the index-file dialect: the native decoder
-            # (hyperspace_tpu/native) mmaps these and memcpys column chunks
-            # into device-feedable buffers with zero decompression work
-            rows = chunk.take(pa.array(perm_np[lo:hi]))
-            pq.write_table(rows, path, use_dictionary=False, compression="NONE")
-            return path
 
         from concurrent.futures import ThreadPoolExecutor
 
@@ -546,26 +563,20 @@ def write_bucketed(
         next_piece = 0
         futures = []
         pieces = state["pieces"]
-        with ThreadPoolExecutor(max_workers=8) as ex:
+        with spans.stage("take-write", "build"), ThreadPoolExecutor(max_workers=8) as ex:
             for b in range(num_buckets):
                 lo, hi = int(boundaries[b]), int(boundaries[b + 1])
                 if hi <= lo:
                     continue
                 while arrived < hi:
-                    piece = np.asarray(pieces[next_piece])  # blocks for this piece only
+                    with spans.stage("d2h-perm", "build"):
+                        piece = np.asarray(pieces[next_piece])  # blocks for this piece only
                     perm_np[arrived : arrived + piece.shape[0]] = piece
                     arrived += piece.shape[0]
                     next_piece += 1
-                futures.append(ex.submit(_take_write, b, lo, hi))
+                futures.append(ex.submit(_take_write, chunk, perm_np[lo:hi], out_dir, b))
             out = [f.result() for f in futures]
-        if timing:
-            marks["perm_drain_take_write"] = round(_time.perf_counter() - t, 3)
-            # stderr: bench.py's stdout contract is exactly one JSON line.
-            # (Coarse wall-clock marks complement session.profile()'s XLA
-            # traces for machines without trace tooling; labels match.)
-            import sys as _sys
-
-            print(f"HS_BUILD_TIMING rows={chunk.num_rows} {marks}", file=_sys.stderr, flush=True)
+        D.link_bytes("d2h", "build-perm", perm_np.nbytes)  # every piece was sent for
         return out
 
     def _launch_mesh(chunk: pa.Table) -> dict:
@@ -577,33 +588,25 @@ def write_bucketed(
 
         from hyperspace_tpu.ops.bucketize import _next_pow2, distributed_bucket_sort_build
 
-        marks = {}
-        t = _time.perf_counter()
-        batch = table_to_batch(chunk.select(bucket_sort_columns))
-        keys, kinds, host_hashes = encode.encode_sort_columns(
-            [batch[c] for c in bucket_sort_columns]
-        )
-        if timing:
-            marks["encode_keys"] = round(_time.perf_counter() - t, 3)
-        t = _time.perf_counter()
-        cn = chunk.num_rows
-        n_dev = int(mesh.devices.size)
-        per_dev = padded_size(-(-cn // n_dev))
-        pad_n = per_dev * n_dev
-        sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
-        dev_keys = [jax.device_put(np.pad(k, (0, pad_n - cn)), sharding) for k in keys]
-        dev_hashes = [jax.device_put(np.pad(h, (0, pad_n - cn)), sharding) for h in host_hashes]
-        row_idx = jax.device_put(np.arange(pad_n, dtype=np.int32), sharding)
-        capacity = min(
-            _next_pow2(int(per_dev / n_dev * capacity_factor)), _next_pow2(per_dev)
-        )
-        bkts, ridx, vld, ovf = distributed_bucket_sort_build(
-            mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
-        )
-        for a in (ovf, bkts, ridx, vld):
-            a.copy_to_host_async()
-        if timing:
-            marks["pad_upload_launch"] = round(_time.perf_counter() - t, 3)
+        cn, keys, kinds, host_hashes = _encode_keys(chunk)
+        with spans.stage("h2d-launch", "build"):
+            n_dev = int(mesh.devices.size)
+            per_dev = padded_size(-(-cn // n_dev))
+            pad_n = per_dev * n_dev
+            sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
+            dev_keys = [D.put(np.pad(k, (0, pad_n - cn)), "build-keys", sharding) for k in keys]
+            dev_hashes = [
+                D.put(np.pad(h, (0, pad_n - cn)), "build-keys", sharding) for h in host_hashes
+            ]
+            row_idx = D.put(np.arange(pad_n, dtype=np.int32), "build-keys", sharding)
+            capacity = min(
+                _next_pow2(int(per_dev / n_dev * capacity_factor)), _next_pow2(per_dev)
+            )
+            bkts, ridx, vld, ovf = distributed_bucket_sort_build(
+                mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
+            )
+            for a in (ovf, bkts, ridx, vld):
+                a.copy_to_host_async()
         return {
             "chunk": chunk,
             "bkts": bkts,
@@ -614,7 +617,6 @@ def write_bucketed(
             "capacity": capacity,
             "per_dev": per_dev,
             "retry": (dev_keys, dev_hashes, kinds, row_idx, cn),
-            "marks": marks,
         }
 
     def _finish_mesh(state: dict, chunk_payload_fn) -> List[str]:
@@ -623,45 +625,41 @@ def write_bucketed(
         device shard yields its own contiguous bucket runs."""
         from hyperspace_tpu.ops.bucketize import _next_pow2, distributed_bucket_sort_build
 
-        marks = state["marks"]
         chunk = _prepare_chunk(state, chunk_payload_fn)
-        t = _time.perf_counter()
-
         capacity, per_dev = state["capacity"], state["per_dev"]
         bkts, ridx, vld, ovf = state["bkts"], state["ridx"], state["vld"], state["ovf"]
-        while int(np.asarray(ovf).sum()) > 0:
-            # skew overflowed a destination's slots: double capacity and rerun
-            # (a source holds per_dev rows total, so capacity == per_dev
-            # always fits and the loop terminates)
-            if capacity >= per_dev:
-                raise RuntimeError(
-                    "distributed build exchange overflow at full capacity "
-                    f"(capacity={capacity}, per_dev={per_dev})"
+        with spans.stage("exchange-drain", "build"):
+            while True:
+                with spans.stage("d2h-counts", "build"):
+                    ovf_np = np.asarray(ovf)
+                D.link_bytes("d2h", "build-counts", ovf_np.nbytes)
+                if int(ovf_np.sum()) == 0:
+                    break
+                # skew overflowed a destination's slots: double capacity and
+                # rerun (a source holds per_dev rows total, so capacity ==
+                # per_dev always fits and the loop terminates)
+                if capacity >= per_dev:
+                    raise RuntimeError(
+                        "distributed build exchange overflow at full capacity "
+                        f"(capacity={capacity}, per_dev={per_dev})"
+                    )
+                capacity = min(_next_pow2(capacity * 2), _next_pow2(per_dev))
+                dev_keys, dev_hashes, kinds, row_idx, cn = state["retry"]
+                bkts, ridx, vld, ovf = distributed_bucket_sort_build(
+                    mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
                 )
-            capacity = min(_next_pow2(capacity * 2), _next_pow2(per_dev))
-            dev_keys, dev_hashes, kinds, row_idx, cn = state["retry"]
-            bkts, ridx, vld, ovf = distributed_bucket_sort_build(
-                mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
-            )
-        bkts_np = np.asarray(bkts)
-        ridx_np = np.asarray(ridx)
-        vld_np = np.asarray(vld)
-        if timing:
-            marks["exchange_drain"] = round(_time.perf_counter() - t, 3)
-        t = _time.perf_counter()
-
-        def _take_write(b: int, indices: np.ndarray) -> str:
-            path = os.path.join(out_dir, _bucket_file_name(b))
-            rows = chunk.take(pa.array(indices))
-            pq.write_table(rows, path, use_dictionary=False, compression="NONE")
-            return path
+            with spans.stage("d2h-perm", "build"):
+                bkts_np = np.asarray(bkts)
+                ridx_np = np.asarray(ridx)
+                vld_np = np.asarray(vld)
+            D.link_bytes("d2h", "build-perm", bkts_np.nbytes + ridx_np.nbytes + vld_np.nbytes)
 
         from concurrent.futures import ThreadPoolExecutor
 
         n_dev = state["n_dev"]
         shard_len = bkts_np.shape[0] // n_dev
         futures = []
-        with ThreadPoolExecutor(max_workers=8) as ex:
+        with spans.stage("take-write", "build"), ThreadPoolExecutor(max_workers=8) as ex:
             for d in range(n_dev):
                 sl = slice(d * shard_len, (d + 1) * shard_len)
                 v_d = vld_np[sl]
@@ -674,13 +672,8 @@ def write_bucketed(
                 for b in range(d, num_buckets, n_dev):
                     lo, hi = int(bounds[b]), int(bounds[b + 1])
                     if hi > lo:
-                        futures.append(ex.submit(_take_write, b, r_v[lo:hi]))
+                        futures.append(ex.submit(_take_write, chunk, r_v[lo:hi], out_dir, b))
             out = [f.result() for f in futures]
-        if timing:
-            marks["bucket_take_write"] = round(_time.perf_counter() - t, 3)
-            import sys as _sys
-
-            print(f"HS_BUILD_TIMING mesh rows={chunk.num_rows} {marks}", file=_sys.stderr, flush=True)
         return out
 
     launch, finish = (_launch_mesh, _finish_mesh) if mesh is not None else (_launch, _finish)

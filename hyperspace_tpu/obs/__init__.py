@@ -54,6 +54,7 @@ from hyperspace_tpu.obs.spans import (
     graft_remote,
     parse_traceparent,
     span,
+    stage,
     start_trace,
     to_chrome_trace,
     to_wire,
@@ -91,6 +92,7 @@ __all__ = [
     "graft_remote",
     "parse_traceparent",
     "span",
+    "stage",
     "start_trace",
     "to_chrome_trace",
     "to_wire",

@@ -62,7 +62,9 @@ class Counter:
 
 class Gauge:
     """Last-write-wins instantaneous value; ``fn`` makes it a read-time
-    callback gauge (queue depth, cache bytes) instead of a stored value."""
+    callback gauge (queue depth, cache bytes) instead of a stored value. A
+    callback that returns None has nothing to report: ``value`` is None and
+    the expositions leave the series out."""
 
     __slots__ = ("_lock", "_v", "fn")
 
@@ -83,10 +85,11 @@ class Gauge:
         self.inc(-n)
 
     @property
-    def value(self) -> float:
+    def value(self) -> Optional[float]:
         if self.fn is not None:
             try:
-                return float(self.fn())
+                v = self.fn()
+                return None if v is None else float(v)
             except Exception:
                 return float("nan")
         return self._v
@@ -261,7 +264,9 @@ class MetricsRegistry:
             )
             lab = dict(labels)
             if isinstance(m, Counter) or isinstance(m, Gauge):
-                entry["series"].append({"labels": lab, "value": m.value})
+                v = m.value
+                if v is not None:
+                    entry["series"].append({"labels": lab, "value": v})
             else:
                 entry["series"].append(
                     {
@@ -289,6 +294,8 @@ class MetricsRegistry:
             for labels, m in by_name[name]:
                 if isinstance(m, (Counter, Gauge)):
                     v = m.value
+                    if v is None:
+                        continue
                     sv = f"{v:g}" if v == v else "NaN"
                     lines.append(f"{name}{_fmt_labels(labels)} {sv}")
                 else:

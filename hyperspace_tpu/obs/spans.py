@@ -36,9 +36,12 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
+
+from hyperspace_tpu.obs.metrics import REGISTRY
 
 __all__ = [
     "Span",
@@ -54,6 +57,7 @@ __all__ = [
     "attach",
     "wrap",
     "add_manual",
+    "stage",
     "to_wire",
     "from_wire",
     "graft_remote",
@@ -233,14 +237,28 @@ NULL_SPAN = _NullSpan()
 _NULL_CM = _NullCM()
 
 
+def _annotation(name: str, cat: str):
+    """The span as an event of the profiler's host plane, on the profiler's
+    own clock: an entered ``jax.profiler.TraceAnnotation("hs:<cat>:<name>")``,
+    or None in a process that never imported jax (no profiler session can be
+    running there, and this package must not be the one to import it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(f"hs:{cat}:{name}")
+    ann.__enter__()
+    return ann
+
+
 class _SpanCM:
     """Context manager creating a child of ``parent`` and making it current.
 
     Class-based (not a generator) so the disabled path stays allocation-free
-    and the enabled path costs one object + one contextvar set/reset.
+    and the enabled path costs one object + one contextvar set/reset, plus
+    the profiler annotation that puts the span on the device trace's clock.
     """
 
-    __slots__ = ("_parent", "_name", "_cat", "_attrs", "_span", "_token")
+    __slots__ = ("_parent", "_name", "_cat", "_attrs", "_span", "_token", "_ann")
 
     def __init__(self, parent: Span, name: str, cat: str, attrs: Optional[dict]):
         self._parent = parent
@@ -249,6 +267,7 @@ class _SpanCM:
         self._attrs = attrs
         self._span: Any = None
         self._token = None
+        self._ann = None
 
     def __enter__(self):
         tr = self._parent.trace
@@ -267,10 +286,13 @@ class _SpanCM:
         self._parent.children.append(sp)  # list.append: atomic under the GIL
         self._span = sp
         self._token = _current.set(sp)
+        self._ann = _annotation(self._name, self._cat)
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._token is not None:
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
             _current.reset(self._token)
             if exc_type is not None:
                 self._span.attrs.setdefault("error", exc_type.__name__)
@@ -293,6 +315,79 @@ def span(name: str, cat: str = "", **attrs):
     if parent is None:
         return _NULL_CM
     return _SpanCM(parent, name, cat, attrs or None)
+
+
+# (cat, stage) -> the hs_stage_seconds_total series, held here so that a
+# stage entry costs a dict read and not a registry lookup under its lock
+_STAGE_SECONDS: Dict[tuple, Any] = {}
+
+
+def stage_seconds(name: str, cat: str):
+    """The ``hs_stage_seconds_total{cat,stage}`` counter of one stage."""
+    c = _STAGE_SECONDS.get((cat, name))
+    if c is None:
+        c = _STAGE_SECONDS[(cat, name)] = REGISTRY.counter(
+            "hs_stage_seconds_total",
+            "Seconds spent in coarse stages of work that runs under no request "
+            "tree (index build, refresh, optimize), by category and stage",
+            cat=cat,
+            stage=name,
+        )
+    return c
+
+
+_open_stage = threading.local()
+
+
+class _StageCM:
+    __slots__ = ("_name", "_cat", "_counter", "_t0", "_span_cm", "_ann", "_outer", "_nested")
+
+    def __init__(self, name: str, cat: str):
+        self._name = name
+        self._cat = cat
+        self._counter = stage_seconds(name, cat)
+
+    def __enter__(self):
+        parent = _current.get()
+        if parent is None:
+            self._span_cm = None
+            self._ann = _annotation(self._name, self._cat)
+            sp = NULL_SPAN
+        else:
+            self._ann = None  # the span brings its own
+            self._span_cm = _SpanCM(parent, self._name, self._cat, None)
+            sp = self._span_cm.__enter__()
+        self._outer = getattr(_open_stage, "top", None)
+        _open_stage.top = self
+        self._nested = 0.0
+        self._t0 = time.perf_counter()
+        return sp
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        wall = time.perf_counter() - self._t0
+        self._counter.inc(wall - self._nested)
+        _open_stage.top = self._outer
+        if self._outer is not None:
+            self._outer._nested += wall
+        if self._span_cm is not None:
+            self._span_cm.__exit__(exc_type, exc, tb)
+        elif self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def stage(name: str, cat: str):
+    """Time one coarse stage of work that runs under no request tree (index
+    builds, refresh, optimize). Always adds its wall seconds to
+    ``hs_stage_seconds_total{cat,stage}`` — its own seconds: what a stage
+    nested inside it on the same thread takes counts for the nested stage
+    only, so the stages of one thread add up to its wall. Opens a child span
+    when a trace is current, and is an ``hs:<cat>:<name>`` event of the
+    profiler's host plane while a profiler session runs. For chunk
+    granularity (a few hundred entries a build): never per row, per bucket
+    file or per request.
+    """
+    return _StageCM(name, cat)
 
 
 _DEFAULT_MAX_SPANS = 100_000

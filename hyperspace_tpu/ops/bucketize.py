@@ -176,7 +176,7 @@ def rebucket(
         out, out_mask = _exchange_packed(staged, mask, axis)
         return (*out, out_mask, overflow[None])
 
-    results = exchange(*values, bucket_ids)
+    results = _hlo_lint.named("index-rebucket", exchange)(*values, bucket_ids)
     out_arrays = dict(zip(names, results[: len(names)]))
     out_buckets, valid, overflow = results[len(names)], results[len(names) + 1], results[len(names) + 2]
     return out_arrays, out_buckets, valid, overflow
@@ -302,26 +302,29 @@ def _build_exchange_program(mesh: Mesh, kinds: Tuple[str, ...], num_buckets: int
             ks = args[:n_keys]
             hh = args[n_keys : n_keys + n_str]
             ridx, vld = args[-2], args[-1]
-            hash_cols = []
-            hidx = 0
-            for kind, key in zip(kinds, ks):
-                if kind == "s":
-                    hash_cols.append(hh[hidx])
-                    hidx += 1
-                else:
-                    hash_cols.append(_device_hash32(kind, key))
-            buckets = bucket_ids_jnp(hash_cols, num_buckets).astype(jnp.int32)
-            dest = (buckets % n_dev).astype(jnp.int32)
-            staged, mask, counts = _stage_for_exchange(
-                [*ks, ridx, buckets], dest, n_dev, capacity, valid=vld
-            )
-            sent = jnp.minimum(counts, capacity)
-            overflow = jnp.sum(counts - sent)
-            outs, out_mask = _exchange_packed(staged, mask, axis)
+            with jax.named_scope("hash"):
+                hash_cols = []
+                hidx = 0
+                for kind, key in zip(kinds, ks):
+                    if kind == "s":
+                        hash_cols.append(hh[hidx])
+                        hidx += 1
+                    else:
+                        hash_cols.append(_device_hash32(kind, key))
+                buckets = bucket_ids_jnp(hash_cols, num_buckets).astype(jnp.int32)
+            with jax.named_scope("exchange"):
+                dest = (buckets % n_dev).astype(jnp.int32)
+                staged, mask, counts = _stage_for_exchange(
+                    [*ks, ridx, buckets], dest, n_dev, capacity, valid=vld
+                )
+                sent = jnp.minimum(counts, capacity)
+                overflow = jnp.sum(counts - sent)
+                outs, out_mask = _exchange_packed(staged, mask, axis)
             *out_keys, out_ridx, out_buckets = outs
-            order = lex_argsort(
-                [(~out_mask).astype(jnp.int32), out_buckets, *out_keys, out_ridx]
-            )
+            with jax.named_scope("sort"):
+                order = lex_argsort(
+                    [(~out_mask).astype(jnp.int32), out_buckets, *out_keys, out_ridx]
+                )
             return (
                 out_buckets[order],
                 out_ridx[order],
@@ -331,7 +334,7 @@ def _build_exchange_program(mesh: Mesh, kinds: Tuple[str, ...], num_buckets: int
 
         return exchange(*keys, *host_hashes, row_idx, valid)
 
-    return jax.jit(run)
+    return jax.jit(_hlo_lint.named("index-build-exchange", run))
 
 
 def distributed_bucket_sort_build(
@@ -427,7 +430,7 @@ def rebucket_hierarchical(
         *out_vals, out_buckets = out
         return (*out_vals, out_buckets, out_mask, overflow[None])
 
-    results = exchange(*values, bucket_ids)
+    results = _hlo_lint.named("hierarchical-exchange", exchange)(*values, bucket_ids)
     out_arrays = dict(zip(names, results[: len(names)]))
     out_buckets, valid, overflow = results[len(names)], results[len(names) + 1], results[len(names) + 2]
     return out_arrays, out_buckets, valid, overflow

@@ -33,6 +33,7 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp  # noqa: E402
 
+from hyperspace_tpu.check import hlo_lint as _hlo_lint
 from hyperspace_tpu.utils.x64 import ensure_x64
 import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
@@ -153,32 +154,49 @@ def _device_hash32(kind: str, key):
     return hi ^ lo
 
 
-@partial(jax.jit, static_argnames=("num_buckets", "kinds", "interpret"))
 def _build_sorted(keys, host_hashes, n_valid, num_buckets: int, kinds, interpret: bool):
     from hyperspace_tpu.ops.hashing import bucket_ids_jnp
     from hyperspace_tpu.ops.kernels import _hist_call
 
-    hash_cols = []
-    hidx = 0
-    for kind, key in zip(kinds, keys):
-        if kind == "s":
-            hash_cols.append(host_hashes[hidx])
-            hidx += 1
-        else:
-            hash_cols.append(_device_hash32(kind, key))
-    buckets = bucket_ids_jnp(hash_cols, num_buckets)
+    with jax.named_scope("hash"):
+        hash_cols = []
+        hidx = 0
+        for kind, key in zip(kinds, keys):
+            if kind == "s":
+                hash_cols.append(host_hashes[hidx])
+                hidx += 1
+            else:
+                hash_cols.append(_device_hash32(kind, key))
+        buckets = bucket_ids_jnp(hash_cols, num_buckets)
 
     n = buckets.shape[0]
     idx = lax.iota(jnp.int32, n)
     # padding rows get the sentinel bucket ``num_buckets`` so they cluster
     # after every real bucket and fall outside the returned counts
     buckets = jnp.where(idx < n_valid, buckets, jnp.int32(num_buckets))
-    out = lax.sort((buckets, *keys, idx), num_keys=2 + len(keys), is_stable=False)
+    with jax.named_scope("sort"):
+        out = lax.sort((buckets, *keys, idx), num_keys=2 + len(keys), is_stable=False)
     sorted_buckets, perm = out[0], out[-1]
 
     nb_p = -(-(num_buckets + 1) // 128) * 128
-    counts = _hist_call(sorted_buckets[None, :], nb_p, interpret)[:, 0]
+    with jax.named_scope("histogram"):
+        counts = _hist_call(sorted_buckets[None, :], nb_p, interpret)[:, 0]
     return perm, counts[:num_buckets]
+
+
+_hlo_lint.register_contract(
+    "index-build",
+    collectives={},
+    description=(
+        "single-device index build: device hash, one multi-operand sort by "
+        "(bucket, keys), Pallas bucket histogram; permutation and counts out"
+    ),
+)
+# the executable is jit_hs_index_build in the profiler's module line
+_build_sorted = jax.jit(
+    _hlo_lint.named("index-build", _build_sorted),
+    static_argnames=("num_buckets", "kinds", "interpret"),
+)
 
 
 def bucket_sort_build(
@@ -310,8 +328,6 @@ def fused_topk_fn(num_keys: int, cap: int):
 
 # --- declared HLO contracts (hyperspace_tpu/check/hlo_lint.py), stated next
 # to the program builders like exec/device.py's families ---------------------
-from hyperspace_tpu.check import hlo_lint as _hlo_lint
-
 _hlo_lint.register_contract(
     "topk-chunk",
     collectives={"all-gather": (0, None)},

@@ -58,7 +58,7 @@ class _Request:
     __slots__ = (
         "plan", "fp", "token", "enabled", "future", "deadline", "submitted_at",
         "root", "tenant", "query_text", "cost_class", "brand", "dequeued_at",
-        "sched_charge", "snapshot",
+        "sched_charge", "snapshot", "enqueued_pc", "taken_pc",
     )
 
     def __init__(self, plan, fp: Fingerprint, token, enabled: bool, deadline, root=None,
@@ -72,6 +72,11 @@ class _Request:
         self.future: "Future" = Future()
         self.deadline = deadline
         self.submitted_at = time.monotonic()
+        # the same moment on the span tracer's clock: where the request's
+        # queue-wait span starts; taken_pc is when a worker took it off the
+        # queue (a micro-batch then still gathers: batch-wait)
+        self.enqueued_pc = time.perf_counter()
+        self.taken_pc: Optional[float] = None
         # per-request span-tree root (None when obs tracing is off); workers
         # attach() it so each request's spans land in its own disjoint tree
         self.root = root
@@ -568,6 +573,7 @@ class QueryServer:
                 continue
             group = [first]
             if self.micro_batch_enabled and self.micro_batch_max > 1:
+                first.taken_pc = time.perf_counter()
                 waited = 0.0
                 while len(group) < self.micro_batch_max:
                     nxt = self.admission.take_nowait()
@@ -577,11 +583,13 @@ class QueryServer:
                         time.sleep(min(0.001, self.micro_batch_wait_s - waited))
                         waited += 0.001
                         continue
+                    nxt.taken_pc = time.perf_counter()
                     group.append(nxt)
             self._process_group(group)
 
     def _process_group(self, group: List[_Request]) -> None:
         now = time.monotonic()
+        now_pc = time.perf_counter()
         for r in group:
             r.dequeued_at = now
             self.registry.histogram(
@@ -589,6 +597,12 @@ class QueryServer:
                 "seconds a request waited in the admission queue before dispatch",
                 tenant=r.tenant, cost_class=r.cost_class, server=self.server_name,
             ).observe(now - r.submitted_at)
+            if r.root is not None:
+                # the same wait inside the request's tree: enqueue to
+                # dispatch, the micro-batch's gather its child
+                wait = spans.add_manual(r.root, "queue-wait", "serving", r.enqueued_pc, now_pc)
+                if wait is not None and r.taken_pc is not None:
+                    spans.add_manual(wait, "batch-wait", "serving", r.taken_pc, now_pc)
         # coalesce by (token, structure); order within a key is preserved
         by_key: Dict[tuple, List[_Request]] = {}
         for r in group:

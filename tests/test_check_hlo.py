@@ -173,7 +173,7 @@ class TestMaybeVerify:
         assert runtime_violations() == []
 
     def test_verifies_and_dedups(self, scratch_contract, runtime_default_on):
-        jitted = jax.jit(lambda x: x * 2)
+        jitted = jax.jit(hlo_lint.named(scratch_contract, lambda x: x * 2))
         before = REGISTRY.counter(
             "hs_check_programs_verified_total", program=scratch_contract
         ).value
@@ -203,6 +203,22 @@ class TestMaybeVerify:
         assert REGISTRY.counter(
             "hs_check_programs_verified_total", program=scratch_contract
         ).value == after + 1
+
+    def test_unnamed_program_violates_program_name(self, scratch_contract, runtime_default_on):
+        """A family's executable must be jit_hs_<family>: that name is how the
+        profiler's module line, HLO dumps and the compile-cache log find it."""
+        assert hlo_lint.program_name("fused-stage-agg") == "hs_fused_stage_agg"
+        x = jnp.ones(4, jnp.float32)
+        maybe_verify(None, scratch_contract, "unnamed", jax.jit(lambda x: x + 1), (x,))
+        rules = [f.rule for f in runtime_violations()]
+        assert "program-name" in rules
+        reset_runtime_state()
+        named = jax.jit(hlo_lint.named(scratch_contract, lambda x: x + 1))
+        assert named.lower(x).as_text().startswith("module @jit_hs_hscheck_test_family")
+        maybe_verify(None, scratch_contract, "named", named, (x,))
+        assert "program-name" not in [f.rule for f in runtime_violations()]
+        with pytest.raises(KeyError):
+            hlo_lint.named("never-registered-family", lambda x: x)
 
     def test_violations_warn_never_raise(self, scratch_contract, runtime_default_on):
         jitted = jax.jit(lambda x: x + 1)

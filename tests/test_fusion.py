@@ -324,11 +324,38 @@ class TestDonation:
         c = stage_ir.compile_stage("test-donation[cache]", fn)
         assert c is not a  # donation vector is part of the cache key
 
-    def test_peak_bytes_gauge_tracks_high_water(self, tmp_path):
+    def test_peak_bytes_gauge_tracks_high_water(self, tmp_path, monkeypatch):
+        """``hs_device_peak_bytes`` is the allocator's own high-water mark,
+        read when someone looks — no sampling on the fold path."""
+        import jax
+
+        from hyperspace_tpu.exec import stage_ir
+
         data = _write_q1(str(tmp_path / "q1"))
         sess = _mk_session(tmp_path, "on", fusion=True)
+        monkeypatch.setattr(
+            jax, "live_arrays", lambda *a, **k: pytest.fail("live_arrays walked on the query path")
+        )
         _q1(sess.read_parquet(data)).collect()
-        assert REGISTRY.gauge("hs_device_peak_bytes", "").value > 0
+        gauge = REGISTRY.gauge("hs_device_peak_bytes", "")
+        # the CPU backend keeps no memory statistics: the series is absent
+        assert jax.local_devices()[0].memory_stats() is None
+        assert gauge.value is None
+        assert REGISTRY.snapshot()["hs_device_peak_bytes"]["series"] == []
+        assert "hs_device_peak_bytes{" not in REGISTRY.prometheus_text()
+
+        class Dev:
+            def __init__(self, peak):
+                self.peak = peak
+
+            def memory_stats(self):
+                return {"peak_bytes_in_use": self.peak, "bytes_in_use": 1}
+
+        # a backend that reports: the largest peak over the local devices
+        monkeypatch.setattr(jax, "local_devices", lambda: [Dev(1 << 20), Dev(3 << 20)])
+        assert stage_ir.device_peak_bytes() == 3 << 20
+        assert gauge.value == float(3 << 20)
+        assert "hs_device_peak_bytes 3.14573e+06" in REGISTRY.prometheus_text()
 
 
 # --------------------------------------------------------------------------

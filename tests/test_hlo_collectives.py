@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from hyperspace_tpu.check import hlo_lint
 from hyperspace_tpu.check.hlo_lint import (
     assert_contract,
     collective_counts,
@@ -88,7 +89,7 @@ class TestCompiledCollectives:
 
         v = _sharded(mesh, np.arange(n, dtype=np.float64))
         b = _sharded(mesh, (np.arange(n) % (2 * N_DEV)).astype(np.int32))
-        txt = jax.jit(run).lower(v, b).compile().as_text()
+        txt = jax.jit(hlo_lint.named("index-rebucket", run)).lower(v, b).compile().as_text()
         assert_contract("index-rebucket", txt, "rebucket")
 
     def test_hierarchical_is_two_all_to_alls(self):
@@ -105,7 +106,7 @@ class TestCompiledCollectives:
 
         v = jax.device_put(np.arange(n, dtype=np.float64), sh2)
         b = jax.device_put((np.arange(n) % (4 * N_DEV)).astype(np.int32), sh2)
-        txt = jax.jit(run).lower(v, b).compile().as_text()
+        txt = jax.jit(hlo_lint.named("hierarchical-exchange", run)).lower(v, b).compile().as_text()
         assert_contract("hierarchical-exchange", txt, "hierarchical exchange")
 
     def test_bucketed_join_has_no_data_collectives(self, mesh):
@@ -204,7 +205,7 @@ class TestShardedExecPrograms:
         dev = jax.device_put(
             np.arange(N_DEV * 16, dtype=np.int64), NamedSharding(mesh, P("buckets"))
         )
-        txt = shim_text_of(jax.jit(fn), {"a": dev}, (np.int64(3),))
+        txt = shim_text_of(jax.jit(hlo_lint.named("fused-filter", fn)), {"a": dev}, (np.int64(3),))
         assert_shuffle_free(txt, "sharded filter")
         assert_contract("fused-filter", txt, "sharded filter")
 
@@ -221,7 +222,10 @@ class TestShardedExecPrograms:
             (np.arange(N_DEV * 64) % 17).astype(np.int64),
             NamedSharding(mesh, P("buckets")),
         )
-        txt = hlo_text_of(jax.jit(prog), {"k": dev}, (), np.int64(N_DEV * 64), np.int64(0))
+        txt = hlo_text_of(
+            jax.jit(hlo_lint.named("sharded-grouped", prog)),
+            {"k": dev}, (), np.int64(N_DEV * 64), np.int64(0),
+        )
         got = collective_counts(txt)
         assert got["all-gather"] >= 1, got
         assert not verify_hlo("sharded-grouped", txt, "sharded grouped chunk")

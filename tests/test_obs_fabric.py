@@ -483,7 +483,7 @@ class TestFleetIdentity:
         )
 
 
-# --- device-program timing hooks ---------------------------------------------
+# --- device-program hooks: compile seconds, the span event, device-wait --------
 
 
 class TestDeviceProgramTiming:
@@ -504,14 +504,18 @@ class TestDeviceProgramTiming:
             _observe_program(family, False, t0)
         root.finish()
 
-        hist = REGISTRY.histogram("hs_device_program_seconds", program=family)
-        assert hist.count == 2
         total = REGISTRY.counter("hs_device_compile_seconds_total", program=family)
-        assert total.value > 0.0  # only the first-seen call contributed
+        first = total.value
+        assert first > 0.0  # the first-seen call's wall, compile-dominated
+        with spans.attach(root):
+            _observe_program(family, False, t0)
+        assert total.value == first  # a cached signature adds nothing
         events = [ev for sp in root.walk() for ev in (sp.events or [])]
-        kinds = [k for k, _ in events]
-        assert kinds.count("device-program") == 2
-        assert any("(compile)" in detail for _, detail in events)
+        assert [d for k, d in events if k == "device-program"] == [
+            f"{family} (compile)", family, family
+        ]  # the family alone: no host-clock milliseconds round an async dispatch
+        # the host-clock histogram this hook used to feed is gone
+        assert "hs_device_program_seconds" not in REGISTRY.snapshot()
 
     def test_fused_programs_observed_end_to_end(self, traced_sess):
         # the device filter only engages over index/file scans — give the
@@ -519,18 +523,22 @@ class TestDeviceProgramTiming:
         hst.Hyperspace(traced_sess).create_index(
             traced_sess.test_dataframe, hst.CoveringIndexConfig("obsFab", ["c1"], ["m"])
         )
-        base = REGISTRY.histogram(
-            "hs_device_program_seconds", program="fused-filter"
-        ).count
+        down = REGISTRY.counter("hs_d2h_bytes_total", site="filter-mask")
+        base = down.value
         traced_sess.conf.set(hst.keys.TPU_QUERY_DEVICE_MIN_ROWS, 0)
         try:
-            res = traced_sess.sql(
-                "SELECT m FROM t WHERE c1 > 10 AND c1 < 300"
-            ).collect()
+            with spans.trace("q") as root:
+                res = traced_sess.sql(
+                    "SELECT m FROM t WHERE c1 > 10 AND c1 < 300"
+                ).collect()
         finally:
             traced_sess.conf.set(hst.keys.TPU_QUERY_DEVICE_MIN_ROWS, 1 << 40)
         assert len(res["m"]) > 0
-        got = REGISTRY.histogram(
-            "hs_device_program_seconds", program="fused-filter"
-        ).count
-        assert got > base
+        # what replaced the histogram: the host's wait for the program is a
+        # device-wait span naming the family, under the tier that asked for it
+        waits = [sp for sp in root.walk() if sp.name == "device-wait"]
+        assert [sp.attrs["program"] for sp in waits] == ["fused-filter"]
+        assert waits[0].cat == "device" and waits[0].t1 is not None
+        mask_spans = root.find("filter-mask")
+        assert len(mask_spans) == 1 and waits[0] in list(mask_spans[0].walk())
+        assert down.value > base  # and the mask's bytes crossed the link

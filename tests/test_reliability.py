@@ -409,7 +409,10 @@ class TestTornLog:
 
 
 class TestPipelineTypedErrors:
-    def test_decode_fault_surfaces_typed_cancels_queue_leaks_no_spans(self, tmp_path):
+    def test_decode_fault_surfaces_typed_cancels_queue_leaks_no_spans(self, tmp_path, monkeypatch):
+        from concurrent.futures import ThreadPoolExecutor
+
+        from hyperspace_tpu.exec import pipeline
         from hyperspace_tpu.obs import spans
 
         data = _write_files(str(tmp_path / "data"), num_files=8, rows_per=2000)
@@ -421,6 +424,13 @@ class TestPipelineTypedErrors:
                 hst.keys.OBS_TRACING_ENABLED: True,
             },
         )
+        # the prefetch pool is 4 wide and the lookahead 2 deep: every submitted
+        # chunk starts at once and nothing is ever queued, so whether close()
+        # finds a future to cancel was a race between this thread and the
+        # pool's thread start-up. One worker makes the queue real: chunk 0
+        # fails, chunk 1 holds the worker for its 0.3 s stall, chunk 2 waits.
+        one_wide = ThreadPoolExecutor(max_workers=1, thread_name_prefix="hs-pipeline-test")
+        monkeypatch.setattr(pipeline, "_PIPELINE_POOL", one_wide)
         df = sess.read_parquet(data)
         q = df.filter(hst.col("c1") >= 0).select("c1", "c2")
 
@@ -443,6 +453,7 @@ class TestPipelineTypedErrors:
                 open_spans = [s for s in root.walk() if s is not root and s.t1 is None]
                 assert open_spans == []
             assert spans.current_span() is None
+        one_wide.shutdown(wait=True)
         assert counter_value("hs_pipeline_cancelled_total") > cancelled0
         assert counter_value(
             "hs_io_errors_total", op="io.decode", kind="corrupt", outcome="raised"
