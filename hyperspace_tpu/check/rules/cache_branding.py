@@ -1,19 +1,21 @@
-"""``cache-branding``: pruning provenance must reach the cache key.
+"""``cache-branding``: what the read kept must reach the cache key.
 
-Device-cache entries are branded by ``scan_key`` (immutable file-set
-identity, extended with the pushed row-group predicate via
-``_pruned_scan_key``). A call site that drops the branding kwarg doesn't
-fail — it silently caches under the unpruned key, so a later scan with a
-*different* pushed predicate reuses stale device buffers. This rule
-enforces the three call-site contracts:
+Device-cache entries are keyed by ``scan_key``: the immutable file-set
+identity, extended by ``_pruned_scan_key`` with the kept signature of the
+read that produced the batch (which row groups of which files survived;
+None for a whole read) — never with the predicate. A call site that drops
+the kwarg doesn't fail — it silently caches a pruned batch under the whole
+file set's key, so a later read that kept *other* rows reuses stale device
+buffers. This rule enforces the three call-site contracts:
 
-1. ``…._filter_mask(...)`` must pass ``pruned_by=`` explicitly,
+1. ``…._filter_mask(...)`` must pass ``kept=`` explicitly,
 2. ``device_filter_mask(...)`` must pass ``scan_key=`` (kwarg or the
    4th positional),
 3. ``stage_filter_columns(...)`` must pass ``scan_key`` likewise.
 
-``scan_key=None`` / ``pruned_by=None`` is fine — that is an explicit
-"transient batch, don't cache" decision, visible at the call site.
+``scan_key=None`` is fine — that is an explicit "transient batch, don't
+cache" decision — and so is ``kept=None``, an explicit "this batch is the
+whole file set", visible at the call site.
 """
 
 from __future__ import annotations
@@ -28,7 +30,7 @@ NAME = "cache-branding"
 
 # callee name -> (required kwarg, positional index that also satisfies it)
 _CONTRACTS = {
-    "_filter_mask": ("pruned_by", None),
+    "_filter_mask": ("kept", None),
     "device_filter_mask": ("scan_key", 3),
     "stage_filter_columns": ("scan_key", 3),
 }

@@ -656,6 +656,36 @@ def _device_cache_get(key):
     return _device_cache.get(key)
 
 
+# "hit" / "miss" -> counter of resident-column lookups, held as _LINK_BYTES is
+_COLUMN_LOOKUPS: dict = {}
+
+
+def resident_column(key, n: int):
+    """The ``(device array, codec, rows)`` resident under column key ``key``
+    (``(scan key, column, mesh fingerprint)``), or None when there is none
+    or it holds other than ``n`` rows; None for a None key (a batch with no
+    scan identity is never cached, and not counted). Every lookup counts in
+    ``hs_device_cache_lookups_total{result}``: a miss is an upload, unless
+    the predicate then proves to be outside the device language."""
+    if key is None:
+        return None
+    cached = _device_cache.get(key)
+    if cached is not None and cached[2] != n:
+        cached = None
+    result = "miss" if cached is None else "hit"
+    c = _COLUMN_LOOKUPS.get(result)
+    if c is None:
+        from hyperspace_tpu.obs.metrics import REGISTRY
+
+        c = _COLUMN_LOOKUPS[result] = REGISTRY.counter(
+            "hs_device_cache_lookups_total",
+            "Lookups of a scan column in the device-resident column cache, by result",
+            result=result,
+        )
+    c.inc()
+    return cached
+
+
 def _device_cache_put(key, value, nbytes: int) -> None:
     # overwrite semantics matter: a stale same-key entry (e.g. rows changed)
     # must be replaced, not pinned
@@ -676,8 +706,8 @@ def purge_device_cache_files(paths) -> int:
     (data-version commit invalidation); returns entries removed.
 
     Cache keys are ``(scan_key, col, mesh_fp)`` where scan_key is a tuple of
-    ``(path, mtime_ns, size)`` file triples (optionally suffixed with a
-    row-group-pruning marker), so a purge scans those leading triples.
+    ``(path, size, mtime_ns)`` file triples (suffixed, where the read pruned,
+    with the row groups it kept), so a purge scans those leading triples.
     """
     wanted = set(paths)
     if not wanted:
@@ -902,8 +932,8 @@ def device_filter_mask(session, batch: B.Batch, condition: Expr, scan_key=None, 
     missing: List[str] = []
     for r in refs:
         ckey = (scan_key, r, fp) if scan_key is not None else None
-        cached = _device_cache_get(ckey) if ckey is not None else None
-        if cached is not None and cached[2] == n:
+        cached = resident_column(ckey, n)
+        if cached is not None:
             dev_cols[r], codecs[r] = cached[0], cached[1]
         else:
             missing.append(r)
@@ -978,8 +1008,7 @@ def stage_filter_columns(session, batch: B.Batch, condition: Optional[Expr], sca
         with obs_spans.span("h2d-stage", cat="pipeline", rows=n):
             for r in cols:
                 ckey = (scan_key, r, fp)
-                cached = _device_cache_get(ckey)
-                if cached is not None and cached[2] == n:
+                if resident_column(ckey, n) is not None:
                     continue
                 dev, codec, nbytes = _put_encoded(session, mesh, sharding, n_dev, batch[r])
                 _device_cache_put(ckey, (dev, codec, n), nbytes)
@@ -1047,8 +1076,8 @@ def device_filtered_aggregate(
     codecs: Dict[str, ColumnCodec] = {}
     for r in sorted(set(refs) | set(agg_inputs)):
         ckey = (scan_key, r, fp) if scan_key is not None else None
-        cached = _device_cache_get(ckey) if ckey is not None else None
-        if cached is not None and cached[2] == n:
+        cached = resident_column(ckey, n)
+        if cached is not None:
             dev_cols[r], codecs[r] = cached[0], cached[1]
             continue
         arr, codec = encode_column(batch[r])
@@ -1592,8 +1621,8 @@ class GroupedAggStream:
         codecs: Dict[str, ColumnCodec] = {}
         for col in sorted(set(refs) | set(agg_inputs) | set(self.group_keys)):
             ckey = (scan_key, col, fp) if scan_key is not None else None
-            cached = _device_cache_get(ckey) if ckey is not None else None
-            if cached is not None and cached[2] == n:
+            cached = resident_column(ckey, n)
+            if cached is not None:
                 dev_cols[col], codecs[col] = cached[0], cached[1]
                 continue
             if col in agg_inputs and batch[col].dtype.kind in ("U", "S", "O"):

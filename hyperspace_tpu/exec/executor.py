@@ -43,20 +43,37 @@ _TOPK_RID = "__hs_topk_rid__"
 
 
 def _scan_identity(scan):
-    """Stable identity of a scan's file set for device-side caching: any
-    rewrite of a file (new index version, compaction) changes mtime/size and
-    naturally invalidates. Returns None (= don't cache) when any file can't
-    be stat'ed — a path-only key could serve stale device columns after an
-    in-place rewrite."""
+    """Stable identity of a scan's file set for device-side caching: the
+    ``(path, size, mtime)`` of every file, in scan order.
+
+    An IndexScan has it from its log entry, which recorded each data file's
+    size and mtime when the index version was committed. Index files are
+    written once; a refresh or optimize commits new files, a vacuumed and
+    rebuilt index other mtimes, and commits purge what they replace
+    (``purge_device_cache_files``) — so nothing is stat'ed per query. On a
+    lake's filesystem a stat is a round trip: 136 us a file on the 9p mount
+    of the benchmark's machine, 100 ms a lookup over 800 files.
+
+    Any other scan reads files that their owners may rewrite in place, so
+    each is stat'ed: a rewrite changes mtime/size and naturally invalidates.
+    Returns None (= don't cache) when a file can't be stat'ed — a path-only
+    key could serve stale device columns after an in-place rewrite."""
     import os
 
+    entry = getattr(scan, "entry", None)
+    if entry is not None:
+        committed = entry.content.file_keys()
+        try:
+            return tuple(committed[f] for f in scan.files)
+        except KeyError:
+            pass  # files of no committed version of this entry: stat them
     parts = []
     for f in scan.files:
         try:
             st = os.stat(f)
         except OSError:
             return None
-        parts.append((f, st.st_mtime_ns, st.st_size))
+        parts.append((f, st.st_size, st.st_mtime_ns))
     return tuple(parts)
 
 
@@ -94,12 +111,14 @@ def _read_files(
     partition_dtypes: Optional[dict] = None,
     format_options: Optional[dict] = None,
     predicate=None,
+    kept: Optional[list] = None,
 ) -> B.Batch:
     """Read ``files`` into one batch. ``partition_values`` ({file -> {col ->
     typed value}}) attaches hive-partition columns — constant per file, absent
     from the file bytes — to each file's rows. ``predicate`` (the scan's
     pushed-down filter, re-applied by the Filter above) enables parquet
-    row-group min/max pruning in the reader."""
+    row-group min/max pruning in the reader, which reports the groups it
+    kept in ``kept`` (io.read_parquet_batch)."""
     from hyperspace_tpu.exec.io import _decode_pool, read_parquet_batch
 
     if not files:
@@ -134,7 +153,7 @@ def _read_files(
             b: B.Batch = {}
             n = F.count_rows(f, file_format, format_options)
         elif file_format == "parquet":
-            b = read_parquet_batch([f], file_columns, predicate=predicate)
+            b = read_parquet_batch([f], file_columns, predicate=predicate, kept=kept)
             n = B.num_rows(b)
         else:
             b = B.table_to_batch(F.read_table(f, file_format, file_columns, format_options))
@@ -161,7 +180,7 @@ def _read_files(
             return B.concat(list(_decode_pool().map(spans.wrap(read_one), files)))
         return B.concat([read_one(f) for f in files])
     if file_format == "parquet":
-        return read_parquet_batch(list(files), columns, predicate=predicate)
+        return read_parquet_batch(list(files), columns, predicate=predicate, kept=kept)
     from hyperspace_tpu.sources import formats as F
 
     t = F.open_dataset(list(files), file_format, format_options).to_table(columns=columns)
@@ -413,14 +432,42 @@ def _chain_pushdown_condition(chain):
     return cond
 
 
-def _pruned_scan_key(key, pruned_by):
-    """Brand a device-cache scan key with the predicate whose row-group
-    pruning shaped the batch: two predicates can prune the same files to
-    EQUAL row counts but DIFFERENT rows, and the device cache's (key, col,
-    n_rows) check alone would alias them."""
-    if key is None or pruned_by is None:
+def _read_scan_files(scan, *args, **kwargs) -> B.Batch:
+    """``_read_files`` for a scan leaf under its pushed-down predicate. What
+    the read kept is left on the leaf for ``_kept_groups``: a leaf that
+    carries a predicate is this execution's own clone, read once."""
+    predicate = getattr(scan, "pushdown_predicate", None)
+    if predicate is None:
+        return _read_files(*args, **kwargs)
+    kept: list = []
+    batch = _read_files(*args, predicate=predicate, kept=kept, **kwargs)
+    # sorted: files decode concurrently and report in the order they finish
+    scan.kept_row_groups = tuple(sorted(kept)) or None
+    return batch
+
+
+def _kept_groups(scan):
+    """Kept signature of the batch that was read for ``scan``: None when it
+    holds every row of ``scan.files`` — nothing was pushed down, the reader
+    pruned nothing, or a cache that ignores the predicate answered — else
+    the ``(file, kept row groups)`` of the files the read pruned."""
+    return getattr(scan, "kept_row_groups", None)
+
+
+def _pruned_scan_key(key, kept):
+    """Device-cache key of a scan's batch: the scan identity ``key``, branded
+    with the read's kept signature (``_kept_groups``) and nothing else.
+
+    Invariant: two batches under one key hold the same rows in the same
+    order. The identity fixes the files, their versions and their order; a
+    read that pruned adds which row groups of which files survived, because
+    two predicates can prune the same files to EQUAL row counts but
+    DIFFERENT rows. A whole read shares the plain identity with every other
+    predicate, literal and call site over that file set, so its columns stay
+    resident on the device."""
+    if key is None or kept is None:
         return key
-    return key + (("rg-pred", str(pruned_by)),)
+    return key + (("rg-kept", kept),)
 
 
 def _rebuild_chain(chain, leaf: L.LogicalPlan) -> L.LogicalPlan:
@@ -827,9 +874,9 @@ class Executor:
         thunk, so prefetched chunks pick up whatever threshold the fold has
         reached by the time their decode starts (stale thresholds are merely
         conservative; pruning is row-group granularity and never row-exact).
-        H2D staging is disabled with a dynamic predicate: the branded scan
-        key changes per threshold, so staged columns would never be looked
-        up again."""
+        H2D staging stays off with a dynamic predicate, as it was when the
+        scan key carried the predicate's text; keyed on what each read kept
+        it could be turned on, which no test or benchmark cell covers yet."""
         conf = self.session.conf
         pushed = _chain_pushdown_condition(chain) if conf.rowgroup_pruning_enabled else None
         leaves, subs = [], []
@@ -886,7 +933,7 @@ class Executor:
         def stage(i, batch):
             if B.num_rows(batch) < conf.device_exec_min_rows:
                 return
-            key = _pruned_scan_key(_scan_identity(leaves[i]), pushed)
+            key = _pruned_scan_key(_scan_identity(leaves[i]), _kept_groups(leaves[i]))
             # stage onto the mesh the consumer will execute over, so the
             # sharded path's device-cache lookups (keyed by mesh fingerprint)
             # hit the columns placed here
@@ -979,7 +1026,8 @@ class Executor:
             ):
                 trace.record("scan", "bucket-cache-filescan")
                 return bucket_cache.read(list(plan.files), list(plan.columns))
-            return _read_files(
+            return _read_scan_files(
+                plan,
                 list(plan.files),
                 plan.file_format,
                 list(plan.columns),
@@ -987,7 +1035,6 @@ class Executor:
                 partition_values=plan.partition_values,
                 partition_dtypes=plan.partition_dtypes,
                 format_options=plan.format_options,
-                predicate=getattr(plan, "pushdown_predicate", None),
             )
 
         if isinstance(plan, L.IndexScan):
@@ -1000,12 +1047,8 @@ class Executor:
             if bucket_cache is not None and not with_file_names and plan.files:
                 batch = bucket_cache.read(list(plan.files), list(fcols))
             else:
-                batch = _read_files(
-                    list(plan.files),
-                    "parquet",
-                    list(fcols),
-                    with_file_names,
-                    predicate=getattr(plan, "pushdown_predicate", None),
+                batch = _read_scan_files(
+                    plan, list(plan.files), "parquet", list(fcols), with_file_names
                 )
             if plan.file_columns is not None:
                 # nested index columns are stored under their flat
@@ -1020,42 +1063,39 @@ class Executor:
 
         if isinstance(plan, L.Filter):
             rg_ok = self.session.conf.rowgroup_pruning_enabled
-            pushed = None
+            leaf = plan.child  # the node the batch is read for
             if isinstance(plan.child, L.Scan):
                 # partition pruning: conjuncts over partition columns decide
                 # per-file from path-derived values which files to read at all
                 # (Spark's PartitioningAwareFileIndex.listFiles role)
                 files = _prune_partitions(plan.child, plan.condition)
-                if rg_ok:
-                    pushed = plan.condition
                 child = self._exec_scan(
-                    plan.child, with_file_names, files=files, predicate=pushed
+                    plan.child,
+                    with_file_names,
+                    files=files,
+                    predicate=plan.condition if rg_ok else None,
                 )
-            else:
-                existing = getattr(plan.child, "pushdown_predicate", None)
-                if existing is not None:
-                    # a streamed leaf subset arrives with its pushdown already
-                    # attached (_stream_chunks); just execute it
-                    pushed = existing
-                    child = self._exec(plan.child, with_file_names)
-                elif (
-                    rg_ok
-                    and isinstance(plan.child, (L.FileScan, L.IndexScan))
-                    and id(plan.child) not in self._shared
-                ):
-                    # push the predicate down for row-group pruning on a CLONE:
-                    # the original node may be referenced by plan caches or
-                    # shared subtrees, which must keep full-read semantics
-                    import copy
+            elif getattr(plan.child, "pushdown_predicate", None) is not None:
+                # a streamed leaf subset arrives with its pushdown already
+                # attached (_stream_chunks); just execute it
+                child = self._exec(plan.child, with_file_names)
+            elif (
+                rg_ok
+                and isinstance(plan.child, (L.FileScan, L.IndexScan))
+                and id(plan.child) not in self._shared
+            ):
+                # push the predicate down for row-group pruning on a CLONE:
+                # the original node may be referenced by plan caches or
+                # shared subtrees, which must keep full-read semantics
+                import copy
 
-                    clone = copy.copy(plan.child)
-                    clone.pushdown_predicate = plan.condition
-                    pushed = plan.condition
-                    child = self._exec(clone, with_file_names)
-                else:
-                    child = self._exec(plan.child, with_file_names)
+                leaf = copy.copy(plan.child)
+                leaf.pushdown_predicate = plan.condition
+                child = self._exec(leaf, with_file_names)
+            else:
+                child = self._exec(plan.child, with_file_names)
             with spans.span("filter-mask", cat="exec"):
-                mask = self._filter_mask(plan, child, pruned_by=pushed)
+                mask = self._filter_mask(plan, child, kept=_kept_groups(leaf))
             with spans.span("filter-apply", cat="exec"):
                 return B.mask_rows(child, mask)
 
@@ -1255,10 +1295,10 @@ class Executor:
             ids.append(int(lit.value))
         return inner.child.name, ids
 
-    def _filter_mask(self, plan: L.Filter, child: B.Batch, pruned_by=None) -> np.ndarray:
+    def _filter_mask(self, plan: L.Filter, child: B.Batch, kept=None) -> np.ndarray:
         """Predicate evaluation: device path over index/file scans when the
-        session mesh is available, host numpy otherwise. ``pruned_by`` is the
-        predicate whose row-group pruning produced ``child``, if any."""
+        session mesh is available, host numpy otherwise. ``kept`` is the kept
+        signature of the read that produced ``child`` (``_kept_groups``)."""
         if self.session.conf.device_execution_enabled and isinstance(
             plan.child, (L.IndexScan, L.FileScan)
         ):
@@ -1279,7 +1319,7 @@ class Executor:
                             child,
                             col,
                             ids,
-                            scan_key=_pruned_scan_key(_scan_identity(plan.child), pruned_by),
+                            scan_key=_pruned_scan_key(_scan_identity(plan.child), kept),
                             parallel=px,
                         )
                         trace.record("filter", "device-lineage")
@@ -1300,7 +1340,7 @@ class Executor:
                         self.session,
                         child,
                         plan.condition,
-                        scan_key=_pruned_scan_key(_scan_identity(plan.child), pruned_by),
+                        scan_key=_pruned_scan_key(_scan_identity(plan.child), kept),
                         parallel=px,
                     )
                     trace.record("filter", "device-sharded" if px is not None else "device")
@@ -1356,7 +1396,7 @@ class Executor:
                 trace.record("agg", "streamed-partial")
                 return got
         if not with_file_names and self.session.conf.device_execution_enabled:
-            got, scan_batch, filter_node, pruned = self._try_device_aggregate(plan)
+            got, scan_batch, filter_node, kept = self._try_device_aggregate(plan)
             if got is not None:
                 trace.record(
                     "agg", "device-grouped-scan" if plan.keys else "device-fused-scan"
@@ -1367,7 +1407,7 @@ class Executor:
                 # instead of re-reading parquet on the host fallback
                 if filter_node is not None:
                     with spans.span("filter-mask", cat="exec"):
-                        mask = self._filter_mask(filter_node, scan_batch, pruned_by=pruned)
+                        mask = self._filter_mask(filter_node, scan_batch, kept=kept)
                     with spans.span("filter-apply", cat="exec"):
                         child = B.mask_rows(scan_batch, mask)
                 else:
@@ -1886,9 +1926,7 @@ class Executor:
                         trace.fallback("agg", "min-rows")
                         device_ok = False
                     else:
-                        key = _pruned_scan_key(
-                            _scan_identity(lf), getattr(lf, "pushdown_predicate", None)
-                        )
+                        key = _pruned_scan_key(_scan_identity(lf), _kept_groups(lf))
                         try:
                             stream.update(leaf_batch, fuse_cond, scan_key=key)
                             continue
@@ -2001,10 +2039,10 @@ class Executor:
         return {name: out[name] for name, _, _ in plan.aggs}
 
     def _try_device_aggregate(self, plan: L.Aggregate):
-        """Returns (result, scan_batch, filter_node, pruned_by): result=None
+        """Returns (result, scan_batch, filter_node, kept): result=None
         means the caller runs the host path — reusing scan_batch (the
         materialized scan, pre-filter) when it was already read for the gate.
-        ``pruned_by`` is the scan's attached row-group-pruning predicate; the
+        ``kept`` is the kept signature of that read (``_kept_groups``); the
         caller must thread it into any further device-cache use of
         scan_batch, or a pruned batch gets branded with an unpruned key."""
         conf = self.session.conf
@@ -2019,13 +2057,13 @@ class Executor:
             return None, None, None, None
         from hyperspace_tpu.exec import device as D
 
-        pruned = getattr(node, "pushdown_predicate", None)
         batch = self._exec(node, with_file_names=False)
+        kept = _kept_groups(node)
         if B.num_rows(batch) < conf.device_exec_min_rows:
             trace.fallback("agg", "min-rows")
-            return None, batch, filter_node, pruned
+            return None, batch, filter_node, kept
         condition = filter_node.condition if filter_node is not None else None
-        scan_key = _pruned_scan_key(_scan_identity(node), pruned)
+        scan_key = _pruned_scan_key(_scan_identity(node), kept)
         name = "agg-device-grouped-scan" if plan.keys else "agg-device-fused-scan"
         with spans.span(name, cat="exec") as tier:
             try:
@@ -2045,15 +2083,15 @@ class Executor:
                     got = D.device_filtered_aggregate(
                         self.session, batch, condition, plan.aggs, scan_key=scan_key
                     )
-                return got, batch, filter_node, pruned
+                return got, batch, filter_node, kept
             except D.GroupCapacityExceeded:
                 trace.fallback("agg", "spill")
                 tier.set(fallback="spill")
-                return None, batch, filter_node, pruned
+                return None, batch, filter_node, kept
             except D.DeviceUnsupported:
                 trace.fallback("agg", "unsupported")
                 tier.set(fallback="unsupported")
-                return None, batch, filter_node, pruned
+                return None, batch, filter_node, kept
 
     def _exec_join(self, plan: L.Join, with_file_names: bool) -> B.Batch:
         """Join tiers in order: bucketed SMJ (device or host spans), broadcast

@@ -421,6 +421,7 @@ def _native_rg_scan(
     schemas: List[pa.Schema],
     predicate,
     concat_key,
+    kept: Optional[list] = None,
 ) -> Optional[B.Batch]:
     """Decode a whole scan natively at row-group granularity; None when the
     scan can't be answered natively end to end (caller falls back to the
@@ -430,7 +431,9 @@ def _native_rg_scan(
     requested column decodes to one consistent dtype, and nothing about the
     scan is already cached. Row-group pruning applies per file with the same
     counter accounting as _read_row_groups; a pruned scan skips all cache
-    writes (a pruned batch under an unpruned key would poison later readers).
+    writes (a pruned batch under an unpruned key would poison later readers)
+    and reports the pruned files' surviving groups in ``kept``, as
+    read_parquet_batch documents.
     """
     from hyperspace_tpu import native
 
@@ -463,7 +466,9 @@ def _native_rg_scan(
             rerr.count_io_error("io.decode", exc, swallowed=True)
             _native_fallback_counter("io-error").inc()
             return None
-        return _native_rg_decode(files, cols, columns, hints, predicate, concat_key, handles)
+        return _native_rg_decode(
+            files, cols, columns, hints, predicate, concat_key, handles, kept
+        )
     finally:
         for h in handles:
             h.close()
@@ -477,6 +482,7 @@ def _native_rg_decode(
     predicate,
     concat_key,
     handles,
+    kept: Optional[list],
 ) -> Optional[B.Batch]:
     from hyperspace_tpu import native
 
@@ -520,12 +526,12 @@ def _native_rg_decode(
             else:
                 pruned_any = True
                 ks = keep
-                kept = set(ks)
+                kept_set = set(ks)
                 md = pq.read_metadata(f)
                 sk_bytes = sum(
                     md.row_group(i).total_byte_size
                     for i in range(h.num_row_groups)
-                    if i not in kept
+                    if i not in kept_set
                 )
                 scanned_c, skipped_c, bytes_c = _rg_counters()
                 scanned_c.inc(len(ks))
@@ -782,11 +788,21 @@ def _native_rg_decode(
             _io_cache_put(_io_cache_key(f, columns), {c: out[c][s:e] for c in cols})
         if concat_key is not None:
             _io_cache_put(concat_key, dict(out))
+    elif kept is not None:
+        # only now: a scan that gave up above is read again per file
+        kept.extend(
+            (f, tuple(ks))
+            for f, ks, skip in zip(files, per_file_keep, file_skip)
+            if skip is not None
+        )
     return out
 
 
 def read_parquet_batch(
-    files: List[str], columns: Optional[List[str]], predicate=None
+    files: List[str],
+    columns: Optional[List[str]],
+    predicate=None,
+    kept: Optional[list] = None,
 ) -> B.Batch:
     """Read ``columns`` of ``files`` into one concatenated batch, native-first.
 
@@ -799,6 +815,12 @@ def read_parquet_batch(
     pruning: groups its statistics definitively exclude are never decoded.
     The caller's Filter still applies the predicate, so a cached full-file
     batch (more rows) is always an acceptable answer.
+
+    ``kept`` (a list) is where the read reports which rows the batch holds:
+    it receives one ``(file, kept row groups)`` entry for every file this read
+    pruned and nothing for a file read whole, decoded or from the host cache.
+    It stays empty exactly when the batch is every row of ``files`` in order;
+    the executor keys resident device columns on it (_pruned_scan_key).
     """
     from hyperspace_tpu import native
 
@@ -926,7 +948,7 @@ def read_parquet_batch(
             _native_fallback_counter("schema-evolved").inc(len(missing))
 
     if not evolved and not any(b is not None for b in cached):
-        got = _native_rg_scan(files, columns, schemas, predicate, concat_key)
+        got = _native_rg_scan(files, columns, schemas, predicate, concat_key, kept)
         if got is not None:
             return got
 
@@ -940,7 +962,11 @@ def read_parquet_batch(
             if predicate is not None and f not in evolved:
                 keep = prune_row_groups(f, predicate)
                 if keep is not None:
-                    return _read_row_groups(f, columns, schema, keep, dsp)
+                    got = _read_row_groups(f, columns, schema, keep, dsp)
+                    if kept is not None:
+                        kept.append((f, tuple(keep)))
+                    return got
+
             def _decode() -> B.Batch:
                 if FAULTS.active:
                     FAULTS.check("io.decode", f)

@@ -39,7 +39,6 @@ from hyperspace_tpu.exec import batch as B
 from hyperspace_tpu.exec.device import (
     DeviceUnsupported,
     _cached_predicate_jit,
-    _device_cache_get,
     _device_cache_put,
     _mesh_fp,
     _note_compile,
@@ -50,6 +49,7 @@ from hyperspace_tpu.exec.device import (
     ensure_x64,
     fetch,
     put,
+    resident_column,
 )
 
 _hlo_lint.register_contract(
@@ -118,8 +118,8 @@ def lineage_delete_mask(
     # column residency: same key + value format as device_filter_mask, so
     # staging, predicate evaluation and lineage filtering share one entry
     ckey = (scan_key, column, fp) if scan_key is not None else None
-    cached = _device_cache_get(ckey) if ckey is not None else None
-    if cached is not None and cached[2] == n:
+    cached = resident_column(ckey, n)
+    if cached is not None:
         dev_col = cached[0]
     else:
         arr, codec = encode_column(col_np)

@@ -189,6 +189,15 @@ class Content:
             cached = self.__dict__["_file_infos"] = out
         return list(cached)
 
+    def file_keys(self) -> Dict[str, FileKey]:
+        """``FileInfo.key`` of every leaf file by its absolute name: what the
+        log recorded of each file when this content was committed (memoized
+        like ``file_infos``)."""
+        cached = self.__dict__.get("_file_keys")
+        if cached is None:
+            cached = self.__dict__["_file_keys"] = {fi.name: fi.key for fi in self.file_infos()}
+        return cached
+
     @property
     def total_size(self) -> int:
         cached = self.__dict__.get("_total_size")

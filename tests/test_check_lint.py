@@ -62,7 +62,7 @@ class TestRulePairs:
     def test_cache_branding_bad(self):
         found = lint_one(fixture("bad_branding.py"), "cache-branding")
         assert [f.line for f in found] == [7, 8, 9]
-        assert "pruned_by" in found[0].message
+        assert "'kept'" in found[0].message
         assert "scan_key" in found[1].message
 
     def test_cache_branding_clean(self):

@@ -181,6 +181,236 @@ class TestDeviceFilter:
         run_both(session, q)
 
 
+def _link_counters():
+    """(h2d bytes of scan columns, resident-column hits, misses) so far."""
+    from hyperspace_tpu.obs.metrics import REGISTRY
+
+    return (
+        REGISTRY.counter("hs_h2d_bytes_total", "", site="filter-cols").value,
+        REGISTRY.counter("hs_device_cache_lookups_total", "", result="hit").value,
+        REGISTRY.counter("hs_device_cache_lookups_total", "", result="miss").value,
+    )
+
+
+def _growth(before):
+    return tuple(a - b for a, b in zip(_link_counters(), before))
+
+
+def _column_keys(column):
+    """Scan keys under which ``column`` is resident in the device cache."""
+    return [k[0] for k in D._device_cache.keys() if len(k) == 3 and k[1] == column]
+
+
+class TestResidentColumnKey:
+    """A device-resident column is keyed by the rows of the batch it was made
+    from — the files and the row groups the read kept — never by the
+    predicate (executor._pruned_scan_key). Two batches under one key hold the
+    same rows in the same order."""
+
+    @pytest.fixture()
+    def indexed(self, session, hs, tmp_path):
+        root = tmp_path / "resident"
+        root.mkdir()
+        rng = np.random.default_rng(26)
+        n = 4000
+        pq.write_table(
+            pa.table(
+                {
+                    "k": rng.integers(0, 200, n).astype(np.int64),
+                    "v": np.arange(n, dtype=np.int64),
+                    "d": np.datetime64("2024-01-01") + rng.integers(0, 90, n).astype("timedelta64[D]"),
+                }
+            ),
+            root / "p.parquet",
+        )
+        session.conf.set(hst.keys.NUM_BUCKETS, 4)
+        df = session.read_parquet(str(root))
+        hs.create_index(df, hst.CoveringIndexConfig("resIdx", ["k"], ["v", "d"]))
+        session.enable_hyperspace()
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, True)
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_MIN_ROWS, 0)
+        df.create_or_replace_temp_view("resident_t")
+        D.clear_device_cache()
+        return df
+
+    @pytest.mark.parametrize("leaf", ["index-scan", "file-scan"])
+    def test_scan_identity_is_the_logs_for_an_index_and_stat_for_files(
+        self, session, indexed, tmp_path, monkeypatch, leaf
+    ):
+        """An IndexScan's identity is what its log entry recorded at commit,
+        with no stat per query; any other scan's files are stat'ed, so an
+        in-place rewrite changes the identity."""
+        import os
+
+        import hyperspace_tpu.exec.executor as E
+
+        stats = []
+        real_stat = os.stat
+        monkeypatch.setattr(os, "stat", lambda p, *a, **k: (stats.append(p), real_stat(p, *a, **k))[1])
+        if leaf == "index-scan":
+            plan = indexed.filter(col("k") == 90).select("v").optimized_plan()
+            (scan,) = [p for p in L.collect(plan, lambda x: True) if isinstance(p, L.IndexScan)]
+            del stats[:]
+            ident = E._scan_identity(scan)
+            assert stats == []
+            assert ident == tuple(fi.key for fi in scan.entry.content.file_infos())
+            assert [part[0] for part in ident] == list(scan.files)
+            # a scan over files its entry never committed falls back to stat
+            stray = L.IndexScan(scan.entry, ["k"], None, files=[scan.files[0], __file__])
+            assert E._scan_identity(stray)[1][0] == __file__ and stats == list(stray.files)
+        else:
+            f = str(tmp_path / "plain.parquet")
+            pq.write_table(pa.table({"x": np.arange(10, dtype=np.int64)}), f)
+            scan = L.FileScan([f], "parquet", ["x"])
+            before = E._scan_identity(scan)
+            assert stats == [f] and before[0][0] == f
+            pq.write_table(pa.table({"x": np.arange(11, dtype=np.int64)}), f)
+            assert E._scan_identity(scan) != before
+            os.remove(f)
+            assert E._scan_identity(scan) is None
+
+    @pytest.mark.parametrize("how", ["bare-session", "query-server"])
+    def test_second_literal_finds_the_column_resident(self, session, indexed, how):
+        """Two literals over one index scan: every bucket file spans the key
+        range, so the bare session's row-group pruning keeps everything, and
+        the served path's bucket cache never prunes; both share the plain
+        key, and only the first query uploads."""
+        from hyperspace_tpu.serving import QueryServer
+
+        pdf = indexed.collect()
+
+        def ask(run, lit_value):
+            before = _link_counters()
+            got = run(lit_value)
+            want = np.sort(pdf["v"][pdf["k"] == lit_value])
+            np.testing.assert_array_equal(np.sort(got["v"]), want)
+            return _growth(before)
+
+        def check(run):
+            up, hits, misses = ask(run, 90)
+            assert up > 0 and (hits, misses) == (0, 1)
+            for lit_value in (91, 117):
+                assert ask(run, lit_value) == (0, 1, 0)
+            (key,) = _column_keys("k")
+            assert all(len(part) == 3 and part[0].endswith(".parquet") for part in key)
+
+        if how == "bare-session":
+            check(lambda v: indexed.filter(col("k") == v).select("v").collect())
+        else:
+            with QueryServer(session, workers=2) as srv:
+                check(lambda v: srv.query(f"SELECT v FROM resident_t WHERE k = {v}"))
+
+    @pytest.mark.parametrize("reader", ["native-rg-scan", "per-file"])
+    def test_equal_counts_different_rows_never_alias(self, session, tmp_path, monkeypatch, reader):
+        """The case the brand exists for: two predicates prune one file to
+        500 rows each — different rows. They get different keys and right
+        answers; two predicates that keep the same groups share a column;
+        a whole read has the plain key. Both readers of exec/io.py report
+        what they kept. The control at the end shows the scenario bites:
+        keyed on the file set alone it answers wrongly."""
+        import hyperspace_tpu.exec.executor as E
+
+        if reader == "per-file":
+            monkeypatch.setenv("HS_NATIVE_RG", "0")
+
+        f = str(tmp_path / "two_groups.parquet")
+        pq.write_table(
+            pa.table(
+                {
+                    "x": np.arange(1000, dtype=np.int64),
+                    "v": np.arange(1000, dtype=np.int64) * 7,
+                }
+            ),
+            f,
+            row_group_size=500,
+        )
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, True)
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_MIN_ROWS, 0)
+
+        def ask(lo, hi):
+            from hyperspace_tpu.exec import io as IO
+
+            IO.clear_io_cache()  # a host-cached whole file would answer unpruned
+            cond = (col("x") >= lit(lo)) & (col("x") < lit(hi))
+            plan = L.Filter(cond, L.FileScan([f], "parquet", ["x", "v"]))
+            return np.sort(E.Executor(session).execute(plan)["v"])
+
+        D.clear_device_cache()
+        np.testing.assert_array_equal(ask(10, 20), np.arange(10, 20) * 7)
+        np.testing.assert_array_equal(ask(510, 520), np.arange(510, 520) * 7)
+        first, second = _column_keys("x")
+        assert first[:-1] == second[:-1] == E._scan_identity(L.FileScan([f], "parquet", ["x"]))
+        assert {first[-1], second[-1]} == {("rg-kept", ((f, (0,)),)), ("rg-kept", ((f, (1,)),))}
+        # another predicate that keeps group 0 shares its column
+        before = _link_counters()
+        np.testing.assert_array_equal(ask(100, 400), np.arange(100, 400) * 7)
+        assert _growth(before) == (0, 1, 0)
+        # a read that prunes nothing has the plain key
+        np.testing.assert_array_equal(ask(400, 600), np.arange(400, 600) * 7)
+        assert E._scan_identity(L.FileScan([f], "parquet", ["x"])) in _column_keys("x")
+
+        # control: the file set alone as the key aliases the two 500-row batches
+        monkeypatch.setattr(E, "_pruned_scan_key", lambda key, kept: key)
+        D.clear_device_cache()
+        ask(10, 20)
+        assert ask(510, 520).size == 0  # evaluated over rows 0..499: wrong
+        D.clear_device_cache()
+
+    @pytest.mark.parametrize("how", ["purge", "refresh"])
+    def test_purge_and_refresh_drop_the_resident_column(self, session, hs, indexed, tmp_path, how):
+        q = indexed.filter(col("k") == 90).select("v")
+        first = q.collect()
+        (key,) = _column_keys("k")
+        files = [part[0] for part in key]
+        if how == "purge":
+            # a key that carries a kept signature is found by its file triples too
+            D._device_cache_put((key + (("rg-kept", ((files[0], (0,)),)),), "k", "fp"), ("a", None, 1), 8)
+            assert D.purge_device_cache_files([files[0]]) == 2
+            assert _column_keys("k") == []
+            before = _link_counters()
+            assert_batches_equal(q.collect(), first)
+            up, hits, misses = _growth(before)
+            assert up > 0 and (hits, misses) == (0, 1)
+        else:
+            pq.write_table(
+                pa.table(
+                    {
+                        "k": np.full(5, 90, dtype=np.int64),
+                        "v": np.arange(10_000, 10_005, dtype=np.int64),
+                        "d": np.full(5, np.datetime64("2024-06-01")),
+                    }
+                ),
+                tmp_path / "resident" / "p1.parquet",
+            )
+            hs.refresh_index("resIdx", "full")
+            q2 = session.read_parquet(str(tmp_path / "resident")).filter(col("k") == 90).select("v")
+            before = _link_counters()
+            got = q2.collect()
+            assert B.num_rows(got) == B.num_rows(first) + 5
+            assert _growth(before)[2] == 1  # new files, new key: the old column is not served
+            assert not set(files) & {part[0] for k in _column_keys("k") for part in k}
+
+    def test_range_aggregate_fallback_shares_the_filters_key(self, session, indexed):
+        """max over a datetime column is outside the fused aggregate program,
+        so Aggregate falls back to _filter_mask over the batch its gate read:
+        same scan, same kept signature, the column the plain filter left."""
+        from hyperspace_tpu.exec import trace
+
+        cond = (col("k") >= 50) & (col("k") < 60)
+        indexed.filter(cond).select("v").collect()
+        before = _link_counters()
+        with trace.recording() as events:
+            got = indexed.filter((col("k") >= 70) & (col("k") < 80)).agg(
+                last=("d", "max"), n=("*", "count")
+            ).collect()
+        assert ("agg", "device-fused-scan") not in events and ("filter", "device") in events
+        assert _growth(before) == (0, 1, 0)
+        assert len(_column_keys("k")) == 1
+        pdf = indexed.collect()
+        sel = (pdf["k"] >= 70) & (pdf["k"] < 80)
+        assert int(got["n"][0]) == int(sel.sum()) and got["last"][0] == pdf["d"][sel].max()
+
+
 class TestDeviceJoin:
     @pytest.fixture()
     def two_tables(self, tmp_path):

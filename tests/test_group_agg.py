@@ -373,19 +373,23 @@ class TestFallbacks:
 
 class TestPrunedScanBranding:
     """Regression: a row-group-pruned scan batch must be cached under a key
-    branded with the pruning predicate. Two predicates can prune the same
+    branded with what the read kept. Two predicates can prune the same
     scan to EQUAL row counts but DIFFERENT rows; an unbranded key aliases
-    them in the device column cache."""
+    them in the device column cache. The predicate itself never reaches
+    the key: a whole read shares one key whatever was asked."""
 
     def test_pruned_key_distinct(self):
         from hyperspace_tpu.exec.executor import _pruned_scan_key
 
-        base = (("files", ("a.parquet",)),)
-        a = _pruned_scan_key(base, hst.col("x") < 5)
-        b = _pruned_scan_key(base, hst.col("x") >= 5)
+        base = (("a.parquet", 1, 2), ("b.parquet", 3, 4))
+        a = _pruned_scan_key(base, (("a.parquet", (0,)),))
+        b = _pruned_scan_key(base, (("a.parquet", (1,)),))
         assert a != b != base and a != base
+        # the same kept groups, whichever predicate kept them: the same key
+        assert a == _pruned_scan_key(base, (("a.parquet", (0,)),))
+        assert a[: len(base)] == base  # purge_device_cache_files finds the files
         assert _pruned_scan_key(base, None) == base
-        assert _pruned_scan_key(None, hst.col("x") < 5) is None
+        assert _pruned_scan_key(None, (("a.parquet", (0,)),)) is None
 
     def test_same_count_different_rows_no_aliasing(self, session, tmp_path):
         """Two streamed grouped aggregates over the SAME files whose pushdown

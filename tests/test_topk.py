@@ -314,11 +314,11 @@ class TestEarlyLimit:
         lock = threading.Lock()
         real = hio.read_parquet_batch
 
-        def slow(files, columns, predicate=None):
+        def slow(files, columns, **kw):
             with lock:
                 calls.append(list(files))
             time.sleep(0.15)  # keep later futures queued behind the pool
-            return real(files, columns, predicate=predicate)
+            return real(files, columns, **kw)
 
         cancelled = REGISTRY.counter("hs_pipeline_cancelled_total", "")
         c0 = cancelled.value
