@@ -61,6 +61,29 @@ _BUILD_SOURCE_BYTES = REGISTRY.counter(
 # eight seconds a second; the pool's wall is the take-write stage
 _TAKE_SECONDS = spans.stage_seconds("take", "build")
 _WRITE_SECONDS = spans.stage_seconds("write", "build")
+# the mesh build's exchange: whether ``bucket % n_devices`` and
+# ``tpu.rebucket.capacityFactor`` fit the data
+_EXCHANGE_RETRIES = REGISTRY.counter(
+    "hs_build_exchange_retries_total",
+    "Re-runs of the mesh build's exchange at doubled slot capacity (skew overflow)",
+)
+_EXCHANGE_SLOTS = {
+    kind: REGISTRY.counter(
+        "hs_build_exchange_slots_total",
+        "Slots the mesh build's exchange downloaded: valid = rows that arrived, "
+        "shipped = n_devices^2 x capacity a chunk",
+        kind=kind,
+    )
+    for kind in ("valid", "shipped")
+}
+
+
+def _exchange_rows(device: int):
+    return REGISTRY.counter(
+        "hs_build_exchange_rows_total",
+        "Valid rows each device of the mesh ended up owning after the build's exchange",
+        device=str(device),
+    )
 
 #: Version of the bucket hash function the index's data files were
 #: partitioned with. Bumped whenever ops/hashing changes bucket placement
@@ -644,6 +667,7 @@ def write_bucketed(
                         f"(capacity={capacity}, per_dev={per_dev})"
                     )
                 capacity = min(_next_pow2(capacity * 2), _next_pow2(per_dev))
+                _EXCHANGE_RETRIES.inc()
                 dev_keys, dev_hashes, kinds, row_idx, cn = state["retry"]
                 bkts, ridx, vld, ovf = distributed_bucket_sort_build(
                     mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
@@ -658,12 +682,15 @@ def write_bucketed(
 
         n_dev = state["n_dev"]
         shard_len = bkts_np.shape[0] // n_dev
+        _EXCHANGE_SLOTS["shipped"].inc(vld_np.shape[0])
+        _EXCHANGE_SLOTS["valid"].inc(int(vld_np.sum()))
         futures = []
         with spans.stage("take-write", "build"), ThreadPoolExecutor(max_workers=8) as ex:
             for d in range(n_dev):
                 sl = slice(d * shard_len, (d + 1) * shard_len)
                 v_d = vld_np[sl]
                 nv = int(v_d.sum())  # valid rows sort to the shard's prefix
+                _exchange_rows(d).inc(nv)
                 if nv == 0:
                     continue
                 b_v = bkts_np[sl][:nv]

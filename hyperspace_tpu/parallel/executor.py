@@ -8,9 +8,10 @@ switch from GSPMD jit to the explicit ``shard_map`` programs in
 
 Gating (``ShardedExecutor.maybe``): ``hyperspace.parallel.enabled`` is the
 default-off master switch — when off, ``maybe`` returns None and every caller
-falls through to the byte-identical single-device path. The mesh spans
-``hyperspace.parallel.mesh.devices`` devices (0 = all local devices) on the
-session's bucket axis; chunks below ``hyperspace.parallel.minRows`` rows stay
+falls through to the byte-identical single-device path. The mesh is the
+session's (``Session.mesh``: ``hyperspace.parallel.mesh.devices`` devices, 0 =
+all local devices, on the session's bucket axis), the same the index build's
+exchange runs over; chunks below ``hyperspace.parallel.minRows`` rows stay
 on the single-device path even when the switch is on (per-shard padding and
 the collective merge would dominate).
 
@@ -24,7 +25,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
-from hyperspace_tpu.parallel.mesh import make_mesh, mesh_fingerprint
+from hyperspace_tpu.parallel.mesh import mesh_fingerprint
 
 
 class ShardedExecutor:
@@ -33,8 +34,7 @@ class ShardedExecutor:
     def __init__(self, session, mesh=None):
         conf = session.conf
         if mesh is None:
-            n = conf.parallel_mesh_devices
-            mesh = make_mesh(n if n > 0 else None, axis=conf.mesh_axis)
+            mesh = session.mesh  # the one mesh of the session: the build's too
         self.session = session
         self.mesh = mesh
         self.axis = mesh.axis_names[0]
@@ -57,17 +57,17 @@ class ShardedExecutor:
     @classmethod
     def maybe(cls, session) -> Optional["ShardedExecutor"]:
         """The session's executor, or None when ``hyperspace.parallel.enabled``
-        is off. Memoized on the session per mesh-shaping conf so repeated
-        queries reuse one mesh (and its jit/device caches)."""
-        conf = session.conf
-        if not conf.parallel_enabled:
+        is off. Memoized on the session per session mesh, so repeated queries
+        reuse one executor (and its jit/device caches) until the mesh-shaping
+        conf or ``set_mesh`` gives the session another mesh."""
+        if not session.conf.parallel_enabled:
             return None
-        key = (conf.parallel_mesh_devices, conf.mesh_axis)
+        mesh = session.mesh
         cached = getattr(session, "_parallel_executor", None)
-        if cached is not None and cached[0] == key:
+        if cached is not None and cached[0] is mesh:
             return cached[1]
-        px = cls(session)
-        session._parallel_executor = (key, px)
+        px = cls(session, mesh)
+        session._parallel_executor = (mesh, px)
         return px
 
     def rows_ok(self, n_rows: int) -> bool:

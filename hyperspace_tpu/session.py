@@ -72,6 +72,7 @@ class Session:
         self._index_manager = None
         self._lifecycle_bus = None
         self._mesh = None
+        self._mesh_key = None
         self._temp_views: Dict[str, Any] = {}
         # most recent QueryProfile from a traced collect() (obs tracing on)
         self._last_profile = None
@@ -285,20 +286,26 @@ class Session:
     # --- device mesh --------------------------------------------------------
     @property
     def mesh(self):
-        """Lazily created 1-D device mesh over all local devices; the axis name
-        comes from conf ``hyperspace.tpu.mesh.axis``."""
-        if self._mesh is None:
-            import jax
-            from jax.sharding import Mesh
-            import numpy as np
+        """The session's 1-D device mesh, the one the index build and the
+        sharded query programs (``parallel/executor.py``) both run over; the
+        axis name comes from conf ``hyperspace.tpu.mesh.axis``. It spans all
+        local devices, or with ``hyperspace.parallel.enabled`` the first
+        ``hyperspace.parallel.mesh.devices`` of them (0 = all). Built lazily,
+        and again when those keys change; a mesh given to ``set_mesh`` stays."""
+        conf = self.conf
+        n = conf.parallel_mesh_devices if conf.parallel_enabled else 0
+        key = (n, conf.mesh_axis)
+        if self._mesh is None or self._mesh_key not in (None, key):
+            from hyperspace_tpu.parallel.mesh import make_mesh
 
-            devices = np.array(jax.devices())
-            self._mesh = Mesh(devices, (self.conf.mesh_axis,))
+            self._mesh = make_mesh(n if n > 0 else None, axis=key[1])
+            self._mesh_key = key
             self._note_mesh(self._mesh)
         return self._mesh
 
     def set_mesh(self, mesh) -> "Session":
         self._mesh = mesh
+        self._mesh_key = None  # pinned: conf no longer shapes it
         self._note_mesh(mesh)
         return self
 
