@@ -40,8 +40,9 @@ def _session(root, num_buckets=64):
         conf={
             hst.keys.SYSTEM_PATH: sysd,
             hst.keys.NUM_BUCKETS: num_buckets,
-            # equality/IN filters on the indexed column read only their hash
-            # bucket's files (same knob as the reference's useBucketSpec)
+            # filter-rule scans advertise the index's bucket spec (equality/IN
+            # filters on the indexed column read only their hash bucket's
+            # files with or without it)
             hst.keys.FILTER_RULE_USE_BUCKET_SPEC: True,
         }
     )

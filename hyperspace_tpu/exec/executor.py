@@ -470,6 +470,37 @@ def _pruned_scan_key(key, kept):
     return key + (("rg-kept", kept),)
 
 
+_BUCKET_PRUNE_COUNTERS: dict = {}
+
+
+def _bucket_pruned(scan, count: bool = False) -> bool:
+    """True when ``scan`` is an IndexScan narrowed to the buckets an
+    equality's literal hashes to (``rules/utils.prune_index_buckets``). Its
+    filter and aggregate are answered on the host, whatever
+    ``deviceMinRows`` says: those rows are not among the device-resident
+    columns (keyed on whole reads), every bucket has a row count of its own
+    (a first-seen program shape), and comparing one bucket is microseconds
+    of host work. With ``count`` the scan, read under a Filter, is counted
+    in ``hs_index_bucket_prune_total{result=pruned|full}``."""
+    if not isinstance(scan, L.IndexScan):
+        return False
+    pruned = scan.pruned_buckets is not None
+    if count:
+        result = "pruned" if pruned else "full"
+        c = _BUCKET_PRUNE_COUNTERS.get(result)
+        if c is None:
+            from hyperspace_tpu.obs.metrics import REGISTRY
+
+            c = _BUCKET_PRUNE_COUNTERS[result] = REGISTRY.counter(
+                "hs_index_bucket_prune_total",
+                "Index scans read under a Filter, by whether an equality on the "
+                "bucket column narrowed them to its buckets",
+                result=result,
+            )
+        c.inc()
+    return pruned
+
+
 def _rebuild_chain(chain, leaf: L.LogicalPlan) -> L.LogicalPlan:
     """Clone the row-wise wrappers over a replacement leaf (bottom-up)."""
     node = leaf
@@ -1089,10 +1120,12 @@ class Executor:
                 # shared subtrees, which must keep full-read semantics
                 import copy
 
+                _bucket_pruned(plan.child, count=True)
                 leaf = copy.copy(plan.child)
                 leaf.pushdown_predicate = plan.condition
                 child = self._exec(leaf, with_file_names)
             else:
+                _bucket_pruned(plan.child, count=True)
                 child = self._exec(plan.child, with_file_names)
             with spans.span("filter-mask", cat="exec"):
                 mask = self._filter_mask(plan, child, kept=_kept_groups(leaf))
@@ -1299,8 +1332,10 @@ class Executor:
         """Predicate evaluation: device path over index/file scans when the
         session mesh is available, host numpy otherwise. ``kept`` is the kept
         signature of the read that produced ``child`` (``_kept_groups``)."""
-        if self.session.conf.device_execution_enabled and isinstance(
-            plan.child, (L.IndexScan, L.FileScan)
+        if (
+            self.session.conf.device_execution_enabled
+            and isinstance(plan.child, (L.IndexScan, L.FileScan))
+            and not _bucket_pruned(plan.child)
         ):
             # hybrid-scan lineage delete filter: fused device anti-semi-join
             # instead of the general predicate path (which has no IN support)
@@ -2057,8 +2092,11 @@ class Executor:
             return None, None, None, None
         from hyperspace_tpu.exec import device as D
 
+        pruned = _bucket_pruned(node, count=filter_node is not None)
         batch = self._exec(node, with_file_names=False)
         kept = _kept_groups(node)
+        if pruned:
+            return None, batch, filter_node, kept
         if B.num_rows(batch) < conf.device_exec_min_rows:
             trace.fallback("agg", "min-rows")
             return None, batch, filter_node, kept

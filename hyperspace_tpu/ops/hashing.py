@@ -13,7 +13,7 @@ HS/index/covering/CoveringIndexRuleUtils.scala:357-417 on-the-fly re-bucketing).
 from __future__ import annotations
 
 import hashlib
-from typing import List, Sequence
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -126,16 +126,32 @@ def numeric_hash32(arr: np.ndarray) -> np.ndarray:
     return ((bits ^ (bits >> np.uint64(32))) & np.uint64(0xFFFFFFFF)).astype(np.uint32)
 
 
-def literal_hash32(value) -> np.uint32:
-    """Hash input of a scalar literal — used for query-time bucket pruning
+def bucket_of_key_literal(value, kind: str, num_buckets: int) -> Optional[int]:
+    """Bucket that holds every row whose single bucket column equals
+    ``value``, or None where that cannot be told. The literal is hashed as
+    the comparison sees it (``plan/expr._coerce_compare``), which needs the
+    column's ``kind``: ``"num"`` (integer, float, boolean: the hash is
+    value-consistent across them), ``"str"``, or a ``datetime64[unit]``
+    dtype string (a date string or a coarser/finer datetime is cast to the
+    column's unit first, as the comparison casts it). A NULL, a NaT, or a
+    literal of a kind the column is not compared in as-is gives None
     (ref: FilterIndexRule useBucketSpec, HS/index/covering/FilterIndexRule.scala:162-167)."""
-    if isinstance(value, str):
-        return string_hash32(value)
-    arr = np.asarray([value])
-    return numeric_hash32(arr)[0]
-
-
-def bucket_of_literals(values: List, num_buckets: int) -> int:
-    """Bucket id for one composite key tuple (one value per bucket column)."""
-    inputs = [np.asarray([literal_hash32(v)], dtype=np.uint32) for v in values]
-    return int(bucket_ids_np(inputs, num_buckets)[0])
+    if value is None:
+        return None
+    if kind == "str":
+        if not isinstance(value, str):
+            return None
+        h = string_hash32(value)
+    elif kind == "num":
+        if not isinstance(value, (bool, int, float, np.number, np.bool_)):
+            return None
+        h = numeric_hash32(np.asarray([value]))[0]
+    else:
+        try:
+            arr = np.asarray([value]).astype(np.dtype(kind))
+        except (TypeError, ValueError, OverflowError):
+            return None
+        if np.isnat(arr[0]):
+            return None
+        h = numeric_hash32(arr)[0]
+    return int(bucket_ids_np([np.asarray([h], dtype=np.uint32)], num_buckets)[0])

@@ -328,9 +328,13 @@ class FileScan(LogicalPlan):
 class IndexScan(LogicalPlan):
     """Scan of covering-index data files instead of source files.
 
-    ``pruned_buckets`` — when bucket pruning applies (selective equality
-    predicate on the first indexed column), only those buckets' files are read
-    (ref: FilterIndexRule's useBucketSpec path,
+    ``bucket_key`` — ``(column, numBuckets, kind)`` when an equality on that
+    one column decides the bucket a row lives in (single bucket column, data
+    files hashed under the current hash version, no hybrid scan); None
+    otherwise. The rules set it and prune nothing: ``pruned_buckets`` and the
+    narrowed ``files`` come from ``rules/utils.prune_index_buckets`` once the
+    literal of the ``Filter`` above is bound, so a plan-cache template stays
+    free of any literal (ref: FilterIndexRule's useBucketSpec path,
     HS/index/covering/FilterIndexRule.scala:162-167).
     """
 
@@ -342,12 +346,14 @@ class IndexScan(LogicalPlan):
         files: Optional[List[str]] = None,
         pruned_buckets: Optional[List[int]] = None,
         file_columns: Optional[List[str]] = None,
+        bucket_key: Optional[Tuple[str, int, str]] = None,
     ):
         self.entry = entry
         self.columns = list(columns)
         self.bucket_spec = bucket_spec
         self.files = files if files is not None else entry.content.files
         self.pruned_buckets = pruned_buckets
+        self.bucket_key = bucket_key
         # parallel to ``columns``: the flat column names inside the index
         # parquet files when they differ from the output names (nested fields
         # are stored under their __hs_nested.-prefixed flat name)

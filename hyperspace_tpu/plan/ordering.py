@@ -41,8 +41,8 @@ def index_sort_order(leaf: L.LogicalPlan) -> List[Tuple[str, bool]]:
     """The within-bucket physical ordering an IndexScan's files carry:
     ascending over ``bucket_spec.sort_columns``, or [] when unknown.
 
-    A plan-level ``bucket_spec`` is only attached under ``useBucketSpec``
-    (it gates bucket *pruning*), but the data files are written sorted either
+    A filter-rule scan advertises a plan-level ``bucket_spec`` only under
+    ``useBucketSpec``, but the data files are written sorted either
     way — so fall back to the log entry's own spec. Sortedness is advisory
     here regardless: the executor verifies every run and stable-repairs
     disagreement, so a wrong answer is impossible, only a slower merge."""

@@ -140,7 +140,14 @@ class ApplyHyperspace:
             # nothing rewritten — hand back the untouched user plan so explain
             # shows no spurious diff and execution shape is unchanged
             return original, 0
-        return (new_plan if score > 0 else plan), score + sub_score
+        if score > 0:
+            # the literals of a concrete query are known here: narrow every
+            # equality on a bucket column to its bucket (a plan-cache
+            # template is re-pruned for each request's literals at bind)
+            from hyperspace_tpu.rules.utils import prune_index_buckets
+
+            plan = prune_index_buckets(new_plan)
+        return plan, score + sub_score
 
     # --- subquery recursion ------------------------------------------------
     def _rewrite_subqueries(self, plan: L.LogicalPlan) -> Tuple[L.LogicalPlan, int]:

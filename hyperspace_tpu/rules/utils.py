@@ -4,7 +4,8 @@
 Two rewrite shapes:
 
   1. index-only scan — swap the source Scan for an IndexScan over the index's
-     bucket files, optionally bucket-pruned (ref: :98-130);
+     bucket files (ref: :98-130); ``prune_index_buckets`` narrows it to the
+     buckets an equality's bound literal hashes to;
   2. Hybrid Scan — index data + appended source files re-bucketed on the fly,
      merged with BucketUnion; rows from deleted source files are filtered out
      via the lineage column (ref: :146-288).
@@ -108,29 +109,108 @@ def hybrid_thresholds_ok(ctx: RuleContext, entry: IndexLogEntry, scan: L.Scan) -
     return True
 
 
+def index_bucket_key(index: CoveringIndex) -> Optional[Tuple[str, int, str]]:
+    """``IndexScan.bucket_key`` of ``index``: (bucket column, numBuckets,
+    kind) when one column decides a row's bucket and its type is one whose
+    literals hash as they compare (``ops/hashing.bucket_of_key_literal``);
+    None otherwise (two bucket columns, a decimal or nested key, ...)."""
+    import pyarrow as pa
+
+    from hyperspace_tpu.plan.expr import strip_nested_prefix
+    from hyperspace_tpu.sources import schema as schema_codec
+
+    indexed = index.indexed_columns
+    if len(indexed) != 1 or not index.schema_json:
+        return None
+    want = strip_nested_prefix(indexed[0]).lower()
+    t = next(
+        (
+            f.type
+            for f in schema_codec.schema_from_json(index.schema_json)
+            if strip_nested_prefix(f.name).lower() == want
+        ),
+        None,
+    )
+    if t is None:
+        return None
+    if pa.types.is_integer(t) or pa.types.is_floating(t) or pa.types.is_boolean(t):
+        kind = "num"
+    elif pa.types.is_string(t) or pa.types.is_large_string(t):
+        kind = "str"
+    elif pa.types.is_date32(t) or (pa.types.is_timestamp(t) and t.tz is None):
+        kind = str(schema_codec.arrow_to_numpy_dtype(t))
+    else:
+        return None
+    return indexed[0], index.num_buckets, kind
+
+
 def pruned_buckets_for_predicate(
-    condition: Optional[Expr], bucket_columns: Tuple[str, ...], num_buckets: int
+    condition: Expr, bucket_key: Tuple[str, int, str]
 ) -> Optional[List[int]]:
     """Bucket pruning: an equality (or IN) conjunct on the single bucket
-    column narrows the scan to specific buckets
+    column narrows the scan to the buckets its literals hash to; None when
+    no conjunct does, or a literal's bucket cannot be told
     (ref: FilterIndexRule useBucketSpec, HS/index/covering/FilterIndexRule.scala:162-167)."""
-    from hyperspace_tpu.ops.hashing import bucket_of_literals
+    from hyperspace_tpu.ops.hashing import bucket_of_key_literal
     from hyperspace_tpu.plan.expr import strip_nested_prefix
 
-    if condition is None or len(bucket_columns) != 1:
-        return None
-    key = strip_nested_prefix(bucket_columns[0]).lower()
+    column, num_buckets, kind = bucket_key
+    key = strip_nested_prefix(column).lower()
     for term in split_conjunctive(condition):
         eq = extract_eq_literal(term)
         if eq is not None and strip_nested_prefix(eq[0]).lower() == key:
-            return [bucket_of_literals([eq[1]], num_buckets)]
-        if (
+            values = [eq[1]]
+        elif (
             isinstance(term, In)
             and isinstance(term.child, Col)
             and strip_nested_prefix(term.child.name).lower() == key
+            and all(isinstance(v, Lit) for v in term.values)
         ):
-            return sorted({bucket_of_literals([v.value], num_buckets) for v in term.values})
+            values = [v.value for v in term.values]
+        else:
+            continue
+        buckets = {bucket_of_key_literal(v, kind, num_buckets) for v in values}
+        if None not in buckets:
+            return sorted(buckets)
     return None
+
+
+def prune_index_buckets(plan: L.LogicalPlan, literals_bound: bool = True) -> L.LogicalPlan:
+    """THE place bucket pruning happens, for FilterIndexRule and
+    JoinIndexRule alike: every ``Filter`` directly over an ``IndexScan``
+    that carries a ``bucket_key`` reads the buckets its bound literals hash
+    to. Runs where the literals are known — at the end of the optimizer's
+    rewrite of a concrete query, and in ``CompiledPlan.bind`` for a
+    plan-cache template — and decides from the condition and the log entry
+    alone, never from what an earlier literal left on the scan: a plan that
+    was pruned for another key is re-pruned. ``literals_bound=False`` takes
+    every prune back (what the plan cache stores as a template: no scan of
+    it holds the files of the literal it was compiled with). Untouched
+    subtrees keep their identity."""
+    memo: dict = {}
+
+    def walk(p: L.LogicalPlan) -> L.LogicalPlan:
+        got = memo.get(id(p))
+        if got is not None:
+            return got
+        children = list(p.children())
+        new_children = [walk(c) for c in children]
+        q = p
+        if any(nc is not c for nc, c in zip(new_children, children)):
+            q = p.with_children(new_children)
+        if isinstance(q, L.Filter) and isinstance(q.child, L.IndexScan) and q.child.bucket_key:
+            scan = q.child
+            buckets = (
+                pruned_buckets_for_predicate(q.condition, scan.bucket_key)
+                if literals_bound
+                else None
+            )
+            if buckets != scan.pruned_buckets:
+                q = L.Filter(q.condition, scan_of_buckets(scan, buckets))
+        memo[id(p)] = q
+        return q
+
+    return walk(plan)
 
 
 def index_file_columns(entry: IndexLogEntry, output_cols: List[str]) -> Optional[List[str]]:
@@ -149,16 +229,31 @@ def index_file_columns(entry: IndexLogEntry, output_cols: List[str]) -> Optional
 
 
 def index_files_for_buckets(entry: IndexLogEntry, buckets: Optional[List[int]]) -> List[str]:
-    files = entry.content.files
     if buckets is None:
-        return files
-    # bucket ids are parsed from file names once per Content (immutable after
-    # load); re-running the regex per query dominated bucket-pruned rewrites
-    pairs = entry.content.__dict__.get("_file_buckets")
-    if pairs is None or len(pairs) != len(files):
-        pairs = entry.content.__dict__["_file_buckets"] = [(f, bucket_of_file(f)) for f in files]
-    allowed = set(buckets)
-    return [f for f, b in pairs if b in allowed]
+        return entry.content.files
+    # bucket -> [(position, file)], parsed from the file names once per
+    # Content (immutable after load): a pruned lookup is a dict probe, not a
+    # walk over every file of the index
+    by_bucket = entry.content.__dict__.get("_bucket_files")
+    if by_bucket is None:
+        by_bucket = {}
+        for pos, f in enumerate(entry.content.files):
+            by_bucket.setdefault(bucket_of_file(f), []).append((pos, f))
+        entry.content.__dict__["_bucket_files"] = by_bucket
+    if len(buckets) == 1:
+        return [f for _, f in by_bucket.get(buckets[0], ())]
+    # several buckets: the files in the order the content lists them
+    return [f for _, f in sorted(pf for b in set(buckets) for pf in by_bucket.get(b, ()))]
+
+
+def scan_of_buckets(scan: L.IndexScan, buckets: Optional[List[int]]) -> L.IndexScan:
+    """A copy of ``scan`` that reads ``buckets`` only (None: every bucket)."""
+    import copy
+
+    out = copy.copy(scan)
+    out.pruned_buckets = buckets
+    out.files = index_files_for_buckets(scan.entry, buckets)
+    return out
 
 
 def transform_plan_to_use_index(
@@ -193,18 +288,16 @@ def transform_plan_to_use_index(
     file_cols = index_file_columns(entry, required_all)
 
     if not hybrid:
-        buckets = (
-            pruned_buckets_for_predicate(condition, bucket_spec.bucket_columns, bucket_spec.num_buckets)
-            if use_bucket_spec
-            else None
-        )
+        # ``use_bucket_spec`` decides only whether the plan ADVERTISES the
+        # bucketed layout (ordering, aggregate and join tiers read it);
+        # pruning needs no key: a trusted layout makes it exact, and
+        # prune_index_buckets applies it once the literal is bound
         new_scan: L.LogicalPlan = L.IndexScan(
             entry,
             columns=required_all,
             bucket_spec=bucket_spec if use_bucket_spec else None,
-            files=index_files_for_buckets(entry, buckets),
-            pruned_buckets=buckets,
             file_columns=file_cols,
+            bucket_key=index_bucket_key(index) if trusted_layout else None,
         )
     else:
         new_scan = _hybrid_scan_plan(

@@ -72,6 +72,22 @@ def _bound_conditions(bound_plan: L.LogicalPlan) -> List:
     return out
 
 
+def _shared_leaf(leaf: L.LogicalPlan, bound_plans: List[L.LogicalPlan]) -> L.LogicalPlan:
+    """The one scan the group reads: the template's leaf — or, where every
+    request's bind narrowed it to the buckets its own literals hash to, the
+    union of those buckets (each request's rows lie in its own)."""
+    from hyperspace_tpu.rules.utils import scan_of_buckets
+
+    buckets: set = set()
+    for p in bound_plans:
+        while not isinstance(p, (L.Scan, L.FileScan, L.IndexScan)):
+            p = p.child
+        if getattr(p, "pruned_buckets", None) is None:
+            return leaf
+        buckets.update(p.pruned_buckets)
+    return scan_of_buckets(leaf, sorted(buckets))
+
+
 def execute_shared_scan(
     session,
     ops: List[tuple],
@@ -89,8 +105,10 @@ def execute_shared_scan(
     request over its concatenated masked rows, dispatching through
     ``aggregate_batch`` so grouped shapes hit the device segment-reduction
     engine."""
-    from hyperspace_tpu.exec.executor import Executor, aggregate_batch
+    from hyperspace_tpu.exec.executor import Executor, _bucket_pruned, aggregate_batch
 
+    leaf = _shared_leaf(leaf, bound_plans)
+    _bucket_pruned(leaf, count=True)
     topk = None
     if ops and ops[0][0] == "topk":
         topk, ops = ops[0][1], ops[1:]

@@ -152,12 +152,10 @@ def _canon_plan(plan: L.LogicalPlan, st: _Canon, exact: bool = False) -> Tuple[s
 
     if isinstance(plan, L.IndexScan):
         env = {c: c for c in plan.output_columns}
-        pb = "" if plan.pruned_buckets is None else f";pb={sorted(plan.pruned_buckets)}"
-        return (
-            f"IndexScan[{plan.entry.name}#{plan.entry.id};{','.join(plan.columns)};"
-            f"nfiles={len(plan.files)}{pb}]",
-            env,
-        )
+        # neither the pruned buckets nor the narrowed file list: both follow
+        # from the bound literal (rules/utils.prune_index_buckets), and a
+        # structure must not
+        return f"IndexScan[{plan.entry.name}#{plan.entry.id};{','.join(plan.columns)}]", env
 
     if isinstance(plan, L.FileScan):
         env = {c: c for c in plan.output_columns}

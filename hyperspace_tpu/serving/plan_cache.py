@@ -8,10 +8,12 @@ Two lookup tiers share one LRU:
   in predicate literals binds its literals into the stored template
   (prepared-statement execution). A template is parameterized only when the
   optimizer's rewrite provably does not depend on the literal values — a
-  data-skipping prune (``FileScan.via_index``) or a bucket prune
-  (``IndexScan.pruned_buckets``) chose *files* from the literal, so those
-  templates fall back to exact-only reuse. Subquery-bearing plans are also
-  exact-only: the inner plan's result depends on its literals.
+  data-skipping prune (``FileScan.via_index``) chose *files* from the
+  literal, so those templates fall back to exact-only reuse. A bucket prune
+  (``IndexScan.pruned_buckets``) is no such rewrite: the rules only mark the
+  scan prunable (``bucket_key``), and ``bind`` prunes the bound plan for the
+  request's own literals. Subquery-bearing plans are also exact-only: the
+  inner plan's result depends on its literals.
 
 The session token folds in everything that can change what "compiled" means:
 the hyperspace flag, the ACTIVE index set (name + log version), and the conf
@@ -28,6 +30,7 @@ from typing import Any, List, Optional, Tuple
 
 from hyperspace_tpu.check.locks import named_lock
 from hyperspace_tpu.plan import logical as L
+from hyperspace_tpu.rules.utils import prune_index_buckets
 from hyperspace_tpu.serving.fingerprint import (
     Fingerprint,
     Unparameterizable,
@@ -63,32 +66,38 @@ def _literal_dependent_rewrite(plan: L.LogicalPlan) -> bool:
     """True when the optimized plan's *shape* encodes literal values — then a
     different literal could have produced a different file set, so the
     template must not be re-bound."""
-    if L.collect(plan, lambda p: isinstance(p, L.FileScan) and p.via_index is not None):
-        return True
-    if L.collect(plan, lambda p: isinstance(p, L.IndexScan) and p.pruned_buckets is not None):
-        return True
-    return False
+    return bool(L.collect(plan, lambda p: isinstance(p, L.FileScan) and p.via_index is not None))
 
 
 class CompiledPlan:
     """One cache entry: the optimized+pruned template and how to reuse it."""
 
-    __slots__ = ("template", "fp", "parameterizable", "output_columns")
+    __slots__ = ("template", "fp", "parameterizable", "output_columns", "prefetch_leaves")
 
     def __init__(self, template: L.LogicalPlan, fp: Fingerprint, parameterizable: bool):
         self.template = template
         self.fp = fp
         self.parameterizable = parameterizable
         self.output_columns = tuple(template.output_columns)
+        # what a request of this template will read whatever its literals:
+        # the scans of the compiled plan that no bucket prune narrowed (a
+        # pruned scan's files follow the literal, and the worker reads them
+        # microseconds after the hint would)
+        self.prefetch_leaves = [
+            leaf
+            for leaf in L.collect(template, lambda p: isinstance(p, (L.IndexScan, L.FileScan)))
+            if leaf.files and getattr(leaf, "pruned_buckets", None) is None
+        ]
 
     def bind(self, request_fp: Fingerprint) -> L.LogicalPlan:
-        """Template plan with this request's literals bound in (raises
-        ``Unparameterizable`` when the slots cannot be aligned)."""
+        """Template plan with this request's literals bound in, and every
+        prunable index scan narrowed to the buckets THOSE literals hash to
+        (raises ``Unparameterizable`` when the slots cannot be aligned)."""
         mapping = slot_mapping(self.fp, request_fp)
         values = [request_fp.literals[j] for j in mapping]
         if not values:
             return self.template
-        return bind_literals(self.template, values)
+        return prune_index_buckets(bind_literals(self.template, values))
 
 
 class PlanCache:
@@ -156,6 +165,12 @@ class PlanCache:
                 slot_mapping(tfp, fp)
             except Unparameterizable:
                 entry.parameterizable = False
+        if entry.parameterizable:
+            # literal-free: the compiling request's bucket prune is taken
+            # back, so nothing that reads ``entry.template`` (the prefetch
+            # hint, a shared scan) can read the bucket of another key; bind()
+            # prunes each request's own plan
+            entry.template = prune_index_buckets(template, literals_bound=False)
         key = (
             ("param", token, fp.structure)
             if entry.parameterizable

@@ -544,18 +544,13 @@ class QueryServer:
         entry = self.plan_cache_entry(token, fp)
         if entry is None:
             return
-        from hyperspace_tpu.plan import logical as L
-
-        for leaf in L.collect(
-            entry.template, lambda p: isinstance(p, (L.IndexScan, L.FileScan))
-        ):
-            if leaf.files:
-                cols = (
-                    leaf.file_columns
-                    if getattr(leaf, "file_columns", None) is not None
-                    else list(leaf.columns)
-                )
-                self.bucket_cache.prefetch(list(leaf.files), list(cols))
+        for leaf in entry.prefetch_leaves:
+            cols = (
+                leaf.file_columns
+                if getattr(leaf, "file_columns", None) is not None
+                else list(leaf.columns)
+            )
+            self.bucket_cache.prefetch(list(leaf.files), list(cols))
 
     def plan_cache_entry(self, token, fp: Fingerprint) -> Optional[CompiledPlan]:
         """Peek (no hit/miss accounting) at the template a request would use."""

@@ -231,7 +231,7 @@ class TestCoveringIndexData:
         import pyarrow.parquet as pq
 
         from hyperspace_tpu.indexes.covering import bucket_of_file
-        from hyperspace_tpu.ops.hashing import bucket_of_literals
+        from hyperspace_tpu.ops.hashing import bucket_of_key_literal
 
         session.conf.set(hst.keys.NUM_BUCKETS, 8)
         df = session.read_parquet(sample_parquet)
@@ -243,7 +243,7 @@ class TestCoveringIndexData:
             vals = pq.read_table(f).column("c1").to_numpy()
             assert np.all(np.diff(vals) >= 0), f"bucket {b} not sorted"
             for v in np.unique(vals):
-                assert bucket_of_literals([v], 8) == b
+                assert bucket_of_key_literal(v, "num", 8) == b
 
 
 class TestColumnPruning:
