@@ -5,8 +5,8 @@ The framework's headline claims are *compiled-program properties* (SURVEY.md
 HS/index/covering/JoinIndexRule.scala:604-618): the bucketed SMJ span program
 is collective-free, the sharded grouped aggregate all-gathers fixed-size
 partial tables and never rows, the distributed index build exchanges rows
-with exactly ONE all-to-all. ``parallel/hlo_check.py`` asserted two of these
-for two hand-built programs; this module generalizes it into a rule engine:
+with exactly ONE all-to-all. This module is the rule engine that holds
+every program family to such a claim:
 
 - each device-program family **declares** its collective budget and
   forbidden-op patterns at registration (:func:`register_contract`, called
@@ -20,8 +20,7 @@ for two hand-built programs; this module generalizes it into a rule engine:
   program-cache-fill time, bumping ``hs_check_violations_total{rule,program}``
   and ``hs_check_programs_verified_total{program}``.
 
-The disabled path is one conf-dict lookup — bench.py ``--check-overhead``
-pins it at <= 1% of a program-cache fill.
+The disabled path is one conf-dict lookup.
 """
 
 from __future__ import annotations
@@ -35,8 +34,7 @@ from typing import Dict, List, Optional, Tuple
 from hyperspace_tpu.check.findings import Finding
 
 # --------------------------------------------------------------------------
-# HLO text scanning (moved here from parallel/hlo_check.py; that module is
-# now a compat shim re-exporting these names)
+# HLO text scanning (``hyperspace_tpu.parallel`` re-exports these names)
 # --------------------------------------------------------------------------
 
 COLLECTIVE_OPS = (

@@ -3,8 +3,8 @@
 Each rule lives in :mod:`hyperspace_tpu.check.rules` and receives a
 :class:`LintContext` — parsed ASTs for every file in scope plus the doc
 texts and the registered conf-key set — and returns Findings. The default
-scope is the package tree plus the repo-root drivers (``bench.py``,
-``__graft_entry__.py``); tests and fixtures are deliberately outside it
+scope is the package tree plus the repo-root drivers (``__graft_entry__.py``,
+``chip_smoke.py``); tests and fixtures are deliberately outside it
 (seeded-violation fixtures MUST fire when pointed at directly, and must not
 fail the repo run).
 
@@ -42,7 +42,7 @@ def default_paths(root: str) -> List[str]:
         for f in sorted(filenames):
             if f.endswith(".py"):
                 out.append(os.path.join(dirpath, f))
-    for extra in ("bench.py", "__graft_entry__.py"):
+    for extra in ("__graft_entry__.py", "chip_smoke.py"):
         p = os.path.join(root, extra)
         if os.path.exists(p):
             out.append(p)

@@ -6,9 +6,9 @@ outputs — after the call, the Python reference still exists but the buffer
 is deleted. Reading it raises on TPU and (worse) works by accident on some
 backends, so the bug ships silently. This rule tracks names bound to
 donation-compiled callables — any call carrying a ``donate_argnums``
-keyword, e.g. ``jitted = compile_stage(key, fn, donate_argnums=(0, 1))``
-or ``jax.jit(fn, donate_argnums=0)`` — and flags any later read of a name
-that was passed in a donated position, until the name is rebound.
+keyword, e.g. ``jitted = jax.jit(fn, donate_argnums=(0, 1))`` or a
+program-cache wrapper that passes the keyword on — and flags any later read
+of a name that was passed in a donated position, until the name is rebound.
 
 Only plain-name positional arguments are tracked (``jitted(*args)`` and
 attribute/subscript operands are conservatively skipped); rebinding the

@@ -59,9 +59,8 @@ def _is_jit_decorator(dec: ast.AST) -> bool:
 def _jitted_by_name(tree: ast.Module) -> Set[str]:
     """Function names passed positionally into any ``*jit*``-named wrapper
     (``jax.jit(fn)``, ``_cached_predicate_jit(key, fn)``, …) or into any
-    call carrying a ``donate_argnums`` keyword — the stage compiler
-    (``compile_stage(skeleton, fn, donate_argnums=...)``) jits exactly like
-    ``jax.jit`` does."""
+    call carrying a ``donate_argnums`` keyword — a program-cache wrapper
+    that donates jits exactly like ``jax.jit`` does."""
     names: Set[str] = set()
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call):

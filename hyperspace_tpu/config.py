@@ -90,13 +90,6 @@ class keys:
     EXEC_TOPK_ENABLED = "hyperspace.exec.topk.enabled"
     EXEC_TOPK_MAX_K = "hyperspace.exec.topk.maxK"
     EXEC_TOPK_THRESHOLD_PUSHDOWN = "hyperspace.exec.topk.thresholdPushdown"
-    # Whole-plan fusion (exec/stage_ir.py): compile a chunk's
-    # filter→project→fold chain into ONE jitted stage program per
-    # (pipeline skeleton, shape bucket, mesh fingerprint), and donate the
-    # streamed fold state so it updates in place instead of reallocating
-    # every chunk.
-    EXEC_FUSION_ENABLED = "hyperspace.exec.fusion.enabled"
-    EXEC_FUSION_DONATION = "hyperspace.exec.fusion.donation"
     # Query-serving runtime (hyperspace_tpu/serving/): concurrent request
     # admission, compiled-plan caching, micro-batching, bucket prefetch.
     SERVING_QUEUE_DEPTH = "hyperspace.serving.queueDepth"
@@ -169,7 +162,6 @@ class keys:
     # pinning, the background refresh manager, and the device lineage
     # anti-semi-join for hybrid-scan delete filtering.
     LIFECYCLE_SNAPSHOT_ENABLED = "hyperspace.lifecycle.snapshot.enabled"
-    LIFECYCLE_REFRESH_ENABLED = "hyperspace.lifecycle.refresh.enabled"
     LIFECYCLE_REFRESH_INTERVAL_SECONDS = "hyperspace.lifecycle.refresh.intervalSeconds"
     LIFECYCLE_REFRESH_MODE = "hyperspace.lifecycle.refresh.mode"
     LIFECYCLE_DEVICE_LINEAGE_ENABLED = "hyperspace.lifecycle.deviceLineage.enabled"
@@ -387,17 +379,6 @@ DEFAULTS: Dict[str, Any] = {
     # min/max pruning as a dynamic filter (only row groups that provably
     # cannot beat the current k-th candidate are skipped).
     keys.EXEC_TOPK_THRESHOLD_PUSHDOWN: True,
-    # Whole-plan fusion: fold each streamed chunk with ONE jitted program
-    # (chunk select + state merge in a single XLA executable) instead of the
-    # per-family chunk-then-merge dispatch pair. Default off this release:
-    # the per-family path stays the reference; flip on after soak. Results
-    # are byte-identical either way (proved by the fusion test tier).
-    keys.EXEC_FUSION_ENABLED: False,
-    # With fusion on, pass the device-resident fold state via
-    # `donate_argnums` so XLA reuses its buffers for the outputs (in-place
-    # update, no per-chunk HBM realloc). Only consulted when fusion is
-    # enabled; off = same fused program without donation.
-    keys.EXEC_FUSION_DONATION: True,
     # Serving runtime. Queue depth bounds memory under overload: submits
     # beyond it are REJECTED (AdmissionRejected), never silently queued.
     keys.SERVING_QUEUE_DEPTH: 64,
@@ -445,7 +426,7 @@ DEFAULTS: Dict[str, Any] = {
     # predicate by re-filtering the cached batch.
     keys.SERVING_RESULT_CACHE_SUBSUMPTION: True,
     # Span tracing is opt-in: when off, each instrumentation point costs one
-    # contextvar read (bench.py --obs-overhead pins the bar at <= 3%).
+    # contextvar read.
     keys.OBS_TRACING_ENABLED: False,
     # Per-trace span budget; beyond it the tree stops growing and the trace
     # reports droppedSpans (bounded memory under pathological plans).
@@ -517,9 +498,6 @@ DEFAULTS: Dict[str, Any] = {
     # request, so a refresh committing mid-flight never changes a running
     # query's answer (docs/lifecycle.md).
     keys.LIFECYCLE_SNAPSHOT_ENABLED: True,
-    # Run the background RefreshManager alongside serving; off by default —
-    # refreshes are an explicit operational decision.
-    keys.LIFECYCLE_REFRESH_ENABLED: False,
     # Seconds between RefreshManager drift polls.
     keys.LIFECYCLE_REFRESH_INTERVAL_SECONDS: 5.0,
     # Refresh mode the manager schedules: "auto" picks incremental when the
@@ -876,14 +854,6 @@ class HyperspaceConf:
     def topk_threshold_pushdown(self) -> bool:
         return bool(self.get(keys.EXEC_TOPK_THRESHOLD_PUSHDOWN))
 
-    @property
-    def fusion_enabled(self) -> bool:
-        return bool(self.get(keys.EXEC_FUSION_ENABLED))
-
-    @property
-    def fusion_donation(self) -> bool:
-        return bool(self.get(keys.EXEC_FUSION_DONATION))
-
     # Serving runtime --------------------------------------------------------
     @property
     def serving_queue_depth(self) -> int:
@@ -1103,10 +1073,6 @@ class HyperspaceConf:
     @property
     def lifecycle_snapshot_enabled(self) -> bool:
         return bool(self.get(keys.LIFECYCLE_SNAPSHOT_ENABLED))
-
-    @property
-    def lifecycle_refresh_enabled(self) -> bool:
-        return bool(self.get(keys.LIFECYCLE_REFRESH_ENABLED))
 
     @property
     def lifecycle_refresh_interval_seconds(self) -> float:

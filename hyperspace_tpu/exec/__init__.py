@@ -1,12 +1,8 @@
-"""Execution engine: host/device executors, streaming folds, stage IR.
+"""Execution engine: host/device executors, streaming folds.
 
-Everything here loads lazily (PEP 562): ``exec.device`` imports
-``exec.stage_ir`` at module level, and ``stage_ir`` reaches back into
-``device``'s program machinery from inside its functions — an eager
-import from this package would freeze one direction of that cycle and
-break the other. Lazy attributes also keep ``import hyperspace_tpu``
-cheap for callers that never execute a query (jax loads on first use,
-not at import).
+Everything here loads lazily (PEP 562): lazy attributes keep
+``import hyperspace_tpu`` cheap for callers that never execute a query
+(jax loads on first use, not at import).
 
 Public surface (mirrors ``parallel/__init__``):
 
@@ -16,10 +12,6 @@ Public surface (mirrors ``parallel/__init__``):
   ``exec.topk``).
 - ``stream_broadcast_join``, ``BroadcastSpec``, ``broadcast_spec`` — the
   streaming broadcast hash join (``exec.join_stream``).
-- Stage IR (``exec.stage_ir``): ``StagePlan`` + ``FilterOp`` /
-  ``ProjectOp`` / ``JoinProbeOp`` / ``GroupAggOp`` / ``TopKOp`` nodes,
-  the donation-aware ``compile_stage`` program cache, and
-  ``stream_join_aggregate`` — the whole-plan fused q3 entry point.
 """
 
 from __future__ import annotations
@@ -28,18 +20,10 @@ __all__ = [
     "BroadcastSpec",
     "DeviceUnsupported",
     "Executor",
-    "FilterOp",
-    "GroupAggOp",
     "GroupedAggStream",
-    "JoinProbeOp",
-    "ProjectOp",
-    "StagePlan",
-    "TopKOp",
     "TopKStream",
     "broadcast_spec",
-    "compile_stage",
     "stream_broadcast_join",
-    "stream_join_aggregate",
 ]
 
 _HOMES = {
@@ -50,14 +34,6 @@ _HOMES = {
     "BroadcastSpec": "join_stream",
     "broadcast_spec": "join_stream",
     "stream_broadcast_join": "join_stream",
-    "StagePlan": "stage_ir",
-    "FilterOp": "stage_ir",
-    "ProjectOp": "stage_ir",
-    "JoinProbeOp": "stage_ir",
-    "GroupAggOp": "stage_ir",
-    "TopKOp": "stage_ir",
-    "compile_stage": "stage_ir",
-    "stream_join_aggregate": "stage_ir",
 }
 
 

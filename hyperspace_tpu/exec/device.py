@@ -540,6 +540,7 @@ import threading as _threading
 import time as _ptime
 
 from hyperspace_tpu.obs import spans as _obs_spans
+from hyperspace_tpu.obs.metrics import REGISTRY as _REGISTRY
 
 _COMPILE_SEEN: set = set()
 _COMPILE_SEEN_LOCK = _threading.Lock()
@@ -582,6 +583,37 @@ def _observe_program(family: str, first_seen: bool, t0: float) -> None:
     sp = _obs_spans.current_span()
     if sp is not None:
         sp.event("device-program", family + (" (compile)" if first_seen else ""))
+
+
+def count_dispatch(program: str) -> None:
+    """Count one jitted device-program dispatch, at every jitted call site."""
+    _REGISTRY.counter(
+        "hs_device_dispatches_total",
+        "Jitted device-program dispatches, by program family",
+        program=program,
+    ).inc()
+
+
+def device_peak_bytes() -> Optional[int]:
+    """The allocator's own high-water mark: the largest ``peak_bytes_in_use``
+    of ``memory_stats()`` over this process's devices. None where the backend
+    keeps no such statistic (the CPU backend), and in a process whose JAX
+    backend is not up yet — reading a gauge must not be what claims the chip."""
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in jax.local_devices()]
+    peaks = [p for p in peaks if p is not None]
+    return int(max(peaks)) if peaks else None
+
+
+_REGISTRY.gauge(
+    "hs_device_peak_bytes",
+    "High-water bytes in use on a device, from the allocator's memory_stats() "
+    "at read time; absent where the backend reports none",
+    fn=device_peak_bytes,
+)
 
 
 # -- the host-device link ----------------------------------------------------
@@ -808,27 +840,11 @@ _hlo_lint.register_contract(
     description="device-materialized inner join: pair expansion + numeric payload gathers, final columns out",
 )
 _hlo_lint.register_contract(
-    "fused-stage-agg",
-    collectives={"all-gather": _ANY, "all-reduce": _ANY},
-    description="whole-stage filter+group+state-merge with donated fold state: one executable per chunk",
-    single_fusion=True,
-)
-_hlo_lint.register_contract(
-    "fused-stage-agg-sharded",
-    collectives={"all-gather": (1, None), "all-reduce": _ANY},
-    description="shard_map whole-stage grouped fold: gathers per-shard partial TABLES (>=1), one executable",
-    single_fusion=True,
-)
-_hlo_lint.register_contract(
     "dict-expand",
     collectives={},
     description="on-device dictionary expansion: codes gather a replicated remap table, shuffle-free",
     single_fusion=True,
 )
-
-# whole-plan fusion helpers (stage compiler, dispatch counter, HBM gauge);
-# stage_ir imports device only lazily inside functions, so this is acyclic
-from hyperspace_tpu.exec import stage_ir as _stage_ir
 
 
 def _dry_codecs(batch: B.Batch, refs) -> Dict[str, ColumnCodec]:
@@ -890,7 +906,7 @@ def _put_encoded(session, mesh, sharding, n_dev, arr):
         _hlo_lint.maybe_verify(session.conf, "dict-expand", key, jitted, (dev_codes, dev_remap))
         t0 = _ptime.perf_counter()
         dev = jitted(dev_codes, dev_remap)
-        _stage_ir.count_dispatch("dict-expand")
+        count_dispatch("dict-expand")
         _observe_program("dict-expand", first, t0)
         return dev, ColumnCodec("string", uniques=su), int(padded.nbytes + remap.nbytes)
     enc, codec = encode_column(arr)
@@ -964,7 +980,7 @@ def device_filter_mask(session, batch: B.Batch, condition: Expr, scan_key=None, 
     _hlo_lint.maybe_verify(session.conf, "fused-filter", key, jitted, (dev_cols, lit_values))
     t0 = _ptime.perf_counter()
     mask = jitted(dev_cols, lit_values)
-    _stage_ir.count_dispatch("fused-filter")
+    count_dispatch("fused-filter")
     out = fetch(mask, "filter-mask", "fused-filter")[:n]
     _observe_program("fused-filter", first, t0)
     return out
@@ -1145,7 +1161,7 @@ def device_filtered_aggregate(
     _hlo_lint.maybe_verify(session.conf, "fused-agg", key, jitted, (dev_cols, lit_values, np.int64(n)))
     t0 = _ptime.perf_counter()
     outs, valids = jitted(dev_cols, lit_values, np.int64(n))
-    _stage_ir.count_dispatch("fused-agg")
+    count_dispatch("fused-agg")
     outs, valids = fetch((outs, valids), "agg-table", "fused-agg")
     valids = [int(v) for v in valids]
     _observe_program("fused-agg", first, t0)
@@ -1439,59 +1455,6 @@ def _dev_pad(arr, target, fill):
     return jnp.concatenate([arr, jnp.full((target - n,), fill, arr.dtype)])
 
 
-def _fused_grouped_update_program(pred_fn, key_specs, slot_specs, cap):
-    """Whole-stage grouped fold (``hyperspace.exec.fusion.enabled``): the
-    chunk's filter+group+segment-reduce AND the merge into the running
-    partial as ONE program, so a streamed chunk costs a single dispatch and
-    the fold state can be donated (args 0-2) for in-place buffer reuse.
-
-    Overflow contract: the rank-compressed group counts are exact even above
-    ``cap``, so ``n_b > cap`` (chunk-local) or ``n_m > cap`` (merged) flags a
-    lost-groups hazard; every state output then selects the ORIGINAL state
-    via ``jnp.where`` — with donation the buffers were reused, but their
-    VALUES round-trip unchanged, and the host redoes the chunk per-family.
-    """
-    import jax.numpy as jnp
-
-    chunk = _grouped_chunk_program(pred_fn, key_specs, slot_specs, cap)
-
-    def program(state_keys, state_slots, state_fs, state_n, cols, lits, n_valid, row_base):
-        n_b, fs_b, key_b, slot_b = chunk(cols, lits, n_valid, row_base)
-        idx = jnp.arange(cap)
-        mask = jnp.concatenate([idx < state_n, idx < n_b])
-        kcat = tuple(jnp.concatenate([a, b]) for a, b in zip(state_keys, key_b))
-        scat = tuple(jnp.concatenate([a, b]) for a, b in zip(state_slots, slot_b))
-        fs_cat = jnp.concatenate([state_fs, fs_b])
-        n_m, fs_m, key_m, slot_m = _merge_concat_parts(
-            key_specs, slot_specs, cap, kcat, scat, fs_cat, mask
-        )
-        ok = (n_b <= cap) & (n_m <= cap)
-        n_out = jnp.where(ok, n_m, state_n)
-        fs_out = jnp.where(ok, fs_m, state_fs)
-        keys_out = tuple(jnp.where(ok, m, s) for m, s in zip(key_m, state_keys))
-        slots_out = tuple(jnp.where(ok, m, s) for m, s in zip(slot_m, state_slots))
-        return n_b, n_m, n_out, fs_out, keys_out, slots_out
-
-    return program
-
-
-def _fused_state_dtypes(key_specs, slot_specs):
-    """(key dtype per group key, slot dtype per state slot) of the fused fold
-    state — must match the chunk/merge program outputs EXACTLY or the
-    overflow ``jnp.where`` selects would promote and break donation
-    aliasing."""
-    import jax.numpy as jnp
-
-    key_dts = tuple(jnp.float64 if tag == "f" else jnp.int64 for _, tag in key_specs)
-    slot_dts = tuple(
-        jnp.int64
-        if (kind in ("cntm", "cnt") or (isint and kind in ("sum", "min", "max")))
-        else jnp.float64
-        for kind, _, isint in slot_specs
-    )
-    return key_dts, slot_dts
-
-
 class GroupedAggStream:
     """Streaming grouped aggregation with device-resident partials.
 
@@ -1656,19 +1619,6 @@ class GroupedAggStream:
         cap = group_capacity(max(self._cap_hint, 1), self.cap_floor)
         shapes = tuple(dev_cols[r].shape for r in sorted(dev_cols))
         sharded = self._parallel is not None
-        if _stage_ir.fusion_wanted(self.session.conf) and not any(
-            tag == "s" for tag, _, _ in keys_schema
-        ):
-            # whole-stage fold: chunk select + state merge in ONE dispatch,
-            # fold state donated. String group keys stay per-family (their
-            # chunk->global dictionary remap is a host step between the chunk
-            # and merge programs that fusion removes).
-            if self._update_fused(
-                mesh, sharded, dev_cols, lit_values, pred_fn, key_specs,
-                base_sk, n, shapes,
-            ):
-                return
-            trace.fallback("fusion", "grouped-overflow")
         while True:
             if sharded:
                 from hyperspace_tpu.parallel import collectives as _collectives
@@ -1698,7 +1648,7 @@ class GroupedAggStream:
                 n_g_dev, fs, key_out, slot_out = jitted(
                     dev_cols, lit_values, np.int64(n), np.int64(self._row_base)
                 )
-            _stage_ir.count_dispatch(family)
+            count_dispatch(family)
             n_g = int(fetch(n_g_dev, "agg-table", family))
             _observe_program(family, first, t0)
             if n_g > self.max_groups:
@@ -1722,99 +1672,6 @@ class GroupedAggStream:
             self._partial = new
         else:
             self._merge(new)
-
-    def _ensure_fused_state(self, key_specs, cap):
-        """The running partial as (keys, slots, fs, n) device arrays padded
-        to ``cap`` — zero-filled when the stream is fresh (``state_n == 0``
-        masks them out of the fused merge)."""
-        import jax.numpy as jnp
-
-        key_dts, slot_dts = _fused_state_dtypes(key_specs, self._slots)
-        p = self._partial
-        if p is None:
-            keys = tuple(jnp.zeros(cap, dtype=dt) for dt in key_dts)
-            slots = tuple(jnp.zeros(cap, dtype=dt) for dt in slot_dts)
-            fs = jnp.full(cap, _FS_SENTINEL, dtype=jnp.int64)
-            return keys, slots, fs, 0
-        if p["cap"] < cap:
-            p["fs"] = _dev_pad(p["fs"], cap, _FS_SENTINEL)
-            p["keys"] = [_dev_pad(k, cap, 0 if k.dtype != np.float64 else np.nan) for k in p["keys"]]
-            p["slots"] = [_dev_pad(s, cap, 0) for s in p["slots"]]
-            p["cap"] = cap
-        return tuple(p["keys"]), tuple(p["slots"]), p["fs"], int(p["n"])
-
-    def _update_fused(self, mesh, sharded, dev_cols, lit_values, pred_fn,
-                      key_specs, base_sk, n, shapes) -> bool:
-        """One-dispatch whole-stage fold of this chunk. Returns False on
-        capacity overflow — the state values round-tripped unchanged through
-        the (possibly donated) buffers and the caller redoes the chunk on the
-        per-family path."""
-        conf = self.session.conf
-        cap = group_capacity(max(self._cap_hint, 1), self.cap_floor)
-        if self._partial is not None:
-            cap = max(cap, self._partial["cap"])
-        state_keys, state_slots, state_fs, state_n = self._ensure_fused_state(
-            key_specs, cap
-        )
-        # donation stays off under shard_map: XLA cannot reliably alias the
-        # replicated fold state there, and an unhonored donation both warns
-        # and silently loses the in-place win
-        donate = _stage_ir.donation_wanted(conf) and not sharded
-        if sharded:
-            from hyperspace_tpu.parallel import collectives as _collectives
-
-            program = _collectives.sharded_fused_grouped_program(
-                mesh, mesh.axis_names[0], pred_fn, key_specs, self._slots, cap
-            )
-        else:
-            program = _fused_grouped_update_program(
-                pred_fn, key_specs, self._slots, cap
-            )
-        family = self._family = "fused-stage-agg-sharded" if sharded else "fused-stage-agg"
-        key = _program_key(
-            f"gaggfused[{cap}{'+d' if donate else ''}]:{base_sk}",
-            mesh, sharded=sharded,
-        )
-        jitted = _stage_ir.compile_stage(
-            key, program, donate_argnums=(0, 1, 2) if donate else (), family=family
-        )
-        first = _note_compile(key, shapes + ((cap,),))
-        args = (
-            state_keys, state_slots, state_fs, np.int64(state_n),
-            dev_cols, lit_values, np.int64(n), np.int64(self._row_base),
-        )
-        _hlo_lint.maybe_verify(conf, family, key, jitted, args)
-        t0 = _ptime.perf_counter()
-        if sharded:
-            n_b_d, n_m_d, n_out_d, fs_out, keys_out, slots_out = (
-                self._parallel.timed_call("grouped-agg", jitted, *args)
-            )
-        else:
-            n_b_d, n_m_d, n_out_d, fs_out, keys_out, slots_out = jitted(*args)
-        _stage_ir.count_dispatch(family)
-        n_b, n_m, n_out = (int(v) for v in fetch((n_b_d, n_m_d, n_out_d), "agg-table", family))
-        _observe_program(family, first, t0)
-        # the donated state is consumed either way: rebind the partial to the
-        # returned (aliased) buffers, which carry the original values on
-        # overflow
-        self._partial = {
-            "cap": cap, "n": n_out, "fs": fs_out,
-            "keys": list(keys_out), "slots": list(slots_out),
-        }
-        if n_b > cap or n_m > cap:
-            self._cap_hint = max(self._cap_hint, n_b, n_m)
-            if state_n == 0:
-                self._partial = None  # nothing folded yet; keep the redo cheap
-            return False
-        self._row_base += n
-        self._cap_hint = max(self._cap_hint, n_m)
-        if n_m > self.max_groups:
-            exc = GroupCapacityExceeded(
-                f"group cardinality {n_m} exceeds maxGroups {self.max_groups}"
-            )
-            exc.folded = True  # the chunk IS in the stored partial
-            raise exc
-        return True
 
     def _remap_string_key(self, name, dev_codes, codec: ColumnCodec, n_g: int, cap: int):
         """Chunk-local dictionary codes -> global int64 codes (host remap of
@@ -1876,7 +1733,7 @@ class GroupedAggStream:
                 tuple(a["slots"]), tuple(b["slots"]),
                 a["fs"], b["fs"], np.int64(a["n"]), np.int64(b["n"]),
             )
-            _stage_ir.count_dispatch("grouped-merge")
+            count_dispatch("grouped-merge")
             n_g = int(fetch(n_g_dev, "agg-table", "grouped-merge"))
         _observe_program("grouped-merge", first, t0)
         REGISTRY.counter(
@@ -3107,7 +2964,7 @@ def device_bucketed_join(session, plan: L.Join, _compat=None, _setup=None) -> B.
     )
     t0 = _ptime.perf_counter()
     lo, hi = spans(lmat_dev, rmat_dev)
-    _stage_ir.count_dispatch("bucketed-smj-span")
+    count_dispatch("bucketed-smj-span")
     _observe_program("bucketed-smj-span", first, t0)
 
     if plan.how == "inner" and session.conf.join_device_materialize:

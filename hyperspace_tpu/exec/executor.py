@@ -1403,7 +1403,8 @@ class Executor:
                 if isinstance(join_node, L.Compute):
                     # computed aggregate inputs / group keys (q3's
                     # sum(l_extendedprice * (1 - l_discount))): single-side
-                    # expressions evaluate per bucket inside the fusion
+                    # expressions evaluate per bucket inside
+                    # aggregate_over_bucketed_join
                     computes.extend(join_node.exprs)
                 join_node = join_node.child
             if isinstance(join_node, L.Join):
@@ -1423,9 +1424,6 @@ class Executor:
         # materializes the whole scan to size its decision, which is exactly
         # what the out-of-core path exists to avoid
         if not with_file_names:
-            got = self._try_fused_join_aggregate(plan)
-            if got is not None:
-                return got
             got = self._try_streaming_aggregate(plan)
             if got is not None:
                 trace.record("agg", "streamed-partial")
@@ -1712,52 +1710,6 @@ class Executor:
             + (f"-repaired:{repaired}" if repaired else ""),
         )
         return {c: np.asarray(v)[out_idx] for c, v in total.items()}
-
-    def _try_fused_join_aggregate(self, plan: L.Aggregate) -> Optional[B.Batch]:
-        """Whole-plan fused q3 shape: Aggregate over (Filter over) an inner
-        broadcast Join compiles to ONE donated XLA program per chunk
-        (exec/stage_ir.stream_join_aggregate) instead of the per-family
-        probe/verify/postjoin/fold/merge dispatch chain. Returns None (caller
-        falls through to the per-family streaming and materialized paths)
-        unless ``hyperspace.exec.fusion.enabled`` is set and the shape fuses."""
-        conf = self.session.conf
-        from hyperspace_tpu.exec import device as D
-        from hyperspace_tpu.exec import join_stream as JS
-        from hyperspace_tpu.exec import stage_ir
-
-        if not (
-            conf.device_execution_enabled
-            and conf.agg_device_grouped_enabled
-            and stage_ir.fusion_wanted(conf)
-        ):
-            return None
-        if not plan.keys:
-            return None
-        if any(fn not in _STREAMABLE_AGGS or fn.endswith("_distinct")
-               for _, fn, _ in plan.aggs):
-            return None
-        node = plan.child
-        post_filter = None
-        if isinstance(node, L.Filter) and isinstance(node.child, L.Join):
-            post_filter, node = node.condition, node.child
-        if not isinstance(node, L.Join):
-            return None
-        spec = JS.broadcast_spec(self.session, node)
-        if spec is None:
-            return None
-        with spans.span("agg-fused-join-agg-stream", cat="exec") as tier:
-            try:
-                return stage_ir.stream_join_aggregate(
-                    self, node, spec, post_filter, list(plan.keys), list(plan.aggs)
-                )
-            except D.DeviceUnsupported:
-                trace.fallback("fusion", "join-agg-unsupported")
-                tier.set(fallback="join-agg-unsupported")
-                return None
-            except _STREAM_FALLBACK_ERRORS:
-                trace.record("agg", "stream-fallback")
-                tier.set(fallback="stream-fallback")
-                return None
 
     def _try_streaming_aggregate(self, plan: L.Aggregate) -> Optional[B.Batch]:
         """Out-of-core aggregate: when the child is a scan chain over more

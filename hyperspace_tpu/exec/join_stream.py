@@ -49,6 +49,7 @@ from hyperspace_tpu.exec.device import (
     _program_key,
     bucket_rows,
     compile_predicate,
+    count_dispatch,
     encode_column,
     fetch,
     link_bytes,
@@ -260,9 +261,7 @@ def build_hash_side(session, build_plan: L.LogicalPlan, build_cols: List[str],
     prog = _hash_build_program(len(bkeys))
     link_bytes("h2d", "join-mats", sum(int(p.nbytes) for p in planes))
     table, order = prog(planes, np.int64(n))
-    from hyperspace_tpu.exec import stage_ir as _stage_ir
-
-    _stage_ir.count_dispatch("hash-build")
+    count_dispatch("hash-build")
     sig = (len(bkeys), planes[0].shape[0])
     _note_compile("hash-build", sig)
     _hlo_lint.maybe_verify(
@@ -329,9 +328,7 @@ def _probe_chunk(session, build: BuildSide, chunk: B.Batch,
     prog = _hash_probe_program(len(planes))
     link_bytes("h2d", "join-mats", sum(int(p.nbytes) for p in padded))
     lo_d, hi_d = prog(build.table, np.int64(build.n), padded)
-    from hyperspace_tpu.exec import stage_ir as _stage_ir
-
-    _stage_ir.count_dispatch("hash-probe")
+    count_dispatch("hash-probe")
     sig = (len(planes), int(build.table.shape[0]), padded[0].shape[0])
     _note_compile("hash-probe", sig)
     _hlo_lint.maybe_verify(
@@ -504,9 +501,7 @@ def _device_postjoin_mask(session, condition, pbatch: B.Batch, build: BuildSide,
         sum(int(a.nbytes) for a in (*pcols.values(), *bcols.values(), pidx_pad, bidx_pad)),
     )
     mask = jitted(*args)
-    from hyperspace_tpu.exec import stage_ir as _stage_ir
-
-    _stage_ir.count_dispatch("fused-postjoin")
+    count_dispatch("fused-postjoin")
     return fetch(mask, "join-out", "fused-postjoin")[:n]
 
 

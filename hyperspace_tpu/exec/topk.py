@@ -176,38 +176,14 @@ class TopKStream:
         self.rows_seen += n
         rid = base + np.arange(n, dtype=np.int64)
 
-        from hyperspace_tpu.exec import stage_ir as _stage_ir
-
-        # whole-stage fold: chunk select + state merge in one dispatch, the
-        # candidate state donated. String keys stay per-family (their merge
-        # needs the host re-encode between select and merge).
-        use_fused = (
-            self._state is not None
-            and not any(self._string_keys)
-            and _stage_ir.fusion_wanted(self.session.conf)
-        )
-        if use_fused:
-            merged, cand = self._run_fused(planes + [rid])
-        else:
-            cand = self._run_chunk(planes + [rid])
-        family = "fused-stage-topk" if use_fused else "topk-chunk"
-        crid = D.fetch(cand[-1], "topk", family)
+        cand = self._run_chunk(planes + [rid])
+        crid = D.fetch(cand[-1], "topk", "topk-chunk")
         valid = crid < _SENT
         add_rid = crid[valid]
         local = (add_rid - base).astype(np.int64)
         add_pool: B.Batch = {c: np.asarray(v)[local] for c, v in batch.items()}
 
-        if use_fused:
-            pool_all = B.concat([self._pool, add_pool]) if self._pool else add_pool
-            rid_all = (
-                np.concatenate([self._order, add_rid])
-                if self._order is not None else add_rid
-            )
-            self._state = merged
-            _merges_total().inc()
-            mrid = D.fetch(merged[-1], "topk", family)
-            merged_rid = mrid[mrid < _SENT]
-        elif self._state is None:
+        if self._state is None:
             self._state = cand
             merged_rid = add_rid
             pool_all, rid_all = add_pool, add_rid
@@ -254,58 +230,8 @@ class TopKStream:
         D._note_compile(key, (mat.shape,))
         _hlo_lint.maybe_verify(self.session.conf, family, key, jitted, (dev,))
         out = jitted(dev)
-        from hyperspace_tpu.exec import stage_ir as _stage_ir
-
-        _stage_ir.count_dispatch(family)
+        D.count_dispatch(family)
         return out
-
-    def _run_fused(self, mat_rows: List[np.ndarray]):
-        """One-dispatch whole-stage fold: the chunk's select-top-k and the
-        merge with the running candidate state as a single program, state
-        donated (``hyperspace.exec.fusion.donation``). Returns
-        ``(merged, cand)``; the caller MUST rebind ``self._state`` to
-        ``merged`` before touching the old state again."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from hyperspace_tpu.check import hlo_lint as _hlo_lint
-        from hyperspace_tpu.exec import stage_ir as _stage_ir
-        from hyperspace_tpu.ops import sort as S
-
-        mesh = self.mesh
-        n_dev = mesh.devices.size
-        nk = len(self.keys)
-        padded = [D._pad_to_bucket(r, n_dev, _SENT) for r in mat_rows]
-        mat = np.stack(padded)
-        axis = mesh.axis_names[0]
-        dev = D.put(mat, "topk", NamedSharding(mesh, P(None, axis)))
-
-        sharded = self.parallel is not None
-        # donation stays off under shard_map (same stance as the grouped
-        # fold: replicated-state aliasing there is not reliably honored)
-        donate = _stage_ir.donation_wanted(self.session.conf) and not sharded
-        if sharded:
-            from hyperspace_tpu.parallel import collectives as C
-
-            fn = C.sharded_fused_topk_program(mesh, axis, nk, self.cap)
-            family = "fused-stage-topk-sharded"
-            self.parallel.note_op("topk")
-        else:
-            fn = S.fused_topk_fn(nk, self.cap)
-            family = "fused-stage-topk"
-        plan = _stage_ir.StagePlan((_stage_ir.TopKOp(nk, self.cap),))
-        key = D._program_key(
-            f"{plan.skeleton()}{'+d' if donate else ''}", mesh, sharded=sharded
-        )
-        jitted = _stage_ir.compile_stage(
-            key, fn, donate_argnums=(0,) if donate else (), family=family
-        )
-        D._note_compile(key, (mat.shape,))
-        state = self._state
-        _hlo_lint.maybe_verify(self.session.conf, family, key, jitted, (state, dev))
-        merged, cand = jitted(state, dev)
-        _stage_ir.count_dispatch(family)
-        return merged, cand
 
     def _merge(self, cand, add_pool: B.Batch, add_rid: np.ndarray):
         """Merge the chunk's candidate matrix into the running buffer.
@@ -335,9 +261,7 @@ class TopKStream:
         D._note_compile(mkey, ((nk + 1, self.cap),))
         _hlo_lint.maybe_verify(self.session.conf, "topk-merge", mkey, mjit, (a, b))
         merged = mjit(a, b)
-        from hyperspace_tpu.exec import stage_ir as _stage_ir
-
-        _stage_ir.count_dispatch("topk-merge")
+        D.count_dispatch("topk-merge")
         self._state = merged
         _merges_total().inc()
         mrid = D.fetch(merged[-1], "topk", "topk-merge")

@@ -15,7 +15,7 @@ the *current* span is carried in a :mod:`contextvars` variable, so
 Overhead discipline: when no trace is active, :func:`span` performs ONE
 contextvar read and returns a shared no-op context manager — no allocation,
 no lock. That is what lets instrumentation points stay unconditionally in
-the hot paths (bench.py ``--obs-overhead`` pins the bar).
+the hot paths.
 
 Export: :func:`to_chrome_trace` renders a finished trace as Chrome
 trace-event JSON (``{"traceEvents": [...]}``, complete ``"ph": "X"`` events)

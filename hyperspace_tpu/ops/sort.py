@@ -233,7 +233,7 @@ def bucket_sort_build(
 def warm_build(n: int, kinds: Tuple[str, ...], key_dtypes: Sequence, num_buckets: int) -> None:
     """Pre-compile the build program for a given padded size class so the
     first real build at that size is a cache hit (first XLA compile of the
-    sort is tens of seconds; see bench.py methodology)."""
+    sort is tens of seconds)."""
     ensure_x64()
     keys = tuple(jnp.zeros(n, dtype=dt) for dt in key_dtypes)
     hh = tuple(jnp.zeros(n, dtype=jnp.uint32) for k in kinds if k == "s")
@@ -301,31 +301,6 @@ def topk_merge_fn(num_keys: int, cap: int):
     return run
 
 
-def fused_topk_fn(num_keys: int, cap: int):
-    """Whole-stage fold (``hyperspace.exec.fusion.enabled``): chunk select
-    AND the merge with the running candidate state as ONE program, so a
-    streamed chunk costs a single dispatch and the ``(num_keys + 1, cap)``
-    state matrix can be donated for in-place buffer reuse.
-
-    Returns ``(merged, cand)`` — the updated state plus the chunk's own
-    candidate matrix (whose row-id plane tells the host which chunk rows to
-    pool). Identical math to ``topk_chunk_fn`` then ``topk_merge_fn``, so
-    results stay bit-identical to the per-family pair."""
-
-    def run(state, planes):
-        ensure_x64()
-        ops = tuple(planes[i] for i in range(num_keys + 1))
-        out = lax.sort(ops, num_keys=num_keys + 1, is_stable=False)
-        cand = jnp.stack([_take_cap(o, cap, _TOPK_SENTINEL) for o in out])
-        both = tuple(
-            jnp.concatenate([state[i], cand[i]]) for i in range(num_keys + 1)
-        )
-        merged = lax.sort(both, num_keys=num_keys + 1, is_stable=False)
-        return jnp.stack([o[:cap] for o in merged]), cand
-
-    return run
-
-
 # --- declared HLO contracts (hyperspace_tpu/check/hlo_lint.py), stated next
 # to the program builders like exec/device.py's families ---------------------
 _hlo_lint.register_contract(
@@ -348,22 +323,4 @@ _hlo_lint.register_contract(
         "shard_map top-k chunk: per-shard select + EXACTLY one fixed-size "
         "all-gather of candidate planes (never rows), replicated final merge"
     ),
-)
-_hlo_lint.register_contract(
-    "fused-stage-topk",
-    collectives={"all-gather": (0, None)},
-    description=(
-        "whole-stage chunk select + state merge with donated candidate "
-        "buffer: one executable per chunk"
-    ),
-    single_fusion=True,
-)
-_hlo_lint.register_contract(
-    "fused-stage-topk-sharded",
-    collectives={"all-gather": (1, 1)},
-    description=(
-        "shard_map whole-stage top-k fold: per-shard select, one fixed-size "
-        "candidate all-gather, replicated merge with the running state"
-    ),
-    single_fusion=True,
 )

@@ -6,7 +6,7 @@ collectives module load lazily — they import ``exec/device.py``'s program
 machinery, which callers of a bare ``make_mesh`` should not pay for.
 """
 
-from hyperspace_tpu.parallel.hlo_check import (
+from hyperspace_tpu.check.hlo_lint import (
     assert_collectives,
     assert_shuffle_free,
     collective_counts,

@@ -160,138 +160,3 @@ def sharded_grouped_chunk_program(mesh, axis, pred_fn, key_specs, slot_specs, ca
         return per_shard(cols, lits, n_valid, row_base)
 
     return program
-
-
-def sharded_fused_grouped_program(mesh, axis, pred_fn, key_specs, slot_specs, cap):
-    """Sharded twin of ``device._fused_grouped_update_program``: the whole
-    streamed fold — per-shard chunk select, the all_gather table merge AND
-    the merge into the running (replicated) partial — as ONE program, so a
-    chunk costs a single dispatch under ``hyperspace.exec.fusion.enabled``.
-
-    Same signature as the single-device fused program:
-    ``program(state_keys, state_slots, state_fs, state_n, cols, lits,
-    n_valid, row_base) -> (n_b, n_m, n_out, fs_out, keys_out, slots_out)``.
-
-    Overflow contract matches the single-device twin, with the sharded
-    subtlety folded in: ``n_b`` is maxed with every shard's LOCAL cardinality
-    (a shard over ``cap`` silently dropped groups in its own table), and any
-    overflow makes every state output select the ORIGINAL state so the host
-    can redo the chunk per-family.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    n_dev = mesh.devices.size
-
-    def program(state_keys, state_slots, state_fs, state_n, cols, lits, n_valid, row_base):
-        @partial(
-            shard_map,
-            mesh=mesh,
-            in_specs=(P(), P(), P(), P(), P(axis), P(), P(), P()),
-            out_specs=(P(), P(), P(), P(), P(), P()),
-            check_vma=False,
-        )
-        def per_shard(state_keys_, state_slots_, state_fs_, state_n_, cols_, lits_, n_valid_, row_base_):
-            per = next(iter(cols_.values())).shape[0]
-            d = jax.lax.axis_index(axis).astype(jnp.int64)
-            gidx = d * per + jnp.arange(per, dtype=jnp.int64)
-            valid = gidx < n_valid_
-            mask = valid if pred_fn is None else (pred_fn(cols_, lits_) & valid)
-            codes = [D._key_code(cols_[name], tag) for name, tag in key_specs]
-            order, ms, ng_local, segs = D._segment_ids(codes, mask, cap)
-            from jax import ops as jops
-
-            rep = jops.segment_min(
-                jnp.where(ms, order.astype(jnp.int64), jnp.int64(per)),
-                segs, num_segments=cap, indices_are_sorted=True,
-            )
-            repc = jnp.clip(rep, 0, per - 1)
-            fs_local = jnp.where(rep < per, rep + d * per + row_base_, D._FS_SENTINEL)
-            keys_local = tuple(cols_[name][repc] for name, _ in key_specs)
-            cols_sorted = {c: cols_[c][order] for _, c, _ in slot_specs if c is not None}
-            slots_local = D._segment_reduce_slots(cols_sorted, ms, segs, cap, slot_specs)
-
-            ng_all = jax.lax.all_gather(ng_local, axis)
-            fs_all = jax.lax.all_gather(fs_local, axis).reshape(n_dev * cap)
-            keys_all = tuple(
-                jax.lax.all_gather(k, axis).reshape(n_dev * cap) for k in keys_local
-            )
-            slots_all = tuple(
-                jax.lax.all_gather(s, axis).reshape(n_dev * cap) for s in slots_local
-            )
-            part_mask = (
-                jnp.arange(cap, dtype=jnp.int64)[None, :] < ng_all[:, None]
-            ).reshape(n_dev * cap)
-            n_b, fs_b, key_b, slot_b = D._merge_concat_parts(
-                key_specs, slot_specs, cap, keys_all, slots_all, fs_all, part_mask
-            )
-            n_b = jnp.maximum(n_b, jnp.max(ng_all))
-            # replicated merge into the running partial — identical body to
-            # the single-device fused program's tail
-            idx = jnp.arange(cap)
-            smask = jnp.concatenate([idx < state_n_, idx < n_b])
-            kcat = tuple(jnp.concatenate([a, b]) for a, b in zip(state_keys_, key_b))
-            scat = tuple(jnp.concatenate([a, b]) for a, b in zip(state_slots_, slot_b))
-            fs_cat = jnp.concatenate([state_fs_, fs_b])
-            n_m, fs_m, key_m, slot_m = D._merge_concat_parts(
-                key_specs, slot_specs, cap, kcat, scat, fs_cat, smask
-            )
-            ok = (n_b <= cap) & (n_m <= cap)
-            n_out = jnp.where(ok, n_m, state_n_)
-            fs_out = jnp.where(ok, fs_m, state_fs_)
-            keys_out = tuple(jnp.where(ok, m, s) for m, s in zip(key_m, state_keys_))
-            slots_out = tuple(jnp.where(ok, m, s) for m, s in zip(slot_m, state_slots_))
-            return n_b, n_m, n_out, fs_out, keys_out, slots_out
-
-        return per_shard(state_keys, state_slots, state_fs, state_n, cols, lits, n_valid, row_base)
-
-    return program
-
-
-def sharded_fused_topk_program(mesh, axis, num_keys, cap):
-    """Sharded twin of ``ops.sort.fused_topk_fn``: per-shard chunk select +
-    one all_gather + replicated merge WITH the running candidate state, one
-    dispatch per chunk. Same signature as the single-device fused program:
-    ``program(state, planes) -> (merged, cand)`` where ``state`` and both
-    outputs are replicated ``(num_keys + 1, cap)`` matrices."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-
-    from hyperspace_tpu.ops.sort import _TOPK_SENTINEL, _take_cap
-
-    n_dev = mesh.devices.size
-
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(), P(None, axis)),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )
-    def program(state, planes):
-        local = lax.sort(
-            tuple(planes[i] for i in range(num_keys + 1)),
-            num_keys=num_keys + 1,
-            is_stable=False,
-        )
-        mine = jnp.stack([_take_cap(o, cap, _TOPK_SENTINEL) for o in local])
-        gathered = jax.lax.all_gather(mine, axis)  # (n_dev, K+1, cap)
-        cat = jnp.transpose(gathered, (1, 0, 2)).reshape(num_keys + 1, n_dev * cap)
-        merged_chunk = lax.sort(
-            tuple(cat[i] for i in range(num_keys + 1)),
-            num_keys=num_keys + 1,
-            is_stable=False,
-        )
-        cand = jnp.stack([_take_cap(o, cap, _TOPK_SENTINEL) for o in merged_chunk])
-        both = jnp.concatenate([state, cand], axis=1)
-        merged = lax.sort(
-            tuple(both[i] for i in range(num_keys + 1)),
-            num_keys=num_keys + 1,
-            is_stable=False,
-        )
-        return jnp.stack([_take_cap(o, cap, _TOPK_SENTINEL) for o in merged]), cand
-
-    return program

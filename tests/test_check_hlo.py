@@ -207,7 +207,7 @@ class TestMaybeVerify:
     def test_unnamed_program_violates_program_name(self, scratch_contract, runtime_default_on):
         """A family's executable must be jit_hs_<family>: that name is how the
         profiler's module line, HLO dumps and the compile-cache log find it."""
-        assert hlo_lint.program_name("fused-stage-agg") == "hs_fused_stage_agg"
+        assert hlo_lint.program_name("grouped-agg-chunk") == "hs_grouped_agg_chunk"
         x = jnp.ones(4, jnp.float32)
         maybe_verify(None, scratch_contract, "unnamed", jax.jit(lambda x: x + 1), (x,))
         rules = [f.rule for f in runtime_violations()]
@@ -295,9 +295,9 @@ class TestEndToEnd:
         ):
             assert family in have
 
-    def test_shim_still_exports(self):
-        # parallel/hlo_check is a compat shim over this module now
-        from hyperspace_tpu.parallel import hlo_check as shim
+    def test_parallel_package_reexports_this_module(self):
+        import hyperspace_tpu.parallel as par
 
-        assert shim.collective_counts is collective_counts
-        assert shim.hlo_text_of is hlo_text_of
+        assert par.collective_counts is collective_counts
+        assert par.hlo_text_of is hlo_text_of
+        assert par.assert_shuffle_free is hlo_lint.assert_shuffle_free

@@ -160,10 +160,10 @@ class TestRulePairs:
 
         assert _in_scope(os.path.join("hyperspace_tpu", "exec", "io.py"))
         assert not _in_scope(os.path.join("hyperspace_tpu", "obs", "x.py"))
-        assert not _in_scope("bench.py")
+        assert not _in_scope("chip_smoke.py")
 
     def test_donation_compiler_counts_as_jit_for_purity(self):
-        # compile_stage(skeleton, fn, donate_argnums=...) jits fn — a host
+        # any call that takes fn with donate_argnums=... jits fn — a host
         # numpy call inside fn must fire jit-purity just like jax.jit(fn)
         import ast as _ast
 
@@ -184,7 +184,7 @@ class TestRulePairs:
         assert _in_scope(os.path.join("hyperspace_tpu", "fabric", "x.py"))
         assert _in_scope(os.path.join("hyperspace_tpu", "serving", "x.py"))
         assert not _in_scope(os.path.join("hyperspace_tpu", "obs", "x.py"))
-        assert not _in_scope("bench.py")
+        assert not _in_scope("chip_smoke.py")
 
     def test_process_local_state_only_fires_under_serving_or_reliability(self):
         # Full-scope runs keep the rule off layers whose module state the
@@ -194,7 +194,7 @@ class TestRulePairs:
         assert _in_scope(os.path.join("hyperspace_tpu", "serving", "x.py"))
         assert _in_scope(os.path.join("hyperspace_tpu", "reliability", "x.py"))
         assert not _in_scope(os.path.join("hyperspace_tpu", "obs", "x.py"))
-        assert not _in_scope("bench.py")
+        assert not _in_scope("chip_smoke.py")
 
 
 class TestSuppression:
@@ -230,7 +230,7 @@ class TestRunLint:
         paths = default_paths(default_root())
         assert paths, "default scope is empty"
         assert not any(os.sep + "tests" + os.sep in p for p in paths)
-        assert any(p.endswith("bench.py") for p in paths)
+        assert any(p.endswith("__graft_entry__.py") for p in paths)
 
     def test_repo_tree_is_clean(self):
         # The acceptance gate: the shipped tree carries zero findings.

@@ -239,6 +239,32 @@ class TestStreaming:
         q2.collect()
         assert compiles.value == warm2
 
+    def test_chunk_over_compiled_capacity_reruns_once_right_sized(self, session, lineitems):
+        """A cold stream compiles for the capacity floor. A chunk that finds
+        more groups than that is re-run once at the capacity that holds them,
+        the chunks after it start there, and the answer equals the host's."""
+        from hyperspace_tpu.exec import device as D
+
+        df = session.read_parquet(lineitems)
+        q = df.group_by("qty").agg(n=("*", "count"), s=("price", "sum"), hi=("ship", "max"))
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, True)
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_MIN_ROWS, 0)
+        session.conf.set(hst.keys.EXEC_STREAM_AGG_MIN_BYTES, 1)
+        session.conf.set(hst.keys.EXEC_STREAM_CHUNK_BYTES, 1)  # one file per chunk
+        session.conf.set(hst.keys.EXEC_AGG_CAPACITY_FLOOR, 2)
+        D.clear_device_cache()  # no capacity hint from an earlier run of this shape
+        chunk_runs = REGISTRY.counter("hs_device_dispatches_total", "", program="grouped-agg-chunk")
+        before = chunk_runs.value
+        with trace.recording() as events:
+            dev = q.collect()
+        assert ("agg", "device-grouped-stream") in events
+        assert len(dev["qty"]) == 50 > D.group_capacity(1, 2)
+        assert chunk_runs.value - before == 4 + 1  # four chunks, the first of them twice
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, False)
+        host = q.collect()
+        session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, True)
+        assert_grouped_equal(dev, host, float_cols=("s",))
+
     def test_cardinality_spill_matches_host(self, session, lineitems):
         """Group cardinality above ``hyperspace.exec.agg.maxGroups`` folds the
         device partial into the host merge mid-stream — same result, plus a

@@ -13,9 +13,6 @@ rule engine (``assert_contract``):
 - the bucketed equi-join: NO data-movement collective at all (all-reduce is
   permitted only for a query's own aggregate),
 - plane packing is bit-exact for every exchanged dtype.
-
-``parallel/hlo_check`` remains as a compat shim; one test drives the old
-import path to keep it honest.
 """
 
 from functools import partial
@@ -196,8 +193,8 @@ class TestShardedExecPrograms:
         assert_contract("bucketed-smj-span", txt, "bucketed SMJ span program")
 
     def test_sharded_filter_program_is_shuffle_free(self, mesh):
-        """The sharded predicate program moves no rows between devices; the
-        old parallel.hlo_check import path (compat shim) must keep working."""
+        """The sharded predicate program moves no rows between devices (through
+        the ``hyperspace_tpu.parallel`` re-exports of ``check.hlo_lint``)."""
         from hyperspace_tpu.parallel import assert_shuffle_free, hlo_text_of as shim_text_of
         from hyperspace_tpu.parallel import collectives as C
 

@@ -241,6 +241,60 @@ class TestQ3Chain:
         got_mat = pd.DataFrame(q.collect())
         assert _norm(got_mat[["dname", "region", "amount"]]) == _norm(exp)
 
+    def test_grouped_aggregate_over_streamed_join_matches_host(self, tmp_path):
+        """TPC-H q3's shape: Filter -> broadcast Join -> grouped Aggregate.
+        The probe side streams a file a chunk through the broadcast join and
+        its post-join filter, every chunk folds into one GroupedAggStream, and
+        the answer equals the host executor's (deviceExecution off)."""
+        from hyperspace_tpu.exec import device as D
+        from hyperspace_tpu.exec.executor import Executor
+
+        probe_dir, build_dir = str(tmp_path / "probe"), str(tmp_path / "build")
+        os.makedirs(probe_dir), os.makedirs(build_dir)
+        rng = np.random.default_rng(3)
+        for i in range(4):
+            pq.write_table(pa.table({
+                "k": rng.integers(0, 80, 900).astype(np.int64),
+                "g": rng.integers(0, 12, 900).astype(np.int64),
+                "v": np.round(rng.standard_normal(900), 4),
+            }), os.path.join(probe_dir, f"p{i}.parquet"))
+        pq.write_table(pa.table({
+            "k2": rng.integers(0, 90, 120).astype(np.int64),
+            "w": np.round(rng.standard_normal(120), 4),
+        }), os.path.join(build_dir, "b.parquet"))
+
+        def joined(sess):
+            probe, build = sess.read_parquet(probe_dir), sess.read_parquet(build_dir)
+            return probe.join(build, on=col("k") == col("k2"), how="inner").filter(col("v") > -0.5)
+
+        aggs = dict(n=("*", "count"), s=("v", "sum"), a=("w", "avg"), mn=("v", "min"), mx=("w", "max"))
+        host = _mk_session(tmp_path, **{hst.keys.TPU_QUERY_DEVICE_EXECUTION: False})
+        want = pd.DataFrame(joined(host).group_by("g").agg(**aggs).collect())
+
+        sess = _mk_session(
+            tmp_path,
+            **{hst.keys.TPU_QUERY_DEVICE_MIN_ROWS: 0, hst.keys.EXEC_STREAM_CHUNK_BYTES: 1},
+        )
+        gs = D.GroupedAggStream(
+            sess, ["g"],
+            [("n", "count", None), ("s", "sum", "v"), ("a", "avg", "w"),
+             ("mn", "min", "v"), ("mx", "max", "w")],
+            max_groups=sess.conf.agg_max_groups,
+            cap_floor=sess.conf.agg_capacity_floor,
+        )
+        before = _counter("hs_join_broadcast_total")
+        chunks = 0
+        for chunk in Executor(sess).execute_stream(joined(sess).plan):
+            gs.update({c: np.asarray(v) for c, v in chunk.items()}, None)
+            chunks += 1
+        assert chunks == 4 and _counter("hs_join_broadcast_total") == before + 1
+        got = pd.DataFrame(gs.finalize())
+
+        got, want = (d.sort_values("g").reset_index(drop=True) for d in (got, want))
+        assert list(got["g"]) == list(want["g"]) and list(got["n"]) == list(want["n"])
+        for c in ("s", "a", "mn", "mx"):
+            np.testing.assert_allclose(got[c], want[c], rtol=1e-9, err_msg=c)
+
     def test_probe_compile_flatness_across_chunk_sizes(self, tmp_path):
         """Sweeping the probe chunk size must not mint per-chunk-shape probe
         executables: √2 shape buckets keep it to ≤3 per stream."""

@@ -64,7 +64,10 @@ def write_marked_part(root, marker, n=120):
         }
     )
     final = os.path.join(root, f"part-{marker:05d}.parquet")
-    tmp = final + ".tmp"
+    # written under a name the source listing skips (DataPathFilter: "_" and
+    # "." prefixes), as Spark writes under _temporary: a lister must never
+    # meet a file that is renamed away before it is stat'ed
+    tmp = os.path.join(root, f"_part-{marker:05d}.parquet.tmp")
     pq.write_table(t, tmp)
     os.replace(tmp, final)
     return final

@@ -118,9 +118,8 @@ def _wide(table, keyed):
     return [c for c in TPCDS_SCHEMAS[table] if c not in keyed]
 
 
-# Round-5 leverage expansion, driven by the whyNot sweep over the 103 texts
-# (benchmarks/tpcds_whynot.py — the CandidateIndexAnalyzer.scala:29-346
-# workflow): every fact-table FK used as a join key gets a bucketed slice,
+# Round-5 leverage expansion, driven by a whyNot sweep over the 103 texts
+# (the CandidateIndexAnalyzer.scala:29-346 workflow): every fact-table FK used as a join key gets a bucketed slice,
 # the returns tables join their sales counterparts on composite
 # (item, ticket/order) keys, and every dimension is covered on its
 # surrogate key.
