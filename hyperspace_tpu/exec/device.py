@@ -38,6 +38,7 @@ import numpy as np
 
 from hyperspace_tpu.exec import batch as B
 from hyperspace_tpu.exec import trace
+from hyperspace_tpu.exec.file_identity import committed_keys, plan_identity
 from hyperspace_tpu.plan import logical as L
 from hyperspace_tpu.plan.expr import (
     BinaryOp,
@@ -2002,9 +2003,10 @@ def _read_buckets(scan: L.IndexScan, columns: List[str], sort_keys: Optional[Lis
     file_cols = [scan.file_column_of(c) for c in columns]
     rename = file_cols != list(columns)
 
+    committed = committed_keys(scan)
     out: Dict[int, B.Batch] = {}
     for b, files in per_bucket.items():
-        batch = read_parquet_batch(files, file_cols)
+        batch = read_parquet_batch(files, file_cols, committed=committed)
         if rename:
             batch = {o: batch[fc] for o, fc in zip(columns, file_cols)}
         if sort_keys and len(files) > 1:
@@ -2101,22 +2103,13 @@ def _side_buckets(
         # executions skip the decode + hash + sort entirely. A new append
         # changes the file list/mtimes and naturally misses.
         cache_key = None
-        files = []
-        for p in L.collect(node.child, lambda x: isinstance(x, (L.FileScan, L.Scan))):
-            files.extend(_side_files(p) if not isinstance(p, L.Scan)
-                         else [fi.name for fi in p.relation.all_file_infos()])
-        if files:
-            try:
-                ident = tuple(
-                    (f, os.stat(f).st_mtime_ns, os.stat(f).st_size) for f in files
-                )
-                cache_key = (
-                    "rebucket", ident, spec.num_buckets,
-                    tuple(spec.bucket_columns), tuple(columns), tuple(sort_keys),
-                    node.child.pretty(),
-                )
-            except OSError:
-                cache_key = None
+        ident = plan_identity(node.child, (L.FileScan, L.Scan))
+        if ident:
+            cache_key = (
+                "rebucket", ident, spec.num_buckets,
+                tuple(spec.bucket_columns), tuple(columns), tuple(sort_keys),
+                node.child.pretty(),
+            )
         if cache_key is not None:
             hit = _REBUCKET_CACHE.get(cache_key)
             if hit is not None:
@@ -2185,10 +2178,11 @@ def _side_bucket_readers(session, node: L.LogicalPlan, columns: List[str], sort_
             per_bucket.setdefault(b, []).append(f)
         file_cols = [node.file_column_of(c) for c in columns]
         rename = file_cols != list(columns)
+        committed = committed_keys(node)
 
         def make(files):
             def read() -> B.Batch:
-                batch = read_parquet_batch(files, file_cols)
+                batch = read_parquet_batch(files, file_cols, committed=committed)
                 if rename:
                     batch = {o: batch[fc] for o, fc in zip(columns, file_cols)}
                 if sort_keys and len(files) > 1:
@@ -2504,26 +2498,24 @@ def _join_key_of(batch: B.Batch, key: str) -> np.ndarray:
 _FOOTER_ROWS_CACHE: Dict[Tuple[str, int, int], int] = {}
 
 
-def _file_num_rows(path: str) -> int:
-    """Row count from the parquet footer, memoized on (path, mtime, size)."""
+def _file_num_rows(key) -> int:
+    """Row count from the parquet footer, memoized on the file's identity
+    ``key`` (file_identity: path, size, mtime)."""
     import pyarrow.parquet as pq
 
-    st = os.stat(path)
-    key = (path, st.st_mtime_ns, st.st_size)
     got = _FOOTER_ROWS_CACHE.get(key)
     if got is None:
         if len(_FOOTER_ROWS_CACHE) > 65536:
             _FOOTER_ROWS_CACHE.clear()
-        got = pq.read_metadata(path).num_rows
+        got = pq.read_metadata(key[0]).num_rows
         _FOOTER_ROWS_CACHE[key] = got
     return got
 
 
-def _side_files(node: L.LogicalPlan) -> List[str]:
-    files: List[str] = []
-    for p in L.collect(node, lambda x: isinstance(x, (L.IndexScan, L.FileScan))):
-        files.extend(p.files)
-    return files
+def _side_identity(node: L.LogicalPlan):
+    """Identity of every file a join side's index and file scans read
+    (file_identity.plan_identity); None when one cannot be stat'ed."""
+    return plan_identity(node, (L.IndexScan, L.FileScan))
 
 
 # composite-key rank encodings keyed on both sides' full identity, byte-capped
@@ -2538,22 +2530,15 @@ _REBUCKET_CACHE = BytesLRU(int(os.environ.get("HS_REBUCKET_CACHE_BYTES", 1 << 28
 
 
 def _rank_cache_key(lside, rside, lkeys: List[str], rkeys: List[str]):
-    """Identity of a rank encoding: both sides' (file, mtime, size) sets, the
-    key names, AND the sides' plan text — ranks are computed over rows that
+    """Identity of a rank encoding: both sides' file identities, the key
+    names, AND the sides' plan text — ranks are computed over rows that
     survive the sides' Filters (lineage NOT-IN, pushed predicates), so a
     changed filter over identical files must miss. None (= don't cache) when
-    any file can't be stat'ed."""
-    parts = [tuple(lkeys), tuple(rkeys), lside.pretty(), rside.pretty()]
-    for side in (lside, rside):
-        files = []
-        for f in _side_files(side):
-            try:
-                st = os.stat(f)
-            except OSError:
-                return None
-            files.append((f, st.st_mtime_ns, st.st_size))
-        parts.append(tuple(files))
-    return tuple(parts)
+    any file has no identity."""
+    sides = (_side_identity(lside), _side_identity(rside))
+    if None in sides:
+        return None
+    return (tuple(lkeys), tuple(rkeys), lside.pretty(), rside.pretty()) + sides
 
 
 def _fold_streamed_join(session, plan: L.Join, compat) -> B.Batch:
@@ -2618,23 +2603,22 @@ def dispatch_bucketed_join(session, plan: L.Join) -> B.Batch:
     if compat is None:
         raise DeviceUnsupported("join sides are not compatible bucketed index scans")
     lside, rside, lkeys, rkeys = compat
-    try:
-        total = sum(
-            _file_num_rows(f) for side in (lside, rside) for f in _side_files(side)
-        )
-    except OSError:
-        total = 0  # unreadable footer -> stay on host
+    # both gates below read one pass over the sides' file identities: the
+    # log entry's for index files, so sizing the dispatch costs no syscall
+    sides = (_side_identity(lside), _side_identity(rside))
+    file_keys = [k for side in sides for k in side] if None not in sides else None
+    total = 0  # a file without identity or an unreadable footer -> stay on host
+    if file_keys is not None:
+        try:
+            total = sum(_file_num_rows(k) for k in file_keys)
+        except OSError:
+            pass
     # out-of-core gate: above the streaming threshold (estimated from file
     # sizes — no decode), walk buckets one at a time instead of decoding
     # both whole sides; peak memory drops to O(bucket pair + output)
     stream_min = session.conf.stream_join_min_bytes
     if stream_min and stream_min > 0:
-        try:
-            input_bytes = sum(
-                os.stat(f).st_size for side in (lside, rside) for f in _side_files(side)
-            )
-        except OSError:
-            input_bytes = 0
+        input_bytes = sum(k[1] for k in file_keys) if file_keys is not None else 0
         if input_bytes >= stream_min:
             with _obs_spans.span("join-host-span-smj-stream", cat="exec"):
                 return _fold_streamed_join(session, plan, compat)
@@ -2993,8 +2977,7 @@ def _encoded_join_keys(plan: L.Join, setup, compat, _pair_key=None):
     ranks, cached across queries on the sides' immutable file + filter
     identity. The SAME arrays feed the host merge walk and the device span
     program, so both backends cover every key shape. ``_pair_key`` lets a
-    caller that already computed `_rank_cache_key` (one os.stat sweep per
-    side) pass it through instead of re-statting."""
+    caller that already computed `_rank_cache_key` pass it through."""
     lbuckets, rbuckets, lkeys, rkeys, _nb, _lc, _rc = setup
 
     single_int = len(lkeys) == 1

@@ -19,6 +19,12 @@ import pyarrow.dataset as pads
 from hyperspace_tpu.exec import batch as B
 from hyperspace_tpu.exec import trace
 from hyperspace_tpu.exec.device import DeviceUnsupported
+from hyperspace_tpu.exec.file_identity import (
+    committed_keys,
+    file_identities,
+    leaf_files,
+    scan_identity,
+)
 from hyperspace_tpu.obs import spans
 from hyperspace_tpu.plan import logical as L
 from hyperspace_tpu.plan.expr import (
@@ -40,41 +46,6 @@ _STREAM_FALLBACK_ERRORS = (DeviceUnsupported, ReliabilityError)
 
 #: synthetic global-row-id column carried by the top-k host fallback
 _TOPK_RID = "__hs_topk_rid__"
-
-
-def _scan_identity(scan):
-    """Stable identity of a scan's file set for device-side caching: the
-    ``(path, size, mtime)`` of every file, in scan order.
-
-    An IndexScan has it from its log entry, which recorded each data file's
-    size and mtime when the index version was committed. Index files are
-    written once; a refresh or optimize commits new files, a vacuumed and
-    rebuilt index other mtimes, and commits purge what they replace
-    (``purge_device_cache_files``) — so nothing is stat'ed per query. On a
-    lake's filesystem a stat is a round trip: 136 us a file on the 9p mount
-    of the benchmark's machine, 100 ms a lookup over 800 files.
-
-    Any other scan reads files that their owners may rewrite in place, so
-    each is stat'ed: a rewrite changes mtime/size and naturally invalidates.
-    Returns None (= don't cache) when a file can't be stat'ed — a path-only
-    key could serve stale device columns after an in-place rewrite."""
-    import os
-
-    entry = getattr(scan, "entry", None)
-    if entry is not None:
-        committed = entry.content.file_keys()
-        try:
-            return tuple(committed[f] for f in scan.files)
-        except KeyError:
-            pass  # files of no committed version of this entry: stat them
-    parts = []
-    for f in scan.files:
-        try:
-            st = os.stat(f)
-        except OSError:
-            return None
-        parts.append((f, st.st_size, st.st_mtime_ns))
-    return tuple(parts)
 
 
 def _maybe_parallel(session, n_rows: Optional[int] = None):
@@ -112,13 +83,15 @@ def _read_files(
     format_options: Optional[dict] = None,
     predicate=None,
     kept: Optional[list] = None,
+    committed=None,
 ) -> B.Batch:
     """Read ``files`` into one batch. ``partition_values`` ({file -> {col ->
     typed value}}) attaches hive-partition columns — constant per file, absent
     from the file bytes — to each file's rows. ``predicate`` (the scan's
     pushed-down filter, re-applied by the Filter above) enables parquet
     row-group min/max pruning in the reader, which reports the groups it
-    kept in ``kept`` (io.read_parquet_batch)."""
+    kept in ``kept`` (io.read_parquet_batch). ``committed`` is what the
+    scan's log entry recorded of its files (file_identity.committed_keys)."""
     from hyperspace_tpu.exec.io import _decode_pool, read_parquet_batch
 
     if not files:
@@ -153,7 +126,9 @@ def _read_files(
             b: B.Batch = {}
             n = F.count_rows(f, file_format, format_options)
         elif file_format == "parquet":
-            b = read_parquet_batch([f], file_columns, predicate=predicate, kept=kept)
+            b = read_parquet_batch(
+                [f], file_columns, predicate=predicate, kept=kept, committed=committed
+            )
             n = B.num_rows(b)
         else:
             b = B.table_to_batch(F.read_table(f, file_format, file_columns, format_options))
@@ -180,7 +155,9 @@ def _read_files(
             return B.concat(list(_decode_pool().map(spans.wrap(read_one), files)))
         return B.concat([read_one(f) for f in files])
     if file_format == "parquet":
-        return read_parquet_batch(list(files), columns, predicate=predicate, kept=kept)
+        return read_parquet_batch(
+            list(files), columns, predicate=predicate, kept=kept, committed=committed
+        )
     from hyperspace_tpu.sources import formats as F
 
     t = F.open_dataset(list(files), file_format, format_options).to_table(columns=columns)
@@ -436,6 +413,7 @@ def _read_scan_files(scan, *args, **kwargs) -> B.Batch:
     """``_read_files`` for a scan leaf under its pushed-down predicate. What
     the read kept is left on the leaf for ``_kept_groups``: a leaf that
     carries a predicate is this execution's own clone, read once."""
+    kwargs["committed"] = committed_keys(scan)
     predicate = getattr(scan, "pushdown_predicate", None)
     if predicate is None:
         return _read_files(*args, **kwargs)
@@ -509,12 +487,6 @@ def _rebuild_chain(chain, leaf: L.LogicalPlan) -> L.LogicalPlan:
     return node
 
 
-def _leaf_files(leaf: L.LogicalPlan) -> List[str]:
-    if isinstance(leaf, L.Scan):
-        return [fi.name for fi in leaf.relation.all_file_infos()]
-    return list(leaf.files)
-
-
 def _leaf_subset(leaf: L.LogicalPlan, files: List[str], needed=None) -> L.LogicalPlan:
     """A scan leaf over only ``files``; a relation-backed Scan becomes a
     FileScan carrying the relation's format/partition metadata (and pruned
@@ -548,19 +520,18 @@ def _leaf_subset(leaf: L.LogicalPlan, files: List[str], needed=None) -> L.Logica
     )
 
 
-def _chunk_files_by_bytes(files: List[str], target_bytes: int) -> List[List[str]]:
-    """Greedy size-bounded file groups (a single file above the target forms
-    its own group)."""
-    import os
-
+def _chunk_files_by_bytes(leaf, files: List[str], target_bytes: int, keys=None) -> List[List[str]]:
+    """Greedy size-bounded groups of a scan leaf's ``files`` (a single file
+    above the target forms its own group). Sizes are those of the files'
+    identities ``keys`` (file_identity), asked for here unless the caller
+    already has them."""
+    if keys is None:
+        keys = file_identities(files, committed_keys(leaf))
     groups: List[List[str]] = []
     cur: List[str] = []
     cur_bytes = 0
-    for f in files:
-        try:
-            sz = os.stat(f).st_size
-        except OSError:
-            sz = target_bytes  # unknown -> isolate conservatively
+    for f, key in zip(files, keys):
+        sz = key[1] if key is not None else target_bytes  # unknown -> isolate conservatively
         if cur and cur_bytes + sz > target_bytes:
             groups.append(cur)
             cur, cur_bytes = [], 0
@@ -866,9 +837,9 @@ class Executor:
                             return
                 chain, leaf = _chain_to_scan(plan)
                 if leaf is not None:
-                    files = _leaf_files(leaf)
+                    files = leaf_files(leaf)
                     groups = _chunk_files_by_bytes(
-                        files, max(1, self.session.conf.stream_chunk_bytes)
+                        leaf, files, max(1, self.session.conf.stream_chunk_bytes)
                     )
                     if len(groups) > 1:
                         needed = _chain_needed_columns(chain) | set(plan.output_columns)
@@ -964,7 +935,7 @@ class Executor:
         def stage(i, batch):
             if B.num_rows(batch) < conf.device_exec_min_rows:
                 return
-            key = _pruned_scan_key(_scan_identity(leaves[i]), _kept_groups(leaves[i]))
+            key = _pruned_scan_key(scan_identity(leaves[i]), _kept_groups(leaves[i]))
             # stage onto the mesh the consumer will execute over, so the
             # sharded path's device-cache lookups (keyed by mesh fingerprint)
             # hit the columns placed here
@@ -1076,7 +1047,9 @@ class Executor:
             fcols = plan.file_columns if plan.file_columns is not None else list(plan.columns)
             bucket_cache = getattr(self.session, "bucket_cache", None)
             if bucket_cache is not None and not with_file_names and plan.files:
-                batch = bucket_cache.read(list(plan.files), list(fcols))
+                batch = bucket_cache.read(
+                    list(plan.files), list(fcols), committed=committed_keys(plan)
+                )
             else:
                 batch = _read_scan_files(
                     plan, list(plan.files), "parquet", list(fcols), with_file_names
@@ -1354,7 +1327,7 @@ class Executor:
                             child,
                             col,
                             ids,
-                            scan_key=_pruned_scan_key(_scan_identity(plan.child), kept),
+                            scan_key=_pruned_scan_key(scan_identity(plan.child), kept),
                             parallel=px,
                         )
                         trace.record("filter", "device-lineage")
@@ -1375,7 +1348,7 @@ class Executor:
                         self.session,
                         child,
                         plan.condition,
-                        scan_key=_pruned_scan_key(_scan_identity(plan.child), kept),
+                        scan_key=_pruned_scan_key(scan_identity(plan.child), kept),
                         parallel=px,
                     )
                     trace.record("filter", "device-sharded" if px is not None else "device")
@@ -1482,8 +1455,8 @@ class Executor:
         chain, leaf = _chain_to_scan(plan.child)
         if leaf is None:
             return None
-        files = _leaf_files(leaf)
-        groups = _chunk_files_by_bytes(files, max(1, conf.stream_chunk_bytes))
+        files = leaf_files(leaf)
+        groups = _chunk_files_by_bytes(leaf, files, max(1, conf.stream_chunk_bytes))
         if len(groups) < 2:
             return None
         needed = _chain_needed_columns(chain) | set(plan.output_columns)
@@ -1525,10 +1498,10 @@ class Executor:
         chain, leaf = _chain_to_scan(sort_plan.child)
         if leaf is None:
             return None
-        files = _leaf_files(leaf)
+        files = leaf_files(leaf)
         if len(files) < 2:
             return None
-        groups = _chunk_files_by_bytes(files, max(1, conf.stream_chunk_bytes))
+        groups = _chunk_files_by_bytes(leaf, files, max(1, conf.stream_chunk_bytes))
         if len(groups) < 2:
             return None
         try:
@@ -1639,7 +1612,7 @@ class Executor:
 
         from hyperspace_tpu.plan.expr import get_column
 
-        files = _leaf_files(leaf)
+        files = leaf_files(leaf)
         if len(files) < 2:
             return None  # a single run needs no merge; host path is fine
         needed = _chain_needed_columns(chain) | set(sort_plan.output_columns)
@@ -1728,18 +1701,13 @@ class Executor:
         chain, leaf = _chain_to_scan(plan.child)
         if leaf is None:
             return None
-        files = _leaf_files(leaf)
+        files = leaf_files(leaf)
         if len(files) < 2:
             return None
-        import os
-
-        try:
-            total_bytes = sum(os.stat(f).st_size for f in files)
-        except OSError:
+        keys = file_identities(files, committed_keys(leaf))
+        if None in keys or sum(k[1] for k in keys) < min_bytes:
             return None
-        if total_bytes < min_bytes:
-            return None
-        groups = _chunk_files_by_bytes(files, max(1, conf.stream_chunk_bytes))
+        groups = _chunk_files_by_bytes(leaf, files, max(1, conf.stream_chunk_bytes), keys)
         if len(groups) < 2:
             return None
         needed = _chain_needed_columns(chain, plan.aggs, plan.keys)
@@ -1891,7 +1859,7 @@ class Executor:
                 # capacity hint shared across repeated runs of the same
                 # query shape over the same file set (skips the first
                 # chunk's right-sizing re-run once cardinality is known)
-                hint_key=("stream",) + tuple(_leaf_files(leaf)),
+                hint_key=("stream",) + tuple(leaf_files(leaf)),
                 # per-stream mode decision (chunk sizes aren't known yet):
                 # minRows gates the one-shot ops, not stream chunks
                 parallel=_maybe_parallel(self.session),
@@ -1913,7 +1881,7 @@ class Executor:
                         trace.fallback("agg", "min-rows")
                         device_ok = False
                     else:
-                        key = _pruned_scan_key(_scan_identity(lf), _kept_groups(lf))
+                        key = _pruned_scan_key(scan_identity(lf), _kept_groups(lf))
                         try:
                             stream.update(leaf_batch, fuse_cond, scan_key=key)
                             continue
@@ -2053,7 +2021,7 @@ class Executor:
             trace.fallback("agg", "min-rows")
             return None, batch, filter_node, kept
         condition = filter_node.condition if filter_node is not None else None
-        scan_key = _pruned_scan_key(_scan_identity(node), kept)
+        scan_key = _pruned_scan_key(scan_identity(node), kept)
         name = "agg-device-grouped-scan" if plan.keys else "agg-device-fused-scan"
         with spans.span(name, cat="exec") as tier:
             try:

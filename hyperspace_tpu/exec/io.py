@@ -21,6 +21,7 @@ import pyarrow.parquet as pq
 
 from hyperspace_tpu.exec import batch as B
 from hyperspace_tpu.exec import trace
+from hyperspace_tpu.exec.file_identity import file_identities
 from hyperspace_tpu.obs import spans
 from hyperspace_tpu.reliability import errors as rerr
 from hyperspace_tpu.reliability.degrade import QUARANTINE
@@ -31,7 +32,8 @@ from hyperspace_tpu.reliability.retry import with_retry
 # Per-file decoded-batch cache (the framework's buffer pool). Spark gets this
 # from the OS page cache + executor columnar caching; here repeated scans of
 # the same immutable index/bucket files skip decode entirely. Entries key on
-# (path, mtime_ns, size, columns) so any rewrite invalidates naturally.
+# the file's identity (path, size, mtime_ns: exec/file_identity.py) plus the
+# columns, so any rewrite invalidates naturally.
 # ---------------------------------------------------------------------------
 
 from hyperspace_tpu.utils.lru import BytesLRU
@@ -53,12 +55,12 @@ def _batch_nbytes(batch: B.Batch) -> int:
     return total
 
 
-def _io_cache_key(path: str, columns: Optional[List[str]]):
-    try:
-        st = os.stat(path)
-    except OSError:
+def _io_cache_key(identity, columns: Optional[List[str]]):
+    """Cache key of one file's decoded ``columns`` from the file's identity
+    (file_identity.file_identities); None (= don't cache) where it has none."""
+    if identity is None:
         return None
-    return (path, st.st_mtime_ns, st.st_size, tuple(columns) if columns is not None else None)
+    return identity + (tuple(columns) if columns is not None else None,)
 
 
 def _io_cache_get(key) -> Optional[B.Batch]:
@@ -298,11 +300,12 @@ def prune_row_groups(path: str, predicate) -> Optional[List[int]]:
 
 
 def _read_row_groups(
-    f: str, columns: Optional[List[str]], schema: pa.Schema, keep: List[int], dsp
+    f: str, columns: Optional[List[str]], schema: pa.Schema, keep: List[int], dsp, ckey
 ) -> B.Batch:
     """Decode only the surviving row groups of one file (pyarrow path; the
     native decoder reads whole column chunks). Fully-pruned files return a
-    typed empty batch from the file schema."""
+    typed empty batch from the file schema. ``ckey`` is the whole file's
+    cache key (_io_cache_key)."""
     scanned_c, skipped_c, bytes_c = _rg_counters()
     md = pq.read_metadata(f)
     n_rg = md.num_row_groups
@@ -320,7 +323,6 @@ def _read_row_groups(
         if columns is not None:
             t = t.select(columns)
         return B.table_to_batch(t)
-    ckey = _io_cache_key(f, columns)
     ckey = ckey + (("rg",) + tuple(keep),) if ckey is not None else None
     got = _io_cache_get(ckey)
     if got is not None:
@@ -420,6 +422,7 @@ def _native_rg_scan(
     columns: Optional[List[str]],
     schemas: List[pa.Schema],
     predicate,
+    file_keys: list,
     concat_key,
     kept: Optional[list] = None,
 ) -> Optional[B.Batch]:
@@ -467,7 +470,7 @@ def _native_rg_scan(
             _native_fallback_counter("io-error").inc()
             return None
         return _native_rg_decode(
-            files, cols, columns, hints, predicate, concat_key, handles, kept
+            files, cols, hints, predicate, file_keys, concat_key, handles, kept
         )
     finally:
         for h in handles:
@@ -477,9 +480,9 @@ def _native_rg_scan(
 def _native_rg_decode(
     files: List[str],
     cols: List[str],
-    columns: Optional[List[str]],
     hints: Dict[str, np.dtype],
     predicate,
+    file_keys: list,
     concat_key,
     handles,
     kept: Optional[list],
@@ -785,7 +788,7 @@ def _native_rg_decode(
     if not pruned_any:
         for fi, f in enumerate(files):
             s, e = starts[fi], starts[fi] + file_rows[fi]
-            _io_cache_put(_io_cache_key(f, columns), {c: out[c][s:e] for c in cols})
+            _io_cache_put(file_keys[fi], {c: out[c][s:e] for c in cols})
         if concat_key is not None:
             _io_cache_put(concat_key, dict(out))
     elif kept is not None:
@@ -803,6 +806,7 @@ def read_parquet_batch(
     columns: Optional[List[str]],
     predicate=None,
     kept: Optional[list] = None,
+    committed=None,
 ) -> B.Batch:
     """Read ``columns`` of ``files`` into one concatenated batch, native-first.
 
@@ -821,8 +825,15 @@ def read_parquet_batch(
     pruned and nothing for a file read whole, decoded or from the host cache.
     It stays empty exactly when the batch is every row of ``files`` in order;
     the executor keys resident device columns on it (_pruned_scan_key).
+
+    ``committed`` is what the caller's index log entry recorded of these files
+    (``Content.file_keys()``: path -> identity). The host cache is keyed on
+    each file's identity, asked for once a file a call: from ``committed``
+    where it knows the file, by a stat otherwise (file_identity.py).
     """
     from hyperspace_tpu import native
+
+    file_keys = [_io_cache_key(k, columns) for k in file_identities(files, committed)]
 
     def _dataset_read() -> B.Batch:
         trace.record("decode", "pyarrow-dataset")
@@ -876,11 +887,10 @@ def read_parquet_batch(
     # so dispatch goldens are insensitive to which cache tier answered.
     concat_key = None
     if columns is not None and len(files) > 1:
-        per_file = [_io_cache_key(f, columns) for f in files]
         # a None per-file key (stat failed) disables caching everywhere
         # else; embedding it in the tuple would collide unrelated scans
-        if all(k is not None for k in per_file):
-            concat_key = ("concat", tuple(per_file))
+        if None not in file_keys:
+            concat_key = ("concat", tuple(file_keys))
             got = _io_cache_get(concat_key)
             if got is not None:
                 for _ in files:
@@ -892,7 +902,7 @@ def read_parquet_batch(
     # pre-scan can be skipped. With columns=None per-file schemas may differ
     # (cached entries then have heterogeneous keys), so that case still goes
     # through the pre-scan below before trusting the cache.
-    cached = [_io_cache_get(_io_cache_key(f, columns)) for f in files]
+    cached = [_io_cache_get(k) for k in file_keys]
     if columns is not None and cached and all(b is not None for b in cached):
         for _ in cached:
             trace.record("decode", "cached")
@@ -948,13 +958,12 @@ def read_parquet_batch(
             _native_fallback_counter("schema-evolved").inc(len(missing))
 
     if not evolved and not any(b is not None for b in cached):
-        got = _native_rg_scan(files, columns, schemas, predicate, concat_key, kept)
+        got = _native_rg_scan(files, columns, schemas, predicate, file_keys, concat_key, kept)
         if got is not None:
             return got
 
-    def read_one(f: str, schema) -> B.Batch:
+    def read_one(f: str, schema, ckey) -> B.Batch:
         with spans.span("decode", cat="io", file=os.path.basename(f)) as dsp:
-            ckey = _io_cache_key(f, columns)
             got = _io_cache_get(ckey)
             if got is not None:
                 trace.record("decode", "cached")
@@ -962,7 +971,7 @@ def read_parquet_batch(
             if predicate is not None and f not in evolved:
                 keep = prune_row_groups(f, predicate)
                 if keep is not None:
-                    got = _read_row_groups(f, columns, schema, keep, dsp)
+                    got = _read_row_groups(f, columns, schema, keep, dsp, ckey)
                     if kept is not None:
                         kept.append((f, tuple(keep)))
                     return got
@@ -1040,9 +1049,9 @@ def read_parquet_batch(
         # spans.wrap binds the submitting request's current span into the
         # pool workers — contextvars do NOT cross ThreadPoolExecutor on
         # their own, and decode spans must land in the caller's tree
-        batches = list(_decode_pool().map(spans.wrap(read_one), files, schemas))
+        batches = list(_decode_pool().map(spans.wrap(read_one), files, schemas, file_keys))
     else:
-        batches = [read_one(f, s) for f, s in zip(files, schemas)]
+        batches = [read_one(f, s, k) for f, s, k in zip(files, schemas, file_keys)]
     if not batches:
         return _dataset_read()
     if len(batches) == 1:

@@ -33,7 +33,6 @@ invalidated on brand rotation like the result cache.
 
 from __future__ import annotations
 
-import os
 from collections import OrderedDict
 from functools import lru_cache
 from typing import Dict, List, Optional, Tuple
@@ -42,6 +41,7 @@ import numpy as np
 
 from hyperspace_tpu.exec import batch as B
 from hyperspace_tpu.exec import trace
+from hyperspace_tpu.exec.file_identity import plan_identity, scan_identity
 from hyperspace_tpu.exec.device import (
     DeviceUnsupported,
     _join_column_source,
@@ -121,7 +121,10 @@ def _plan_leaf_bytes(plan: L.LogicalPlan) -> Optional[int]:
             else:
                 if not leaf.files:
                     return None
-                total += sum(os.stat(f).st_size for f in leaf.files)
+                keys = scan_identity(leaf)
+                if keys is None:
+                    raise OSError("a leaf file cannot be stat'ed")
+                total += sum(k[1] for k in keys)
         except Exception as exc:
             # no estimate -> no broadcast decision; count the swallow so a
             # flaky mount degrading every join to SMJ is visible in metrics
@@ -677,24 +680,12 @@ def stream_broadcast_join(executor, plan: L.Join, spec: Optional[BroadcastSpec] 
 
 def _build_identity(build_plan: L.LogicalPlan, build_cols: List[str], bkeys: List[str]):
     """Cache identity of a built hash table: the plan text (filters included)
-    + every leaf file's (path, mtime, size) + columns + keys. None (= don't
-    cache) when a leaf can't be stat'ed."""
-    files = []
-    for leaf in L.collect(
-        build_plan, lambda p: isinstance(p, (L.Scan, L.FileScan, L.IndexScan))
-    ):
-        names = (
-            [fi.name for fi in leaf.relation.all_file_infos()]
-            if isinstance(leaf, L.Scan)
-            else list(leaf.files)
-        )
-        for f in names:
-            try:
-                st = os.stat(f)
-            except OSError:
-                return None
-            files.append((f, st.st_mtime_ns, st.st_size))
-    return (build_plan.pretty(), tuple(files), tuple(build_cols), tuple(bkeys))
+    + every leaf file's identity (file_identity) + columns + keys. None (=
+    don't cache) when a leaf file has none."""
+    files = plan_identity(build_plan)
+    if files is None:
+        return None
+    return (build_plan.pretty(), files, tuple(build_cols), tuple(bkeys))
 
 
 def _shared_build_side(session, build_plan, build_cols: List[str], bkeys: List[str]) -> BuildSide:

@@ -40,24 +40,28 @@ class BucketCache:
         self.prefetch_completed = 0
 
     # -- synchronous read path ----------------------------------------------
-    def read(self, files: List[str], columns: Optional[List[str]]):
+    def read(self, files: List[str], columns: Optional[List[str]], committed=None):
         """Decoded batch for ``files``/``columns`` — cached, or decoded now
         and cached. Returns a fresh dict; the arrays inside are shared and
-        frozen (same contract as the io cache)."""
+        frozen (same contract as the io cache). ``committed`` is what an
+        index scan's log entry recorded of its files, for the read below
+        (exec/file_identity.py)."""
         from hyperspace_tpu.exec.io import _batch_nbytes, read_parquet_batch
 
         k = _key(files, columns)
         got = self._lru.get(k)
         if got is not None:
             return dict(got)
-        batch = read_parquet_batch(list(files), list(columns) if columns is not None else None)
+        batch = read_parquet_batch(
+            list(files), list(columns) if columns is not None else None, committed=committed
+        )
         for a in batch.values():
             a.setflags(write=False)
         self._lru.put(k, dict(batch), _batch_nbytes(batch))
         return dict(batch)
 
     # -- async prefetch ------------------------------------------------------
-    def prefetch(self, files: List[str], columns: Optional[List[str]]) -> bool:
+    def prefetch(self, files: List[str], columns: Optional[List[str]], committed=None) -> bool:
         """Schedule a background decode if the group is neither cached nor
         already being fetched. Returns True when a fetch was issued."""
         k = _key(files, columns)
@@ -70,7 +74,7 @@ class BucketCache:
 
         def work():
             try:
-                self.read(files, columns)
+                self.read(files, columns, committed=committed)
                 self.prefetch_completed += 1
             except Exception:
                 pass  # the request path will surface the real error

@@ -26,6 +26,7 @@ from collections import deque
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional
 
+from hyperspace_tpu.exec.file_identity import committed_keys
 from hyperspace_tpu.obs import metrics as obs_metrics
 from hyperspace_tpu.obs import spans
 from hyperspace_tpu.obs.profile import build_profile
@@ -550,7 +551,9 @@ class QueryServer:
                 if getattr(leaf, "file_columns", None) is not None
                 else list(leaf.columns)
             )
-            self.bucket_cache.prefetch(list(leaf.files), list(cols))
+            self.bucket_cache.prefetch(
+                list(leaf.files), list(cols), committed=committed_keys(leaf)
+            )
 
     def plan_cache_entry(self, token, fp: Fingerprint) -> Optional[CompiledPlan]:
         """Peek (no hit/miss accounting) at the template a request would use."""

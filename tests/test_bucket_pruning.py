@@ -352,9 +352,9 @@ def test_fifty_literals_one_miss_and_each_reads_its_own_bucket(lake):
         read = []
         real = srv.bucket_cache.read
 
-        def spy(files, columns):
+        def spy(files, columns, **kw):
             read.append(list(files))
-            return real(files, columns)
+            return real(files, columns, **kw)
 
         srv.bucket_cache.read = spy
         for k in keys:
@@ -422,7 +422,8 @@ def test_queued_lookups_share_one_scan_of_their_buckets(lake):
         srv._process_group = held
         read = []
         real_read = srv.bucket_cache.read
-        srv.bucket_cache.read = lambda files, columns: (read.append(list(files)), real_read(files, columns))[1]
+        srv.bucket_cache.read = lambda files, columns, **kw: (
+            read.append(list(files)), real_read(files, columns, **kw))[1]
         # the worker takes the blocker and waits; the lookups queue up behind it
         blocker = srv.submit("SELECT v FROM t_str WHERE ks >= 'name299'")
         futures = [srv.submit(f"SELECT v FROM t_int WHERE ki = {k}") for k in keys[1:]]

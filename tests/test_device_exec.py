@@ -14,6 +14,7 @@ import pytest
 import hyperspace_tpu as hst
 from hyperspace_tpu.exec import batch as B
 from hyperspace_tpu.exec import device as D
+from hyperspace_tpu.exec.file_identity import scan_identity
 from hyperspace_tpu.plan import logical as L
 from hyperspace_tpu.plan.expr import col, lit
 
@@ -249,7 +250,7 @@ class TestResidentColumnKey:
         in-place rewrite changes the identity."""
         import os
 
-        import hyperspace_tpu.exec.executor as E
+        import hyperspace_tpu.exec.file_identity as E
 
         stats = []
         real_stat = os.stat
@@ -258,23 +259,24 @@ class TestResidentColumnKey:
             plan = indexed.filter(_at(90)).select("v").optimized_plan()
             (scan,) = [p for p in L.collect(plan, lambda x: True) if isinstance(p, L.IndexScan)]
             del stats[:]
-            ident = E._scan_identity(scan)
+            ident = E.scan_identity(scan)
             assert stats == []
             assert ident == tuple(fi.key for fi in scan.entry.content.file_infos())
             assert [part[0] for part in ident] == list(scan.files)
-            # a scan over files its entry never committed falls back to stat
+            # a file its entry never committed is stat'ed, and only that file
             stray = L.IndexScan(scan.entry, ["k"], None, files=[scan.files[0], __file__])
-            assert E._scan_identity(stray)[1][0] == __file__ and stats == list(stray.files)
+            assert E.scan_identity(stray)[1][0] == __file__ and stats == [__file__]
+            assert E.scan_identity(stray)[0] == ident[0]
         else:
             f = str(tmp_path / "plain.parquet")
             pq.write_table(pa.table({"x": np.arange(10, dtype=np.int64)}), f)
             scan = L.FileScan([f], "parquet", ["x"])
-            before = E._scan_identity(scan)
+            before = E.scan_identity(scan)
             assert stats == [f] and before[0][0] == f
             pq.write_table(pa.table({"x": np.arange(11, dtype=np.int64)}), f)
-            assert E._scan_identity(scan) != before
+            assert E.scan_identity(scan) != before
             os.remove(f)
-            assert E._scan_identity(scan) is None
+            assert E.scan_identity(scan) is None
 
     @pytest.mark.parametrize("how", ["bare-session", "query-server"])
     def test_second_literal_finds_the_column_resident(self, session, indexed, how):
@@ -346,7 +348,7 @@ class TestResidentColumnKey:
         np.testing.assert_array_equal(ask(10, 20), np.arange(10, 20) * 7)
         np.testing.assert_array_equal(ask(510, 520), np.arange(510, 520) * 7)
         first, second = _column_keys("x")
-        assert first[:-1] == second[:-1] == E._scan_identity(L.FileScan([f], "parquet", ["x"]))
+        assert first[:-1] == second[:-1] == scan_identity(L.FileScan([f], "parquet", ["x"]))
         assert {first[-1], second[-1]} == {("rg-kept", ((f, (0,)),)), ("rg-kept", ((f, (1,)),))}
         # another predicate that keeps group 0 shares its column
         before = _link_counters()
@@ -354,7 +356,7 @@ class TestResidentColumnKey:
         assert _growth(before) == (0, 1, 0)
         # a read that prunes nothing has the plain key
         np.testing.assert_array_equal(ask(400, 600), np.arange(400, 600) * 7)
-        assert E._scan_identity(L.FileScan([f], "parquet", ["x"])) in _column_keys("x")
+        assert scan_identity(L.FileScan([f], "parquet", ["x"])) in _column_keys("x")
 
         # control: the file set alone as the key aliases the two 500-row batches
         monkeypatch.setattr(E, "_pruned_scan_key", lambda key, kept: key)

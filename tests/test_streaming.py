@@ -264,9 +264,9 @@ class TestStreamingJoin:
         spans = []
         orig = io_mod.read_parquet_batch
 
-        def spy(files, columns=None):
+        def spy(files, columns=None, **kw):
             spans.append({bucket_of_file(f) for f in files})
-            return orig(files, columns)
+            return orig(files, columns, **kw)
 
         io_mod.read_parquet_batch = spy
         try:
