@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import os
 from functools import lru_cache, partial
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
@@ -1979,16 +1979,10 @@ def join_sides_compatible(plan: L.Join) -> Optional[Tuple[L.LogicalPlan, L.Logic
     return plan.left, plan.right, lkeys, rkeys
 
 
-def _read_buckets(scan: L.IndexScan, columns: List[str], sort_keys: Optional[List[str]] = None) -> Dict[int, B.Batch]:
-    """Read an IndexScan's files grouped per bucket id (file name carries the
-    bucket; ref layout: part-<bucket>.parquet, indexes/covering.py).
-
-    Only ``columns`` are decoded. When ``sort_keys`` is given, each bucket is
-    re-sorted on them if needed: a bucket holding several files (incremental
-    refresh merges delta files into existing buckets, UpdateMode.Merge —
-    ref: actions/RefreshIncrementalAction.scala:115-128) is only piecewise
-    sorted after concatenation."""
-    trace.record("scan", "index-bucketed")
+def _bucket_files(scan: L.IndexScan) -> Dict[int, List[str]]:
+    """An IndexScan's files grouped per bucket id, in scan order (the file
+    name carries the bucket; ref layout: part-<bucket>.parquet,
+    indexes/covering.py)."""
     from hyperspace_tpu.indexes.covering import bucket_of_file
 
     per_bucket: Dict[int, List[str]] = {}
@@ -1997,21 +1991,187 @@ def _read_buckets(scan: L.IndexScan, columns: List[str], sort_keys: Optional[Lis
         if b is None:
             raise DeviceUnsupported(f"index file {f!r} has no bucket id")
         per_bucket.setdefault(b, []).append(f)
+    return per_bucket
+
+
+def _merged_bucket(
+    files: List[str], file_cols: List[str], columns: List[str], sort_keys: List[str], committed
+) -> B.Batch:
+    """One bucket's files as one batch sorted on ``sort_keys``: the files'
+    ``file_cols`` under the names ``columns`` (nested index columns live
+    under flat __hs_nested. names in the files). A bucket holds one sorted
+    file for every build chunk that had rows for it (any table larger than
+    ``batchRows``) and every incremental refresh in merge mode
+    (UpdateMode.Merge — ref: actions/RefreshIncrementalAction.scala:115-128),
+    so its concatenation is only piecewise sorted: the stable re-sort here is
+    the one place that merges the runs."""
     from hyperspace_tpu.exec.io import read_parquet_batch
 
-    # nested index columns live under flat __hs_nested. names in the files
-    file_cols = [scan.file_column_of(c) for c in columns]
-    rename = file_cols != list(columns)
+    batch = read_parquet_batch(files, file_cols, committed=committed)
+    if file_cols != list(columns):
+        batch = {o: batch[fc] for o, fc in zip(columns, file_cols)}
+    if sort_keys and len(files) > 1:
+        batch = _sort_bucket(batch, sort_keys)
+    return batch
 
+
+class _JoinSide(NamedTuple):
+    """One join side as contiguous columns: every column ONE array holding
+    the buckets in ascending order, each bucket merged on the sort keys, and
+    ``offsets`` (int64[num_buckets + 1]) delimiting them. ``buckets`` are the
+    ids that have a file, in scan order: a bucket without one is absent from
+    ``views()``, a bucket a filter emptied is present with no rows.
+
+    ``narrow[column][bucket]`` keeps a bucket's own array where its dtype
+    differs from the contiguous column's (a nullable int column decodes as
+    float64, a nullable bool as object, only in files that hold nulls): the
+    views hand out exactly the per-bucket dtypes a per-bucket read gives."""
+
+    columns: B.Batch
+    offsets: np.ndarray
+    buckets: Tuple[int, ...]
+    narrow: Dict[str, Dict[int, np.ndarray]]
+
+    def filtered(self, condition: Expr, columns: List[str]) -> "_JoinSide":
+        """``columns`` of the rows ``condition`` keeps: one evaluation over
+        the whole side, one mask, and the kept rows' offsets read from the
+        mask's running count (order preserving: every bucket stays sorted)."""
+        from hyperspace_tpu.plan.expr import as_bool_mask
+
+        if not self.buckets:
+            return self
+        mask = as_bool_mask(condition.eval(self.columns))
+        if mask.ndim == 0:  # a scalar predicate applies uniformly (B.mask_rows)
+            mask = np.broadcast_to(mask, (int(self.offsets[-1]),))
+        kept = B.mask_rows({c: self.columns[c] for c in columns}, mask)
+        running = np.zeros(mask.shape[0] + 1, dtype=np.int64)
+        np.cumsum(mask, out=running[1:])
+        narrow = {
+            c: {
+                b: arr[mask[self.offsets[b] : self.offsets[b + 1]]]
+                for b, arr in self.narrow[c].items()
+            }
+            for c in columns
+            if c in self.narrow
+        }
+        return _JoinSide(kept, running[self.offsets], self.buckets, narrow)
+
+    def views(self) -> Dict[int, B.Batch]:
+        """The per-bucket batches the join tiers consume, as slices."""
+        offs = self.offsets.tolist()
+        out: Dict[int, B.Batch] = {}
+        for b in self.buckets:
+            lo, hi = offs[b], offs[b + 1]
+            out[b] = {
+                c: self.narrow[c][b] if b in self.narrow.get(c, ()) else arr[lo:hi]
+                for c, arr in self.columns.items()
+            }
+        return out
+
+
+_JOIN_SIDE_COUNTERS: Dict[str, object] = {}
+
+
+def _count_join_side(result: str) -> None:
+    c = _JOIN_SIDE_COUNTERS.get(result)
+    if c is None:
+        from hyperspace_tpu.obs.metrics import REGISTRY
+
+        c = _JOIN_SIDE_COUNTERS[result] = REGISTRY.counter(
+            "hs_join_side_total",
+            "Index scans read as a bucketed join side, by whether every merged "
+            "column was resident in the host cache or some were decoded and merged now",
+            result=result,
+        )
+    c.inc()
+
+
+def _read_buckets(scan: L.IndexScan, columns: List[str], sort_keys: List[str]) -> _JoinSide:
+    """An IndexScan as a join side: ``columns`` of its files, bucket by
+    bucket, each bucket merged on ``sort_keys`` (``_merged_bucket``).
+
+    The merged order is a function of the index version alone, so the side is
+    merged once and kept: every column is one entry of the host cache
+    (exec/io._io_cache), keyed on the files' identities from the log entry,
+    the sort keys and the file column — never on who asks, so every query
+    over the same index version shares it, whatever its literals — and dropped
+    with the files by ``purge_io_cache``. A column that is not resident is
+    decoded and merged now with the sort keys; the stable sort over the same
+    files in the same order lays every column out alike."""
+    trace.record("scan", "index-bucketed")
+    from hyperspace_tpu.exec.file_identity import scan_identity
+    from hyperspace_tpu.exec.io import _io_cache_get
+
+    if not scan.files:
+        empty = np.zeros(scan.bucket_spec.num_buckets + 1, dtype=np.int64)
+        return _JoinSide({c: np.empty(0) for c in columns}, empty, (), {})
+    file_cols = [scan.file_column_of(c) for c in columns]
+    sort_cols = [scan.file_column_of(k) for k in sort_keys]
+    identity = scan_identity(scan)
+    base = None if identity is None else ("join-side", identity, tuple(sort_cols))
+    entries = {}
+    if base is not None:
+        for fc in file_cols:
+            got = _io_cache_get(base + (fc,))
+            if got is not None:
+                entries[fc] = got
+    missing = [fc for fc in dict.fromkeys(file_cols) if fc not in entries]
+    if missing:
+        _count_join_side("built")
+        entries.update(_merge_side_columns(scan, missing, sort_cols, identity, base))
+    else:
+        _count_join_side("resident")
+        for _ in scan.files:  # the events of a read the per-file cache answers
+            trace.record("decode", "cached")
+    first = entries[file_cols[0]]
+    cols: B.Batch = {}
+    narrow: Dict[str, Dict[int, np.ndarray]] = {}
+    for c, fc in zip(columns, file_cols):
+        entry = entries[fc]
+        cols[c] = entry["values"]
+        own = {b: a for b, a in entry.items() if not isinstance(b, str)}
+        if own:
+            narrow[c] = own
+    return _JoinSide(cols, first["offsets"], tuple(first["buckets"].tolist()), narrow)
+
+
+def _merge_side_columns(scan: L.IndexScan, file_cols: List[str], sort_cols: List[str], identity, base):
+    """Decode ``file_cols`` of every bucket of ``scan`` with the sort
+    columns, merge each bucket, and lay each column out as one array in
+    ascending bucket order. Returns {file column -> cache entry}: ``values``,
+    ``offsets``, ``buckets`` (ids in scan order) and, under its id, the own
+    array of every bucket whose dtype the concatenation promoted
+    (``_JoinSide.narrow``). Entries are cached under ``base`` + the column
+    where the scan's files have an identity."""
+    from hyperspace_tpu.exec.io import _io_cache_put, discard_reads
+
+    per_bucket = _bucket_files(scan)
+    read_cols = list(dict.fromkeys(list(sort_cols) + list(file_cols)))
     committed = committed_keys(scan)
-    out: Dict[int, B.Batch] = {}
-    for b, files in per_bucket.items():
-        batch = read_parquet_batch(files, file_cols, committed=committed)
-        if rename:
-            batch = {o: batch[fc] for o, fc in zip(columns, file_cols)}
-        if sort_keys and len(files) > 1:
-            batch = _sort_bucket(batch, sort_keys)
-        out[b] = batch
+    order = sorted(per_bucket)
+    parts = [
+        _merged_bucket(per_bucket[b], read_cols, read_cols, sort_cols, committed) for b in order
+    ]
+    if base is not None:
+        # the merged copy takes the place of what the reads above left in the
+        # cache under this column set, which no other reader asks for
+        by_path = {k[0]: k for k in identity}
+        for b in order:
+            discard_reads([by_path[f] for f in per_bucket[b]], read_cols)
+    nb = max(scan.bucket_spec.num_buckets, order[-1] + 1)
+    counts = np.zeros(nb, dtype=np.int64)
+    counts[order] = [B.num_rows(p) for p in parts]
+    offsets = np.zeros(nb + 1, dtype=np.int64)
+    np.cumsum(counts, out=offsets[1:])
+    buckets = np.asarray(list(per_bucket), dtype=np.int64)
+    out = {}
+    for fc in file_cols:
+        arrs = [p[fc] for p in parts]
+        values = np.concatenate(arrs)
+        entry = {"values": values, "offsets": offsets, "buckets": buckets}
+        entry.update({b: a for b, a in zip(order, arrs) if a.dtype != values.dtype})
+        _io_cache_put(None if base is None else base + (fc,), entry)
+        out[fc] = entry
     return out
 
 
@@ -2063,26 +2223,52 @@ def _composite_ranks(
     return ranks[:n], ranks[n:]
 
 
+def _filter_input_columns(node: L.Filter, columns: List[str]) -> List[str]:
+    """What a join side's Filter needs of its child: ``columns`` and what
+    the condition reads."""
+    from hyperspace_tpu.plan.expr import contains_input_file_name
+
+    if contains_input_file_name(node.condition):
+        raise DeviceUnsupported("input_file_name() predicate on a join side")
+    return list(dict.fromkeys(list(columns) + list(node.condition.references())))
+
+
+def _contiguous_side(node: L.LogicalPlan, columns: List[str], sort_keys: List[str]) -> Optional[_JoinSide]:
+    """A join side that is an IndexScan leaf under layout-preserving
+    Projects and Filters, as one ``_JoinSide``: the leaf's resident side, and
+    each Filter evaluated once over it. None for the hybrid-scan shapes,
+    which ``_side_buckets`` assembles bucket by bucket."""
+    node, _proj = _strip_projects(node)
+    if isinstance(node, L.IndexScan):
+        return _read_buckets(node, columns, sort_keys)
+    if isinstance(node, L.Filter):
+        inner_cols = _filter_input_columns(node, columns)
+        child = _contiguous_side(node.child, inner_cols, sort_keys)
+        if child is not None:
+            return child.filtered(node.condition, columns)
+    return None
+
+
 def _side_buckets(
     session, node: L.LogicalPlan, columns: List[str], sort_keys: List[str]
 ) -> Dict[int, B.Batch]:
     """Per-bucket batches of one join side, each sorted on ``sort_keys``.
 
-    Handles the full hybrid-scan shape: IndexScan leaves, lineage NOT-IN
-    Filters (evaluated per bucket — layout preserving), Repartition of
-    appended files (host re-bucketing with the SAME hash as the index build,
-    so rows land in their index bucket), and BucketUnion (per-bucket concat
-    of sorted runs, re-sorted once)."""
+    An IndexScan leaf under Projects and Filters is read as one contiguous
+    side and handed out as slices (``_contiguous_side``). The hybrid-scan
+    shapes are assembled per bucket: lineage NOT-IN Filters over them
+    (evaluated per bucket — layout preserving), Repartition of appended
+    files (host re-bucketing with the SAME hash as the index build, so rows
+    land in their index bucket), and BucketUnion (per-bucket concat of sorted
+    runs, re-sorted once)."""
+    side = _contiguous_side(node, columns, sort_keys)
+    if side is not None:
+        return side.views()
     node, _proj = _strip_projects(node)
-    if isinstance(node, L.IndexScan):
-        return _read_buckets(node, columns, sort_keys=sort_keys)
     if isinstance(node, L.Filter):
-        refs = [c for c in node.condition.references()]
-        inner_cols = list(dict.fromkeys(list(columns) + refs))
-        from hyperspace_tpu.plan.expr import as_bool_mask, contains_input_file_name
+        from hyperspace_tpu.plan.expr import as_bool_mask
 
-        if contains_input_file_name(node.condition):
-            raise DeviceUnsupported("input_file_name() predicate on a join side")
+        inner_cols = _filter_input_columns(node, columns)
         buckets = _side_buckets(session, node.child, inner_cols, sort_keys)
         out: Dict[int, B.Batch] = {}
         for b, batch in buckets.items():
@@ -2167,38 +2353,20 @@ def _side_bucket_readers(session, node: L.LogicalPlan, columns: List[str], sort_
     """
     node, _proj = _strip_projects(node)
     if isinstance(node, L.IndexScan):
-        from hyperspace_tpu.indexes.covering import bucket_of_file
-        from hyperspace_tpu.exec.io import read_parquet_batch
-
-        per_bucket: Dict[int, List[str]] = {}
-        for f in node.files:
-            b = bucket_of_file(f)
-            if b is None:
-                raise DeviceUnsupported(f"index file {f!r} has no bucket id")
-            per_bucket.setdefault(b, []).append(f)
         file_cols = [node.file_column_of(c) for c in columns]
-        rename = file_cols != list(columns)
         committed = committed_keys(node)
 
         def make(files):
             def read() -> B.Batch:
-                batch = read_parquet_batch(files, file_cols, committed=committed)
-                if rename:
-                    batch = {o: batch[fc] for o, fc in zip(columns, file_cols)}
-                if sort_keys and len(files) > 1:
-                    batch = _sort_bucket(batch, sort_keys)
-                return batch
+                return _merged_bucket(files, file_cols, columns, sort_keys, committed)
 
             return read
 
-        return {b: make(fs) for b, fs in per_bucket.items()}
+        return {b: make(fs) for b, fs in _bucket_files(node).items()}
     if isinstance(node, L.Filter):
-        from hyperspace_tpu.plan.expr import as_bool_mask, contains_input_file_name
+        from hyperspace_tpu.plan.expr import as_bool_mask
 
-        if contains_input_file_name(node.condition):
-            raise DeviceUnsupported("input_file_name() predicate on a join side")
-        refs = [c for c in node.condition.references()]
-        inner_cols = list(dict.fromkeys(list(columns) + refs))
+        inner_cols = _filter_input_columns(node, columns)
         child = _side_bucket_readers(session, node.child, inner_cols, sort_keys)
 
         def wrap(thunk):

@@ -72,7 +72,13 @@ def _io_cache_get(key) -> Optional[B.Batch]:
     return None
 
 
-def _io_cache_put(key, batch: B.Batch) -> None:
+def _io_cache_put(key, batch: B.Batch, cold: bool = False) -> None:
+    """``cold``: the entry is the first to go under pressure (BytesLRU.put).
+    The per-file pieces of a multi-file read whose concatenation is cached
+    too are: the concatenation answers the next read, and the pieces beside
+    it count the same rows against the cap a second time — left warm, one
+    pass of whole-scan reads pushed live entries (join sides, other
+    concatenations) out to keep them."""
     if key is None:
         return
     # cached buffers are shared with every future reader of this file —
@@ -81,7 +87,25 @@ def _io_cache_put(key, batch: B.Batch) -> None:
     # read-only views; copy before mutating)
     for a in batch.values():
         a.setflags(write=False)
-    _io_cache.put(key, dict(batch), _batch_nbytes(batch))
+    _io_cache.put(key, dict(batch), _batch_nbytes(batch), cold=cold)
+
+
+def _concat_key(file_keys):
+    """Cache key of a multi-file read's concatenated batch; None when one
+    of the per-file keys is (a stat failed): embedding a None in the tuple
+    would collide unrelated scans."""
+    return None if None in file_keys else ("concat", tuple(file_keys))
+
+
+def discard_reads(identities, columns: List[str]) -> None:
+    """Drop what a read of ``columns`` over the files with ``identities``
+    left in the cache, the per-file entries and their concatenation: a
+    reader that keeps its own merged copy of those rows (a join side,
+    exec/device._read_buckets) holds them once, not beside these."""
+    keys = [_io_cache_key(k, columns) for k in identities]
+    for key in keys + [_concat_key(keys)]:
+        if key is not None:
+            _io_cache.discard(key)
 
 
 def clear_io_cache() -> None:
@@ -91,8 +115,9 @@ def clear_io_cache() -> None:
 def _key_mentions_path(key, paths) -> bool:
     # cache keys are nested tuples whose leaves include the source path
     # string: file keys are (path, mtime, size, cols), concat keys wrap a
-    # tuple of per-file keys, row-group keys append a suffix tuple — a
-    # recursive scan covers every shape without coupling to each layout
+    # tuple of per-file keys, row-group keys append a suffix tuple, a join
+    # side's column holds its scan's file identities — a recursive scan
+    # covers every shape without coupling to each layout
     if isinstance(key, str):
         return key in paths
     if isinstance(key, tuple):
@@ -788,7 +813,9 @@ def _native_rg_decode(
     if not pruned_any:
         for fi, f in enumerate(files):
             s, e = starts[fi], starts[fi] + file_rows[fi]
-            _io_cache_put(file_keys[fi], {c: out[c][s:e] for c in cols})
+            _io_cache_put(
+                file_keys[fi], {c: out[c][s:e] for c in cols}, cold=concat_key is not None
+            )
         if concat_key is not None:
             _io_cache_put(concat_key, dict(out))
     elif kept is not None:
@@ -887,15 +914,12 @@ def read_parquet_batch(
     # so dispatch goldens are insensitive to which cache tier answered.
     concat_key = None
     if columns is not None and len(files) > 1:
-        # a None per-file key (stat failed) disables caching everywhere
-        # else; embedding it in the tuple would collide unrelated scans
-        if None not in file_keys:
-            concat_key = ("concat", tuple(file_keys))
-            got = _io_cache_get(concat_key)
-            if got is not None:
-                for _ in files:
-                    trace.record("decode", "cached")
-                return got
+        concat_key = _concat_key(file_keys)
+        got = _io_cache_get(concat_key)
+        if got is not None:
+            for _ in files:
+                trace.record("decode", "cached")
+            return got
 
     # fully-cached scan with an explicit projection: every cached batch holds
     # exactly ``columns``, so concatenation is schema-safe and the pq schema
@@ -961,6 +985,10 @@ def read_parquet_batch(
         got = _native_rg_scan(files, columns, schemas, predicate, file_keys, concat_key, kept)
         if got is not None:
             return got
+
+    # a read that caches its concatenation (below) is answered from that: its
+    # files' batches beside it are a second copy of the same rows, cached cold
+    concat_cached = concat_key is not None and predicate is None
 
     def read_one(f: str, schema, ckey) -> B.Batch:
         with spans.span("decode", cat="io", file=os.path.basename(f)) as dsp:
@@ -1034,7 +1062,7 @@ def read_parquet_batch(
             if QUARANTINE.enabled:
                 QUARANTINE.note_ok(f)
             dsp.set(rows=B.num_rows(got))
-            _io_cache_put(ckey, got)
+            _io_cache_put(ckey, got, cold=concat_cached)
             return got
 
     # decode files concurrently (pyarrow and the native decoder release the
@@ -1060,6 +1088,6 @@ def read_parquet_batch(
     # a predicate-pruned concatenation holds FEWER rows than the full scan;
     # caching it under the unpruned concat key would poison predicate-less
     # readers of the same files with silently missing rows
-    if concat_key is not None and predicate is None:
+    if concat_cached:
         _io_cache_put(concat_key, out)
     return out

@@ -3,7 +3,7 @@
 One policy implementation shared by the host batch cache (exec/io.py) and the
 HBM column cache (exec/device.py): get() refreshes recency, put() overwrites
 existing keys (adjusting the byte count) and evicts least-recently-used
-entries until the total fits the cap.
+entries until the total fits the cap; a cold put enters at that end.
 """
 
 from __future__ import annotations
@@ -44,7 +44,10 @@ class BytesLRU:
             self._entries.move_to_end(key)
             return got[0]
 
-    def put(self, key: Hashable, value: Any, nbytes: int) -> None:
+    def put(self, key: Hashable, value: Any, nbytes: int, cold: bool = False) -> None:
+        """``cold`` enters the entry as the least recently used one: kept
+        while there is room, first to go when there is not (a ``get`` makes
+        it as recent as any)."""
         if self.cap <= 0 or nbytes > self.cap:
             return
         with self._lock:
@@ -52,6 +55,8 @@ class BytesLRU:
             if old is not None:
                 self._bytes -= old[1]
             self._entries[key] = (value, nbytes)
+            if cold:
+                self._entries.move_to_end(key, last=False)
             self._bytes += nbytes
             while self._bytes > self.cap and self._entries:
                 _, (_, nb) = self._entries.popitem(last=False)

@@ -32,9 +32,9 @@ class _CountingLRU:
         self._inner = BytesLRU(cap_bytes)
         self.inserted_bytes = 0
 
-    def put(self, key, value, nbytes):
+    def put(self, key, value, nbytes, **kw):
         self.inserted_bytes += nbytes
-        self._inner.put(key, value, nbytes)
+        self._inner.put(key, value, nbytes, **kw)
 
     def __getattr__(self, name):
         return getattr(self._inner, name)
@@ -126,3 +126,57 @@ class TestCachePressure:
         assert io_.inserted_bytes > 4 * io_.cap
         evicted = io_.inserted_bytes - io_.total_bytes
         assert evicted > 0
+
+
+class TestColdEntries:
+    """A cold put is kept while there is room and goes first when there is
+    not: the per-file pieces of a read whose concatenation is cached too are
+    put cold (exec/io.py), so a pass of whole-scan reads cannot push a live
+    entry out to keep a second copy of its own rows."""
+
+    def test_cold_put_goes_first_and_a_get_warms_it(self):
+        from hyperspace_tpu.utils.lru import BytesLRU
+
+        lru = BytesLRU(100)
+        lru.put("live", 1, 40)
+        lru.put("piece-a", 2, 30, cold=True)
+        lru.put("piece-b", 3, 30, cold=True)
+        assert lru.keys() == ["piece-b", "piece-a", "live"] and lru.evictions == 0  # room: all kept
+        lru.put("next", 4, 40)
+        assert lru.keys() == ["live", "next"] and lru.evictions == 2
+        lru.put("piece-c", 5, 20, cold=True)
+        assert lru.get("piece-c") == 5  # used: as recent as any
+        lru.put("more", 6, 30)
+        assert lru.keys() == ["next", "piece-c", "more"]
+        lru.put("piece-d", 7, 30, cold=True)  # no room: it is the one that goes
+        assert lru.keys() == ["next", "piece-c", "more"] and lru.total_bytes == 90
+
+    @pytest.mark.parametrize("reader", ["native-rg-scan", "per-file"])
+    def test_whole_scan_reads_keep_live_entries(self, tmp_path, monkeypatch, reader):
+        """Three multi-file reads of 24 KB each, pieces and concatenation,
+        against a cap that holds their concatenations and one live entry but
+        not their pieces as well."""
+        from hyperspace_tpu.utils.lru import BytesLRU
+
+        if reader == "per-file":
+            monkeypatch.setenv("HS_NATIVE_RG", "0")
+        lru = BytesLRU(100_000)
+        monkeypatch.setattr(hs_io, "_io_cache", lru)
+        lru.put("live", {}, 20_000)
+        scans = []
+        for s in range(3):
+            files = []
+            for i in range(3):
+                f = str(tmp_path / f"s{s}_f{i}.parquet")
+                pq.write_table(pa.table({"x": np.arange(1000, dtype=np.int64) + 1000 * i}), f)
+                files.append(f)
+            scans.append(files)
+            got = hs_io.read_parquet_batch(files, ["x"])
+            np.testing.assert_array_equal(got["x"], np.arange(3000))
+        assert lru.get("live") is not None
+        concats = [k for k in lru.keys() if isinstance(k, tuple) and k[0] == "concat"]
+        assert len(concats) == 3 and lru.evictions > 0 and lru.total_bytes <= lru.cap
+        for files in scans:  # each answered from its concatenation
+            before = lru.hits
+            hs_io.read_parquet_batch(files, ["x"])
+            assert lru.hits == before + 1
