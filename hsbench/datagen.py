@@ -1,7 +1,7 @@
 """TPC-H-shaped tables from a seed, sized by scale factor.
 
-The yardstick's own copy of the program's ``benchmarks/tpch_full.py`` (a later
-PR may change that file, not this one). Same schema, row counts (SF 1 =
+The yardstick's own generator (it began as a copy of the program's
+``benchmarks/tpch_full.py``, which PR 29 deleted). Same schema, row counts (SF 1 =
 6,000,000 lineitem rows) and value distributions - not dbgen: values are
 shaped to what the query texts predicate on. What differs is how it is made:
 every file has a random stream of its own, keyed by (seed, table, file), so

@@ -1,8 +1,8 @@
 """Idle seconds of the device inside the program's own annotations of the
 given name (``hs:<cat>:<name>`` events of the trace's host planes), over the
-idle seconds of the whole traced slice: both on the profiler's clock. The
-slice starts at the harness's anchor annotation and lasts ``trace_window_s``.
-Percent."""
+idle seconds of the whole traced window (``tracing.trace_window``: from the
+harness's anchor annotation for ``trace_window_s``), both on the profiler's
+clock. Percent."""
 
 from hsbench import tracing
 
@@ -11,14 +11,12 @@ def read(run, params):
     if run.planes is None or not run.trace_window_s:
         return None
     devices = set(tracing.device_planes(run.planes))
-    host = [(name, start, dur) for plane, lines in run.planes.items() if plane not in devices
-            for events in lines.values() for name, start, dur in events]
-    anchors = [start for name, start, _ in host if name == tracing.ANCHOR]
-    marks = [(params["annotation"], start / 1e9, (start + dur) / 1e9)
-             for name, start, dur in host if name == params["annotation"]]
-    if not anchors or not marks:
+    marks = [(name, start / 1e9, (start + dur) / 1e9) for plane, lines in run.planes.items()
+             if plane not in devices for events in lines.values()
+             for name, start, dur in events if name == params["annotation"]]
+    if not marks:
         return None
-    window = (anchors[0], anchors[0] + run.trace_window_s * 1e9)
+    window = tracing.trace_window(run.planes, run.trace_window_s)
     gaps = tracing.idle_gaps(run.planes, marks, 0.0, window)
     idle = sum(gaps.values())
     if not idle:

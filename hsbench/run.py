@@ -179,14 +179,20 @@ def _host_spans(outcomes) -> list:
 
 
 def _reduce_trace(run: TracedRun, profiler, xplane: str, host_spans) -> dict:
-    planes = tracing.read_planes(xplane)
-    run.planes = planes
+    """Everything the device did is read inside the one traced window: from
+    ``profiler.started`` (the anchor) to ``profiler.stopped``, on the trace's clock."""
+    whole = tracing.read_planes(xplane)
+    window = tracing.trace_window(whole, profiler.window_s)
+    run.planes = planes = tracing.clip(whole, window)
     run.trace_busy_s = tracing.busy_seconds(planes)
-    run.trace_window_s = profiler.window_s
+    run.trace_window_s = (window[1] - window[0]) / 1e9  # its own length: a plane busy for all of it reads this
+    ops = tracing.op_seconds(planes)
+    log(f"trace window: device busy {tracing.busy_seconds(whole):.9f} s in the whole file (the old reading), "
+        f"{run.trace_busy_s:.9f} s inside the window of {run.trace_window_s:.9f} s; operations summed "
+        f"{sum(tracing.op_seconds(whole).values()):.9f} s in the file, {sum(ops.values()):.9f} s inside")
     offset = tracing.anchor_offset_ns(planes, profiler.anchor_perf_ns)
-    window = (profiler.started * 1e9 + offset, profiler.stopped * 1e9 + offset)
     return {
-        "device_ops": tracing.top(tracing.op_seconds(planes)),
+        "device_ops": tracing.top(ops),
         "idle_gaps": tracing.top(tracing.idle_gaps(planes, host_spans, offset, window)),
     }
 

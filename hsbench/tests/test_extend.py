@@ -33,7 +33,7 @@ def test_a_cell_is_added_by_new_files_and_one_list_entry_each(tmp_path):
                   indexes=[i for i in config["indexes"] if i["name"] in ("o_ck", "o_ok")])
     _write(hb / "configs" / "tpch-tiny.json", json.dumps(config))
     _write(hb / "traffic" / "cust-burst.json", json.dumps({
-        "loop": "open", "arrivals": "poisson", "key_skew_zipf_s": 0.8, "tenants": ["t"],
+        "loop": "open", "arrivals": "poisson", "param_seed": 7, "key_skew_zipf_s": 0.8, "tenants": ["t"],
         "burst": {"rate_per_s": 30.0, "on_s": 0.5, "off_s": 0.5},
         "templates": [{"name": "cust_total", "share": 3}, {"name": "lk_o_orderkey", "share": 1}],
         "trace_seconds": 1.0, "trace_lead_s": 0.2}))

@@ -62,6 +62,7 @@ def test_every_name_finds_its_files():
     for w in M["workloads"]:
         mix = traffic.load_mix(w["traffic"])
         assert mix["loop"] in ("open", "closed", "build")
+        assert mix["loop"] == "build" or "param_seed" in mix  # every seed asks a served cell the same requests
         for t in mix.get("templates", []):
             traffic.Template(t["name"])
             assert os.path.exists(os.path.join(deployment.HERE, "oracles", f"{t['name']}.py"))

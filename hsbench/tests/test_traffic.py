@@ -30,6 +30,32 @@ def test_open_schedule_is_a_pure_function_of_the_seed():
     assert abs(len(a) / 20.0 - 5.0) < 2.0  # Poisson at 5/s
 
 
+def _open(mix, seed, seconds=48.0):
+    templates = {t["name"]: traffic.Template(t["name"]) for t in mix["templates"]}
+    # the data under the keys is the same here, so that only the order can differ
+    drawers = {n: traffic.ParamDrawer(t, 7, mix["key_skew_zipf_s"], _values) for n, t in templates.items()}
+    return traffic.open_schedule(mix, templates, drawers, seed, seconds)
+
+
+def test_an_open_loop_offers_every_seed_the_same_requests_in_another_order():
+    mix = traffic.load_mix("lookup-steady")
+    a, b = _open(mix, 1), _open(mix, 2**31 + 9)
+    assert [r.due_s for r in a] == [r.due_s for r in b]          # the same arrivals
+    assert sorted(r.text for r in a) == sorted(r.text for r in b)  # the same requests
+    assert [r.text for r in a] != [r.text for r in b]            # dealt out in another order
+    per_template = {}
+    for r in a:
+        per_template[r.template.name] = per_template.get(r.template.name, 0) + 1
+    assert max(per_template.values()) - min(per_template.values()) <= 1  # equal shares, exactly
+
+
+def test_exact_shares_hand_out_every_request():
+    p = np.array([0.5, 0.3, 0.2])
+    assert np.bincount(traffic._exact_shares(p, 10)).tolist() == [5, 3, 2]
+    assert np.bincount(traffic._exact_shares(p, 7)).tolist() == [4, 2, 1]  # 3.5, 2.1, 1.4: the largest remainder first
+    assert len(traffic._exact_shares(np.full(5, 0.2), 243)) == 243
+
+
 def test_a_seed_beyond_32_signed_bits_is_taken():
     assert _schedule("lookup-steady", 2**31 + 77) == _schedule("lookup-steady", 2**31 + 77)
 

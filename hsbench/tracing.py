@@ -6,6 +6,13 @@ nothing but JAX. Device operations are the events of the ``XLA Ops`` line of a
 ``/device:TPU:n`` plane. Host spans measured with ``time.perf_counter()`` are
 put on the profiler's clock through one ``TraceAnnotation`` (``hsbench:anchor``)
 whose perf_counter reading is kept beside the trace.
+
+The traced window is one interval on the trace's clock: from the anchor, which
+``Profiler.start`` writes as soon as ``start_trace`` has returned, for
+``Profiler.window_s`` seconds, to the reading ``Profiler.stop`` takes before it
+calls ``stop_trace``. The profiler records for longer than that on both sides,
+so ``clip`` cuts what the device planes hold to the window before anything is
+summed: busy time can then not pass the window. Host planes stay whole.
 """
 
 from __future__ import annotations
@@ -40,8 +47,8 @@ class Profiler:
         options.python_tracer_level = 0  # the Python tracer slows the host it measures
         options.host_tracer_level = 2
         jax.profiler.start_trace(self.directory, profiler_options=options)
-        self.started = time.perf_counter()
         self.anchor_perf_ns = time.perf_counter_ns()
+        self.started = self.anchor_perf_ns / 1e9  # the window opens at the anchor
         with jax.profiler.TraceAnnotation(ANCHOR):
             pass
 
@@ -82,6 +89,25 @@ def read_planes(path: str) -> dict:
 
 def device_planes(planes: dict) -> list:
     return sorted(p for p in planes if p.startswith("/device:TPU:"))
+
+
+def trace_window(planes: dict, seconds: float) -> tuple:
+    """The traced window ``(lo, hi)`` in trace ns: ``seconds`` from the anchor."""
+    lo = anchor_ns(planes)
+    return lo, lo + seconds * 1e9
+
+
+def clip(planes: dict, window) -> dict:
+    """``planes`` with every event of every line of the device planes cut to
+    ``window`` (trace ns): an event outside it is dropped, one across an edge
+    keeps the part inside. Host planes are the same objects, whole."""
+    lo, hi = window
+    out = dict(planes)
+    for p in device_planes(planes):
+        out[p] = {line: [(name, max(s, lo), min(s + d, hi) - max(s, lo))
+                         for name, s, d in events if s + d > lo and s < hi]
+                  for line, events in planes[p].items()}
+    return out
 
 
 def union(intervals) -> list:
@@ -135,14 +161,19 @@ def top(table: dict, n: int = 10) -> list:
     return [[k, v] for k, v in sorted(table.items(), key=lambda kv: -kv[1])[:n]]
 
 
-def anchor_offset_ns(planes: dict, anchor_perf_ns: int) -> float:
-    """What to add to a perf_counter reading in ns to get the trace's clock."""
+def anchor_ns(planes: dict) -> float:
+    """Where the anchor annotation starts on the trace's clock."""
     for plane in planes.values():
         for events in plane.values():
             for name, start, _ in events:
                 if name == ANCHOR:
-                    return start - anchor_perf_ns
+                    return start
     raise RuntimeError(f"no {ANCHOR!r} annotation in the trace: host spans cannot be placed")
+
+
+def anchor_offset_ns(planes: dict, anchor_perf_ns: int) -> float:
+    """What to add to a perf_counter reading in ns to get the trace's clock."""
+    return anchor_ns(planes) - anchor_perf_ns
 
 
 def idle_gaps(planes: dict, host_spans, offset_ns: float, window) -> dict:

@@ -5,7 +5,9 @@ that names its loop kind and its parameters; a query template is
 here knows a template, a mix or a cell by name.
 
 Everything drawn comes from ``numpy.random.default_rng([seed, stream])``: the
-same seed gives the same requests at the same times.
+same seed gives the same requests at the same times. A served mix asks every
+seed for the same set of requests, drawn from its own ``param_seed``: the
+run's seed changes the data under them and their order, and nothing else.
 """
 
 from __future__ import annotations
@@ -17,7 +19,7 @@ import os
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-STREAM_ARRIVALS, STREAM_MIX, STREAM_PARAMS, STREAM_KEYS, STREAM_POOL = range(5)
+STREAM_ARRIVALS, STREAM_MIX, STREAM_PARAMS, STREAM_KEYS, STREAM_POOL, STREAM_ORDER = range(6)
 
 
 def load_mix(name: str) -> dict:
@@ -146,19 +148,33 @@ def arrival_times(mix: dict, seed: int, seconds: float) -> np.ndarray:
     return t[t < seconds]
 
 
+def _exact_shares(p: np.ndarray, n: int) -> np.ndarray:
+    """``n`` template indexes with every template as near its share as whole
+    requests allow (largest remainders), in no particular order."""
+    counts = np.floor(p * n).astype(int)
+    for k in np.argsort(-(p * n - counts), kind="stable")[:n - counts.sum()]:
+        counts[k] += 1
+    return np.repeat(np.arange(len(p)), counts)
+
+
 def open_schedule(mix: dict, templates: dict, drawers: dict, seed: int, seconds: float) -> list:
-    """The requests of an open loop, each with its due time."""
-    times = arrival_times(mix, seed, seconds)
+    """The requests of an open loop, each with its due time. Every seed offers
+    the same set of requests at the same due times, in another order: the
+    arrivals, exact template shares and the draws (a key by its rank) come
+    from the MIX's ``param_seed``, and the run's seed deals the requests out
+    over the due times. What a rank's key is comes from the run's seed, with
+    the data (``ParamDrawer``). Drawn from the run's seed, two windows differed
+    in how many requests they offered, of which templates, and in how often a
+    key came again: the median latency moved 5.2 -> 8.6 ms with the seed."""
+    fixed = int(mix["param_seed"])
+    times = arrival_times(mix, fixed, seconds)
     names, p = _shares(mix)
-    mix_rng = np.random.default_rng([int(seed), STREAM_MIX])
-    par_rng = np.random.default_rng([int(seed), STREAM_PARAMS])
+    par_rng = np.random.default_rng([fixed, STREAM_PARAMS])
+    asked = [(names[k], drawers[names[k]].draw(par_rng)) for k in _exact_shares(p, len(times))]
+    order = np.random.default_rng([int(seed), STREAM_ORDER]).permutation(len(asked))
     tenants = mix.get("tenants", ["default"])
-    picks = mix_rng.choice(len(names), size=len(times), p=p)
-    return [
-        Request(templates[names[k]], drawers[names[k]].draw(par_rng),
-                tenants[i % len(tenants)], float(t))
-        for i, (t, k) in enumerate(zip(times, picks))
-    ]
+    return [Request(templates[asked[k][0]], asked[k][1], tenants[i % len(tenants)], float(t))
+            for i, (t, k) in enumerate(zip(times, order))]
 
 
 def pool(mix: dict, templates: dict, drawers: dict) -> list:
