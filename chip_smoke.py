@@ -18,7 +18,8 @@ times in it are smoke timings (one cold reading each, compile included), not
 benchmark results.
 
 The second query pass asserts the device programs it exists to reach
-(``filter: device``, ``join: device-smj``, ``agg: device-grouped-scan``). They
+(``filter: device``, ``join: device-smj``, ``agg: device-grouped-scan``,
+``agg: device-fused-scan``). They
 are what the product dispatches while an index stays under its 1 GiB
 streaming gates, as at the default ``--sf``; from about ``--sf 5`` it streams
 the join and the aggregates instead and that assertion says so, after both
@@ -84,6 +85,10 @@ def queries(point_key: int) -> dict:
         "groupf": f"""
             SELECT l_discount, COUNT(*) AS n, SUM(l_quantity) AS sum_qty
             FROM lineitem WHERE l_shipdate <= date '{D_Q1}' GROUP BY l_discount""",
+        # rows, not an aggregate: the device filter's mask comes back to the host
+        "year": f"""
+            SELECT l_extendedprice, l_quantity FROM lineitem
+            WHERE l_shipdate >= date '{D_Q6_LO}' AND l_shipdate < date '{D_Q6_HI}'""",
     }
 
 
@@ -119,7 +124,10 @@ def references(li, o, point_key: int) -> dict:
         n=("l_discount", "size"), sum_qty=("l_quantity", "sum")
     )
     groupf = {c: gf[c].to_numpy() for c in ("l_discount", "n", "sum_qty")}
-    return {"q6": q6, "join": join, "q3": q3, "q1": q1, "point": point, "groupf": groupf}
+
+    y = li[(li.l_shipdate >= d(D_Q6_LO)) & (li.l_shipdate < d(D_Q6_HI))]
+    year = {c: y[c].to_numpy() for c in ("l_extendedprice", "l_quantity")}
+    return {"q6": q6, "join": join, "q3": q3, "q1": q1, "point": point, "groupf": groupf, "year": year}
 
 
 # q3 keeps its ORDER BY ... LIMIT order; the others are row sets
@@ -229,7 +237,7 @@ def metric_samples(name: str) -> dict:
 # hyperspace.parallel.enabled changes: the distributed build (one all_to_all)
 # and the sharded filter and grouped aggregate, both over li_shipdate.
 PARALLEL_BUILDS = ("li_shipdate",)
-PARALLEL_QUERIES = ("q6", "q1")
+PARALLEL_QUERIES = ("q6", "q1", "year")
 
 
 def device_gate(args) -> tuple:
@@ -415,7 +423,7 @@ def main(argv=None) -> dict:
         answers, summary_device, events = run_queries("device")
         tags = (
             ("filter: device-sharded", "agg: device-grouped-scan") if args.parallel
-            else ("filter: device", "join: device-smj", "agg: device-grouped-scan")
+            else ("filter: device", "join: device-smj", "agg: device-grouped-scan", "agg: device-fused-scan")
         )
         for tag in tags:
             assert tag in summary_device, f"{tag!r} missing from the device pass"

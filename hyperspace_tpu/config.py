@@ -43,6 +43,7 @@ class keys:
     TPU_BUILD_DISTRIBUTED_MIN_ROWS = "hyperspace.tpu.build.distributedMinRows"
     TPU_QUERY_DEVICE_EXECUTION = "hyperspace.tpu.query.deviceExecution"
     TPU_QUERY_DEVICE_MIN_ROWS = "hyperspace.tpu.query.deviceMinRows"
+    TPU_QUERY_DEVICE_CACHE_BYTES = "hyperspace.tpu.query.deviceCacheBytes"
     TPU_JOIN_DEVICE_MATERIALIZE = "hyperspace.tpu.join.deviceMaterialize"
     TPU_JOIN_DEVICE_MATERIALIZE_MAX_BYTES = "hyperspace.tpu.join.deviceMaterializeMaxBytes"
     TPU_JOIN_DEVICE_SPAN_MAX_BYTES = "hyperspace.tpu.join.deviceSpanMaxBytes"
@@ -273,6 +274,13 @@ DEFAULTS: Dict[str, Any] = {
     # compute it offloads; the executor keeps small batches on host. Tune to 0
     # on co-located TPU hosts where the whole pipeline stays device-resident.
     keys.TPU_QUERY_DEVICE_MIN_ROWS: 1 << 25,
+    # Byte budget of the device-resident column cache (exec/device.py): the
+    # encoded columns of index scans and the join matrices, LRU-evicted. A
+    # deployment that answers aggregates from a resident index states a
+    # budget that holds the index's columns (the cache is the process's: the
+    # most recently constructed session's value wins). HS_DEVICE_CACHE_BYTES
+    # overrides this default, not a value a session states.
+    keys.TPU_QUERY_DEVICE_CACHE_BYTES: int(os.environ.get("HS_DEVICE_CACHE_BYTES", 1 << 31)),
     # Inner-join pair expansion + numeric column gather on device (host
     # gathers only string/object columns); False reverts to the host
     # expansion for every column.
@@ -757,6 +765,10 @@ class HyperspaceConf:
     @property
     def device_exec_min_rows(self) -> int:
         return int(self.get(keys.TPU_QUERY_DEVICE_MIN_ROWS))
+
+    @property
+    def device_cache_bytes(self) -> int:
+        return int(self.get(keys.TPU_QUERY_DEVICE_CACHE_BYTES))
 
     @property
     def join_device_materialize(self) -> bool:

@@ -57,6 +57,13 @@ class DeviceUnsupported(Exception):
     """Raised when an expression/plan shape cannot run on the device path."""
 
 
+class ResidentOverCap(DeviceUnsupported):
+    """A scan's columns would not fit the device column cache's budget
+    (conf ``hyperspace.tpu.query.deviceCacheBytes``): they could not stay
+    resident, so the resident aggregate tier leaves the query to the tiers
+    behind it (the out-of-core stream, the host)."""
+
+
 class GroupCapacityExceeded(DeviceUnsupported):
     """Observed group cardinality exceeds conf ``hyperspace.exec.agg.maxGroups``
     — the caller spills to the host hash-combine path (the accumulated device
@@ -77,12 +84,21 @@ class ColumnCodec:
                     the datetime64 unit for literal conversion
       - "string":   device array is int32 codes into ``uniques`` (sorted);
                     code -1 encodes null
+
+    ``dtype`` is the host column's dtype before encoding, and ``nulls`` says
+    whether a string column holds a null code; both None where the encoder
+    did not look. They let a program over a RESIDENT column (no host batch
+    at hand) give its result the host path's dtype, and size a dictionary
+    key's domain.
     """
 
-    def __init__(self, kind: str, uniques: Optional[np.ndarray] = None, unit: Optional[str] = None):
+    def __init__(self, kind: str, uniques: Optional[np.ndarray] = None, unit: Optional[str] = None,
+                 dtype=None, nulls: Optional[bool] = None):
         self.kind = kind
         self.uniques = uniques
         self.unit = unit
+        self.dtype = dtype
+        self.nulls = nulls
 
 
 def encode_column(arr: np.ndarray) -> Tuple[np.ndarray, ColumnCodec]:
@@ -91,17 +107,19 @@ def encode_column(arr: np.ndarray) -> Tuple[np.ndarray, ColumnCodec]:
     # buffers, and a copy here would break the zero-copy staging handoff
     kind = arr.dtype.kind
     if kind in ("i", "u", "b"):
-        return np.asarray(arr, dtype=np.int64), ColumnCodec("numeric")
+        return np.asarray(arr, dtype=np.int64), ColumnCodec("numeric", dtype=arr.dtype)
     if kind == "f":
-        return np.asarray(arr, dtype=np.float64), ColumnCodec("numeric")
+        return np.asarray(arr, dtype=np.float64), ColumnCodec("numeric", dtype=arr.dtype)
     if kind == "M":
         unit = np.datetime_data(arr.dtype)[0]
-        return arr.view("int64"), ColumnCodec("datetime", unit=unit)
+        return arr.view("int64"), ColumnCodec("datetime", unit=unit, dtype=arr.dtype)
     if kind in ("U", "S", "O"):
         from hyperspace_tpu.ops.encode import factorize_strings
 
         codes, uniques, _ = factorize_strings(arr)
-        return codes.astype(np.int32), ColumnCodec("string", uniques=uniques)
+        return codes.astype(np.int32), ColumnCodec(
+            "string", uniques=uniques, dtype=arr.dtype, nulls=bool((codes < 0).any())
+        )
     raise DeviceUnsupported(f"unsupported column dtype {arr.dtype}")
 
 
@@ -138,12 +156,13 @@ class _LitSlots:
     """Collects literal values during compilation; each gets a slot index in
     the ``lits`` tuple passed to the compiled program at call time."""
 
-    def __init__(self):
+    def __init__(self, base: int = 0):
+        self.base = base  # slots of ``lits`` another compile already took
         self.values: List = []
 
     def add(self, value) -> int:
         self.values.append(value)
-        return len(self.values) - 1
+        return self.base + len(self.values) - 1
 
 
 def predicate_skeleton(expr: Expr, codecs: Dict[str, ColumnCodec]) -> str:
@@ -181,6 +200,91 @@ def predicate_skeleton(expr: Expr, codecs: Dict[str, ColumnCodec]) -> str:
     return rec(expr)
 
 
+def _const_subtree(e: Expr) -> bool:
+    if isinstance(e, Lit):
+        return True
+    if isinstance(e, BinaryOp) and e.op in ("+", "-", "*", "/", "%"):
+        return _const_subtree(e.left) and _const_subtree(e.right)
+    return False
+
+
+def _fold_const(e: Expr) -> Expr:
+    """Fold literal-only arithmetic on host: calendar-unit intervals
+    (date '1994-01-01' + interval '1' year => timedelta64[M]) have no
+    JAX dtype, but their folded result is a plain datetime scalar."""
+    if isinstance(e, Lit) or not _const_subtree(e):
+        return e
+    v = e.eval({})
+    arr = np.asarray(v)
+    return Lit(arr.reshape(-1)[0] if arr.ndim else arr[()])
+
+
+def _build_num(e: Expr, codecs: Dict[str, ColumnCodec], slots: "_LitSlots"):
+    """Numeric-valued subexpression -> device fn ``f(cols, lits)``; its
+    literals take slots of ``slots``. The one numeric expression compiler:
+    predicates (``compile_predicate``) and computed aggregate inputs
+    (``compile_computes``) build through it."""
+    e = _fold_const(e)
+    if isinstance(e, Col):
+        codec = codecs[e.name]
+        if codec.kind == "string":
+            raise DeviceUnsupported("string column used in numeric context")
+        name = e.name
+        return lambda cols, lits: cols[name]
+    if isinstance(e, Lit):
+        v = e.value
+        if isinstance(v, str):
+            raise DeviceUnsupported("string literal in numeric context")
+        if isinstance(v, np.datetime64):
+            v = int(v.view("int64"))
+        i = slots.add(_as_lit_scalar(v))
+        return lambda cols, lits: lits[i]
+    if isinstance(e, BinaryOp) and e.op in ("+", "-", "*", "/", "%"):
+        lf, rf = _build_num(e.left, codecs, slots), _build_num(e.right, codecs, slots)
+        op = e.op
+        def f(cols, lits):
+            l, r = lf(cols, lits), rf(cols, lits)
+            if op == "+":
+                return l + r
+            if op == "-":
+                return l - r
+            if op == "*":
+                return l * r
+            if op == "/":
+                return l / r
+            return l % r
+        return f
+    raise DeviceUnsupported(f"unsupported numeric expr {type(e).__name__}")
+
+
+def compile_computes(computes, codecs: Dict[str, ColumnCodec], lit_base: int = 0):
+    """Compile computed aggregate inputs, ``(name, expr)`` pairs of a
+    ``Compute`` node between an ``Aggregate`` and its scan, into ``(f,
+    lit_values, skeleton)``: ``f(cols, lits)`` returns ``cols`` with every
+    computed column added, to run inside an aggregate program. Literal slots
+    start at ``lit_base``, behind the predicate's, so one ``lits`` tuple
+    serves both. Numeric operands only: a datetime operand has a per-column
+    epoch unit and a string none (DeviceUnsupported)."""
+    slots = _LitSlots(lit_base)
+    codecs = dict(codecs)
+    fns, parts = [], []
+    for name, expr in computes:
+        for r in expr.references():
+            if r not in codecs or codecs[r].kind != "numeric":
+                raise DeviceUnsupported(f"computed input {name!r} over non-numeric column {r!r}")
+        fns.append((name, _build_num(expr, codecs, slots)))
+        parts.append(f"{name}={predicate_skeleton(expr, codecs)}")
+        codecs[name] = ColumnCodec("numeric")
+
+    def fn(cols, lits):
+        out = dict(cols)
+        for name, f in fns:
+            out[name] = f(out, lits)
+        return out
+
+    return fn, tuple(slots.values), ";".join(parts)
+
+
 def compile_predicate(expr: Expr, codecs: Dict[str, ColumnCodec]):
     """Compile ``expr`` into ``(f, lit_values)`` where
     ``f(cols: dict[str, jnp.ndarray], lits: tuple) -> bool mask`` and
@@ -196,23 +300,6 @@ def compile_predicate(expr: Expr, codecs: Dict[str, ColumnCodec]):
     def is_string_col(e: Expr) -> bool:
         return isinstance(e, Col) and codecs[e.name].kind == "string"
 
-    def _const_subtree(e: Expr) -> bool:
-        if isinstance(e, Lit):
-            return True
-        if isinstance(e, BinaryOp) and e.op in ("+", "-", "*", "/", "%"):
-            return _const_subtree(e.left) and _const_subtree(e.right)
-        return False
-
-    def _fold_const(e: Expr) -> Expr:
-        """Fold literal-only arithmetic on host: calendar-unit intervals
-        (date '1994-01-01' + interval '1' year => timedelta64[M]) have no
-        JAX dtype, but their folded result is a plain datetime scalar."""
-        if isinstance(e, Lit) or not _const_subtree(e):
-            return e
-        v = e.eval({})
-        arr = np.asarray(v)
-        return Lit(arr.reshape(-1)[0] if arr.ndim else arr[()])
-
     def _has_datetime(e: Expr) -> bool:
         if isinstance(e, Col):
             return codecs[e.name].kind == "datetime"
@@ -221,38 +308,7 @@ def compile_predicate(expr: Expr, codecs: Dict[str, ColumnCodec]):
         return any(_has_datetime(c) for c in e.children())
 
     def build_num(e: Expr):
-        """Numeric-valued subexpression -> device fn."""
-        e = _fold_const(e)
-        if isinstance(e, Col):
-            codec = codecs[e.name]
-            if codec.kind == "string":
-                raise DeviceUnsupported("string column used in numeric context")
-            name = e.name
-            return lambda cols, lits: cols[name]
-        if isinstance(e, Lit):
-            v = e.value
-            if isinstance(v, str):
-                raise DeviceUnsupported("string literal in numeric context")
-            if isinstance(v, np.datetime64):
-                v = int(v.view("int64"))
-            i = slots.add(_as_lit_scalar(v))
-            return lambda cols, lits: lits[i]
-        if isinstance(e, BinaryOp) and e.op in ("+", "-", "*", "/", "%"):
-            lf, rf = build_num(e.left), build_num(e.right)
-            op = e.op
-            def f(cols, lits):
-                l, r = lf(cols, lits), rf(cols, lits)
-                if op == "+":
-                    return l + r
-                if op == "-":
-                    return l - r
-                if op == "*":
-                    return l * r
-                if op == "/":
-                    return l / r
-                return l % r
-            return f
-        raise DeviceUnsupported(f"unsupported numeric expr {type(e).__name__}")
+        return _build_num(e, codecs, slots)
 
     # Boolean subtrees compile to (value, unknown) Kleene pairs so NULL stays
     # three-valued on device exactly as on host (expr.NullableBool): a NULL
@@ -682,7 +738,43 @@ _PREDICATE_CACHE_MAX = 256
 # query on an index version pays the host->device transfer.
 from hyperspace_tpu.utils.lru import BytesLRU
 
-_device_cache = BytesLRU(int(os.environ.get("HS_DEVICE_CACHE_BYTES", 1 << 31)))
+from hyperspace_tpu.config import DEFAULTS as _CONF_DEFAULTS, keys as _conf_keys
+
+# until a session states its budget: the key's default (2 GiB, or what
+# HS_DEVICE_CACHE_BYTES says)
+_device_cache = BytesLRU(int(_CONF_DEFAULTS[_conf_keys.TPU_QUERY_DEVICE_CACHE_BYTES]))
+
+
+def set_device_cache_bytes(n: int) -> None:
+    """Record the conf-requested budget of the resident column cache
+    (``hyperspace.tpu.query.deviceCacheBytes``; called on Session
+    construction, most-recent-wins like the decode pool's width: the cache is
+    the process's, as the device is). Entries beyond a smaller budget go with
+    the next ``put``."""
+    _device_cache.cap = int(n)
+
+
+def device_cache_cap() -> int:
+    """Bytes the resident column cache may hold."""
+    return _device_cache.cap
+
+
+def check_fits_device_cache(rows: int, n_columns: int) -> None:
+    """Raises :class:`ResidentOverCap` when ``n_columns`` columns of ``rows``
+    rows, at 8 bytes a value and padded to their shape bucket, outweigh the
+    cache's budget: asked before a scan is read or uploaded to stay."""
+    need = bucket_rows(rows) * 8 * n_columns
+    if need > device_cache_cap():
+        raise ResidentOverCap(
+            f"{n_columns} columns of {rows} rows take {need} bytes, over the device cache's {device_cache_cap()}"
+        )
+
+
+_REGISTRY.gauge(
+    "hs_device_cache_bytes",
+    "Bytes the device-resident column cache holds (scan columns, join matrices)",
+    fn=lambda: _device_cache.total_bytes,
+)
 
 
 def _device_cache_get(key):
@@ -691,6 +783,19 @@ def _device_cache_get(key):
 
 # "hit" / "miss" -> counter of resident-column lookups, held as _LINK_BYTES is
 _COLUMN_LOOKUPS: dict = {}
+
+
+def _count_lookups(result: str, n: int = 1) -> None:
+    c = _COLUMN_LOOKUPS.get(result)
+    if c is None:
+        from hyperspace_tpu.obs.metrics import REGISTRY
+
+        c = _COLUMN_LOOKUPS[result] = REGISTRY.counter(
+            "hs_device_cache_lookups_total",
+            "Lookups of a scan column in the device-resident column cache, by result",
+            result=result,
+        )
+    c.inc(n)
 
 
 def resident_column(key, n: int):
@@ -705,24 +810,22 @@ def resident_column(key, n: int):
     cached = _device_cache.get(key)
     if cached is not None and cached[2] != n:
         cached = None
-    result = "miss" if cached is None else "hit"
-    c = _COLUMN_LOOKUPS.get(result)
-    if c is None:
-        from hyperspace_tpu.obs.metrics import REGISTRY
-
-        c = _COLUMN_LOOKUPS[result] = REGISTRY.counter(
-            "hs_device_cache_lookups_total",
-            "Lookups of a scan column in the device-resident column cache, by result",
-            result=result,
-        )
-    c.inc()
+    _count_lookups("miss" if cached is None else "hit")
     return cached
 
 
 def _device_cache_put(key, value, nbytes: int) -> None:
     # overwrite semantics matter: a stale same-key entry (e.g. rows changed)
-    # must be replaced, not pinned
-    _device_cache.put(key, value, nbytes)
+    # must be replaced, not pinned. An evicted array is freed when the last
+    # query that looked it up lets go of it, not under that query.
+    cache = _device_cache
+    before = cache.evictions
+    cache.put(key, value, nbytes)
+    if cache.evictions > before:
+        _REGISTRY.counter(
+            "hs_device_cache_evictions_total",
+            "Entries the device-resident column cache evicted to stay inside its budget",
+        ).inc(cache.evictions - before)
 
 
 def clear_device_cache() -> None:
@@ -871,9 +974,9 @@ def _dict_expand_fn(codes, remap):
     return jnp.where(codes >= 0, remap[jnp.maximum(codes, 0)], jnp.int32(-1))
 
 
-def _put_encoded(session, mesh, sharding, n_dev, arr):
-    """Encode + bucket-pad + ``device_put`` one column; returns
-    (device array, codec, staged bytes).
+def _put_encoded(session, mesh, sharding, n_dev, arr, site: str = "filter-cols"):
+    """Encode + bucket-pad + ``device_put`` one column, the upload counted
+    under ``site``; returns (device array, codec, staged bytes).
 
     Dict-backed string columns (B.DictBackedArray, produced by the native
     decode fast path) skip host factorization entirely: the int32 codes ship
@@ -899,8 +1002,8 @@ def _put_encoded(session, mesh, sharding, n_dev, arr):
         remap = np.zeros(cap, dtype=np.int32)
         remap[:k] = rank
         padded = _pad_to_bucket(codes, n_dev, 0)
-        dev_codes = put(padded, "filter-cols", sharding)
-        dev_remap = put(remap, "filter-cols", NamedSharding(mesh, P()))
+        dev_codes = put(padded, site, sharding)
+        dev_remap = put(remap, site, NamedSharding(mesh, P()))
         key = _program_key("dict-expand", mesh)
         jitted = _cached_predicate_jit(key, _dict_expand_fn, "dict-expand")
         first = _note_compile(key, (padded.shape, remap.shape))
@@ -909,10 +1012,11 @@ def _put_encoded(session, mesh, sharding, n_dev, arr):
         dev = jitted(dev_codes, dev_remap)
         count_dispatch("dict-expand")
         _observe_program("dict-expand", first, t0)
-        return dev, ColumnCodec("string", uniques=su), int(padded.nbytes + remap.nbytes)
+        codec = ColumnCodec("string", uniques=su, dtype=arr.dtype, nulls=bool((codes < 0).any()))
+        return dev, codec, int(padded.nbytes + remap.nbytes)
     enc, codec = encode_column(arr)
     padded = _pad_to_bucket(enc, n_dev, 0 if enc.dtype != np.float64 else np.nan)
-    dev = put(padded, "filter-cols", sharding)
+    dev = put(padded, site, sharding)
     return dev, codec, int(padded.nbytes)
 
 
@@ -1040,86 +1144,206 @@ def stage_filter_columns(session, batch: B.Batch, condition: Optional[Expr], sca
 _AGG_FNS = ("count", "sum", "min", "max", "avg")
 
 
-def device_filtered_aggregate(
-    session,
-    batch: B.Batch,
-    condition: Optional[Expr],
-    aggs: List[Tuple[str, str, Optional[str]]],
-    scan_key=None,
-) -> Optional[Dict[str, np.ndarray]]:
-    """Global aggregates over (optionally filtered) device-resident columns
-    in ONE fused program: predicate mask, validity mask for padding, and the
-    reductions all execute on device; only per-aggregate scalars transfer
-    back. ``aggs`` as in plan.Aggregate ((out name, fn, input col)).
+class ScanColumns:
+    """The columns ``names`` of one whole scan, on the device.
 
-    Raises DeviceUnsupported outside the device language (string aggregate
-    inputs, unsupported predicate shapes, ...)."""
-    ensure_x64()
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    ``scan_key`` is the scan's identity (file_identity.scan_identity, branded
+    with a pruned read's kept signature): columns resident under it are
+    looked up without reading anything; ``on_device`` counts each column's
+    lookup in ``hs_device_cache_lookups_total`` (a caller that turns away
+    before it, below ``deviceMinRows`` say, asked nothing of the device).
+    ``load()`` reads the scan's host
+    batch; it is called at most once, and only when a column is missing or
+    a caller asks for ``batch()``: a scan whose columns are all resident
+    opens no file, decodes nothing and uploads nothing.
 
-    n = B.num_rows(batch)
-    if n == 0:
-        return None  # empty-input semantics (NaN mins etc.) stay host-side
+    The read and the uploads of missing columns are single-flight,
+    process-wide: of the requests that miss the same columns at once (a
+    server's warm-up) one reads the scan and uploads, and the others find
+    the columns resident when the lock is theirs. Otherwise each would hold
+    a copy of the scan on the host and on the device."""
 
-    agg_inputs = sorted({c for _, fn, c in aggs if c is not None})
-    for _, fn, c in aggs:
-        if fn not in _AGG_FNS:
-            raise DeviceUnsupported(f"unsupported aggregate fn {fn!r}")
-        # datetimes stay host-side: float64 reduction would lose ns precision
-        if c is not None and batch[c].dtype.kind not in ("i", "u", "f", "b"):
-            raise DeviceUnsupported(f"aggregate over non-numeric column {c!r}")
-    refs = sorted(condition.references()) if condition is not None else []
-    if not refs and not agg_inputs:
-        # nothing to put on device (count(*) with no predicate): the program
-        # would see an empty column dict and derive total=0 — host handles it
-        raise DeviceUnsupported("no device-resident columns involved")
-    for r in refs + agg_inputs:
-        if r not in batch:
-            raise DeviceUnsupported(f"column {r!r} missing from batch")
+    _load_lock = _threading.Lock()
 
-    mesh = session.mesh
-    n_dev = mesh.devices.size
-    axis = mesh.axis_names[0]
-    sharding = NamedSharding(mesh, P(axis))
-    fp = _mesh_fp(mesh)
+    def __init__(self, session, scan_key, names, load):
+        self.session = session
+        self.scan_key = scan_key
+        self.names = list(names)
+        self._load = load
+        self._batch: Optional[B.Batch] = None
+        self.mesh = session.mesh
+        self._fp = _mesh_fp(self.mesh)
+        self._found: Dict[str, tuple] = {}
+        self._look()
+        #: every column was resident when the query asked
+        self.resident = bool(self.names) and len(self._found) == len(self.names)
+        self._asked = (len(self._found), len(self.names) - len(self._found))
 
-    # dry-check the predicate before any upload
+    def _ckey(self, c):
+        return (self.scan_key, c, self._fp) if self.scan_key is not None else None
+
+    def _look(self) -> None:
+        """Take what the cache holds of the missing columns (not counted)."""
+        if self.scan_key is None:
+            return
+        for c in self.names:
+            if c not in self._found:
+                got = _device_cache.get(self._ckey(c))
+                if got is not None:
+                    self._found[c] = got
+        if len({e[2] for e in self._found.values()}) > 1:
+            self._found = {}  # cannot be under one key; treat as nothing there
+
+    @property
+    def loaded(self) -> Optional[B.Batch]:
+        """The host batch if it was read, else None."""
+        return self._batch
+
+    def batch(self) -> B.Batch:
+        if self._batch is None:
+            self._batch = self._load()
+        return self._batch
+
+    @property
+    def rows(self) -> int:
+        for entry in self._found.values():
+            return entry[2]
+        return B.num_rows(self.batch())
+
+    def _codecs_before_upload(self) -> Dict[str, ColumnCodec]:
+        """Codecs that are enough to compile against before any upload: the
+        resident columns' own, dtype-kind-only ones for the rest."""
+        out = {c: e[1] for c, e in self._found.items()}
+        missing = [c for c in self.names if c not in out]
+        if missing:
+            out.update(_dry_codecs(self.batch(), missing))
+        return out
+
+    def on_device(self, check=None, site: str = "agg-cols"):
+        """``(device columns, codecs)``, reading the scan and uploading what
+        is not resident. ``check(codecs)`` is asked before any upload, with
+        dry codecs, and may raise DeviceUnsupported: an unsupported shape
+        must not cost HBM space or a wasted upload."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        if not self.resident:
+            mesh = self.mesh
+            sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
+            with self._load_lock:
+                self._look()  # a request ahead of this one in the lock may have put them
+                missing = [c for c in self.names if c not in self._found]
+                if missing:
+                    if check is not None:
+                        check(self._codecs_before_upload())
+                    rows = self.rows
+                    if rows == 0:
+                        raise DeviceUnsupported("empty input stays host-side")
+                    for c in missing:
+                        dev, codec, nbytes = _put_encoded(
+                            self.session, mesh, sharding, mesh.devices.size, self.batch()[c], site
+                        )
+                        self._found[c] = (dev, codec, rows)
+                        if self.scan_key is not None:
+                            _device_cache_put(self._ckey(c), self._found[c], nbytes)
+        if self.scan_key is not None:  # what the query found when it asked
+            _count_lookups("hit", self._asked[0])
+            _count_lookups("miss", self._asked[1])
+        return (
+            {c: self._found[c][0] for c in self.names},
+            {c: self._found[c][1] for c in self.names},
+        )
+
+
+def _compile_aggregate(codecs, condition, computes, aggs, group_keys):
+    """``(predicate fn or None, computes fn or None, literal values, skeleton)``
+    of an aggregate over a scan with ``codecs``. Everything that can say
+    DeviceUnsupported is in here, so that it can be asked with dry codecs
+    before an upload."""
+    for r in sorted(condition.references()) if condition is not None else []:
+        if r not in codecs:
+            raise DeviceUnsupported(f"referenced column {r!r} missing from scan")
     if condition is not None:
-        compile_predicate(condition, _dry_codecs(batch, refs))
-
-    dev_cols: Dict[str, "jax.Array"] = {}
-    codecs: Dict[str, ColumnCodec] = {}
-    for r in sorted(set(refs) | set(agg_inputs)):
-        ckey = (scan_key, r, fp) if scan_key is not None else None
-        cached = resident_column(ckey, n)
-        if cached is not None:
-            dev_cols[r], codecs[r] = cached[0], cached[1]
-            continue
-        arr, codec = encode_column(batch[r])
-        if codec.kind == "string":
-            raise DeviceUnsupported("string aggregate/predicate columns stay host-side here")
-        padded = _pad_to_bucket(arr, n_dev, 0 if arr.dtype != np.float64 else np.nan)
-        dev = put(padded, "filter-cols", sharding)
-        dev_cols[r] = dev
-        codecs[r] = codec
-        if ckey is not None:
-            _device_cache_put(ckey, (dev, codec, n), int(padded.nbytes))
-
-    if condition is not None:
-        pred_fn, lit_values = compile_predicate(condition, codecs)
-        skeleton = "agg:" + predicate_skeleton(condition, codecs)
+        pred_fn, lits = compile_predicate(condition, codecs)
+        skeleton = predicate_skeleton(condition, codecs)
     else:
-        pred_fn, lit_values = None, ()
-        skeleton = "agg:<none>"
+        pred_fn, lits, skeleton = None, (), "<none>"
+    comp_fn = None
+    computed = set()
+    if computes:
+        comp_fn, comp_lits, comp_sk = compile_computes(computes, codecs, lit_base=len(lits))
+        lits = tuple(lits) + comp_lits
+        skeleton += "|c:" + comp_sk
+        computed = {name for name, _ in computes}
+    for _, _fn, c in aggs:
+        if c is None or c in computed:
+            continue
+        if c not in codecs:
+            raise DeviceUnsupported(f"column {c!r} missing from scan")
+        # datetimes stay host-side: float64 reduction would lose ns precision
+        if codecs[c].kind != "numeric":
+            raise DeviceUnsupported(f"aggregate over non-numeric column {c!r}")
+    for k in group_keys:
+        if k not in codecs:
+            raise DeviceUnsupported(f"group key {k!r} missing from scan")
+    return pred_fn, comp_fn, lits, skeleton
+
+
+def device_scan_aggregate(
+    session,
+    cols: ScanColumns,
+    condition: Optional[Expr],
+    computes,
+    group_keys,
+    aggs,
+    *,
+    max_groups: int = 0,
+) -> Optional[B.Batch]:
+    """An aggregate over one whole scan, in ONE program over its resident
+    columns: the predicate, the computed aggregate inputs (``computes``:
+    ``(name, expr)`` pairs of the Compute node under the Aggregate) and the
+    reductions run on the device, and only the result table comes back.
+    Ungrouped aggregates reduce to scalars (``fused-agg``); grouped ones
+    whose keys are all dictionary-coded with a small domain reduce into one
+    slot per group, without a sort (``grouped-agg-dense``). Raises
+    DeviceUnsupported for any other shape: the caller has the sort-based
+    :class:`GroupedAggStream` and the host for those."""
+    ensure_x64()
+    for _, fn, _c in aggs:
+        if fn not in (_GROUPED_AGG_FNS if group_keys else _AGG_FNS):
+            raise DeviceUnsupported(f"unsupported aggregate fn {fn!r}")
+    if not cols.names:
+        # nothing to put on device (count(*) with no predicate): the program
+        # would see an empty column dict — host handles it
+        raise DeviceUnsupported("no device-resident columns involved")
+    def check(dry):
+        _compile_aggregate(dry, condition, computes, aggs, group_keys)
+        if group_keys:
+            _dense_key_plan(group_keys, dry, max_groups, dry_run=True)
+
+    dev_cols, codecs = cols.on_device(check)
+    pred_fn, comp_fn, lits, skeleton = _compile_aggregate(codecs, condition, computes, aggs, group_keys)
+    if group_keys:
+        return _dense_grouped_aggregate(
+            session, cols, dev_cols, codecs, pred_fn, comp_fn, lits, skeleton,
+            list(group_keys), list(aggs), max_groups,
+        )
+    return _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lits, skeleton, list(aggs))
+
+
+def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skeleton, aggs):
+    import jax.numpy as jnp
+
+    mesh = cols.mesh
+    n = cols.rows
     agg_spec = tuple((fn, c) for _, fn, c in aggs)
-    skeleton += "|" + repr(agg_spec)
+    skeleton = "agg:" + skeleton + "|" + repr(agg_spec)
 
     def program(cols, lits, n_valid):
         total = next(iter(cols.values())).shape[0]
         valid = jnp.arange(total) < n_valid
         mask = valid if pred_fn is None else (pred_fn(cols, lits) & valid)
+        if comp_fn is not None:
+            cols = comp_fn(cols, lits)
         cnt = mask.sum()
         outs = []
         valids = []  # per-aggregate non-null match count (NaN-skipping)
@@ -1166,21 +1390,21 @@ def device_filtered_aggregate(
     outs, valids = fetch((outs, valids), "agg-table", "fused-agg")
     valids = [int(v) for v in valids]
     _observe_program("fused-agg", first, t0)
+    trace.agg_rows("device", n)
 
     result: Dict[str, np.ndarray] = {}
     for (name, fn, c), val, n_valid in zip(aggs, outs, valids):
         if fn == "count":
             result[name] = np.asarray([int(val)])
-        elif fn in ("sum", "min", "max", "avg") and n_valid == 0:
+        elif n_valid == 0:
             # no non-null matches: SQL yields NULL (sum included — SUM over
             # zero rows is NULL, not 0)
             result[name] = np.asarray([np.nan])
+        elif fn != "avg" and np.asarray(val).dtype.kind in ("i", "u"):
+            # an integer input (or integer-valued computed one) keeps int64
+            result[name] = np.asarray([int(val)])
         else:
-            src = batch[c]
-            if fn in ("sum", "min", "max") and src.dtype.kind in ("i", "u", "b"):
-                result[name] = np.asarray([int(val)])
-            else:
-                result[name] = np.asarray([float(val)])
+            result[name] = np.asarray([float(val)])
     return result
 
 
@@ -1261,6 +1485,47 @@ def _grouped_slots(aggs, is_int: Dict[str, bool]):
         else:  # stddev_samp
             refs.append([slot("cnt", c, ii), slot("sum", c, False), slot("sumsq", c, False)])
     return slots, refs
+
+
+def _final_columns(aggs, refs, input_dtypes, slot_cols) -> B.Batch:
+    """Per-group final values of ``aggs`` from their state slots, with
+    host-path semantics: count -> int64, int sum -> int64 (exact), float
+    sum/min/max -> NULL (NaN) when every matched row was NULL, int min/max
+    keep the input dtype, avg/stddev from the decomposed states."""
+    out: B.Batch = {}
+    for (name, fn, c), ref in zip(aggs, refs):
+        if fn == "count":
+            out[name] = slot_cols[ref[0]].astype(np.int64)
+            continue
+        dt = input_dtypes[c]
+        is_int = dt.kind in ("i", "u", "b")
+        if fn == "sum":
+            s, cnt = slot_cols[ref[0]], slot_cols[ref[1]]
+            if is_int:
+                out[name] = s.astype(np.int64)  # int inputs have no NULLs
+            else:
+                out[name] = np.where(cnt > 0, s.astype(np.float64), np.nan)
+        elif fn in ("min", "max"):
+            v, cnt = slot_cols[ref[0]], slot_cols[ref[1]]
+            if is_int:
+                out[name] = v.astype(dt if dt.kind != "u" else np.int64)
+            else:
+                out[name] = np.where(cnt > 0, v.astype(np.float64), np.nan)
+        elif fn == "avg":
+            s, cnt = slot_cols[ref[0]], slot_cols[ref[1]]
+            with np.errstate(invalid="ignore", divide="ignore"):
+                out[name] = np.where(cnt > 0, s / np.maximum(cnt, 1), np.nan)
+        else:  # stddev_samp
+            cnt, s, ss = (slot_cols[r] for r in ref)
+            with np.errstate(invalid="ignore", divide="ignore"):
+                m = cnt > 1
+                var = np.where(
+                    m,
+                    (ss - (s * s) / np.maximum(cnt, 1)) / np.maximum(cnt - 1, 1),
+                    np.nan,
+                )
+                out[name] = np.sqrt(np.clip(var, 0.0, None))
+    return out
 
 
 def _key_code(k, tag):
@@ -1662,6 +1927,7 @@ class GroupedAggStream:
                 break
             cap = group_capacity(n_g, self.cap_floor)  # one re-run, right-sized
         self._cap_hint = max(self._cap_hint, n_g)
+        trace.agg_rows("device", n)
 
         key_out = list(key_out)
         for i, (name, (tag, _, _)) in enumerate(zip(self.group_keys, keys_schema)):
@@ -1808,38 +2074,7 @@ class GroupedAggStream:
         n, key_cols, slot_cols = self._host_table()
         _, input_dtypes = self._schema
         out: B.Batch = dict(key_cols)
-        for (name, fn, c), ref in zip(self.aggs, self._refs):
-            if fn == "count":
-                out[name] = slot_cols[ref[0]].astype(np.int64)
-                continue
-            dt = input_dtypes[c]
-            is_int = dt.kind in ("i", "u", "b")
-            if fn == "sum":
-                s, cnt = slot_cols[ref[0]], slot_cols[ref[1]]
-                if is_int:
-                    out[name] = s.astype(np.int64)  # int inputs have no NULLs
-                else:
-                    out[name] = np.where(cnt > 0, s.astype(np.float64), np.nan)
-            elif fn in ("min", "max"):
-                v, cnt = slot_cols[ref[0]], slot_cols[ref[1]]
-                if is_int:
-                    out[name] = v.astype(dt if dt.kind != "u" else np.int64)
-                else:
-                    out[name] = np.where(cnt > 0, v.astype(np.float64), np.nan)
-            elif fn == "avg":
-                s, cnt = slot_cols[ref[0]], slot_cols[ref[1]]
-                with np.errstate(invalid="ignore", divide="ignore"):
-                    out[name] = np.where(cnt > 0, s / np.maximum(cnt, 1), np.nan)
-            else:  # stddev_samp
-                cnt, s, ss = (slot_cols[r] for r in ref)
-                with np.errstate(invalid="ignore", divide="ignore"):
-                    m = cnt > 1
-                    var = np.where(
-                        m,
-                        (ss - (s * s) / np.maximum(cnt, 1)) / np.maximum(cnt - 1, 1),
-                        np.nan,
-                    )
-                    out[name] = np.sqrt(np.clip(var, 0.0, None))
+        out.update(_final_columns(self.aggs, self._refs, input_dtypes, slot_cols))
         REGISTRY.counter(
             "hs_agg_groups_total", "Groups produced by device grouped aggregation"
         ).inc(n)
@@ -1915,6 +2150,162 @@ def device_grouped_aggregate(
     )
     stream.update(batch, condition, scan_key=scan_key)
     return stream.finalize()
+
+
+# --------------------------------------------------------------------------
+# grouped aggregate over dictionary keys: one slot per group, no sort
+#
+# A group key that is a dictionary-coded string has a domain the codec
+# states: the dictionary's size. When the product of the keys' domains is
+# small, a row's group is the mixed-radix number of its codes, and every
+# state slot reduces into ``groups`` masked sums/mins/maxes in one pass over
+# the resident columns — no sort, no gather, one program a query shape. The
+# sort-based engine above stays for every other key (numbers, dates, wide
+# dictionaries), whose domain nothing states.
+# --------------------------------------------------------------------------
+
+#: most groups the direct-addressed program reduces into; the work of a pass
+#: grows with it
+_DENSE_MAX_GROUPS = 64
+
+_hlo_lint.register_contract(
+    "grouped-agg-dense",
+    collectives={"all-gather": _ANY, "all-reduce": _ANY},
+    description="direct-addressed grouped aggregate over dictionary keys: per-group reductions, only the group table leaves",
+)
+
+
+def _dense_key_plan(group_keys, codecs, max_groups: int, dry_run: bool = False):
+    """``[(key, domain size, code offset)]`` and the number of groups, for
+    keys that are all dictionary-coded; DeviceUnsupported otherwise. A null
+    code (-1) takes a slot of its own unless the codec saw none. A dry run
+    has no dictionary yet and checks the key kinds only."""
+    plan, groups = [], 1
+    for k in group_keys:
+        codec = codecs[k]
+        if codec.kind != "string":
+            raise DeviceUnsupported(f"group key {k!r} is not dictionary-coded")
+        if dry_run:
+            continue
+        off = 0 if codec.nulls is False else 1
+        size = len(codec.uniques) + off
+        plan.append((k, size, off))
+        groups *= max(size, 1)
+    limit = min(_DENSE_MAX_GROUPS, max_groups) if max_groups else _DENSE_MAX_GROUPS
+    if groups > limit:
+        raise DeviceUnsupported(f"{groups} dictionary groups exceed the direct-addressed limit {limit}")
+    return plan, groups
+
+
+def _dense_grouped_aggregate(
+    session, cols, dev_cols, codecs, pred_fn, comp_fn, lit_values, skeleton,
+    group_keys, aggs, max_groups,
+) -> B.Batch:
+    import jax
+    import jax.numpy as jnp
+
+    mesh = cols.mesh
+    n = cols.rows
+    plan, groups = _dense_key_plan(group_keys, codecs, max_groups)
+    computed = _computed_dtypes(comp_fn, dev_cols, lit_values)
+    input_dtypes = {}
+    for _, _fn, c in aggs:
+        if c is not None:
+            host_dtype = computed[c] if c in computed else codecs[c].dtype
+            input_dtypes[c] = np.dtype(dev_cols[c].dtype if host_dtype is None else host_dtype)
+    slots, refs = _grouped_slots(aggs, {c: dt.kind in ("i", "u", "b") for c, dt in input_dtypes.items()})
+    if not any(kind == "cntm" for kind, _, _ in slots):
+        slots = slots + [("cntm", None, True)]  # which groups have a row at all
+    cntm_at = next(i for i, (kind, _, _) in enumerate(slots) if kind == "cntm")
+
+    def program(cols, lits, n_valid):
+        total = next(iter(cols.values())).shape[0]
+        rows = jnp.arange(total, dtype=jnp.int64)
+        with jax.named_scope("filter"):
+            mask = rows < n_valid
+            if pred_fn is not None:
+                mask = pred_fn(cols, lits) & mask
+        if comp_fn is not None:
+            cols = comp_fn(cols, lits)
+        with jax.named_scope("key-encode"):
+            gid = jnp.zeros((total,), jnp.int32)
+            for name, size, off in plan:
+                gid = gid * size + (cols[name].astype(jnp.int32) + off)
+            # (groups, rows): a row reduction over the minor axis per group
+            member = (jnp.arange(groups, dtype=jnp.int32)[:, None] == gid[None, :]) & mask[None, :]
+        with jax.named_scope("group-reduce"):
+            fs = jnp.where(member, rows[None, :], _FS_SENTINEL).min(axis=1)
+            out = []
+            for kind, col, isint in slots:
+                if kind == "cntm":
+                    out.append(member.sum(axis=1, dtype=jnp.int64))
+                    continue
+                x = cols[col]
+                nn = member if isint else (member & ~jnp.isnan(x)[None, :])
+                if kind == "cnt":
+                    out.append(nn.sum(axis=1, dtype=jnp.int64))
+                elif kind in ("sum", "sumsq"):
+                    z = x.astype(jnp.int64) if (isint and kind == "sum") else x.astype(jnp.float64)
+                    if kind == "sumsq":
+                        z = z * z
+                    out.append(jnp.where(nn, z[None, :], z.dtype.type(0)).sum(axis=1))
+                elif kind == "min":
+                    z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
+                    big = jnp.iinfo(jnp.int64).max if isint else jnp.inf
+                    out.append(jnp.where(nn, z[None, :], big).min(axis=1))
+                else:  # max
+                    z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
+                    low = jnp.iinfo(jnp.int64).min if isint else -jnp.inf
+                    out.append(jnp.where(nn, z[None, :], low).max(axis=1))
+        return fs, tuple(out)
+
+    skeleton = (
+        f"gdense[{groups}]:{skeleton}|k:{','.join(f'{k}:{size}:{off}' for k, size, off in plan)}"
+        f"|s:{','.join(f'{k}:{c}:{int(i)}' for k, c, i in slots)}"
+    )
+    key = _program_key(skeleton, mesh)
+    jitted = _cached_predicate_jit(key, program, "grouped-agg-dense")
+    first = _note_compile(key, tuple(dev_cols[r].shape for r in sorted(dev_cols)))
+    _hlo_lint.maybe_verify(
+        session.conf, "grouped-agg-dense", key, jitted, (dev_cols, lit_values, np.int64(n))
+    )
+    t0 = _ptime.perf_counter()
+    out = jitted(dev_cols, lit_values, np.int64(n))
+    count_dispatch("grouped-agg-dense")
+    fs, slot_out = fetch(out, "agg-table", "grouped-agg-dense")
+    _observe_program("grouped-agg-dense", first, t0)
+    trace.agg_rows("device", n)
+
+    # groups that hold a row, in first-appearance order (pandas sort=False)
+    live = np.flatnonzero(slot_out[cntm_at] > 0)
+    live = live[np.argsort(fs[live], kind="stable")]
+    result: B.Batch = {}
+    radix = live.copy()
+    for name, size, off in reversed(plan):
+        codes = radix % size - off
+        radix = radix // size
+        vals = np.full(len(live), np.nan, dtype=object)
+        pos = codes >= 0
+        if pos.any():
+            vals[pos] = np.asarray(codecs[name].uniques, dtype=object)[codes[pos]]
+        result[name] = vals
+    result = {k: result[k] for k in group_keys}
+    result.update(_final_columns(aggs, refs, input_dtypes, [s[live] for s in slot_out]))
+    _REGISTRY.counter(
+        "hs_agg_groups_total", "Groups produced by device grouped aggregation"
+    ).inc(len(live))
+    return result
+
+
+def _computed_dtypes(comp_fn, dev_cols, lit_values) -> Dict[str, np.dtype]:
+    """Dtype each computed input evaluates to, from the shapes alone: an
+    integer-valued expression keeps the host path's exact int64 states."""
+    if comp_fn is None:
+        return {}
+    import jax
+
+    shapes = jax.eval_shape(comp_fn, dev_cols, lit_values)
+    return {c: np.dtype(v.dtype) for c, v in shapes.items() if c not in dev_cols}
 
 
 # --------------------------------------------------------------------------

@@ -11,6 +11,7 @@ here the framework owns it (SURVEY.md §7 design stance).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -407,6 +408,46 @@ def _chain_pushdown_condition(chain):
             continue
         break
     return cond
+
+
+def _scan_aggregate_shape(plan: L.Aggregate):
+    """``(scan, filter node or None, computes)`` when ``plan``'s child is the
+    shape the device tier folds in one program: Projects, at most one
+    Compute whose expressions read scan columns (computed aggregate inputs),
+    and at most one Filter, below the Compute, over an index or file scan;
+    None otherwise."""
+    node = plan.child
+    filter_node = None
+    computes: list = []
+    while isinstance(node, (L.Project, L.Compute, L.Filter)):
+        if isinstance(node, L.Compute):
+            if computes or filter_node is not None:
+                return None
+            computes = list(node.exprs)
+        elif isinstance(node, L.Filter):
+            if filter_node is not None:
+                return None
+            filter_node = node
+        node = node.child
+    if not isinstance(node, (L.IndexScan, L.FileScan)):
+        return None
+    names = {name for name, _ in computes}
+    if names & set(plan.keys) or any(names & set(e.references()) for _, e in computes):
+        return None  # a computed group key, or one computed from another
+    if any(c in node.columns for c in names):
+        return None  # a computed column shadows a scan column
+    return node, filter_node, computes
+
+
+def _footer_rows(node, keys) -> Optional[int]:
+    """Rows of scan ``node`` from its Parquet files' footers, memoized on
+    each file's identity (``keys``: ``scan_identity(node)``), so a file set
+    is asked once; None where that cannot be known without reading."""
+    if keys is None or (isinstance(node, L.FileScan) and node.file_format != "parquet"):
+        return None
+    from hyperspace_tpu.exec import device as D
+
+    return sum(D._file_num_rows(k) for k in keys)
 
 
 def _read_scan_files(scan, *args, **kwargs) -> B.Batch:
@@ -1393,36 +1434,58 @@ class Executor:
                     except D.DeviceUnsupported:
                         trace.fallback("agg", "join-unsupported")
                         tier.set(fallback="join-unsupported")
-        # streaming check BEFORE the device-scan gate: _try_device_aggregate
-        # materializes the whole scan to size its decision, which is exactly
-        # what the out-of-core path exists to avoid
+        # the tiers of an aggregate over a scan chain, chosen from what the
+        # code observes: the device tier where the scan's columns are
+        # resident or can be (it reads the scan once an index version, and a
+        # resident hit opens no file); the out-of-core stream where the scan
+        # is stream-sized and its columns cannot stay on the device; the
+        # device tier over a materialized batch for every smaller scan
         if not with_file_names:
-            got = self._try_streaming_aggregate(plan)
-            if got is not None:
-                trace.record("agg", "streamed-partial")
-                return got
-        if not with_file_names and self.session.conf.device_execution_enabled:
-            got, scan_batch, filter_node, kept = self._try_device_aggregate(plan)
-            if got is not None:
-                trace.record(
-                    "agg", "device-grouped-scan" if plan.keys else "device-fused-scan"
-                )
-                return got
-            if scan_batch is not None:
-                # the device gate already materialized the scan — reuse it
-                # instead of re-reading parquet on the host fallback
-                if filter_node is not None:
-                    with spans.span("filter-mask", cat="exec"):
-                        mask = self._filter_mask(filter_node, scan_batch, kept=kept)
-                    with spans.span("filter-apply", cat="exec"):
-                        child = B.mask_rows(scan_batch, mask)
-                else:
-                    child = scan_batch
+            # walks the scan's file sizes: asked at most once, and not at all
+            # by an aggregate whose columns are resident
+            stream_plan = functools.cache(lambda: self._streaming_plan(plan))
+            if self.session.conf.device_execution_enabled:
+                got, scan_batch, filter_node, kept = self._try_device_aggregate(plan, stream_plan)
+                if got is not None:
+                    trace.record(
+                        "agg", "device-grouped-scan" if plan.keys else "device-fused-scan"
+                    )
+                    return got
+                if scan_batch is not None:
+                    # the device gate already materialized the scan — reuse it
+                    # instead of re-reading parquet on the host fallback
+                    child = self._exec_chain_over(plan.child, scan_batch, filter_node, kept)
+            stream = stream_plan() if child is None else None
+            if stream is not None:
+                got = self._try_streaming_aggregate(plan, stream)
+                if got is not None:
+                    trace.record("agg", "streamed-partial")
+                    return got
 
         if child is None:
             child = self._exec(plan.child, with_file_names)
         with spans.span("agg-host", cat="exec"):
+            trace.agg_rows("host", B.num_rows(child))
             return host_aggregate(child, list(plan.keys), list(plan.aggs))
+
+    def _exec_chain_over(self, chain_root, scan_batch: B.Batch, filter_node, kept) -> B.Batch:
+        """The rows ``chain_root`` (an aggregate's child: Projects and at most
+        one Compute over an optional Filter over the scan) gives over the
+        scan's already-read ``scan_batch``."""
+        if filter_node is not None:
+            with spans.span("filter-mask", cat="exec"):
+                mask = self._filter_mask(filter_node, scan_batch, kept=kept)
+            with spans.span("filter-apply", cat="exec"):
+                scan_batch = B.mask_rows(scan_batch, mask)
+        below = filter_node if filter_node is not None else _chain_to_scan(chain_root)[1]
+        if chain_root is below:
+            return scan_batch
+        prev = getattr(self, "_leaf_override", None)
+        self._leaf_override = (below, scan_batch)
+        try:
+            return self._exec(chain_root, False)
+        finally:
+            self._leaf_override = prev
 
     # -- streamed Limit shapes (execute_stream) -------------------------------
 
@@ -1684,14 +1747,13 @@ class Executor:
         )
         return {c: np.asarray(v)[out_idx] for c, v in total.items()}
 
-    def _try_streaming_aggregate(self, plan: L.Aggregate) -> Optional[B.Batch]:
-        """Out-of-core aggregate: when the child is a scan chain over more
-        source bytes than conf ``exec.stream.aggMinBytes``, execute it in
-        file chunks and merge decomposable partial states — Spark's
-        partial/final aggregation split, which is what lets the reference
-        aggregate over tables far larger than executor memory. Returns None
-        (caller materializes) when the shape, size, or aggregate set doesn't
-        stream."""
+    def _streaming_plan(self, plan: L.Aggregate):
+        """``(chain, leaf, groups, needed)`` when ``plan`` can run as an
+        out-of-core aggregate: its child is a scan chain over more source
+        bytes than conf ``exec.stream.aggMinBytes`` and its aggregates have
+        decomposable partial states; None when the shape, size, or aggregate
+        set doesn't stream. Sizes come from the files' identities (an
+        index's from its log entry): nothing is opened to decide."""
         conf = self.session.conf
         min_bytes = conf.stream_agg_min_bytes
         if not min_bytes or min_bytes <= 0:
@@ -1710,7 +1772,15 @@ class Executor:
         groups = _chunk_files_by_bytes(leaf, files, max(1, conf.stream_chunk_bytes), keys)
         if len(groups) < 2:
             return None
-        needed = _chain_needed_columns(chain, plan.aggs, plan.keys)
+        return chain, leaf, groups, _chain_needed_columns(chain, plan.aggs, plan.keys)
+
+    def _try_streaming_aggregate(self, plan: L.Aggregate, stream) -> Optional[B.Batch]:
+        """Out-of-core aggregate over ``stream`` (``_streaming_plan``):
+        execute the scan chain in file chunks and merge decomposable partial
+        states — Spark's partial/final aggregation split, which is what lets
+        the reference aggregate over tables far larger than executor memory.
+        Returns None (caller materializes) when a chunk falls back."""
+        chain, leaf, groups, needed = stream
         with spans.span("agg-streamed-partial", cat="exec") as tier:
             try:
                 return self._streaming_aggregate(plan, chain, leaf, groups, needed)
@@ -1736,6 +1806,7 @@ class Executor:
 
         def fold_chunk(batch):
             with spans.span("agg-host-combine", cat="exec"):
+                trace.agg_rows("host", B.num_rows(batch))
                 host_fold(batch)
 
         def host_fold(batch):
@@ -1993,63 +2064,112 @@ class Executor:
                 out[name] = np.asarray([u.mean() if len(u) else np.nan])
         return {name: out[name] for name, _, _ in plan.aggs}
 
-    def _try_device_aggregate(self, plan: L.Aggregate):
-        """Returns (result, scan_batch, filter_node, kept): result=None
-        means the caller runs the host path — reusing scan_batch (the
-        materialized scan, pre-filter) when it was already read for the gate.
-        ``kept`` is the kept signature of that read (``_kept_groups``); the
-        caller must thread it into any further device-cache use of
-        scan_batch, or a pruned batch gets branded with an unpruned key."""
+    def _try_device_aggregate(self, plan: L.Aggregate, stream_plan):
+        """The device tier of an aggregate over a scan: ``Aggregate`` over
+        Projects, at most one ``Compute`` (computed aggregate inputs) and a
+        ``Filter`` below it, over an index or file scan. Returns (result,
+        scan_batch, filter_node, kept): result=None means the caller runs
+        another tier — reusing scan_batch (the materialized scan, pre-filter)
+        when this one read it. ``kept`` is the kept signature of that read
+        (``_kept_groups``); the caller must thread it into any further
+        device-cache use of scan_batch, or a pruned batch gets branded with
+        an unpruned key.
+
+        The scan's columns are looked up on the device first, by the scan's
+        identity (its files' identities, ``exec/file_identity.py``: a refresh
+        or optimize is another identity): when all are resident the program
+        runs over them and no file is opened. Otherwise the scan is read
+        whole, once an index version, and its columns uploaded to stay.
+        Decided before anything is read, from the files' footers: too few
+        rows for the device (``deviceMinRows``), or columns that outweigh the
+        device cache's budget (``deviceCacheBytes``; fallback ``over-cap``).
+        Then the caller streams, or folds on the host."""
         conf = self.session.conf
-        node = plan.child
-        filter_node = None
-        if isinstance(node, L.Filter):
-            filter_node = node
-            node = node.child
-        if not isinstance(node, (L.IndexScan, L.FileScan)):
-            return None, None, None, None
+        none = (None, None, None, None)
+        shape = _scan_aggregate_shape(plan)
+        if shape is None:
+            return none
+        node, filter_node, computes = shape
         if plan.keys and not conf.agg_device_grouped_enabled:
-            return None, None, None, None
+            return none
         from hyperspace_tpu.exec import device as D
 
-        pruned = _bucket_pruned(node, count=filter_node is not None)
-        batch = self._exec(node, with_file_names=False)
-        kept = _kept_groups(node)
-        if pruned:
-            return None, batch, filter_node, kept
-        if B.num_rows(batch) < conf.device_exec_min_rows:
-            trace.fallback("agg", "min-rows")
-            return None, batch, filter_node, kept
+        if _bucket_pruned(node, count=filter_node is not None):
+            return None, self._exec(node, with_file_names=False), filter_node, _kept_groups(node)
         condition = filter_node.condition if filter_node is not None else None
-        scan_key = _pruned_scan_key(scan_identity(node), kept)
+        computed = {name for name, _ in computes}
+        needed = sorted(
+            (set(condition.references()) if condition is not None else set())
+            | {r for _, e in computes for r in e.references()}
+            | {c for _, _, c in plan.aggs if c is not None and c not in computed}
+            | set(plan.keys)
+        )
+        cols = D.ScanColumns(
+            self.session, scan_identity(node), needed, lambda: self._exec(node, with_file_names=False)
+        )
+        min_rows = conf.device_exec_min_rows
+        rows = None  # of a scan that is not resident: known before it is read
+        if not cols.resident:
+            rows = _footer_rows(node, cols.scan_key)
+            if rows is None:  # no footer says
+                if stream_plan() is not None:
+                    return none  # a stream-sized scan is not read to learn its rows
+                rows = cols.rows
+            if rows < min_rows:
+                trace.fallback("agg", "min-rows")
+                return None, cols.loaded, filter_node, _kept_groups(node)
         name = "agg-device-grouped-scan" if plan.keys else "agg-device-fused-scan"
-        with spans.span(name, cat="exec") as tier:
+        with spans.span(name, cat="exec", resident="hit" if cols.resident else "miss") as tier:
+            got = None
             try:
-                if plan.keys:
-                    got = D.device_grouped_aggregate(
-                        self.session,
-                        batch,
-                        condition,
-                        list(plan.keys),
-                        list(plan.aggs),
-                        scan_key=scan_key,
-                        max_groups=conf.agg_max_groups,
-                        cap_floor=conf.agg_capacity_floor,
-                        parallel=_maybe_parallel(self.session, B.num_rows(batch)),
-                    )
-                else:
-                    got = D.device_filtered_aggregate(
-                        self.session, batch, condition, plan.aggs, scan_key=scan_key
-                    )
-                return got, batch, filter_node, kept
+                if rows is not None:
+                    D.check_fits_device_cache(rows, len(needed))
+                got = self._device_aggregate(plan, cols, condition, computes)
+                tier.set(rows=cols.rows)
+            except D.ResidentOverCap:
+                trace.fallback("agg", "over-cap")
+                tier.set(fallback="over-cap")
             except D.GroupCapacityExceeded:
                 trace.fallback("agg", "spill")
                 tier.set(fallback="spill")
-                return None, batch, filter_node, kept
             except D.DeviceUnsupported:
                 trace.fallback("agg", "unsupported")
                 tier.set(fallback="unsupported")
-                return None, batch, filter_node, kept
+            # not answered here: the caller reuses the scan if this tier read it
+            return got, cols.loaded, filter_node, _kept_groups(node)
+
+    def _device_aggregate(self, plan: L.Aggregate, cols, condition, computes) -> B.Batch:
+        """One program over the scan's device columns; for group keys no
+        codec states the domain of, the sort-based engine over the host
+        batch (which has no computed inputs)."""
+        from hyperspace_tpu.exec import device as D
+
+        conf = self.session.conf
+        parallel = None
+        if plan.keys and conf.parallel_enabled:
+            parallel = _maybe_parallel(self.session, cols.rows)
+        if parallel is None:
+            try:
+                return D.device_scan_aggregate(
+                    self.session, cols, condition, computes, list(plan.keys), list(plan.aggs),
+                    max_groups=conf.agg_max_groups,
+                )
+            except D.DeviceUnsupported:
+                if not plan.keys or computes:
+                    raise
+        elif computes:
+            raise D.DeviceUnsupported("computed inputs under a sharded grouped aggregate")
+        return D.device_grouped_aggregate(
+            self.session,
+            cols.batch(),
+            condition,
+            list(plan.keys),
+            list(plan.aggs),
+            scan_key=cols.scan_key,
+            max_groups=conf.agg_max_groups,
+            cap_floor=conf.agg_capacity_floor,
+            parallel=parallel,
+        )
 
     def _exec_join(self, plan: L.Join, with_file_names: bool) -> B.Batch:
         """Join tiers in order: bucketed SMJ (device or host spans), broadcast

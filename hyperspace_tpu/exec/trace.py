@@ -80,6 +80,25 @@ def fallback(op: str, reason: str) -> None:
     ).inc()
 
 
+_AGG_ROWS: dict = {}
+
+
+def agg_rows(path: str, n: int) -> None:
+    """Count ``n`` rows entering an aggregate's fold on ``path`` (``device``:
+    the rows a device program reads, its filter fused in; ``host``: the rows
+    handed to pandas) in ``hs_agg_rows_total{path}``."""
+    c = _AGG_ROWS.get(path)
+    if c is None:
+        from hyperspace_tpu.obs.metrics import REGISTRY
+
+        c = _AGG_ROWS[path] = REGISTRY.counter(
+            "hs_agg_rows_total",
+            "Rows entering an aggregate's fold, by where it was folded",
+            path=path,
+        )
+    c.inc(n)
+
+
 def active() -> bool:
     return _events is not None
 

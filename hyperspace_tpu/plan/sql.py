@@ -852,6 +852,14 @@ def _parse_factor(p: _Parser) -> Expr:
         elif num[0] != "number":
             raise SqlError("INTERVAL expects a number")
         unit = p.next()[1].lower()
+        # the SQL-standard leading-field precision after the unit (TPC-H Q1:
+        # interval '90' day (3)): how many digits the field may hold, which
+        # changes no value
+        if p.accept_op("("):
+            digits = p.next()
+            if digits[0] != "number" or not digits[1].isdigit():
+                raise SqlError("INTERVAL leading-field precision expects a whole number")
+            p.expect_op(")")
         if unit in ("day", "days"):
             return Lit(np.timedelta64(int(num[1]), "D"))
         if unit in ("month", "months", "mon"):

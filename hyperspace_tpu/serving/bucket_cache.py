@@ -63,10 +63,19 @@ class BucketCache:
     # -- async prefetch ------------------------------------------------------
     def prefetch(self, files: List[str], columns: Optional[List[str]], committed=None) -> bool:
         """Schedule a background decode if the group is neither cached nor
-        already being fetched. Returns True when a fetch was issued."""
+        already being fetched, and could be kept. Returns True when a fetch
+        was issued."""
         k = _key(files, columns)
         if k in self._lru.keys():  # containment probe — keep hit/miss stats honest
             return False
+        if committed is not None:
+            # a group whose files alone outweigh the whole cache can never be
+            # kept: decoding it ahead of the request would be thrown away
+            # (sizes from the log entry, no syscall). Whoever needs such a
+            # scan reads it once and keeps it elsewhere (the device).
+            known = [committed[f][1] for f in files if f in committed]
+            if sum(known) > self._lru.cap:
+                return False
         with self._inflight_lock:
             if k in self._inflight:
                 return False

@@ -45,6 +45,11 @@ class Session:
             rowgroup=self.conf.io_native_rowgroup,
             max_dict_entries=self.conf.io_native_max_dict_entries,
         )
+        # the device column cache is the process's too: its budget follows
+        # the most recently constructed session
+        from hyperspace_tpu.exec import device as _device
+
+        _device.set_device_cache_bytes(self.conf.device_cache_bytes)
         # check-layer runtime switches are process-global for the same
         # reason (compile sites without a session in scope consult them).
         # HLO verification: most recent session's conf wins, like decode

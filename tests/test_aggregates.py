@@ -368,7 +368,8 @@ class TestAggregates:
         df = session.read_parquet(data)
         batch = {"dept": np.arange(10, dtype=np.int64)}
         with pytest.raises(D.DeviceUnsupported):
-            D.device_filtered_aggregate(session, batch, None, [("n", "count", None)])
+            cols = D.ScanColumns(session, None, [], lambda: batch)
+            D.device_scan_aggregate(session, cols, None, (), [], [("n", "count", None)])
         # end to end: correct count either way
         session.conf.set(hst.keys.TPU_QUERY_DEVICE_MIN_ROWS, 0)
         n_dev = df.agg(n=("*", "count")).collect()["n"][0]
