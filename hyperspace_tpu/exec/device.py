@@ -2157,11 +2157,24 @@ def device_grouped_aggregate(
 #
 # A group key that is a dictionary-coded string has a domain the codec
 # states: the dictionary's size. When the product of the keys' domains is
-# small, a row's group is the mixed-radix number of its codes, and every
-# state slot reduces into ``groups`` masked sums/mins/maxes in one pass over
-# the resident columns — no sort, no gather, one program a query shape. The
-# sort-based engine above stays for every other key (numbers, dates, wide
-# dictionaries), whose domain nothing states.
+# small, a row's group is the mixed-radix number of its codes, and the state
+# slots reduce into ``groups`` masked sums/mins/maxes — no sort, no gather,
+# one program a query shape. The sort-based engine above stays for every
+# other key (numbers, dates, wide dictionaries), whose domain nothing states.
+#
+# The program is ONE reduction over the rows: every slot's operand (the
+# selected values, the non-NULL flags, the row index) goes into one variadic
+# ``lax.reduce`` whose combiner adds, or takes the min or max, slot by slot,
+# so a further slot costs accumulators, not a pass over the columns. What it
+# carries through that pass is as narrow as the answer allows: float sums
+# are float64 and integer sums int64 (the answer's own arithmetic; on the
+# TPU a pair of f32 and a pair of u32, so each is several operations a row
+# and group), but the bookkeeping (the row index, ``n_valid``, the ``cnt``
+# and ``cntm`` counts, the first-row min) is int32 wherever the padded row
+# count is below 2^31: none of them can pass the row count, and an emulated
+# 64-bit add or compare costs a pass of its own worth of arithmetic. At 2^31
+# rows and above they are int64 as the result table is; the shape decides,
+# there is no key. The group table comes back int64 either way.
 # --------------------------------------------------------------------------
 
 #: most groups the direct-addressed program reduces into; the work of a pass
@@ -2220,9 +2233,11 @@ def _dense_grouped_aggregate(
 
     def program(cols, lits, n_valid):
         total = next(iter(cols.values())).shape[0]
-        rows = jnp.arange(total, dtype=jnp.int64)
+        # bookkeeping counts rows, so the row count bounds it (see above)
+        idx = jnp.int32 if total < 2**31 else jnp.int64
+        rows = jnp.arange(total, dtype=idx)
         with jax.named_scope("filter"):
-            mask = rows < n_valid
+            mask = rows < n_valid.astype(idx)
             if pred_fn is not None:
                 mask = pred_fn(cols, lits) & mask
         if comp_fn is not None:
@@ -2233,31 +2248,50 @@ def _dense_grouped_aggregate(
                 gid = gid * size + (cols[name].astype(jnp.int32) + off)
             # (groups, rows): a row reduction over the minor axis per group
             member = (jnp.arange(groups, dtype=jnp.int32)[:, None] == gid[None, :]) & mask[None, :]
+
+        def operand(kind, col, isint):
+            """``(values[groups, rows], identity, combiner)`` of one state."""
+            if col is None:
+                return member.astype(idx), 0, jnp.add
+            x = cols[col]
+            nn = member if isint else (member & ~jnp.isnan(x)[None, :])
+            if kind == "cnt":
+                return nn.astype(idx), 0, jnp.add
+            # integer sums stay int64 (exact), every float state is float64
+            z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
+            if kind in ("sum", "sumsq"):
+                fill, fold = 0, jnp.add
+                z = z * z if kind == "sumsq" else z
+            elif kind == "min":
+                fill, fold = (jnp.iinfo(jnp.int64).max if isint else jnp.inf), jnp.minimum
+            else:  # max
+                fill, fold = (jnp.iinfo(jnp.int64).min if isint else -jnp.inf), jnp.maximum
+            return jnp.where(nn, z[None, :], z.dtype.type(fill)), fill, fold
+
         with jax.named_scope("group-reduce"):
-            fs = jnp.where(member, rows[None, :], _FS_SENTINEL).min(axis=1)
-            out = []
+            last = jnp.iinfo(idx).max
+            operands = {"fs": (jnp.where(member, rows[None, :], last), last, jnp.minimum)}
+            at = []
             for kind, col, isint in slots:
-                if kind == "cntm":
-                    out.append(member.sum(axis=1, dtype=jnp.int64))
-                    continue
-                x = cols[col]
-                nn = member if isint else (member & ~jnp.isnan(x)[None, :])
-                if kind == "cnt":
-                    out.append(nn.sum(axis=1, dtype=jnp.int64))
-                elif kind in ("sum", "sumsq"):
-                    z = x.astype(jnp.int64) if (isint and kind == "sum") else x.astype(jnp.float64)
-                    if kind == "sumsq":
-                        z = z * z
-                    out.append(jnp.where(nn, z[None, :], z.dtype.type(0)).sum(axis=1))
-                elif kind == "min":
-                    z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
-                    big = jnp.iinfo(jnp.int64).max if isint else jnp.inf
-                    out.append(jnp.where(nn, z[None, :], big).min(axis=1))
-                else:  # max
-                    z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
-                    low = jnp.iinfo(jnp.int64).min if isint else -jnp.inf
-                    out.append(jnp.where(nn, z[None, :], low).max(axis=1))
-        return fs, tuple(out)
+                if kind == "cntm" or (kind == "cnt" and isint):
+                    # every member row of an int column counts: one operand
+                    kind, col = "cnt", None
+                key = (kind, col, isint)
+                if key not in operands:
+                    operands[key] = operand(kind, col, isint)
+                at.append(key)
+            values, fills, folds = zip(*operands.values())
+            reduced = jax.lax.reduce(
+                values,
+                tuple(v.dtype.type(f) for v, f in zip(values, fills)),
+                lambda acc, x: tuple(fold(a, b) for fold, a, b in zip(folds, acc, x)),
+                (1,),
+            )
+            table = {
+                key: r.astype(jnp.int64) if jnp.issubdtype(r.dtype, jnp.integer) else r
+                for key, r in zip(operands, reduced)
+            }
+        return table["fs"], tuple(table[key] for key in at)
 
     skeleton = (
         f"gdense[{groups}]:{skeleton}|k:{','.join(f'{k}:{size}:{off}' for k, size, off in plan)}"
