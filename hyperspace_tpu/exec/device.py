@@ -621,13 +621,12 @@ def _note_compile(skeleton: str, sig) -> bool:
 
 
 def _observe_program(family: str, first_seen: bool, t0: float) -> None:
-    """Note one invocation of a device program family at its program-cache
-    call site: a ``device-program`` event on the active span, and on a
-    first-seen signature the wall seconds since ``t0`` — which XLA
+    """On a first-seen signature, the wall seconds since ``t0`` — which XLA
     compilation dominates — in ``hs_device_compile_seconds_total``. How long
-    the program RAN is the profiler's to say (module ``jit_hs_<family>``);
-    how long the host waited for it is the ``device-wait`` span of
-    :func:`fetch`."""
+    the host spent in the dispatch call is the ``device-launch`` span of
+    :func:`launch`; how long the program RAN is the profiler's to say (module
+    ``jit_hs_<family>``); how long the host waited for it is the
+    ``device-wait`` span of :func:`fetch`."""
     if first_seen:
         from hyperspace_tpu.obs.metrics import REGISTRY
 
@@ -637,18 +636,37 @@ def _observe_program(family: str, first_seen: bool, t0: float) -> None:
             "program invocations, by program family",
             program=family,
         ).inc(max(0.0, _ptime.perf_counter() - t0))
-    sp = _obs_spans.current_span()
-    if sp is not None:
-        sp.event("device-program", family + (" (compile)" if first_seen else ""))
 
 
-def count_dispatch(program: str) -> None:
-    """Count one jitted device-program dispatch, at every jitted call site."""
-    _REGISTRY.counter(
-        "hs_device_dispatches_total",
-        "Jitted device-program dispatches, by program family",
-        program=program,
-    ).inc()
+# family -> (its hs_device_dispatches_total series, its executable's name on
+# the profiler's device plane), held here so that a launch costs a dict read
+# and one add, not a registry lookup
+_LAUNCHES: dict = {}
+
+
+def launch(program: str):
+    """One dispatch of a jitted device program, round the jitted call:
+    ``with launch(family): out = jitted(...)``. Counts it in
+    ``hs_device_dispatches_total{program}``. Where a trace is current it is a
+    ``device-launch`` span (cat ``device``, attr ``program``) on the tree of
+    the request that dispatched — the host time of the dispatch call itself —
+    and its profiler annotation names the executable as the device plane
+    spells it and the request, ``hs:device:device-launch
+    module=jit_hs_<family> request=<id>``: with :func:`fetch`'s annotation a
+    reader of the trace gives every device program its request. With no trace
+    current: the count, one contextvar read, the shared no-op manager."""
+    entry = _LAUNCHES.get(program)
+    if entry is None:
+        entry = _LAUNCHES[program] = (
+            _REGISTRY.counter(
+                "hs_device_dispatches_total",
+                "Jitted device-program dispatches, by program family",
+                program=program,
+            ),
+            "jit_" + _hlo_lint.program_name(program),
+        )
+    entry[0].inc()
+    return _obs_spans.request_span("device-launch", "device", entry[1], program=program)
 
 
 def device_peak_bytes() -> Optional[int]:
@@ -712,13 +730,23 @@ def put(arr, site: str, sharding=None):
     return dev
 
 
+def wait(dev, program: str):
+    """Block on a device result that stays on the device: :func:`fetch`'s
+    ``device-wait`` span without the download."""
+    import jax
+
+    with _obs_spans.request_span("device-wait", cat="device", program=program):
+        jax.block_until_ready(dev)
+
+
 def fetch(dev, site: str, program: str):
     """Block on a device result (an array, or a pytree of them) and download
     it: the wait is a ``device-wait`` span (attr ``program``: the family that
-    produced ``dev``), the bytes count under ``site``."""
+    produced ``dev``; its annotation names the request like :func:`launch`'s),
+    the bytes count under ``site``."""
     import jax
 
-    with _obs_spans.span("device-wait", cat="device", program=program):
+    with _obs_spans.request_span("device-wait", cat="device", program=program):
         out = jax.device_get(dev)
     link_bytes("d2h", site, sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(out)))
     return out
@@ -1009,8 +1037,8 @@ def _put_encoded(session, mesh, sharding, n_dev, arr, site: str = "filter-cols")
         first = _note_compile(key, (padded.shape, remap.shape))
         _hlo_lint.maybe_verify(session.conf, "dict-expand", key, jitted, (dev_codes, dev_remap))
         t0 = _ptime.perf_counter()
-        dev = jitted(dev_codes, dev_remap)
-        count_dispatch("dict-expand")
+        with launch("dict-expand"):
+            dev = jitted(dev_codes, dev_remap)
         _observe_program("dict-expand", first, t0)
         codec = ColumnCodec("string", uniques=su, dtype=arr.dtype, nulls=bool((codes < 0).any()))
         return dev, codec, int(padded.nbytes + remap.nbytes)
@@ -1084,8 +1112,8 @@ def device_filter_mask(session, batch: B.Batch, condition: Expr, scan_key=None, 
     first = _note_compile(key, tuple(dev_cols[r].shape for r in sorted(dev_cols)))
     _hlo_lint.maybe_verify(session.conf, "fused-filter", key, jitted, (dev_cols, lit_values))
     t0 = _ptime.perf_counter()
-    mask = jitted(dev_cols, lit_values)
-    count_dispatch("fused-filter")
+    with launch("fused-filter"):
+        mask = jitted(dev_cols, lit_values)
     out = fetch(mask, "filter-mask", "fused-filter")[:n]
     _observe_program("fused-filter", first, t0)
     return out
@@ -1385,8 +1413,8 @@ def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skel
     first = _note_compile(key, tuple(dev_cols[r].shape for r in sorted(dev_cols)))
     _hlo_lint.maybe_verify(session.conf, "fused-agg", key, jitted, (dev_cols, lit_values, np.int64(n)))
     t0 = _ptime.perf_counter()
-    outs, valids = jitted(dev_cols, lit_values, np.int64(n))
-    count_dispatch("fused-agg")
+    with launch("fused-agg"):
+        outs, valids = jitted(dev_cols, lit_values, np.int64(n))
     outs, valids = fetch((outs, valids), "agg-table", "fused-agg")
     valids = [int(v) for v in valids]
     _observe_program("fused-agg", first, t0)
@@ -1907,14 +1935,14 @@ class GroupedAggStream:
             t0 = _ptime.perf_counter()
             if sharded:
                 n_g_dev, fs, key_out, slot_out = self._parallel.timed_call(
-                    "grouped-agg", jitted,
+                    "grouped-agg", family, jitted,
                     dev_cols, lit_values, np.int64(n), np.int64(self._row_base),
                 )
             else:
-                n_g_dev, fs, key_out, slot_out = jitted(
-                    dev_cols, lit_values, np.int64(n), np.int64(self._row_base)
-                )
-            count_dispatch(family)
+                with launch(family):
+                    n_g_dev, fs, key_out, slot_out = jitted(
+                        dev_cols, lit_values, np.int64(n), np.int64(self._row_base)
+                    )
             n_g = int(fetch(n_g_dev, "agg-table", family))
             _observe_program(family, first, t0)
             if n_g > self.max_groups:
@@ -1995,12 +2023,12 @@ class GroupedAggStream:
         )
         t0 = _time.perf_counter()
         with obs_spans.span("agg-merge", cat="groupagg", groups_in=a["n"] + b["n"]):
-            n_g_dev, fs, key_out, slot_out = jitted(
-                tuple(a["keys"]), tuple(b["keys"]),
-                tuple(a["slots"]), tuple(b["slots"]),
-                a["fs"], b["fs"], np.int64(a["n"]), np.int64(b["n"]),
-            )
-            count_dispatch("grouped-merge")
+            with launch("grouped-merge"):
+                n_g_dev, fs, key_out, slot_out = jitted(
+                    tuple(a["keys"]), tuple(b["keys"]),
+                    tuple(a["slots"]), tuple(b["slots"]),
+                    a["fs"], b["fs"], np.int64(a["n"]), np.int64(b["n"]),
+                )
             n_g = int(fetch(n_g_dev, "agg-table", "grouped-merge"))
         _observe_program("grouped-merge", first, t0)
         REGISTRY.counter(
@@ -2304,8 +2332,8 @@ def _dense_grouped_aggregate(
         session.conf, "grouped-agg-dense", key, jitted, (dev_cols, lit_values, np.int64(n))
     )
     t0 = _ptime.perf_counter()
-    out = jitted(dev_cols, lit_values, np.int64(n))
-    count_dispatch("grouped-agg-dense")
+    with launch("grouped-agg-dense"):
+        out = jitted(dev_cols, lit_values, np.int64(n))
     fs, slot_out = fetch(out, "agg-table", "grouped-agg-dense")
     _observe_program("grouped-agg-dense", first, t0)
     trace.agg_rows("device", n)
@@ -3540,8 +3568,8 @@ def device_bucketed_join(session, plan: L.Join, _compat=None, _setup=None) -> B.
         spans, (lmat_dev, rmat_dev),
     )
     t0 = _ptime.perf_counter()
-    lo, hi = spans(lmat_dev, rmat_dev)
-    count_dispatch("bucketed-smj-span")
+    with launch("bucketed-smj-span"):
+        lo, hi = spans(lmat_dev, rmat_dev)
     _observe_program("bucketed-smj-span", first, t0)
 
     if plan.how == "inner" and session.conf.join_device_materialize:
@@ -3709,7 +3737,8 @@ def _bucket_pair_totals_fn():
 
 
 def _bucket_pair_totals(lo, hi, ll, rl):
-    return _bucket_pair_totals_fn()(lo, hi, ll, rl)
+    with launch("join-pair-totals"):
+        return _bucket_pair_totals_fn()(lo, hi, ll, rl)
 
 
 def _device_materialize_inner(
@@ -3822,15 +3851,16 @@ def _device_materialize_inner(
             )
 
     run = _expand_gather_program(n_pad)
-    louts, routs, b_idx, i_idx, j_idx, valid = run(
-        lo_dev,
-        hi_dev,
-        llens_dev,
-        rlens_dev,
-        lmats_dev,
-        rmats_dev,
-        np.int64(total),
-    )
+    with launch("join-expand-gather"):
+        louts, routs, b_idx, i_idx, j_idx, valid = run(
+            lo_dev,
+            hi_dev,
+            llens_dev,
+            rlens_dev,
+            lmats_dev,
+            rmats_dev,
+            np.int64(total),
+        )
 
     louts, routs = fetch((louts, routs), "join-out", "join-expand-gather")
     for name, arr in zip(l_device + r_device, louts + routs):

@@ -49,9 +49,9 @@ from hyperspace_tpu.exec.device import (
     _program_key,
     bucket_rows,
     compile_predicate,
-    count_dispatch,
     encode_column,
     fetch,
+    launch,
     link_bytes,
     predicate_skeleton,
     stream_bucketed_join,  # noqa: F401  (re-exported: the streaming join surface)
@@ -263,8 +263,8 @@ def build_hash_side(session, build_plan: L.LogicalPlan, build_cols: List[str],
     planes = tuple(_pad_plane(hash_input_uint32(batch[k]), np.uint32(0)) for k in bkeys)
     prog = _hash_build_program(len(bkeys))
     link_bytes("h2d", "join-mats", sum(int(p.nbytes) for p in planes))
-    table, order = prog(planes, np.int64(n))
-    count_dispatch("hash-build")
+    with launch("hash-build"):
+        table, order = prog(planes, np.int64(n))
     sig = (len(bkeys), planes[0].shape[0])
     _note_compile("hash-build", sig)
     _hlo_lint.maybe_verify(
@@ -330,8 +330,8 @@ def _probe_chunk(session, build: BuildSide, chunk: B.Batch,
     padded = tuple(_pad_plane(p, np.uint32(0)) for p in planes)
     prog = _hash_probe_program(len(planes))
     link_bytes("h2d", "join-mats", sum(int(p.nbytes) for p in padded))
-    lo_d, hi_d = prog(build.table, np.int64(build.n), padded)
-    count_dispatch("hash-probe")
+    with launch("hash-probe"):
+        lo_d, hi_d = prog(build.table, np.int64(build.n), padded)
     sig = (len(planes), int(build.table.shape[0]), padded[0].shape[0])
     _note_compile("hash-probe", sig)
     _hlo_lint.maybe_verify(
@@ -503,8 +503,8 @@ def _device_postjoin_mask(session, condition, pbatch: B.Batch, build: BuildSide,
         "h2d", "join-mats",
         sum(int(a.nbytes) for a in (*pcols.values(), *bcols.values(), pidx_pad, bidx_pad)),
     )
-    mask = jitted(*args)
-    count_dispatch("fused-postjoin")
+    with launch("fused-postjoin"):
+        mask = jitted(*args)
     return fetch(mask, "join-out", "fused-postjoin")[:n]
 
 

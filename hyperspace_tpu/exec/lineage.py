@@ -48,6 +48,7 @@ from hyperspace_tpu.exec.device import (
     encode_column,
     ensure_x64,
     fetch,
+    launch,
     put,
     resident_column,
 )
@@ -140,5 +141,6 @@ def lineage_delete_mask(
     _hlo_lint.maybe_verify(
         session.conf, "lineage-antijoin", key, jitted, (dev_col, dev_ids, n_ids)
     )
-    mask = jitted(dev_col, dev_ids, n_ids)
+    with launch("lineage-antijoin"):
+        mask = jitted(dev_col, dev_ids, n_ids)
     return fetch(mask, "filter-mask", "lineage-antijoin")[:n]

@@ -229,9 +229,8 @@ class TopKStream:
         jitted = D._cached_predicate_jit(key, fn, family)
         D._note_compile(key, (mat.shape,))
         _hlo_lint.maybe_verify(self.session.conf, family, key, jitted, (dev,))
-        out = jitted(dev)
-        D.count_dispatch(family)
-        return out
+        with D.launch(family):
+            return jitted(dev)
 
     def _merge(self, cand, add_pool: B.Batch, add_rid: np.ndarray):
         """Merge the chunk's candidate matrix into the running buffer.
@@ -260,8 +259,8 @@ class TopKStream:
         mjit = D._cached_predicate_jit(mkey, S.topk_merge_fn(nk, self.cap), "topk-merge")
         D._note_compile(mkey, ((nk + 1, self.cap),))
         _hlo_lint.maybe_verify(self.session.conf, "topk-merge", mkey, mjit, (a, b))
-        merged = mjit(a, b)
-        D.count_dispatch("topk-merge")
+        with D.launch("topk-merge"):
+            merged = mjit(a, b)
         self._state = merged
         _merges_total().inc()
         mrid = D.fetch(merged[-1], "topk", "topk-merge")

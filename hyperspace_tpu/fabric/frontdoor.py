@@ -339,6 +339,7 @@ class FrontDoor:
                 "frontdoor-request",
                 cat="fabric",
                 max_spans=self._trace_max_spans,
+                trace_id=ctx.trace_id,
                 query=sql,
                 tenant=tenant,
             )

@@ -537,7 +537,8 @@ def write_bucketed(
             np2 = padded_size(cn)
             dev_keys = [D.put(np.pad(k, (0, np2 - cn)), "build-keys") for k in keys]
             dev_hashes = [D.put(np.pad(h, (0, np2 - cn)), "build-keys") for h in host_hashes]
-            perm, counts = bucket_sort_build(dev_keys, dev_hashes, kinds, num_buckets, cn)
+            with D.launch("index-build"):
+                perm, counts = bucket_sort_build(dev_keys, dev_hashes, kinds, num_buckets, cn)
             counts.copy_to_host_async()
             # the permutation comes back in pieces so bucket writes can start
             # while later pieces are still in flight (device->host is the
@@ -625,9 +626,10 @@ def write_bucketed(
             capacity = min(
                 _next_pow2(int(per_dev / n_dev * capacity_factor)), _next_pow2(per_dev)
             )
-            bkts, ridx, vld, ovf = distributed_bucket_sort_build(
-                mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
-            )
+            with D.launch("index-build-exchange"):
+                bkts, ridx, vld, ovf = distributed_bucket_sort_build(
+                    mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
+                )
             for a in (ovf, bkts, ridx, vld):
                 a.copy_to_host_async()
         return {
@@ -669,9 +671,10 @@ def write_bucketed(
                 capacity = min(_next_pow2(capacity * 2), _next_pow2(per_dev))
                 _EXCHANGE_RETRIES.inc()
                 dev_keys, dev_hashes, kinds, row_idx, cn = state["retry"]
-                bkts, ridx, vld, ovf = distributed_bucket_sort_build(
-                    mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
-                )
+                with D.launch("index-build-exchange"):
+                    bkts, ridx, vld, ovf = distributed_bucket_sort_build(
+                        mesh, dev_keys, dev_hashes, kinds, row_idx, cn, num_buckets, capacity
+                    )
             with spans.stage("d2h-perm", "build"):
                 bkts_np = np.asarray(bkts)
                 ridx_np = np.asarray(ridx)

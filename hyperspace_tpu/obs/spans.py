@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import os
 import sys
@@ -48,6 +49,7 @@ __all__ = [
     "Trace",
     "TraceContext",
     "span",
+    "request_span",
     "trace",
     "start_trace",
     "current_span",
@@ -144,20 +146,31 @@ def bind_context(ctx: Optional[TraceContext]):
         _context.reset(token)
 
 
+_TRACE_SERIAL = itertools.count(1)
+
+
 class Trace:
-    """Shared per-tree state: the span budget that bounds trace memory.
+    """Shared per-tree state: the span budget that bounds trace memory, and
+    the request's identifier.
+
+    ``id`` names the request in every place its spans are written: the
+    router's ``TraceContext.trace_id`` where the request came with one, else
+    a short serial of this process (``r2a``). It is the request's, not the
+    thread's: a helper thread that joins the tree through :func:`wrap` or
+    :func:`attach` writes the same one.
 
     ``count``/``dropped`` updates ride the GIL (int attribute bumps from
     worker threads may lose a tick under contention; the budget is a memory
     guard, not an invariant, and a lock here would tax every span).
     """
 
-    __slots__ = ("max_spans", "count", "dropped")
+    __slots__ = ("max_spans", "count", "dropped", "id")
 
-    def __init__(self, max_spans: int):
+    def __init__(self, max_spans: int, trace_id: Optional[str] = None):
         self.max_spans = int(max_spans)
         self.count = 1  # the root
         self.dropped = 0
+        self.id = trace_id or f"r{next(_TRACE_SERIAL):x}"
 
 
 class Span:
@@ -207,6 +220,11 @@ class Span:
     def find(self, name: str) -> List["Span"]:
         return [s for s in self.walk() if s.name == name]
 
+    @property
+    def trace_id(self) -> Optional[str]:
+        """The identifier every span of this request's tree shares."""
+        return self.trace.id if self.trace is not None else None
+
     def __repr__(self) -> str:
         return f"Span({self.name!r}, {self.duration_s * 1e3:.3f} ms, children={len(self.children)})"
 
@@ -237,15 +255,17 @@ NULL_SPAN = _NullSpan()
 _NULL_CM = _NullCM()
 
 
-def _annotation(name: str, cat: str):
+def _annotation(name: str, cat: str, suffix: str = ""):
     """The span as an event of the profiler's host plane, on the profiler's
     own clock: an entered ``jax.profiler.TraceAnnotation("hs:<cat>:<name>")``,
     or None in a process that never imported jax (no profiler session can be
-    running there, and this package must not be the one to import it)."""
+    running there, and this package must not be the one to import it).
+    ``suffix`` (:func:`request_span`: `` module=<m> request=<id>``) follows
+    the name; ``hs:<cat>:<name>`` stays the prefix."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    ann = jax.profiler.TraceAnnotation(f"hs:{cat}:{name}")
+    ann = jax.profiler.TraceAnnotation(f"hs:{cat}:{name}{suffix}")
     ann.__enter__()
     return ann
 
@@ -258,13 +278,14 @@ class _SpanCM:
     the profiler annotation that puts the span on the device trace's clock.
     """
 
-    __slots__ = ("_parent", "_name", "_cat", "_attrs", "_span", "_token", "_ann")
+    __slots__ = ("_parent", "_name", "_cat", "_attrs", "_suffix", "_span", "_token", "_ann")
 
-    def __init__(self, parent: Span, name: str, cat: str, attrs: Optional[dict]):
+    def __init__(self, parent: Span, name: str, cat: str, attrs: Optional[dict], suffix: str = ""):
         self._parent = parent
         self._name = name
         self._cat = cat
         self._attrs = attrs
+        self._suffix = suffix
         self._span: Any = None
         self._token = None
         self._ann = None
@@ -286,7 +307,7 @@ class _SpanCM:
         self._parent.children.append(sp)  # list.append: atomic under the GIL
         self._span = sp
         self._token = _current.set(sp)
-        self._ann = _annotation(self._name, self._cat)
+        self._ann = _annotation(self._name, self._cat, self._suffix)
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -315,6 +336,23 @@ def span(name: str, cat: str = "", **attrs):
     if parent is None:
         return _NULL_CM
     return _SpanCM(parent, name, cat, attrs or None)
+
+
+def request_span(name: str, cat: str = "", module: Optional[str] = None, **attrs):
+    """:func:`span` for the two ends of a device program, the launch and the
+    wait: its profiler annotation also names the request (``Trace.id``) and,
+    for a launch, the executable as the device plane spells it, so that a
+    reader of the profiler's trace can give a device program to the request
+    that launched it and lay a wait against it:
+    ``hs:<cat>:<name> module=<module> request=<id>``. The same no-op as
+    :func:`span` when no trace is active."""
+    parent = _current.get()
+    if parent is None:
+        return _NULL_CM
+    suffix = f" request={parent.trace_id}"
+    if module is not None:
+        suffix = f" module={module}{suffix}"
+    return _SpanCM(parent, name, cat, attrs or None, suffix)
 
 
 # (cat, stage) -> the hs_stage_seconds_total series, held here so that a
@@ -393,12 +431,23 @@ def stage(name: str, cat: str):
 _DEFAULT_MAX_SPANS = 100_000
 
 
-def start_trace(name: str, cat: str = "query", max_spans: Optional[int] = None, **attrs) -> Span:
+def start_trace(
+    name: str,
+    cat: str = "query",
+    max_spans: Optional[int] = None,
+    trace_id: Optional[str] = None,
+    **attrs,
+) -> Span:
     """Create a detached root span (NOT made current) — for request objects
     whose lifecycle crosses threads (``QueryServer``): the submitting thread
     creates the root, each worker :func:`attach`-es it around its stage.
-    Call ``root.finish()`` when the request completes."""
-    root = Span(name, cat, trace=Trace(max_spans or _DEFAULT_MAX_SPANS))
+    Call ``root.finish()`` when the request completes. The tree's identifier
+    (``Trace.id``) is ``trace_id``, else the bound :class:`TraceContext`'s,
+    else a serial of this process."""
+    if trace_id is None:
+        ctx = _context.get()
+        trace_id = ctx.trace_id if ctx is not None else None
+    root = Span(name, cat, trace=Trace(max_spans or _DEFAULT_MAX_SPANS, trace_id))
     if attrs:
         root.attrs.update(attrs)
     return root

@@ -230,17 +230,6 @@ def bucket_sort_build(
     )
 
 
-def warm_build(n: int, kinds: Tuple[str, ...], key_dtypes: Sequence, num_buckets: int) -> None:
-    """Pre-compile the build program for a given padded size class so the
-    first real build at that size is a cache hit (first XLA compile of the
-    sort is tens of seconds)."""
-    ensure_x64()
-    keys = tuple(jnp.zeros(n, dtype=dt) for dt in key_dtypes)
-    hh = tuple(jnp.zeros(n, dtype=jnp.uint32) for k in kinds if k == "s")
-    perm, counts = bucket_sort_build(keys, hh, kinds, num_buckets, n)
-    jax.block_until_ready((perm, counts))
-
-
 def padded_size(n: int) -> int:
     """Power-of-two size class for ``n`` rows (min 8)."""
     return max(8, 1 << (max(n - 1, 1)).bit_length())

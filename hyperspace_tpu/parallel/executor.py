@@ -84,17 +84,18 @@ class ShardedExecutor:
             op=op,
         ).inc()
 
-    def timed_call(self, op: str, fn, *args):
-        """Run one sharded program synchronously, attributing its wall time
-        (including the collective merge) to ``hs_mesh_collective_seconds_total``."""
-        import jax
-
+    def timed_call(self, op: str, family: str, fn, *args):
+        """Run one sharded program of ``family`` synchronously, through the
+        dispatch layer's launch/wait pair, attributing its wall time (including
+        the collective merge) to ``hs_mesh_collective_seconds_total``."""
+        from hyperspace_tpu.exec import device as D
         from hyperspace_tpu.obs.metrics import REGISTRY
 
         self.note_op(op)
         t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
+        with D.launch(family):
+            out = fn(*args)
+        D.wait(out, family)
         REGISTRY.counter(
             "hs_mesh_collective_seconds_total",
             "Cumulative wall time of sharded programs incl. collective merges (seconds)",

@@ -415,6 +415,7 @@ class QueryServer:
             root = spans.start_trace(
                 "request",
                 max_spans=self._trace_max_spans,
+                trace_id=ctx.trace_id if ctx is not None else None,
                 server=self.server_name,
                 query=query_text,
             )

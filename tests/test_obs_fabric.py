@@ -483,14 +483,14 @@ class TestFleetIdentity:
         )
 
 
-# --- device-program hooks: compile seconds, the span event, device-wait --------
+# --- device-program hooks: compile seconds, the launch span, device-wait -------
 
 
 class TestDeviceProgramTiming:
     def test_observe_program_metrics_and_span_event(self):
         import time
 
-        from hyperspace_tpu.exec.device import _note_compile, _observe_program
+        from hyperspace_tpu.exec.device import _note_compile, _observe_program, launch
 
         family = f"test-family-{os.getpid()}"
         sig = ("unit", (7, 3))
@@ -500,20 +500,26 @@ class TestDeviceProgramTiming:
         root = spans.start_trace("request", cat="query")
         with spans.attach(root):
             t0 = time.perf_counter()
-            _observe_program(family, True, t0)
-            _observe_program(family, False, t0)
+            for first_seen in (True, False):
+                with launch(family):
+                    pass
+                _observe_program(family, first_seen, t0)
         root.finish()
 
         total = REGISTRY.counter("hs_device_compile_seconds_total", program=family)
         first = total.value
         assert first > 0.0  # the first-seen call's wall, compile-dominated
         with spans.attach(root):
+            with launch(family):
+                pass
             _observe_program(family, False, t0)
         assert total.value == first  # a cached signature adds nothing
-        events = [ev for sp in root.walk() for ev in (sp.events or [])]
-        assert [d for k, d in events if k == "device-program"] == [
-            f"{family} (compile)", family, family
-        ]  # the family alone: no host-clock milliseconds round an async dispatch
+        # the device-program event went (ISSUE 39): the device-launch span
+        # says the same, with the host time of the dispatch call
+        assert not [ev for sp in root.walk() for ev in (sp.events or [])]
+        launches = root.find("device-launch")
+        assert [sp.attrs["program"] for sp in launches] == [family] * 3
+        assert all(sp.cat == "device" and sp.t1 is not None for sp in launches)
         # the host-clock histogram this hook used to feed is gone
         assert "hs_device_program_seconds" not in REGISTRY.snapshot()
 

@@ -388,6 +388,162 @@ class TestServedPath:
         assert not host_root.find("device-wait")
 
 
+# --- a launch is a span with a cause, a wait names its request (ISSUE 39) -----------
+
+
+def dispatches():
+    series = REGISTRY.snapshot().get("hs_device_dispatches_total", {"series": []})["series"]
+    return {s["labels"]["program"]: s["value"] for s in series}
+
+
+def launches_of(root):
+    return [(sp.attrs["program"], sp.trace_id) for sp in root.find("device-launch")]
+
+
+class TestLaunchAndWait:
+    def test_with_no_trace_launch_counts_and_is_the_shared_null_manager(self, annotations):
+        from hyperspace_tpu.exec import device as D
+
+        assert spans.current_span() is None
+        before = dispatches().get("fused-filter", 0.0)
+        cm = D.launch("fused-filter")
+        assert cm is spans._NULL_CM
+        with cm as sp:
+            assert sp is spans.NULL_SPAN
+        assert dispatches()["fused-filter"] - before == 1.0
+        assert spans.request_span("device-wait", cat="device", program="fused-filter") is spans._NULL_CM
+        assert annotations == []  # off means off: nothing entered
+        # the series and the module's name are held by the module: no registry lookup a launch
+        assert D._LAUNCHES["fused-filter"][1] == "jit_" + hlo_lint.program_name("fused-filter")
+        assert D._LAUNCHES["fused-filter"][0] is REGISTRY.counter("hs_device_dispatches_total", program="fused-filter")
+
+    def test_the_annotations_name_the_module_and_the_request(self, annotations):
+        from hyperspace_tpu.exec import device as D
+
+        with spans.trace("t") as root:
+            with spans.span("filter-mask", cat="exec"):
+                with D.launch("join-expand-gather") as sp:
+                    assert sp.name == "device-launch" and sp.cat == "device"
+                    assert sp.attrs == {"program": "join-expand-gather"}
+                D.fetch(np.zeros(3), "filter-mask", "join-expand-gather")
+        rid = root.trace.id
+        assert rid and all(sp.trace_id == rid for sp in root.walk())
+        launch = f"hs:device:device-launch module=jit_hs_join_expand_gather request={rid}"
+        wait = f"hs:device:device-wait request={rid}"
+        assert annotations == [
+            ("enter", "hs:exec:filter-mask"), ("enter", launch), ("exit", launch),
+            ("enter", wait), ("exit", wait), ("exit", "hs:exec:filter-mask"),
+        ]
+        # the wait keeps name, category and attr, and has no child
+        waits = root.find("device-wait")
+        assert [(w.cat, w.attrs, w.children) for w in waits] == [("device", {"program": "join-expand-gather"}, [])]
+
+    def test_a_trace_takes_the_bound_context_s_identifier_else_a_serial(self):
+        ctx = spans.TraceContext.new()
+        with spans.bind_context(ctx):
+            with spans.trace("routed") as routed:
+                pass
+        assert routed.trace_id == ctx.trace_id
+        assert spans.start_trace("given", trace_id="abc").trace_id == "abc"
+        a, b = spans.start_trace("a"), spans.start_trace("b")
+        assert a.trace_id != b.trace_id and a.trace_id.startswith("r") and len(a.trace_id) <= 9
+
+    def test_a_helper_thread_launches_for_the_request_that_wrapped_it(self, annotations):
+        from hyperspace_tpu.exec import device as D
+
+        def helper():
+            with D.launch("hash-probe"):
+                pass
+
+        with spans.trace("t") as root:
+            t = threading.Thread(target=spans.wrap(helper))
+            t.start()
+            t.join()
+        (launch,) = root.find("device-launch")
+        assert launch.tid != root.tid and launch.trace_id == root.trace_id
+        assert ("enter", f"hs:device:device-launch module=jit_hs_hash_probe request={root.trace_id}") in annotations
+
+    @pytest.mark.parametrize("shape, programs, tier", [
+        ("filter", ["fused-filter"], "filter-mask"),
+        ("fused-aggregate", ["fused-agg"], "agg-device-fused-scan"),
+        ("grouped-aggregate", ["grouped-agg-chunk"], "agg-device-fold"),
+    ])
+    def test_a_dispatch_is_one_launch_span_and_one_count_on_its_request_s_tree(self, indexed, shape, programs, tier):
+        sess, df = indexed
+        q = {
+            "filter": lambda: df.filter(col("c1") > 20).select("c2"),
+            "fused-aggregate": lambda: df.filter(col("c1") > 20).agg(s=("c3", "sum")),
+            "grouped-aggregate": lambda: df.filter(col("c1") > 20).group_by("c2").agg(s=("c3", "sum")),
+        }[shape]()
+        before = dispatches()
+        with spans.trace("one") as root:
+            q.collect()
+        grew = growth(before, dispatches())
+        found = launches_of(root)
+        assert sorted({p for p, _ in found}) == programs
+        assert grew == dict(collections.Counter(p for p, _ in found))  # one count a span, no other
+        assert {rid for _, rid in found} == {root.trace_id}
+        for sp in root.find("device-launch"):
+            assert sp.cat == "device" and sp.t1 is not None and not sp.children and not sp.events
+        # the launch is a child of the tier span that holds the jitted call,
+        # beside the wait for it
+        holder = root.find(tier)[0]
+        kids = [c.name for c in holder.children]
+        assert "device-launch" in kids and kids.index("device-launch") < kids.index("device-wait")
+        assert not [ev for sp in root.walk() for ev in sp.events if ev[0] == "device-program"]
+
+    def test_a_join_s_programs_launch_on_the_joining_request_s_tree(self, tmp_path):
+        rng = np.random.default_rng(3)
+        left, right = tmp_path / "l", tmp_path / "r"
+        left.mkdir(), right.mkdir()
+        pq.write_table(pa.table({"a": rng.integers(0, 50, 400), "x": rng.standard_normal(400)}),
+                       left / "p.parquet")
+        pq.write_table(pa.table({"b": np.arange(50), "y": np.arange(50) % 5}), right / "p.parquet")
+        sess = hst.Session(conf={hst.keys.SYSTEM_PATH: str(tmp_path / "idx")})
+        hst.set_session(sess)
+        try:
+            l, r = sess.read_parquet(str(left)), sess.read_parquet(str(right))
+            before = dispatches()
+            with spans.trace("join") as root:
+                l.join(r, col("a") == col("b")).select("x", "y").collect()
+        finally:
+            hst.set_session(None)
+        found = launches_of(root)
+        assert {p for p, _ in found} == {"hash-build", "hash-probe"}
+        assert growth(before, dispatches()) == dict(collections.Counter(p for p, _ in found))
+        assert {rid for _, rid in found} == {root.trace_id}
+        tier = root.find("join-broadcast-hash-stream")[0]
+        assert {sp.attrs["program"] for sp in tier.find("device-launch")} == {"hash-build", "hash-probe"}
+
+    def test_two_concurrent_requests_carry_two_identifiers_and_their_own_launches(self, indexed, annotations):
+        from hyperspace_tpu.serving import QueryServer
+
+        sess, df = indexed
+        q = df.filter(col("c1") > 20).select("c2")
+        # two shapes, so that neither request rides on the other's scan
+        queries = {"fused_filter": q, "fused_agg": df.filter(col("c1") > 30).agg(s=("c3", "sum"))}
+        with QueryServer(sess, workers=2, name="inside-launch") as server:
+            futures = {m: server.submit(query) for m, query in queries.items()}
+            for f in futures.values():
+                f.result(30)
+        roots = {m: f.request_root for m, f in futures.items()}
+        assert len({r.trace_id for r in roots.values()}) == 2
+        for module, root in roots.items():
+            rid = root.trace_id
+            found = launches_of(root)
+            assert found and {i for _, i in found} == {rid}
+            assert {sp.trace_id for sp in root.walk()} == {rid}
+            assert ("enter", f"hs:device:device-launch module=jit_hs_{module} request={rid}") in annotations
+            assert ("enter", f"hs:device:device-wait request={rid}") in annotations
+        # a routed request keeps the router's identifier
+        ctx = spans.TraceContext.new()
+        with QueryServer(sess, workers=1, name="inside-launch-routed") as server:
+            f = server.submit(q, trace_context=ctx)
+            f.result(30)
+        assert f.request_root.trace_id == ctx.trace_id
+        assert {rid for _, rid in launches_of(f.request_root)} == {ctx.trace_id}
+
+
 # --- stable names for device programs ---------------------------------------------
 
 
