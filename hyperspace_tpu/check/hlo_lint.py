@@ -106,7 +106,76 @@ def hlo_text_of(jitted, *args, **kwargs) -> str:
 # Forbidden-op text rules (apply to every family unless opted out)
 # --------------------------------------------------------------------------
 
-#: (rule name, compiled regex, human description). These encode device-program
+_INSTRUCTION = re.compile(r"^\s*(ROOT )?(%[\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$")
+_ARRAY_F64 = re.compile(r"f64\[\d")
+_NAME = re.compile(r"%[\w.\-]+")
+
+
+class _F64Upcast:
+    """The ``f64-upcast`` rule: a whole-array ``f64[n] convert(f32[n])``,
+    with ``search`` as a compiled pattern has it.
+
+    One shape of it is not an upcast of data but the resident form of an
+    8-byte column read back (``exec/device.join_planes``): the column's two
+    f32 planes converted and added, ``add(convert(head), convert(tail))``.
+    That join is told apart by where it sits and by who reads it: inside a
+    fused computation (not the entry computation, not a computation's
+    ``ROOT``: nothing of it is written to memory), each convert read by one
+    instruction only, an ``add`` whose other operand is such a convert too
+    and which is not the computation's ``ROOT`` either (the joined column
+    written out is the 8-byte array back). Any other convert of an f32 array
+    to f64 is reported: one that stands in the entry computation or is a
+    fusion's result (materialised, twice the bytes), and one that feeds
+    anything but that add (arithmetic carried out in f64 over f32 data). An
+    operand's type is read from the operand where the text prints it and
+    from the operand's own definition where not."""
+
+    def search(self, hlo_text: str):
+        # a computation is the lines between a header that ends in "{" and
+        # the "}" that closes it; loose lines count as unfused
+        block, unfused = [], True
+        for line in hlo_text.splitlines() + ["}"]:
+            header = line.rstrip().endswith("{") and " = " not in line
+            if not header and not line.startswith("}"):
+                block.append(line)
+                continue
+            found = self._in_block(block, unfused)
+            if found:
+                return found
+            block, unfused = [], not header or line.startswith("ENTRY")
+        return None
+
+    @staticmethod
+    def _in_block(lines, unfused: bool):
+        """The first upcast among ``lines`` (one computation's instructions)
+        that is not the fused join, as a match of its text; None if none."""
+        defs = {}  # name -> (is ROOT, result type, opcode, operand text, operand names, match)
+        for line in lines:
+            m = _INSTRUCTION.match(line)
+            if m:
+                root, name, result, opcode, rest = m.groups()
+                operands = rest.split(")", 1)[0]
+                defs[name] = (bool(root), result, opcode, operands, _NAME.findall(operands), m)
+        upcasts = {}
+        for name, (root, result, opcode, operands, sources, m) in defs.items():
+            if opcode != "convert" or not _ARRAY_F64.match(result):
+                continue
+            source = defs.get(sources[0]) if sources else None
+            if operands.lstrip().startswith("f32[") or (source and source[1].startswith("f32[")):
+                upcasts[name] = (root, m)
+        for name, (root, m) in upcasts.items():
+            readers = [d for d in defs.values() if name in d[4]]
+            joined = (
+                not unfused and not root and len(readers) == 1 and readers[0][0] is False and readers[0][2] == "add"
+                and len(readers[0][4]) == 2 and all(o in upcasts for o in readers[0][4])
+            )
+            if not joined:
+                return re.search(r"f64\[.*", m.group(0).strip())
+        return None
+
+
+#: (rule name, pattern, human description); a pattern is whatever has a
+#: ``search(text)`` that returns a match or None. These encode device-program
 #: hygiene independent of the collective story: a device program must never
 #: round-trip through the host mid-flight (python callbacks, infeed/outfeed),
 #: must not silently double an array's HBM footprint by upcasting f32 data to
@@ -122,8 +191,9 @@ FORBIDDEN_PATTERNS: Tuple[Tuple[str, "re.Pattern", str], ...] = (
     ),
     (
         "f64-upcast",
-        re.compile(r"f64\[\d[^\]]*\]\S* convert\(f32\["),
-        "whole-array f32->f64 convert (doubles HBM footprint; stage f64 or compute in f32)",
+        _F64Upcast(),
+        "whole-array f32->f64 convert (doubles HBM footprint; stage f64 or compute in f32); the fused join of a "
+        "resident column's two f32 planes, add(convert(head), convert(tail)) inside a fusion, is not one",
     ),
     (
         "dynamic-shape",

@@ -79,11 +79,17 @@ class ColumnCodec:
     """How one host column was encoded for the device.
 
     kind:
-      - "numeric":  device array is the column itself (int64/float64/bool)
-      - "datetime": device array is the int64 epoch view; ``unit`` remembers
+      - "numeric":  the device holds the column's values as int64/float64
+                    (bool and narrower integers widen to int64)
+      - "datetime": the device holds the int64 epoch view; ``unit`` remembers
                     the datetime64 unit for literal conversion
       - "string":   device array is int32 codes into ``uniques`` (sorted);
                     code -1 encodes null
+
+    An 8-byte column is one device array where the devices compute 64-bit
+    values natively, and its two 32-bit planes (:class:`ColumnPlanes`) where
+    they compute them as pairs; a program sees the 64-bit values either way
+    (:func:`join_columns`).
 
     ``dtype`` is the host column's dtype before encoding, and ``nulls`` says
     whether a string column holds a null code; both None where the encoder
@@ -121,6 +127,84 @@ def encode_column(arr: np.ndarray) -> Tuple[np.ndarray, ColumnCodec]:
             "string", uniques=uniques, dtype=arr.dtype, nulls=bool((codes < 0).any())
         )
     raise DeviceUnsupported(f"unsupported column dtype {arr.dtype}")
+
+
+# --------------------------------------------------------------------------
+# the resident form of an 8-byte column
+#
+# A TPU has no 64-bit arithmetic: its compiler rewrites a float64 into a pair
+# of float32 (a head, and the tail the head's rounding left) and an int64 into
+# its low and high 32 bits, and a program handed an 8-byte array begins by
+# splitting it, two passes over HBM a column and a call. A resident column is
+# an index file's, the same in every call, so it is split once, by the chip
+# itself when it is uploaded (``split-planes``), and stays resident as the two
+# planes; each program joins them at its entry (:func:`join_columns`), which
+# the same rewrite reduces to a few 32-bit operations fused into whatever
+# reads the value. Where the devices compute 64-bit values natively (the CPU)
+# a column stays whole: an f32 pair holds 48 of a float64's 53 bits.
+# --------------------------------------------------------------------------
+
+
+class ColumnPlanes(NamedTuple):
+    """An int64 or float64 column as two one-dimensional 32-bit device arrays
+    of its (padded) row length, sharded as the column would be. float64:
+    ``first`` is the float32 head, ``second`` the float32 tail, the value
+    their sum. int64: ``first`` is the low 32 bits (uint32), ``second`` the
+    high 32 (int32). ``shape`` and ``dtype`` are the column's own."""
+
+    first: "jax.Array"
+    second: "jax.Array"
+
+    @property
+    def shape(self):
+        return self.first.shape
+
+    @property
+    def dtype(self) -> np.dtype:
+        return np.dtype(np.float64 if self.first.dtype == np.float32 else np.int64)
+
+
+def computes_in_pairs(mesh) -> bool:
+    """Whether ``mesh``'s devices compute 64-bit values as 32-bit pairs."""
+    return mesh.devices.flat[0].platform == "tpu"
+
+
+def split_planes(x) -> ColumnPlanes:
+    """The planes of an int64 or float64 array; traced, the body of the
+    ``split-planes`` program. A float's head is its float32 rounding and its
+    tail what that left, so that on a device that computes in pairs they are
+    the pair it already holds. A head that is not finite (NaN, an infinity,
+    a value beyond float32's range) takes a zero tail where ``inf - inf``
+    would make the sum NaN, and a zero head gives its tail its sign, so that
+    -0.0 comes back."""
+    import jax.numpy as jnp
+
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        head = x.astype(jnp.float32)
+        tail = (x - head.astype(jnp.float64)).astype(jnp.float32)
+        tail = jnp.where(jnp.isfinite(head), jnp.where(head == 0, head, tail), jnp.float32(0))
+        return ColumnPlanes(head, tail)
+    return ColumnPlanes(x.astype(jnp.uint32), (x >> 32).astype(jnp.int32))
+
+
+def join_planes(column):
+    """The 64-bit values of a :class:`ColumnPlanes` (traced); any other
+    device column as it is."""
+    import jax.numpy as jnp
+
+    if not isinstance(column, ColumnPlanes):
+        return column
+    first, second = column
+    if first.dtype == jnp.float32:
+        return first.astype(jnp.float64) + second.astype(jnp.float64)
+    return (second.astype(jnp.int64) << 32) | first.astype(jnp.int64)
+
+
+def join_columns(cols):
+    """``cols`` (name -> device column) with every :class:`ColumnPlanes`
+    joined: called at the entry of each program over resident columns, so
+    that everything behind it sees 64-bit values whatever the resident form."""
+    return {c: join_planes(v) for c, v in cols.items()}
 
 
 def _literal_bounds(codec: ColumnCodec, value) -> Tuple[int, int]:
@@ -759,7 +843,9 @@ from collections import OrderedDict as _OrderedDict
 _PREDICATE_CACHE: "_OrderedDict[str, callable]" = _OrderedDict()
 _PREDICATE_CACHE_MAX = 256
 
-# (scan identity, column, n_dev) -> (sharded device array, codec, n_rows).
+# (scan identity, column, mesh fingerprint) -> (device column, codec, n_rows):
+# a row-sharded device array, or the ColumnPlanes of an 8-byte column where
+# the devices compute in pairs; one form a platform, whichever tier uploaded.
 # Index bucket files are immutable (versioned v__=N dirs), so predicate
 # columns stay resident in HBM across queries — the survey's "index
 # column-chunks resident in HBM" stance (SURVEY.md §3.2); only the first
@@ -826,8 +912,32 @@ def _count_lookups(result: str, n: int = 1) -> None:
     c.inc(n)
 
 
+# "planes" / "whole" -> counter of 64-bit columns handed to a program
+_COLUMN_FORMS: dict = {}
+
+
+def count_column_forms(cols) -> None:
+    """Count the 64-bit columns among ``cols`` (the device columns one launch
+    hands its program) in ``hs_device_program_columns_total{form}``:
+    ``planes`` for a :class:`ColumnPlanes`, ``whole`` for an 8-byte array,
+    which a device that computes in pairs splits again in every call. 4-byte
+    dictionary codes are neither."""
+    for v in cols:
+        form = "planes" if isinstance(v, ColumnPlanes) else "whole" if v.dtype.itemsize == 8 else None
+        if form is None:
+            continue
+        c = _COLUMN_FORMS.get(form)
+        if c is None:
+            c = _COLUMN_FORMS[form] = _REGISTRY.counter(
+                "hs_device_program_columns_total",
+                "64-bit resident columns handed to a device program, by the form they are resident in",
+                form=form,
+            )
+        c.inc()
+
+
 def resident_column(key, n: int):
-    """The ``(device array, codec, rows)`` resident under column key ``key``
+    """The ``(device column, codec, rows)`` resident under column key ``key``
     (``(scan key, column, mesh fingerprint)``), or None when there is none
     or it holds other than ``n`` rows; None for a None key (a batch with no
     scan identity is never cached, and not counted). Every lookup counts in
@@ -977,6 +1087,12 @@ _hlo_lint.register_contract(
     description="on-device dictionary expansion: codes gather a replicated remap table, shuffle-free",
     single_fusion=True,
 )
+_hlo_lint.register_contract(
+    "split-planes",
+    collectives={},
+    description="an uploaded 8-byte column into its two 32-bit planes, once a column: elementwise, shuffle-free",
+    single_fusion=True,
+)
 
 
 def _dry_codecs(batch: B.Batch, refs) -> Dict[str, ColumnCodec]:
@@ -1002,9 +1118,31 @@ def _dict_expand_fn(codes, remap):
     return jnp.where(codes >= 0, remap[jnp.maximum(codes, 0)], jnp.int32(-1))
 
 
-def _put_encoded(session, mesh, sharding, n_dev, arr, site: str = "filter-cols"):
+def _upload_program(session, mesh, family: str, fn, signature, *args):
+    """One run of ``family``, a program that puts a freshly uploaded column
+    into the form the query programs want: once a column, never in a window.
+    Waited for, so that what it read (the 8-byte array, the raw codes) is
+    free again before the next column is uploaded, not beside it."""
+    key = _program_key(family, mesh)
+    jitted = _cached_predicate_jit(key, fn, family)
+    first = _note_compile(key, signature)
+    _hlo_lint.maybe_verify(session.conf, family, key, jitted, args)
+    t0 = _ptime.perf_counter()
+    with launch(family):
+        out = jitted(*args)
+    wait(out, family)
+    _observe_program(family, first, t0)
+    return out
+
+
+def _put_encoded(session, mesh, sharding, n_dev, arr, site: str = "filter-cols", planes: Optional[bool] = None):
     """Encode + bucket-pad + ``device_put`` one column, the upload counted
-    under ``site``; returns (device array, codec, staged bytes).
+    under ``site``; returns (device column, codec, staged bytes).
+
+    An 8-byte column is uploaded whole and, where the mesh's devices compute
+    64-bit values as pairs (``planes`` says so for them in a test), split by
+    the ``split-planes`` program into the :class:`ColumnPlanes` that stay; the
+    8-byte array goes with the call. The planes weigh what the column did.
 
     Dict-backed string columns (B.DictBackedArray, produced by the native
     decode fast path) skip host factorization entirely: the int32 codes ship
@@ -1032,19 +1170,14 @@ def _put_encoded(session, mesh, sharding, n_dev, arr, site: str = "filter-cols")
         padded = _pad_to_bucket(codes, n_dev, 0)
         dev_codes = put(padded, site, sharding)
         dev_remap = put(remap, site, NamedSharding(mesh, P()))
-        key = _program_key("dict-expand", mesh)
-        jitted = _cached_predicate_jit(key, _dict_expand_fn, "dict-expand")
-        first = _note_compile(key, (padded.shape, remap.shape))
-        _hlo_lint.maybe_verify(session.conf, "dict-expand", key, jitted, (dev_codes, dev_remap))
-        t0 = _ptime.perf_counter()
-        with launch("dict-expand"):
-            dev = jitted(dev_codes, dev_remap)
-        _observe_program("dict-expand", first, t0)
+        dev = _upload_program(session, mesh, "dict-expand", _dict_expand_fn, (padded.shape, remap.shape), dev_codes, dev_remap)
         codec = ColumnCodec("string", uniques=su, dtype=arr.dtype, nulls=bool((codes < 0).any()))
         return dev, codec, int(padded.nbytes + remap.nbytes)
     enc, codec = encode_column(arr)
     padded = _pad_to_bucket(enc, n_dev, 0 if enc.dtype != np.float64 else np.nan)
     dev = put(padded, site, sharding)
+    if padded.dtype.itemsize == 8 and (computes_in_pairs(mesh) if planes is None else planes):
+        dev = _upload_program(session, mesh, "split-planes", split_planes, (padded.shape, padded.dtype.str), dev)
     return dev, codec, int(padded.nbytes)
 
 
@@ -1100,7 +1233,11 @@ def device_filter_mask(session, batch: B.Batch, condition: Expr, scan_key=None, 
             if scan_key is not None:
                 _device_cache_put((scan_key, r, fp), (dev, codec, n), nbytes)
 
-    fn, lit_values = compile_predicate(condition, codecs)
+    pred_fn, lit_values = compile_predicate(condition, codecs)
+
+    def fn(cols, lits):
+        return pred_fn(join_columns(cols), lits)
+
     skeleton = predicate_skeleton(condition, codecs)
     if parallel is not None:
         from hyperspace_tpu.parallel import collectives as _collectives
@@ -1112,6 +1249,7 @@ def device_filter_mask(session, batch: B.Batch, condition: Expr, scan_key=None, 
     first = _note_compile(key, tuple(dev_cols[r].shape for r in sorted(dev_cols)))
     _hlo_lint.maybe_verify(session.conf, "fused-filter", key, jitted, (dev_cols, lit_values))
     t0 = _ptime.perf_counter()
+    count_column_forms(dev_cols.values())
     with launch("fused-filter"):
         mask = jitted(dev_cols, lit_values)
     out = fetch(mask, "filter-mask", "fused-filter")[:n]
@@ -1367,6 +1505,7 @@ def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skel
     skeleton = "agg:" + skeleton + "|" + repr(agg_spec)
 
     def program(cols, lits, n_valid):
+        cols = join_columns(cols)
         total = next(iter(cols.values())).shape[0]
         valid = jnp.arange(total) < n_valid
         mask = valid if pred_fn is None else (pred_fn(cols, lits) & valid)
@@ -1413,6 +1552,7 @@ def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skel
     first = _note_compile(key, tuple(dev_cols[r].shape for r in sorted(dev_cols)))
     _hlo_lint.maybe_verify(session.conf, "fused-agg", key, jitted, (dev_cols, lit_values, np.int64(n)))
     t0 = _ptime.perf_counter()
+    count_column_forms(dev_cols.values())
     with launch("fused-agg"):
         outs, valids = jitted(dev_cols, lit_values, np.int64(n))
     outs, valids = fetch((outs, valids), "agg-table", "fused-agg")
@@ -1651,6 +1791,7 @@ def _grouped_chunk_program(pred_fn, key_specs, slot_specs, cap):
     from jax import ops as jops
 
     def program(cols, lits, n_valid, row_base):
+        cols = join_columns(cols)
         total = next(iter(cols.values())).shape[0]
         valid = jnp.arange(total) < n_valid
         with jax.named_scope("filter"):
@@ -1933,6 +2074,7 @@ class GroupedAggStream:
                 (dev_cols, lit_values, np.int64(n), np.int64(self._row_base)),
             )
             t0 = _ptime.perf_counter()
+            count_column_forms(dev_cols.values())
             if sharded:
                 n_g_dev, fs, key_out, slot_out = self._parallel.timed_call(
                     "grouped-agg", family, jitted,
@@ -2260,6 +2402,7 @@ def _dense_grouped_aggregate(
     cntm_at = next(i for i, (kind, _, _) in enumerate(slots) if kind == "cntm")
 
     def program(cols, lits, n_valid):
+        cols = join_columns(cols)
         total = next(iter(cols.values())).shape[0]
         # bookkeeping counts rows, so the row count bounds it (see above)
         idx = jnp.int32 if total < 2**31 else jnp.int64
@@ -2332,6 +2475,7 @@ def _dense_grouped_aggregate(
         session.conf, "grouped-agg-dense", key, jitted, (dev_cols, lit_values, np.int64(n))
     )
     t0 = _ptime.perf_counter()
+    count_column_forms(dev_cols.values())
     with launch("grouped-agg-dense"):
         out = jitted(dev_cols, lit_values, np.int64(n))
     fs, slot_out = fetch(out, "agg-table", "grouped-agg-dense")
@@ -2366,7 +2510,7 @@ def _computed_dtypes(comp_fn, dev_cols, lit_values) -> Dict[str, np.dtype]:
         return {}
     import jax
 
-    shapes = jax.eval_shape(comp_fn, dev_cols, lit_values)
+    shapes = jax.eval_shape(lambda cols, lits: comp_fn(join_columns(cols), lits), dev_cols, lit_values)
     return {c: np.dtype(v.dtype) for c, v in shapes.items() if c not in dev_cols}
 
 

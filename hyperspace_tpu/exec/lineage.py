@@ -42,12 +42,13 @@ from hyperspace_tpu.exec.device import (
     _device_cache_put,
     _mesh_fp,
     _note_compile,
-    _pad_to_bucket,
     _program_key,
+    _put_encoded,
     bucket_rows,
-    encode_column,
+    count_column_forms,
     ensure_x64,
     fetch,
+    join_planes,
     launch,
     put,
     resident_column,
@@ -73,7 +74,7 @@ _ID_BUCKET_FLOOR = 64
 def _antijoin_fn(col, ids, n_ids):
     import jax.numpy as jnp
 
-    c = col.astype(jnp.int64)
+    c = join_planes(col).astype(jnp.int64)
     pos = jnp.searchsorted(ids, c)
     pos_c = jnp.clip(pos, 0, ids.shape[0] - 1)
     found = (pos < n_ids) & (jnp.take(ids, pos_c) == c)
@@ -123,11 +124,9 @@ def lineage_delete_mask(
     if cached is not None:
         dev_col = cached[0]
     else:
-        arr, codec = encode_column(col_np)
-        padded = _pad_to_bucket(arr, n_dev, 0)
-        dev_col = put(padded, "filter-cols", row_sharding)
+        dev_col, codec, nbytes = _put_encoded(session, mesh, row_sharding, n_dev, col_np)
         if ckey is not None:
-            _device_cache_put(ckey, (dev_col, codec, n), int(padded.nbytes))
+            _device_cache_put(ckey, (dev_col, codec, n), nbytes)
 
     m = bucket_rows(int(ids.size), floor=_ID_BUCKET_FLOOR)
     ids_padded = np.full(m, _ID_SENTINEL, dtype=np.int64)
@@ -141,6 +140,7 @@ def lineage_delete_mask(
     _hlo_lint.maybe_verify(
         session.conf, "lineage-antijoin", key, jitted, (dev_col, dev_ids, n_ids)
     )
+    count_column_forms((dev_col,))
     with launch("lineage-antijoin"):
         mask = jitted(dev_col, dev_ids, n_ids)
     return fetch(mask, "filter-mask", "lineage-antijoin")[:n]

@@ -120,6 +120,7 @@ def sharded_grouped_chunk_program(mesh, axis, pred_fn, key_specs, slot_specs, ca
             check_vma=False,
         )
         def per_shard(cols_, lits_, n_valid_, row_base_):
+            cols_ = D.join_columns(cols_)
             per = next(iter(cols_.values())).shape[0]
             d = jax.lax.axis_index(axis).astype(jnp.int64)
             gidx = d * per + jnp.arange(per, dtype=jnp.int64)

@@ -129,6 +129,31 @@ class TestForbiddenPatterns:
         found = verify_hlo(scratch_contract, txt)
         assert [f.rule for f in found] == ["forbidden-op:f64-upcast"]
 
+    @pytest.mark.parametrize("program, flagged", [
+        ("upcast-returned", True), ("upcast-then-arithmetic", True), ("planes-joined-and-returned", True),
+        ("planes-joined-inside-a-fusion", False), ("float32-arithmetic", False),
+    ])
+    def test_f64_upcast_on_compiled_text_tells_the_plane_join_apart(self, scratch_contract, program, flagged):
+        # compiled text prints an operand by name alone: the rule reads its
+        # type from its definition. A materialised upcast (a fusion's result),
+        # f64 arithmetic over f32 data and a joined column written back out
+        # are reported; add(convert(head), convert(tail)) consumed inside the
+        # fusion that reads the column (exec/device.join_planes) is not.
+        _device.ensure_x64()
+        planes = _device.ColumnPlanes(jnp.ones(2048, jnp.float32), jnp.full(2048, 1e-9, jnp.float32))
+        fn, args = {
+            "upcast-returned": (lambda a: a.astype(jnp.float64), (planes.first,)),
+            "upcast-then-arithmetic": (lambda a: (a.astype(jnp.float64) * 3.0).sum(), (planes.first,)),
+            "planes-joined-and-returned": (_device.join_planes, (planes,)),
+            "planes-joined-inside-a-fusion": (
+                lambda p: jnp.where(_device.join_planes(p) > 0.5, _device.join_planes(p) * 2.0, 0.0).sum(), (planes,)),
+            "float32-arithmetic": (lambda a: (a * 3.0).sum(), (planes.first,)),
+        }[program]
+        text = hlo_text_of(jax.jit(fn), *args)
+        assert ("f64[2048]" in text) == (program != "float32-arithmetic")
+        rules = [f.rule for f in verify_hlo(scratch_contract, _hlo("all-to-all") + text)]
+        assert rules.count("forbidden-op:f64-upcast") == int(flagged), text
+
     def test_dynamic_shape(self, scratch_contract):
         txt = _hlo("all-to-all") + "  %p = s32[<=1024] parameter(0)\n"
         found = verify_hlo(scratch_contract, txt)

@@ -1,19 +1,24 @@
 """The shape of the dense grouped program (``exec/device.py``,
-``jit_hs_grouped_agg_dense``), read from what the compilers make of it. No
-chip is used and nothing runs at the real size.
+``jit_hs_grouped_agg_dense``) and of the fused one (``jit_hs_fused_agg``),
+read from what the compilers make of them. No chip is used and nothing runs
+at the real size.
 
-TPC-H Q1 is asked once at toy size and the program the tier built for it is
-taken as it was handed to ``jit``. Then:
+TPC-H Q1 and Q6 are asked once at toy size and the programs the tier built
+for them are taken as they were handed to ``jit``. Then:
 
-- it is compiled at the benchmark's size (67,126,100 padded rows: 60M
-  ``lineitem`` rows of SF 10) for a *described* TPU v5e, and the optimized HLO
-  has to show one pass: at most three fusions that take a row-length input to
-  a group-length output (the slot-by-slot body had twelve), no 64-bit
-  integer pair for a count or for the first row, no loop over the rows, and
-  every resident column read, as a one-dimensional parameter, by a fusion or
-  a 64-bit split of the entry computation: what the benchmark's roofline
-  reader (``hsbench/costs_agg.py``) counts as the call's 48 bytes a row;
-- it is lowered (shapes only) on either side of 2^31 rows: below, the row
+- each is compiled at the benchmark's size (67,126,100 padded rows: 60M
+  ``lineitem`` rows of SF 10) for a *described* TPU v5e, its 8-byte columns
+  handed over as the chip holds them resident, two 32-bit planes each
+  (``ColumnPlanes``). Q1's optimized HLO has to show one pass: at most three
+  fusions that take a row-length input to a group-length output (the
+  slot-by-slot body had twelve), no 64-bit integer pair for a count or for
+  the first row, no loop over the rows. In both, every plane and every code
+  column is a one-dimensional parameter that a fusion of the entry
+  computation reads, and no ``X64SplitLow/High`` takes a row-length operand
+  (a whole 8-byte column would be split so in every call): what the
+  benchmark's roofline reader (``hsbench/costs_agg.py``) counts as Q1's 48
+  and Q6's 32 bytes a row;
+- Q1's is lowered (shapes only) on either side of 2^31 rows: below, the row
   index, the counts and the first-row min are 32-bit; from 2^31 on they are
   64-bit. Integer sums are int64 and float sums float64 on both sides.
 
@@ -39,13 +44,17 @@ import reference_report as ref
 
 SF10_PADDED_ROWS = 67_126_100  # bucket_rows(59,986,052): the rows ``sf10-report`` holds resident
 GROUPS = 6  # l_returnflag (A, N, R) x l_linestatus (F, O)
-Q1_BYTES_A_ROW = 48  # five 8-byte columns and two int32 codes
+BYTES_A_ROW = {"q1": 48, "q6": 32}  # five 8-byte columns and two int32 codes; four 8-byte columns
+FAMILY = {"q1": "grouped-agg-dense", "q6": "fused-agg"}
+# the planes of three float64 and two int64 columns and two codes; of two and two
+PLANES = {"q1": ["f32"] * 6 + ["s32"] * 4 + ["u32"] * 2, "q6": ["f32"] * 4 + ["s32"] * 2 + ["u32"] * 2}
 
 
 @pytest.fixture(scope="module")
-def q1(tmp_path_factory):
-    """``(program, (columns, literals, n_valid))`` of Q1's dense program, as
-    the tier handed them to ``jit`` for a 6,000-row index on one device."""
+def programs(tmp_path_factory):
+    """``{query: (program, (columns, literals, n_valid))}`` of Q1's dense and
+    Q6's fused program, as the tier handed them to ``jit`` for a 6,000-row
+    index on one device."""
     root = tmp_path_factory.mktemp("q1-program")
     frame = ref.lineitem(6000, seed=38)
     (root / "lineitem").mkdir()
@@ -65,11 +74,11 @@ def q1(tmp_path_factory):
 
     def spy(key, fn, family):
         jitted = cached_jit(key, fn, family)
-        if family != "grouped-agg-dense":
+        if family not in FAMILY.values():
             return jitted
 
         def call(*args):
-            seen["program"], seen["args"] = fn, args
+            seen[family] = fn, args
             return jitted(*args)
 
         call.lower = jitted.lower  # hlo_lint.maybe_verify
@@ -78,22 +87,33 @@ def q1(tmp_path_factory):
     mp = pytest.MonkeyPatch()
     mp.setattr(D, "_cached_predicate_jit", spy)
     try:
-        got = sess.sql(ref.SQL["q1"].format(**ref.PARAMS["q1"])).collect()
+        got = {q: sess.sql(ref.SQL[q].format(**ref.PARAMS[q])).collect() for q in FAMILY}
     finally:
         mp.undo()
         hst.set_session(None)
-    ref.compare(got, ref.answer("q1", frame), ref.ORDERED["q1"])
-    assert seen, "Q1 did not take the dense grouped program"
-    return seen["program"], seen["args"]
+    for q, family in FAMILY.items():
+        ref.compare(got[q], ref.answer(q, frame), ref.ORDERED[q])
+        assert family in seen, f"{q} did not take the {family} program"
+    return {q: seen[family] for q, family in FAMILY.items()}
 
 
-def _shapes(args, rows: int, sharding=None):
-    """The call's arguments as shapes, its columns ``rows`` long."""
+@pytest.fixture(scope="module")
+def q1(programs):
+    return programs["q1"]
+
+
+def _shapes(args, rows: int, sharding=None, planes: bool = False):
+    """The call's arguments as shapes, its columns ``rows`` long; with
+    ``planes``, every 8-byte column as the ``ColumnPlanes`` of its split."""
     import jax
 
     def shape(x):
         x = np.asarray(x) if not hasattr(x, "shape") else x
-        return jax.ShapeDtypeStruct((rows,) if len(x.shape) == 1 else x.shape, x.dtype, sharding=sharding)
+        whole = jax.ShapeDtypeStruct((rows,) if len(x.shape) == 1 else x.shape, x.dtype, sharding=sharding)
+        if not (planes and len(x.shape) == 1 and x.dtype.itemsize == 8):
+            return whole
+        return D.ColumnPlanes(*(jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=sharding)
+                                for p in jax.eval_shape(D.split_planes, whole)))
 
     return jax.tree.map(shape, args)
 
@@ -111,28 +131,36 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
-def optimized(q1, one_chip):
-    """``(module name, entry computation's instructions, whole text)`` of
-    Q1's program compiled for one described v5e chip at the benchmark's size."""
+def compiled(programs, one_chip):
+    """``{query: (module name, entry computation's instructions, whole text)}``
+    of each program compiled for one described v5e chip at the benchmark's
+    size, over planes."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    program, args = q1
     # a compile for a described chip can be written to the persistent cache
     # but not read back without one: keep it out
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    out = {}
     try:
-        lowered = jax.jit(hlo_lint.named("grouped-agg-dense", program)).lower(
-            *_shapes(args, SF10_PADDED_ROWS, one_chip))
-        text = lowered.compile().as_text()
+        for q, (program, args) in programs.items():
+            lowered = jax.jit(hlo_lint.named(FAMILY[q], program)).lower(
+                *_shapes(args, SF10_PADDED_ROWS, one_chip, planes=True))
+            text = lowered.compile().as_text()
+            name = re.search(r"^HloModule (\S+?),", text, re.M).group(1)
+            entry = text[text.index("\nENTRY "):]
+            entry = entry[:entry.index("\n}")]
+            out[q] = name, [l.strip() for l in entry.splitlines() if " = " in l and not l.startswith("ENTRY")], text
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    name = re.search(r"^HloModule (\S+?),", text, re.M).group(1)
-    entry = text[text.index("\nENTRY "):]
-    entry = entry[:entry.index("\n}")]
-    return name, [line.strip() for line in entry.splitlines() if " = " in line and not line.startswith("ENTRY")], text
+    return out
+
+
+@pytest.fixture(scope="module")
+def optimized(compiled):
+    return compiled["q1"]
 
 
 def _split(instruction: str):
@@ -168,9 +196,10 @@ def _reductions(entry):
     return out
 
 
-def test_the_program_keeps_its_name(optimized):
-    name, _, _ = optimized
-    assert name == "jit_hs_grouped_agg_dense"
+@pytest.mark.parametrize("q", list(FAMILY))
+def test_the_program_keeps_its_name(compiled, q):
+    name, _, _ = compiled[q]
+    assert name == "jit_" + hlo_lint.program_name(FAMILY[q])
 
 
 def test_q1_at_sf10_is_one_pass_over_the_rows(optimized):
@@ -192,23 +221,37 @@ def test_no_count_and_no_first_row_is_a_64_bit_pair(optimized):
     assert not re.findall(rf"\b[su]64\[{GROUPS}\]", results), results
 
 
-def test_every_resident_column_is_read_by_a_fusion_or_a_split(optimized):
-    _, entry, _ = optimized
+@pytest.mark.parametrize("q", list(FAMILY))
+def test_every_plane_is_read_by_a_fusion_and_nothing_row_long_is_split(compiled, q):
+    _, entry, text = compiled[q]
     columns = {}
     for line in entry:
         m = re.match(rf"(%[\w.\-]+) = (\w+)\[{SF10_PADDED_ROWS}\]\S* parameter\(", line)
         if m:
             columns[m.group(1)] = m.group(2)
-    assert sorted(columns.values()) == ["f64", "f64", "f64", "s32", "s32", "s64", "s64"], columns
-    readers = [l for l in entry if " fusion(" in l or re.search(r'custom_call_target="X64Split(Low|High)"', l)]
+    assert sorted(columns.values()) == PLANES[q], columns
+    readers = [l for l in entry if " fusion(" in l]
     for name in columns:
         assert any(re.search(re.escape(name) + r"\b", l.split(" = ", 1)[1]) for l in readers), \
-            f"{name} is read by no fusion and no 64-bit split of the entry computation"
+            f"{name} is read by no fusion of the entry computation"
+    splits = [l for l in text.splitlines() if re.search(r'custom_call_target="X64Split(Low|High)"', l)]
+    assert splits, "the literals and the row count are 64-bit scalars, split in the call"
+    assert not [l[:160] for l in splits if f"[{SF10_PADDED_ROWS}]" in l], "a pass over a column, every call"
 
 
-def test_the_benchmarks_reader_counts_48_bytes_a_row(optimized):
+def test_a_whole_column_would_be_split_in_every_call(programs, one_chip):
+    """The form this one replaced, and what the reader above must not see."""
+    import jax
+
+    program, args = programs["q6"]
+    text = jax.jit(hlo_lint.named("fused-agg", program)).lower(*_shapes(args, 4096, one_chip)).compile().as_text()
+    assert len(re.findall(r'\[4096\]\S* custom-call\(\S+\), custom_call_target="X64Split(?:Low|High)"', text)) == 8
+
+
+@pytest.mark.parametrize("q", list(FAMILY))
+def test_the_benchmarks_reader_counts_the_planes_as_the_columns_bytes(compiled, q):
     costs_agg = pytest.importorskip("hsbench.costs_agg")
-    _, entry, _ = optimized
+    _, entry, _ = compiled[q]
     # a trace names an operation by its long text, operands with their types;
     # the operations with an event are all but the parameters
     types = {name: result for name, result, _ in map(_split, entry)}
@@ -221,7 +264,7 @@ def test_the_benchmarks_reader_counts_48_bytes_a_row(optimized):
         after = rest.partition(")")[2]
         typed = ", ".join(f"{types[n]} {n}" for n in _operands(tail) if not types[n].startswith("("))
         ran.append(f"{name} = {result} {opcode}({typed}){after}")
-    assert costs_agg.call_least_bytes(ran) == Q1_BYTES_A_ROW * SF10_PADDED_ROWS
+    assert costs_agg.call_least_bytes(ran) == BYTES_A_ROW[q] * SF10_PADDED_ROWS
 
 
 @pytest.mark.parametrize("rows, width", [(SF10_PADDED_ROWS, 32), (2**31 - 1, 32), (2**31, 64), (2**32 + 8, 64)])
