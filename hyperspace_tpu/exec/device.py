@@ -26,6 +26,7 @@ host executor (exec/executor.py) runs the plan instead — mirroring how
 
 from __future__ import annotations
 
+import contextlib
 import os
 from functools import lru_cache, partial
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -1463,6 +1464,7 @@ def device_scan_aggregate(
     aggs,
     *,
     max_groups: int = 0,
+    cap_floor: int = 64,
 ) -> Optional[B.Batch]:
     """An aggregate over one whole scan, in ONE program over its resident
     columns: the predicate, the computed aggregate inputs (``computes``:
@@ -1470,8 +1472,11 @@ def device_scan_aggregate(
     reductions run on the device, and only the result table comes back.
     Ungrouped aggregates reduce to scalars (``fused-agg``); grouped ones
     whose keys are all dictionary-coded with a small domain reduce into one
-    slot per group, without a sort (``grouped-agg-dense``). Raises
-    DeviceUnsupported for any other shape: the caller has the sort-based
+    slot per group, without a sort (``grouped-agg-dense``); every other key
+    that is an integer, a date or a dictionary code goes through
+    ``grouped-agg-keyed`` (skip, sort, scan: as many groups as ``max_groups``
+    allows). Raises DeviceUnsupported for any other shape (a float key, keys
+    spanning more than 32 bits together): the caller has the sort-based
     :class:`GroupedAggStream` and the host for those."""
     ensure_x64()
     for _, fn, _c in aggs:
@@ -1483,17 +1488,26 @@ def device_scan_aggregate(
         raise DeviceUnsupported("no device-resident columns involved")
     def check(dry):
         _compile_aggregate(dry, condition, computes, aggs, group_keys)
-        if group_keys:
-            _dense_key_plan(group_keys, dry, max_groups, dry_run=True)
+        if any(dry[k].kind != "string" for k in group_keys):  # not the dense program's keys: the keyed one's
+            _keyed_key_plan(group_keys, aggs, dry)
 
     dev_cols, codecs = cols.on_device(check)
     pred_fn, comp_fn, lits, skeleton = _compile_aggregate(codecs, condition, computes, aggs, group_keys)
-    if group_keys:
-        return _dense_grouped_aggregate(
-            session, cols, dev_cols, codecs, pred_fn, comp_fn, lits, skeleton,
-            list(group_keys), list(aggs), max_groups,
+    if not group_keys:
+        return _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lits, skeleton, list(aggs))
+    args = (session, cols, dev_cols, codecs, pred_fn, comp_fn, lits, skeleton, list(group_keys), list(aggs), max_groups)
+    try:
+        _dense_key_plan(group_keys, codecs, max_groups)
+    except DeviceUnsupported:
+        computed = {name for name, _ in computes or ()}
+        after = (
+            set(group_keys)
+            | {c for _, _, c in aggs if c is not None and c not in computed}
+            | {r for _, e in computes or () for r in e.references()}
         )
-    return _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lits, skeleton, list(aggs))
+        reads = (sorted(condition.references()) if condition is not None else [], sorted(after))
+        return _keyed_grouped_aggregate(*args, cap_floor, reads)
+    return _dense_grouped_aggregate(*args)
 
 
 def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skeleton, aggs):
@@ -1559,6 +1573,7 @@ def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skel
     valids = [int(v) for v in valids]
     _observe_program("fused-agg", first, t0)
     trace.agg_rows("device", n)
+    _annotate_tier(program="fused-agg")
 
     result: Dict[str, np.ndarray] = {}
     for (name, fn, c), val, n_valid in zip(aggs, outs, valids):
@@ -1609,6 +1624,21 @@ def topk_capacity(k: int, floor: int = 64) -> int:
     buckets over a small floor, so nearby limits (10, 12, 100...) land on a
     handful of compiled top-k executables instead of one per distinct k."""
     return bucket_rows(max(1, int(k)), floor=max(1, int(floor)))
+
+
+def _count_groups(program: str, n: int) -> None:
+    """``n`` groups answered by ``program`` in ``hs_agg_groups_total{program}``."""
+    _REGISTRY.counter(
+        "hs_agg_groups_total", "Groups a device grouped aggregate answered, by program family", program=program
+    ).inc(n)
+
+
+def _annotate_tier(**attrs) -> None:
+    """Say on the tier's span (``agg-device-grouped-scan``, the current one
+    outside a launch or a wait) which program answered and what it found."""
+    sp = _obs_spans.current_span()
+    if sp is not None:
+        sp.set(**attrs)
 
 
 def _grouped_slots(aggs, is_int: Dict[str, bool]):
@@ -2238,16 +2268,12 @@ class GroupedAggStream:
         from hyperspace_tpu.obs.metrics import REGISTRY
 
         if self._hint_key is not None:
-            if len(_CAP_HINT_MEMO) >= 4096:  # bound pathological key churn
-                _CAP_HINT_MEMO.clear()
-            _CAP_HINT_MEMO[self._hint_key] = self._cap_hint
+            _remember_capacity(self._hint_key, self._cap_hint)
         n, key_cols, slot_cols = self._host_table()
         _, input_dtypes = self._schema
         out: B.Batch = dict(key_cols)
         out.update(_final_columns(self.aggs, self._refs, input_dtypes, slot_cols))
-        REGISTRY.counter(
-            "hs_agg_groups_total", "Groups produced by device grouped aggregation"
-        ).inc(n)
+        _count_groups(self._family, n)
         return out
 
     def to_partial_frame(self, plain):
@@ -2289,7 +2315,16 @@ class GroupedAggStream:
         return pd.DataFrame(frame)
 
 
-_CAP_HINT_MEMO: Dict[tuple, int] = {}
+# what a query shape over a scan's files was seen to need: groups (the chunk
+# family's and the keyed program's table), and a predicate's selected rows and
+# blocks (``_keyed_selected``)
+_CAP_HINT_MEMO: Dict[tuple, object] = {}
+
+
+def _remember_capacity(key, value) -> None:
+    if len(_CAP_HINT_MEMO) >= 4096:  # bound pathological key churn
+        _CAP_HINT_MEMO.clear()
+    _CAP_HINT_MEMO[key] = value
 
 
 def device_grouped_aggregate(
@@ -2358,18 +2393,15 @@ _hlo_lint.register_contract(
 )
 
 
-def _dense_key_plan(group_keys, codecs, max_groups: int, dry_run: bool = False):
+def _dense_key_plan(group_keys, codecs, max_groups: int):
     """``[(key, domain size, code offset)]`` and the number of groups, for
     keys that are all dictionary-coded; DeviceUnsupported otherwise. A null
-    code (-1) takes a slot of its own unless the codec saw none. A dry run
-    has no dictionary yet and checks the key kinds only."""
+    code (-1) takes a slot of its own unless the codec saw none."""
     plan, groups = [], 1
     for k in group_keys:
         codec = codecs[k]
         if codec.kind != "string":
             raise DeviceUnsupported(f"group key {k!r} is not dictionary-coded")
-        if dry_run:
-            continue
         off = 0 if codec.nulls is False else 1
         size = len(codec.uniques) + off
         plan.append((k, size, off))
@@ -2390,13 +2422,7 @@ def _dense_grouped_aggregate(
     mesh = cols.mesh
     n = cols.rows
     plan, groups = _dense_key_plan(group_keys, codecs, max_groups)
-    computed = _computed_dtypes(comp_fn, dev_cols, lit_values)
-    input_dtypes = {}
-    for _, _fn, c in aggs:
-        if c is not None:
-            host_dtype = computed[c] if c in computed else codecs[c].dtype
-            input_dtypes[c] = np.dtype(dev_cols[c].dtype if host_dtype is None else host_dtype)
-    slots, refs = _grouped_slots(aggs, {c: dt.kind in ("i", "u", "b") for c, dt in input_dtypes.items()})
+    input_dtypes, slots, refs = _resident_slots(aggs, comp_fn, dev_cols, codecs, lit_values)
     if not any(kind == "cntm" for kind, _, _ in slots):
         slots = slots + [("cntm", None, True)]  # which groups have a row at all
     cntm_at = next(i for i, (kind, _, _) in enumerate(slots) if kind == "cntm")
@@ -2497,10 +2523,421 @@ def _dense_grouped_aggregate(
         result[name] = vals
     result = {k: result[k] for k in group_keys}
     result.update(_final_columns(aggs, refs, input_dtypes, [s[live] for s in slot_out]))
-    _REGISTRY.counter(
-        "hs_agg_groups_total", "Groups produced by device grouped aggregation"
-    ).inc(len(live))
+    _count_groups("grouped-agg-dense", len(live))
+    _annotate_tier(program="grouped-agg-dense", groups=len(live))
     return result
+
+
+# --------------------------------------------------------------------------
+# grouped aggregate over integer and date keys: skip, sort, scan
+#
+# A key whose domain no codec states (an int64, a date, a wide dictionary's
+# codes) has as many groups as the data says: TPC-H Q15's revenue by supplier
+# is 100,000 groups of about 25 rows. Neither form above fits: the dense
+# program costs by groups x rows, and the sort-based chunk family takes a host
+# batch, no computed input, and minutes of compile. This program answers it
+# from the resident columns in one module, ``jit_hs_grouped_agg_keyed``
+# (and a probe before it, the first time a predicate is seen):
+#
+# 1. *skip*: one pass over the predicate's columns gives the mask; rows are
+#    taken in blocks of ``_KEYED_BLOCK_ROWS``, and only the blocks that hold a
+#    selected row go on (their numbers sorted to the front, their rows copied
+#    out of every column the query reads). An index is sorted by its key
+#    inside each bucket, so a range on that key selects runs, and the blocks
+#    that go on hold little else: 2.7 M of 67 M rows for a quarter of Q15. A
+#    predicate that selects rows everywhere keeps every block, and the steps
+#    below then walk the whole scan: slower, the same answer.
+# 2. *sort*: the keys of a row become one 32-bit code, mixed-radix over each
+#    key's offset from its least selected value (a NULL date takes the slot
+#    past the greatest), and ``(code, position, inputs)`` is sorted, unstable,
+#    with one key operand. That is what this chip's compiler builds in
+#    seconds: a sort's compile time grows steeply with its operands (one key
+#    3 s, key and payload 12 s, key and three payloads 25 s, four keys and
+#    three payloads 1,000 s at 3 M rows on a v5e, PERF.md). Keys that span
+#    more than 32 bits together are refused (``DeviceUnsupported``): the
+#    program says so from what it saw, nothing is configured.
+# 3. *scan*: the aggregate inputs ride through the sort as payload (at most
+#    ``_KEYED_MAX_INPUTS`` of them: an aggregate over more is refused), and
+#    every state slot is one segmented inclusive scan, Hillis-Steele, a loop
+#    of shifted combines that ends after log2 of the longest group: float
+#    sums in float64, integer sums in int64, counts and the first position in
+#    int32.
+# 4. the last row of each segment holds its group: their positions are sorted
+#    to the front (one operand) and the group table gathered from them, cut
+#    to the capacity bucket. Only that table leaves the chip.
+#
+# Two capacities shape a program, both on the geometric ladder. The blocks
+# that go on are the call's own: a small program, ``grouped-agg-keyed-probe``
+# (the mask over the predicate's columns, nothing else), counts the selected
+# rows and the blocks that hold one, and what it says of a predicate's
+# literals over a scan's files is remembered (it cannot change), so a query
+# that comes again launches the one program. The groups that come back are
+# remembered for the query shape in ``_CAP_HINT_MEMO`` as the chunk family
+# does; a shape seen for the first time is sized by its selected rows and
+# then run once more at the bucket of the groups it found, so that the steady
+# program is compiled by the first call and not by the second.
+# --------------------------------------------------------------------------
+
+_KEYED_BLOCK_ROWS = 4096
+_KEYED_MAX_INPUTS = 2  # two 32-bit sort operands an input: what compiles in seconds
+_KEYED_NO_CODE = np.uint32(0xFFFFFFFF)
+
+_hlo_lint.register_contract(
+    "grouped-agg-keyed",
+    collectives={"all-gather": _ANY, "all-reduce": _ANY, "all-to-all": _ANY, "collective-permute": _ANY},
+    description="grouped aggregate over integer and date keys of resident columns: block skip, code sort, segmented scan; only the group table leaves",
+)
+_hlo_lint.register_contract(
+    "grouped-agg-keyed-probe",
+    collectives={"all-gather": _ANY, "all-reduce": _ANY, "all-to-all": _ANY, "collective-permute": _ANY},
+    description="the keyed grouped aggregate's predicate alone: selected rows and the blocks that hold one, two scalars back",
+)
+
+
+def _keyed_key_plan(group_keys, aggs, codecs, dev_cols=None):
+    """``[(key, nullable)]`` for keys this program can code: integers, dates
+    (``nullable``: NaT, the int64 minimum, is a group of its own) and
+    dictionary codes (the null code -1 is a value like any other). A float
+    key raises DeviceUnsupported, and so do more aggregate inputs than ride
+    through the sort; dry codecs tell no float from an integer, so that is
+    asked again of the device columns."""
+    inputs = {c for _, _, c in aggs if c is not None}
+    if len(inputs) > _KEYED_MAX_INPUTS:
+        raise DeviceUnsupported(f"{len(inputs)} aggregate inputs, the keyed program carries {_KEYED_MAX_INPUTS}")
+    plan = []
+    for k in group_keys:
+        codec = codecs[k]
+        dtype = codec.dtype if dev_cols is None else dev_cols[k].dtype
+        if codec.kind == "numeric" and dtype is not None and np.dtype(dtype).kind == "f":
+            raise DeviceUnsupported(f"float group key {k!r}")
+        plan.append((k, codec.kind == "datetime"))
+    return plan
+
+
+def _shifted(x, d, fill):
+    """``x[i - d]`` for ``i >= d`` and ``fill`` before; ``d`` is traced."""
+    import jax
+    import jax.numpy as jnp
+
+    n = x.shape[0]
+    padded = jnp.concatenate([jnp.full((n,), fill, x.dtype), x])
+    return jax.lax.dynamic_slice(padded, (n - d,), (n,))
+
+
+def _segmented_scan(start, values, folds):
+    """Inclusive scans of ``values`` that start again wherever ``start`` is
+    set, each with its own combiner of ``folds``: Hillis-Steele, one loop
+    body whatever the length. A row's flag is set once its segment's start
+    lies within the distance covered, so the loop ends when every flag is:
+    after log2 of the LONGEST segment, not of the rows (a row outside any
+    segment has to be flagged a start of its own, or it never ends)."""
+    import jax
+    import jax.numpy as jnp
+
+    steps = max(1, int(np.ceil(np.log2(max(2, start.shape[0])))))
+
+    def more(carry):
+        k, flag, _ = carry
+        return (k < steps) & ~flag.all()
+
+    def body(carry):
+        k, flag, xs = carry
+        d = jnp.int32(1) << k
+        out = tuple(
+            jnp.where(flag, x, fold(_shifted(x, d, x.dtype.type(0)), x)) for x, fold in zip(xs, folds)
+        )
+        return k + 1, flag | _shifted(flag, d, True), out
+
+    return jax.lax.while_loop(more, body, (jnp.int32(0), start, tuple(values)))[2]
+
+
+def _keyed_mask(pred_fn, pred_cols, total: int, cols, lits, n_valid):
+    """``(selected rows' mask, which whole blocks hold one)``: the part the
+    probe and the program share."""
+    import jax
+    import jax.numpy as jnp
+
+    n_full = total // _KEYED_BLOCK_ROWS
+    with jax.named_scope("filter"):
+        mask = jnp.arange(total, dtype=jnp.int32) < n_valid.astype(jnp.int32)
+        if pred_fn is not None:
+            mask = pred_fn(join_columns({c: cols[c] for c in pred_cols}), lits) & mask
+        hit = mask[: n_full * _KEYED_BLOCK_ROWS].reshape(n_full, _KEYED_BLOCK_ROWS).any(axis=1)
+    return mask, hit
+
+
+def _keyed_probe(pred_fn, pred_cols, total: int):
+    """The traced body of ``grouped-agg-keyed-probe``: how many rows the
+    predicate selects and how many whole blocks hold one of them."""
+    import jax.numpy as jnp
+
+    def program(cols, lits, n_valid):
+        mask, hit = _keyed_mask(pred_fn, pred_cols, total, cols, lits, n_valid)
+        return mask.sum(dtype=jnp.int32), hit.sum(dtype=jnp.int32)
+
+    return program
+
+
+def _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total: int, cap_blocks: int, cap: int):
+    """The traced body of ``grouped-agg-keyed`` for ``total`` padded rows,
+    ``cap_blocks`` blocks going on (no fewer than hold a selected row: the
+    probe counted them) and a group table of ``cap`` rows. ``after``: (the
+    predicate's columns, the columns read behind the skip)."""
+    import jax
+    import jax.numpy as jnp
+
+    block = _KEYED_BLOCK_ROWS
+    n_full, tail = divmod(total, block)  # whole blocks, and the rows of the short last one
+    whole = cap_blocks >= n_full  # nothing to skip: the scan itself goes on
+    rows_on = total if whole else (cap_blocks + bool(tail)) * block
+    i64 = jnp.iinfo(jnp.int64)
+    pred_cols, after_cols = after
+    input_cols = sorted({col for _, col, _ in slots if col is not None})
+
+    def sort_front(values, keep: int):
+        """The ``keep`` least of ``values`` (int32), ascending."""
+        return jax.lax.sort((values,), num_keys=1, is_stable=False)[0][:keep]
+
+    def program(cols, lits, n_valid):
+        mask, hit = _keyed_mask(pred_fn, pred_cols, total, cols, lits, n_valid)
+        if whole:
+            sub, on = join_columns({c: cols[c] for c in after_cols}), mask
+        else:
+            with jax.named_scope("skip"):
+                numbers = sort_front(jnp.where(hit, jnp.arange(n_full, dtype=jnp.int32), jnp.int32(n_full)), cap_blocks)
+                rows = jnp.minimum(numbers, n_full - 1)
+
+                def take(plane):
+                    """The blocks that go on, as rows of the column laid out
+                    in blocks, then the short last block, padded."""
+                    out = plane[: n_full * block].reshape(n_full, block)[rows].reshape(cap_blocks * block)
+                    if tail:
+                        out = jnp.concatenate([out, jnp.pad(plane[n_full * block:], (0, block - tail))])
+                    return out
+
+                sub = join_columns({
+                    c: ColumnPlanes(take(v.first), take(v.second)) if isinstance(v, ColumnPlanes) else take(v)
+                    for c, v in cols.items() if c in after_cols
+                })
+                on = jnp.broadcast_to((numbers < n_full)[:, None], (cap_blocks, block)).reshape(cap_blocks * block)
+                if tail:
+                    on = jnp.concatenate([on, jnp.ones((block,), bool)])
+                on = on & take(mask)  # the padding of the last block is not selected
+        if comp_fn is not None:
+            sub = comp_fn(sub, lits)
+        with jax.named_scope("key-encode"):
+            code = jnp.zeros((rows_on,), jnp.uint32)
+            span = jnp.float64(1.0)
+            fits = jnp.bool_(True)
+            least, widths = [], []
+            for name, nullable in key_plan:
+                k = sub[name].astype(jnp.int64)
+                null = (k == i64.min) if nullable else jnp.zeros((rows_on,), bool)
+                live = on & ~null
+                lo = jnp.min(jnp.where(live, k, i64.max))
+                hi = jnp.max(jnp.where(live, k, i64.min))
+                lo, hi = jnp.where(hi >= lo, lo, 0), jnp.where(hi >= lo, hi, 0)
+                reach = hi - lo  # wraps negative where the values span more than 63 bits
+                fits = fits & (reach >= 0) & (reach < 2**32 - 2)
+                width = reach + (2 if nullable else 1)
+                off = jnp.where(null, reach + 1, k - lo).astype(jnp.uint32)
+                code = code * width.astype(jnp.uint32) + off
+                span = span * width.astype(jnp.float64)
+                least.append(lo)
+                widths.append(width)
+            fits = fits & (span < 2.0**32 - 1)
+            code = jnp.where(on, code, _KEYED_NO_CODE)
+        with jax.named_scope("sort"):
+            pos = jnp.arange(rows_on, dtype=jnp.int32)
+            code, pos, *moved = jax.lax.sort(
+                (code, pos) + tuple(sub[c] for c in input_cols), num_keys=1, is_stable=False
+            )
+            inputs = dict(zip(input_cols, moved))
+        with jax.named_scope("segments"):
+            on = code != _KEYED_NO_CODE
+            start = on & jnp.concatenate([jnp.ones((1,), bool), code[1:] != code[:-1]])
+            end = on & jnp.concatenate([code[1:] != code[:-1], jnp.ones((1,), bool)])
+            n_groups = end.sum(dtype=jnp.int32)
+        with jax.named_scope("group-scan"):
+            operands = {"fs": (pos, jnp.minimum)}
+            at = []
+            for kind, col, isint in slots:
+                if kind == "cntm" or (kind == "cnt" and isint):
+                    kind, col = "cnt", None  # every selected row of an int column counts
+                key = (kind, col, isint)
+                if key not in operands:
+                    if col is None:
+                        operands[key] = (on.astype(jnp.int32), jnp.add)
+                    else:
+                        x = inputs[col]
+                        nn = on if isint else (on & ~jnp.isnan(x))
+                        z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
+                        if kind == "cnt":
+                            operands[key] = (nn.astype(jnp.int32), jnp.add)
+                        elif kind in ("sum", "sumsq"):
+                            z = z * z if kind == "sumsq" else z
+                            operands[key] = (jnp.where(nn, z, z.dtype.type(0)), jnp.add)
+                        elif kind == "min":
+                            fill = i64.max if isint else jnp.inf
+                            operands[key] = (jnp.where(nn, z, z.dtype.type(fill)), jnp.minimum)
+                        else:  # max
+                            fill = i64.min if isint else -jnp.inf
+                            operands[key] = (jnp.where(nn, z, z.dtype.type(fill)), jnp.maximum)
+                at.append(key)
+            values, folds = zip(*operands.values())
+            scanned = dict(zip(operands, _segmented_scan(start | ~on, values, folds)))
+        with jax.named_scope("group-table"):
+            last = sort_front(jnp.where(end, jnp.arange(rows_on, dtype=jnp.int32), jnp.int32(rows_on)), cap)
+            last = jnp.minimum(last, rows_on - 1)
+            if last.shape[0] < cap:
+                last = jnp.pad(last, (0, cap - last.shape[0]))
+
+            def table(x):
+                x = x[last]
+                return x.astype(jnp.int64) if jnp.issubdtype(x.dtype, jnp.integer) else x
+
+        return (
+            (n_groups, fits),
+            (jnp.stack(least), jnp.stack(widths)),
+            code[last],
+            table(scanned["fs"]),
+            tuple(table(scanned[key]) for key in at),
+        )
+
+    return program
+
+
+def _keyed_selected(session, cols, dev_cols, pred_fn, pred_cols, lit_values, skeleton, total: int):
+    """``(rows the predicate selects, whole blocks that hold one)``: what the
+    keyed program's shape follows from. One launch of the probe the first
+    time these literals are asked of these files; the answer cannot change,
+    so it is kept with the other capacities and a query that comes again
+    launches the one program."""
+    n = cols.rows
+    if pred_fn is None:
+        return n, total // _KEYED_BLOCK_ROWS
+    memo_key = None
+    if cols.scan_key is not None:
+        memo_key = (cols.scan_key, "keyed-selected", skeleton, tuple(np.asarray(v).tobytes() for v in lit_values))
+        known = _CAP_HINT_MEMO.get(memo_key)
+        if known is not None:
+            return known
+    taken = {c: dev_cols[c] for c in pred_cols}
+    key = _program_key(f"gkeyed-probe[{total}]:{skeleton}", cols.mesh)
+    jitted = _cached_predicate_jit(key, _keyed_probe(pred_fn, pred_cols, total), "grouped-agg-keyed-probe")
+    first = _note_compile(key, tuple(taken[c].shape for c in sorted(taken)))
+    _hlo_lint.maybe_verify(session.conf, "grouped-agg-keyed-probe", key, jitted, (taken, lit_values, np.int64(n)))
+    t0 = _ptime.perf_counter()
+    count_column_forms(taken.values())
+    with launch("grouped-agg-keyed-probe"):
+        out = jitted(taken, lit_values, np.int64(n))
+    known = tuple(int(v) for v in fetch(out, "agg-table", "grouped-agg-keyed-probe"))
+    _observe_program("grouped-agg-keyed-probe", first, t0)
+    if memo_key is not None:
+        _remember_capacity(memo_key, known)
+    return known
+
+
+def _keyed_grouped_aggregate(
+    session, cols, dev_cols, codecs, pred_fn, comp_fn, lit_values, skeleton,
+    group_keys, aggs, max_groups, cap_floor, after,
+) -> B.Batch:
+    mesh = cols.mesh
+    n = cols.rows
+    total = int(next(iter(dev_cols.values())).shape[0])
+    if total >= 2**31:
+        raise DeviceUnsupported("row positions past 32 bits")
+    key_plan = _keyed_key_plan(group_keys, aggs, codecs, dev_cols)
+    input_dtypes, slots, refs = _resident_slots(aggs, comp_fn, dev_cols, codecs, lit_values)
+    max_groups = int(max_groups) if max_groups else 1 << 20
+    cap_floor = max(1, int(cap_floor))
+    n_blocks = total // _KEYED_BLOCK_ROWS  # whole blocks: the short last one always goes on
+
+    n_selected, n_hit = _keyed_selected(session, cols, dev_cols, pred_fn, after[0], lit_values, skeleton, total)
+    cap_blocks = min(n_blocks, group_capacity(n_hit, 4))
+    rows_on = total if cap_blocks >= n_blocks else (cap_blocks + 1) * _KEYED_BLOCK_ROWS
+    hint = (cols.scan_key, tuple(group_keys), tuple((fn, c) for _, fn, c in aggs))
+    groups_hint = _CAP_HINT_MEMO.get(hint) if cols.scan_key is not None else None
+    # a shape no call has answered yet is sized by its selected rows
+    cap = group_capacity(groups_hint or min(n_selected, max_groups), cap_floor)
+    base = (
+        f"{skeleton}|k:{','.join(f'{k}:{int(nullable)}' for k, nullable in key_plan)}"
+        f"|s:{','.join(f'{k}:{c}:{int(i)}' for k, c, i in slots)}"
+    )
+    shapes = tuple(dev_cols[r].shape for r in sorted(dev_cols))
+    while True:
+        cap = min(cap, group_capacity(rows_on, cap_floor))  # no more groups than rows going on
+        program = _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total, cap_blocks, cap)
+        key = _program_key(f"gkeyed[{total},{cap_blocks},{cap}]:{base}", mesh)  # the body is built for its shapes
+        jitted = _cached_predicate_jit(key, program, "grouped-agg-keyed")
+        first = _note_compile(key, shapes)
+        _hlo_lint.maybe_verify(
+            session.conf, "grouped-agg-keyed", key, jitted, (dev_cols, lit_values, np.int64(n))
+        )
+        t0 = _ptime.perf_counter()
+        count_column_forms(dev_cols.values())
+        with launch("grouped-agg-keyed"):
+            out = jitted(dev_cols, lit_values, np.int64(n))
+        n_groups, fits = (int(v) for v in fetch(out[0], "agg-table", "grouped-agg-keyed"))
+        _observe_program("grouped-agg-keyed", first, t0)
+        if not fits:
+            raise DeviceUnsupported("group keys span more than 32 bits together")
+        if n_groups > max_groups:
+            exc = GroupCapacityExceeded(f"group cardinality {n_groups} exceeds maxGroups {max_groups}")
+            exc.folded = False
+            raise exc
+        steady = group_capacity(n_groups, cap_floor)
+        if n_groups > cap or (first and groups_hint is None and steady < cap):
+            # the table was too small; or it was sized by the selected rows, and
+            # the program this shape settles on is compiled now, not by the next call
+            cap, groups_hint = steady, n_groups
+            continue
+        break
+    if cols.scan_key is not None:
+        _remember_capacity(hint, max(groups_hint or 0, n_groups))
+    (least, widths), codes, fs, slot_out = fetch(out[1:], "agg-table", "grouped-agg-keyed")
+    trace.agg_rows("device", n)
+
+    # groups in first-appearance order (pandas sort=False), keys decoded from the code
+    order = np.argsort(fs[:n_groups], kind="stable")
+    radix = codes[:n_groups].astype(np.int64)[order]
+    result: B.Batch = {}
+    for (name, nullable), lo, width in reversed(list(zip(key_plan, least.tolist(), widths.tolist()))):
+        off = radix % width
+        radix = radix // width
+        codec = codecs[name]
+        if codec.kind == "string":
+            vals = np.full(n_groups, np.nan, dtype=object)
+            code = off + lo
+            pos = code >= 0
+            if pos.any():
+                vals[pos] = np.asarray(codec.uniques, dtype=object)[code[pos]]
+            result[name] = vals
+        elif nullable:
+            days = np.where(off == width - 1, np.iinfo(np.int64).min, off + lo)
+            result[name] = days.astype(np.int64).view(f"M8[{codec.unit}]")
+        else:
+            dtype = np.dtype(codec.dtype) if codec.dtype is not None else np.dtype(np.int64)
+            result[name] = (off + lo).astype(dtype if dtype.kind in ("i", "u", "b") else np.int64)
+    result = {k: result[k] for k in group_keys}
+    result.update(_final_columns(aggs, refs, input_dtypes, [s[:n_groups][order] for s in slot_out]))
+    _count_groups("grouped-agg-keyed", n_groups)
+    _annotate_tier(program="grouped-agg-keyed", groups=n_groups, capacity=cap, selected_rows=n_selected, blocks=cap_blocks)
+    return result
+
+
+def _resident_slots(aggs, comp_fn, dev_cols, codecs, lit_values):
+    """``(input dtypes, slots, refs)`` of ``aggs`` over resident columns: an
+    input's dtype is the host column's (the codec kept it) or what its
+    computed expression evaluates to; :func:`_grouped_slots` over those."""
+    computed = _computed_dtypes(comp_fn, dev_cols, lit_values)
+    input_dtypes = {}
+    for _, _fn, c in aggs:
+        if c is not None:
+            host_dtype = computed[c] if c in computed else codecs[c].dtype
+            input_dtypes[c] = np.dtype(dev_cols[c].dtype if host_dtype is None else host_dtype)
+    slots, refs = _grouped_slots(aggs, {c: dt.kind in ("i", "u", "b") for c, dt in input_dtypes.items()})
+    return input_dtypes, slots, refs
 
 
 def _computed_dtypes(comp_fn, dev_cols, lit_values) -> Dict[str, np.dtype]:

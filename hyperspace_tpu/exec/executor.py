@@ -34,6 +34,7 @@ from hyperspace_tpu.plan.expr import (
     InputFileName,
     as_bool_mask,
     extract_equi_join_keys,
+    request_memo,
 )
 from hyperspace_tpu.reliability.errors import ReliabilityError
 
@@ -437,6 +438,21 @@ def _scan_aggregate_shape(plan: L.Aggregate):
     if any(c in node.columns for c in names):
         return None  # a computed column shadows a scan column
     return node, filter_node, computes
+
+
+def _request_aggregate_key(plan: L.Aggregate):
+    """Key of a grouped aggregate over a scan in the request's memo: the
+    sub-plan's exact fingerprint (structure, literals, the scan's relation or
+    index version) and its output names, which the fingerprint leaves out
+    (``sum(x) as a`` and ``sum(x) as b`` are one structure: the result cache
+    relabels, this memo keeps them apart); None for any other shape. The memo
+    lives for one request, which reads one snapshot, so the fingerprint says
+    which files too."""
+    if not plan.keys or _scan_aggregate_shape(plan) is None:
+        return None
+    from hyperspace_tpu.serving.fingerprint import plan_fingerprint
+
+    return ("agg", plan_fingerprint(plan).exact, tuple(plan.output_columns))
 
 
 def _footer_rows(node, keys) -> Optional[int]:
@@ -1403,6 +1419,25 @@ class Executor:
         return as_bool_mask(plan.condition.eval(child))
 
     def _exec_aggregate(self, plan: L.Aggregate, with_file_names: bool) -> B.Batch:
+        """A grouped aggregate over one scan is evaluated once a request: a
+        CTE that the plan reads twice (TPC-H Q15's ``revenue0``: a join side
+        and ``select max(total_revenue)``) is inlined as two equal sub-plans,
+        run by two executors of the same request, and an equality between
+        their float sums holds only if both are the same evaluation. The
+        request's memo (``plan.expr.request_memo``) keeps the group table
+        under the sub-plan's fingerprint, literals and index version in."""
+        key = None if with_file_names else _request_aggregate_key(plan)
+        memo = request_memo() if key is not None else None
+        if memo is not None and key in memo:
+            trace.record("agg", "request-memo")
+            return dict(memo[key])
+        got = self._aggregate_tiers(plan, with_file_names)
+        if memo is not None:
+            memo[key] = got
+            return dict(got)  # a caller may add columns to its copy
+        return got
+
+    def _aggregate_tiers(self, plan: L.Aggregate, with_file_names: bool) -> B.Batch:
         # fused device path for global aggregates over an (optionally
         # filtered) index/file scan: predicate + reductions run in one jitted
         # program over HBM-resident columns; only scalars transfer back
@@ -2139,9 +2174,12 @@ class Executor:
             return got, cols.loaded, filter_node, _kept_groups(node)
 
     def _device_aggregate(self, plan: L.Aggregate, cols, condition, computes) -> B.Batch:
-        """One program over the scan's device columns; for group keys no
-        codec states the domain of, the sort-based engine over the host
-        batch (which has no computed inputs)."""
+        """One program over the scan's device columns: ``fused-agg``,
+        ``grouped-agg-dense`` or ``grouped-agg-keyed``. What none of them
+        takes (a float group key, keys spanning more than 32 bits, a third
+        aggregate input) goes to the sort-based engine over the host batch,
+        which has no computed inputs; so does a grouped aggregate on a mesh the session shards
+        queries over (``hyperspace.parallel.enabled``)."""
         from hyperspace_tpu.exec import device as D
 
         conf = self.session.conf
@@ -2152,8 +2190,10 @@ class Executor:
             try:
                 return D.device_scan_aggregate(
                     self.session, cols, condition, computes, list(plan.keys), list(plan.aggs),
-                    max_groups=conf.agg_max_groups,
+                    max_groups=conf.agg_max_groups, cap_floor=conf.agg_capacity_floor,
                 )
+            except D.GroupCapacityExceeded:
+                raise  # more groups than maxGroups is the same count for the engine below
             except D.DeviceUnsupported:
                 if not plan.keys or computes:
                     raise
@@ -2175,7 +2215,7 @@ class Executor:
         """Join tiers in order: bucketed SMJ (device or host spans), broadcast
         hash stream, then the generic host merge. Each tier that runs is a
         child span named after its dispatch detail."""
-        fallback = None
+        fallback = chosen = None
         if not with_file_names and self.session.conf.device_execution_enabled:
             # deviceExecution=False is the kill switch back to the pandas
             # merge below — it routes around the whole bucketed-SMJ stack
@@ -2186,18 +2226,24 @@ class Executor:
                 return D.dispatch_bucketed_join(self.session, plan)
             except D.DeviceUnsupported:
                 pass  # next tier: broadcast hash join
-            with spans.span("join-broadcast-hash-stream", cat="exec") as tier:
-                try:
-                    return JS.dispatch_broadcast_join(self, plan)
-                except D.DeviceUnsupported:
-                    trace.fallback("join", "unsupported")
-                    tier.set(fallback="unsupported")
-                    fallback = "unsupported"
+            spec = JS.broadcast_spec(self.session, plan)
+            if spec is not None and JS.probes_group_table(plan, spec):
+                chosen = "group-table"  # a decision, not a fallback: nothing is counted as one
+            else:
+                with spans.span("join-broadcast-hash-stream", cat="exec") as tier:
+                    try:
+                        return JS.dispatch_broadcast_join(self, plan, spec)
+                    except D.DeviceUnsupported:
+                        trace.fallback("join", "unsupported")
+                        tier.set(fallback="unsupported")
+                        fallback = "unsupported"
         # the host tier: its span's own time (children are the two sides'
         # scans) is the pandas merge and the payload gathers
         with spans.span("join-generic-merge", cat="exec") as tier:
             if fallback:
                 tier.set(fallback=fallback)
+            if chosen:
+                tier.set(chosen=chosen)
             trace.record("join", "generic-merge")
             return self._generic_merge_join(plan, with_file_names)
 

@@ -175,6 +175,21 @@ def broadcast_spec(session, plan: L.Join) -> Optional[BroadcastSpec]:
     return BroadcastSpec(build_is_left, lkeys, rkeys)
 
 
+def probes_group_table(plan: L.Join, spec: BroadcastSpec) -> bool:
+    """The side ``spec`` would probe with is an aggregate's group table under
+    row-wise wrappers: one host batch whose rows are groups (at most
+    ``hyperspace.exec.agg.maxGroups`` of them from a device aggregate),
+    whatever its leaf files weigh. There is nothing to stream, and probing it
+    on the device is an upload, two programs queued behind whatever runs
+    there and a download, for a merge the host does in milliseconds (TPC-H
+    Q15: 100,000 suppliers against 100,000 groups). The executor's
+    materialized join merges it on the host, by choice."""
+    side = plan.right if spec.build_is_left else plan.left
+    while isinstance(side, (L.Project, L.Rename, L.Filter, L.Sort, L.Limit)):
+        side = side.child
+    return isinstance(side, L.Aggregate)
+
+
 # --------------------------------------------------------------------------
 # device programs
 # --------------------------------------------------------------------------
@@ -712,11 +727,11 @@ def _shared_build_side(session, build_plan, build_cols: List[str], bkeys: List[s
     )
 
 
-def dispatch_broadcast_join(executor, plan: L.Join) -> B.Batch:
+def dispatch_broadcast_join(executor, plan: L.Join, spec: Optional[BroadcastSpec]) -> B.Batch:
     """Materialized entry point (executor._exec_join's middle tier, between
-    the bucketed SMJ and the generic pandas merge): fold the stream
-    incrementally, closing the generator on any exit."""
-    spec = broadcast_spec(executor.session, plan)
+    the bucketed SMJ and the generic pandas merge; ``spec`` is
+    :func:`broadcast_spec`'s answer): fold the stream incrementally, closing
+    the generator on any exit."""
     if spec is None:
         raise DeviceUnsupported("join has no broadcastable side")
     gen = stream_broadcast_join(executor, plan, spec)

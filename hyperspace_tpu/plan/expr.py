@@ -515,6 +515,16 @@ def subquery_scope():
             _subquery_scope.cache = None
 
 
+def request_memo() -> Optional[dict]:
+    """The memo of the outermost ``execute`` this thread is inside, or None
+    outside one. A request's executors (the outer plan's, a scalar
+    subquery's, a join side's) share it: subquery results are kept under the
+    expression's ``id``, and the executor keeps a grouped aggregate over a
+    scan under its plan's fingerprint, so that a CTE read twice is ONE
+    evaluation and ``x = (select max(x) ...)`` compares a value with itself."""
+    return getattr(_subquery_scope, "cache", None)
+
+
 class NullableBool:
     """Three-valued boolean result (Kleene logic): ``value`` where known,
     ``unknown`` marking SQL-NULL positions. Produced by comparisons against a

@@ -86,11 +86,12 @@ def _reference(frame: pd.DataFrame, keys, aggs) -> pd.DataFrame:
     return out.drop(columns="__size")
 
 
-def _run(sess, batch, condition, computes, keys, aggs):
+def _run(sess, batch, condition, computes, keys, aggs, program="grouped-agg-dense"):
     cols = D.ScanColumns(sess, None, sorted(batch), lambda: batch)
-    before = REGISTRY.counter("hs_agg_groups_total", "").value
+    counter = REGISTRY.counter("hs_agg_groups_total", "", program=program)
+    before = counter.value
     got = D.device_scan_aggregate(sess, cols, condition, computes, list(keys), list(aggs), max_groups=0)
-    return got, REGISTRY.counter("hs_agg_groups_total", "").value - before
+    return got, counter.value - before
 
 
 def _same(got: dict, want: pd.DataFrame, keys, aggs, int_inputs=("i", "big")) -> None:
@@ -169,6 +170,15 @@ def test_the_one_pass_program_answers_as_pandas_does(sess, case):
 
 
 def test_a_sixty_fifth_group_is_not_the_dense_programs(sess):
+    """Past 64 groups the dictionary codes are keys like any integer: the
+    keyed program (``grouped-agg-keyed``) answers, the dense one counts none."""
     batch = _batch(9000, {"k1": (_labels(13), 0.0), "k2": (_labels(5), 0.0)})
     with pytest.raises(D.DeviceUnsupported, match="65 dictionary groups"):
-        _run(sess, batch, hst.col("d") <= 80, [], ["k1", "k2"], SLOT_AGGS[:3])
+        D._dense_key_plan(["k1", "k2"], {k: D.encode_column(batch[k])[1] for k in ("k1", "k2")}, 0)
+    dense = REGISTRY.counter("hs_agg_groups_total", "", program="grouped-agg-dense")
+    before = dense.value
+    got, counted = _run(sess, batch, hst.col("d") <= 80, [], ["k1", "k2"], SLOT_AGGS[:3], program="grouped-agg-keyed")
+    frame = pd.DataFrame(batch)
+    want = _reference(frame[frame.d <= 80], ["k1", "k2"], SLOT_AGGS[:3])
+    _same(got, want, ["k1", "k2"], SLOT_AGGS[:3])
+    assert counted == len(want) == 65 and dense.value == before

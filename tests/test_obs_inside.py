@@ -466,7 +466,8 @@ class TestLaunchAndWait:
     @pytest.mark.parametrize("shape, programs, tier", [
         ("filter", ["fused-filter"], "filter-mask"),
         ("fused-aggregate", ["fused-agg"], "agg-device-fused-scan"),
-        ("grouped-aggregate", ["grouped-agg-chunk"], "agg-device-fold"),
+        ("grouped-aggregate", ["grouped-agg-keyed", "grouped-agg-keyed-probe"], "agg-device-grouped-scan"),
+        ("grouped-aggregate-float-key", ["grouped-agg-chunk"], "agg-device-fold"),
     ])
     def test_a_dispatch_is_one_launch_span_and_one_count_on_its_request_s_tree(self, indexed, shape, programs, tier):
         sess, df = indexed
@@ -474,6 +475,8 @@ class TestLaunchAndWait:
             "filter": lambda: df.filter(col("c1") > 20).select("c2"),
             "fused-aggregate": lambda: df.filter(col("c1") > 20).agg(s=("c3", "sum")),
             "grouped-aggregate": lambda: df.filter(col("c1") > 20).group_by("c2").agg(s=("c3", "sum")),
+            # a float key is not the keyed program's: the sort-based engine over the host batch
+            "grouped-aggregate-float-key": lambda: df.filter(col("c1") > 20).group_by("c3").agg(s=("c2", "sum")),
         }[shape]()
         before = dispatches()
         with spans.trace("one") as root:
@@ -703,6 +706,7 @@ class TestProgramNames:
             df.filter(col("k") > 3).select("v").collect()
             df.filter(col("k") > 3).agg(s=("v", "sum")).collect()
             df.filter(col("k") > 3).group_by("g").agg(s=("q", "sum")).collect()
+            df.filter(col("k") > 3).group_by("v").agg(s=("q", "sum")).collect()  # a float key: the chunk family
             a = df.select("k", "v")
             b = df.select("k", "g")
             a.join(b, "k").select("v", "g").collect()
@@ -716,4 +720,4 @@ class TestProgramNames:
             hlo_lint.reset_runtime_state()
         assert violations == []
         assert {"fused-filter", "fused-agg"} <= verified, verified
-        assert "grouped-agg-chunk" in verified, verified
+        assert {"grouped-agg-keyed", "grouped-agg-chunk"} <= verified, verified
