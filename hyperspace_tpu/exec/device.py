@@ -2541,12 +2541,28 @@ def _dense_grouped_aggregate(
 #
 # 1. *skip*: one pass over the predicate's columns gives the mask; rows are
 #    taken in blocks of ``_KEYED_BLOCK_ROWS``, and only the blocks that hold a
-#    selected row go on (their numbers sorted to the front, their rows copied
-#    out of every column the query reads). An index is sorted by its key
-#    inside each bucket, so a range on that key selects runs, and the blocks
-#    that go on hold little else: 2.7 M of 67 M rows for a quarter of Q15. A
-#    predicate that selects rows everywhere keeps every block, and the steps
-#    below then walk the whole scan: slower, the same answer.
+#    selected row go on: their numbers sorted to the front, their rows copied
+#    out of every plane the query reads, the predicate's among them (the mask
+#    of the rows that went on is asked of those again: a pred array is packed
+#    four to a word and would have to be widened to be copied). On one device
+#    the copy is a kernel (``ops/kernels.copy_blocks``: one launch, the
+#    numbers prefetched, one DMA a block and a plane from where the plane
+#    lies, whole and one-dimensional; nothing else of it is read). XLA's own
+#    forms all read or write whole planes, or cost by the block: at the cell's
+#    shapes (925 of 16,388 blocks of a 67 M-row plane, v5e, PERF.md PR 41's
+#    ``micro.json``) a ``while`` of ``dynamic_slice`` took 6.0 ms a plane, a
+#    ``gather`` of blocks 6.1, and rows of the plane laid out in blocks 2.4, of
+#    which 1.6 are the slice to whole blocks and the layout itself, 268 MB
+#    copied twice to keep 15. That last form stays on a mesh (``layout``),
+#    where the planes are sharded by rows and the partitioner has to see the
+#    skip. An index file is sorted by its key and a bucket holds one file a
+#    build chunk, so a range on that key selects one run a file: about 2,500
+#    runs of 1,000 rows for a quarter of Q15 at SF 10, 2.5 M of 67 M rows. The
+#    blocks that go on hold a run and its two edges, so the block is as small
+#    as a copy can be, one tile: at 4,096 rows those runs lay in 15.2 M rows,
+#    at 1,024 in 5.2 M. A predicate that selects rows everywhere keeps
+#    every block (``whole``), and the steps below then walk the whole scan:
+#    slower, the same answer.
 # 2. *sort*: the keys of a row become one 32-bit code, mixed-radix over each
 #    key's offset from its least selected value (a NULL date takes the slot
 #    past the greatest), and ``(code, position, inputs)`` is sorted, unstable,
@@ -2566,19 +2582,24 @@ def _dense_grouped_aggregate(
 #    to the front (one operand) and the group table gathered from them, cut
 #    to the capacity bucket. Only that table leaves the chip.
 #
-# Two capacities shape a program, both on the geometric ladder. The blocks
-# that go on are the call's own: a small program, ``grouped-agg-keyed-probe``
-# (the mask over the predicate's columns, nothing else), counts the selected
-# rows and the blocks that hold one, and what it says of a predicate's
-# literals over a scan's files is remembered (it cannot change), so a query
-# that comes again launches the one program. The groups that come back are
-# remembered for the query shape in ``_CAP_HINT_MEMO`` as the chunk family
-# does; a shape seen for the first time is sized by its selected rows and
-# then run once more at the bucket of the groups it found, so that the steady
-# program is compiled by the first call and not by the second.
+# Two capacities shape a program. The blocks that go on are the call's own:
+# a small program, ``grouped-agg-keyed-probe`` (the mask over the predicate's
+# columns, nothing else), counts the selected rows and the blocks that hold
+# one, and what it says of a predicate's literals over a scan's files is
+# remembered (it cannot change), so a query that comes again launches the one
+# program. Every row that goes on is sorted, scanned and gathered from, so
+# the blocks have a ladder of their own (:func:`_keyed_block_capacity`: the
+# count rounded up to four significant bits, an eighth of waste at most,
+# where the shared sqrt(2) ladder took up to 41 % more): predicates of about
+# the same size still share an executable. The groups that come back
+# are on the geometric ladder, remembered for the query shape in
+# ``_CAP_HINT_MEMO`` as the chunk family does; a shape seen for the first
+# time is sized by its selected rows and then run once more at the bucket of
+# the groups it found, so that the steady program is compiled by the first
+# call and not by the second.
 # --------------------------------------------------------------------------
 
-_KEYED_BLOCK_ROWS = 4096
+_KEYED_BLOCK_ROWS = 1024  # one tile of a one-dimensional 32-bit plane: the least a DMA can take
 _KEYED_MAX_INPUTS = 2  # two 32-bit sort operands an input: what compiles in seconds
 _KEYED_NO_CODE = np.uint32(0xFFFFFFFF)
 
@@ -2678,7 +2699,32 @@ def _keyed_probe(pred_fn, pred_cols, total: int):
     return program
 
 
-def _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total: int, cap_blocks: int, cap: int):
+def _keyed_block_capacity(n_hit: int) -> int:
+    """The blocks a program is built for when ``n_hit`` hold a selected row:
+    ``n_hit`` rounded up to four significant bits (5,000 -> 5,120), a step of
+    an eighth at most. The ladder is this program's own: every row that goes on
+    is sorted, so the sqrt(2) steps of :func:`group_capacity`, which the row
+    shapes and group tables share, would sort up to 41 % more rows than hold
+    a selected one for the sake of fewer executables."""
+    n = max(1, int(n_hit))
+    shift = max(0, n.bit_length() - 4)
+    return -(-n >> shift) << shift
+
+
+def _keyed_skip(total: int, cap_blocks: int, mesh) -> Tuple[str, int]:
+    """``(the skip's form, rows that go on behind it)`` for a program built
+    for ``cap_blocks`` of ``total`` padded rows' blocks: ``whole`` where every
+    block goes on (nothing is skipped, the scan itself is sorted); else
+    ``copy`` on one device (the blocks by DMA out of the planes as they lie)
+    and ``layout`` on a mesh (the planes are sharded by rows there, and the
+    partitioner has to see the skip: rows of the plane laid out in blocks)."""
+    n_full, tail = divmod(total, _KEYED_BLOCK_ROWS)
+    if cap_blocks >= n_full:
+        return "whole", total
+    return "copy" if mesh.devices.size == 1 else "layout", (cap_blocks + bool(tail)) * _KEYED_BLOCK_ROWS
+
+
+def _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total: int, cap_blocks: int, cap: int, mesh):
     """The traced body of ``grouped-agg-keyed`` for ``total`` padded rows,
     ``cap_blocks`` blocks going on (no fewer than hold a selected row: the
     probe counted them) and a group table of ``cap`` rows. ``after``: (the
@@ -2686,10 +2732,11 @@ def _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total: int, cap_blo
     import jax
     import jax.numpy as jnp
 
+    from hyperspace_tpu.ops.kernels import copy_blocks
+
     block = _KEYED_BLOCK_ROWS
     n_full, tail = divmod(total, block)  # whole blocks, and the rows of the short last one
-    whole = cap_blocks >= n_full  # nothing to skip: the scan itself goes on
-    rows_on = total if whole else (cap_blocks + bool(tail)) * block
+    form, rows_on = _keyed_skip(total, cap_blocks, mesh)
     i64 = jnp.iinfo(jnp.int64)
     pred_cols, after_cols = after
     input_cols = sorted({col for _, col, _ in slots if col is not None})
@@ -2700,29 +2747,36 @@ def _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total: int, cap_blo
 
     def program(cols, lits, n_valid):
         mask, hit = _keyed_mask(pred_fn, pred_cols, total, cols, lits, n_valid)
-        if whole:
+        if form == "whole":
             sub, on = join_columns({c: cols[c] for c in after_cols}), mask
         else:
             with jax.named_scope("skip"):
                 numbers = sort_front(jnp.where(hit, jnp.arange(n_full, dtype=jnp.int32), jnp.int32(n_full)), cap_blocks)
                 rows = jnp.minimum(numbers, n_full - 1)
 
-                def take(plane):
-                    """The blocks that go on, as rows of the column laid out
-                    in blocks, then the short last block, padded."""
-                    out = plane[: n_full * block].reshape(n_full, block)[rows].reshape(cap_blocks * block)
+                def take(planes):
+                    """The blocks that go on of every plane, then its short
+                    last block, padded. The two forms of the skip."""
+                    if form == "copy":
+                        out = copy_blocks(rows, planes, block)
+                    else:
+                        out = [p[: n_full * block].reshape(n_full, block)[rows].reshape(cap_blocks * block) for p in planes]
                     if tail:
-                        out = jnp.concatenate([out, jnp.pad(plane[n_full * block:], (0, block - tail))])
+                        out = [jnp.concatenate([o, jnp.pad(p[n_full * block:], (0, block - tail))]) for o, p in zip(out, planes)]
                     return out
 
-                sub = join_columns({
-                    c: ColumnPlanes(take(v.first), take(v.second)) if isinstance(v, ColumnPlanes) else take(v)
-                    for c, v in cols.items() if c in after_cols
-                })
+                # the predicate's columns go with the rest: the mask is asked of them again
+                planes, columns = jax.tree_util.tree_flatten({c: cols[c] for c in sorted({*pred_cols, *after_cols})})
+                sub = join_columns(jax.tree_util.tree_unflatten(columns, take(planes)))
+                at = (rows[:, None] * block + jnp.arange(block, dtype=jnp.int32)).reshape(cap_blocks * block)
                 on = jnp.broadcast_to((numbers < n_full)[:, None], (cap_blocks, block)).reshape(cap_blocks * block)
-                if tail:
+                if tail:  # the padding of the last block lies past every valid row
+                    at = jnp.concatenate([at, n_full * block + jnp.arange(block, dtype=jnp.int32)])
                     on = jnp.concatenate([on, jnp.ones((block,), bool)])
-                on = on & take(mask)  # the padding of the last block is not selected
+                on = on & (at < n_valid.astype(jnp.int32))
+                if pred_fn is not None:
+                    on = on & pred_fn({c: sub[c] for c in pred_cols}, lits)
+                sub = {c: sub[c] for c in after_cols}
         if comp_fn is not None:
             sub = comp_fn(sub, lits)
         with jax.named_scope("key-encode"):
@@ -2807,6 +2861,19 @@ def _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total: int, cap_blo
     return program
 
 
+def _count_keyed_rows(selected: int, rows_on: int) -> None:
+    """One launch of ``grouped-agg-keyed`` in ``hs_keyed_rows_total{kind}``:
+    ``selected``, the rows its predicate selects (the probe's count), and
+    ``sorted``, the rows that went on behind the skip. Their ratio says how
+    much of what is sorted, scanned and gathered the predicate asked for."""
+    for kind, rows in (("selected", selected), ("sorted", rows_on)):
+        _REGISTRY.counter(
+            "hs_keyed_rows_total",
+            "Rows of the keyed grouped aggregate's launches: selected by the predicate, sorted behind the skip",
+            kind=kind,
+        ).inc(rows)
+
+
 def _keyed_selected(session, cols, dev_cols, pred_fn, pred_cols, lit_values, skeleton, total: int):
     """``(rows the predicate selects, whole blocks that hold one)``: what the
     keyed program's shape follows from. One launch of the probe the first
@@ -2854,8 +2921,8 @@ def _keyed_grouped_aggregate(
     n_blocks = total // _KEYED_BLOCK_ROWS  # whole blocks: the short last one always goes on
 
     n_selected, n_hit = _keyed_selected(session, cols, dev_cols, pred_fn, after[0], lit_values, skeleton, total)
-    cap_blocks = min(n_blocks, group_capacity(n_hit, 4))
-    rows_on = total if cap_blocks >= n_blocks else (cap_blocks + 1) * _KEYED_BLOCK_ROWS
+    cap_blocks = min(n_blocks, _keyed_block_capacity(n_hit))
+    skip, rows_on = _keyed_skip(total, cap_blocks, mesh)
     hint = (cols.scan_key, tuple(group_keys), tuple((fn, c) for _, fn, c in aggs))
     groups_hint = _CAP_HINT_MEMO.get(hint) if cols.scan_key is not None else None
     # a shape no call has answered yet is sized by its selected rows
@@ -2867,7 +2934,7 @@ def _keyed_grouped_aggregate(
     shapes = tuple(dev_cols[r].shape for r in sorted(dev_cols))
     while True:
         cap = min(cap, group_capacity(rows_on, cap_floor))  # no more groups than rows going on
-        program = _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total, cap_blocks, cap)
+        program = _keyed_program(pred_fn, comp_fn, key_plan, slots, after, total, cap_blocks, cap, mesh)
         key = _program_key(f"gkeyed[{total},{cap_blocks},{cap}]:{base}", mesh)  # the body is built for its shapes
         jitted = _cached_predicate_jit(key, program, "grouped-agg-keyed")
         first = _note_compile(key, shapes)
@@ -2878,6 +2945,7 @@ def _keyed_grouped_aggregate(
         count_column_forms(dev_cols.values())
         with launch("grouped-agg-keyed"):
             out = jitted(dev_cols, lit_values, np.int64(n))
+        _count_keyed_rows(n_selected, rows_on)
         n_groups, fits = (int(v) for v in fetch(out[0], "agg-table", "grouped-agg-keyed"))
         _observe_program("grouped-agg-keyed", first, t0)
         if not fits:
@@ -2922,7 +2990,10 @@ def _keyed_grouped_aggregate(
     result = {k: result[k] for k in group_keys}
     result.update(_final_columns(aggs, refs, input_dtypes, [s[:n_groups][order] for s in slot_out]))
     _count_groups("grouped-agg-keyed", n_groups)
-    _annotate_tier(program="grouped-agg-keyed", groups=n_groups, capacity=cap, selected_rows=n_selected, blocks=cap_blocks)
+    _annotate_tier(
+        program="grouped-agg-keyed", groups=n_groups, capacity=cap,
+        selected_rows=n_selected, blocks=cap_blocks, rows_on=rows_on, skip=skip,
+    )
     return result
 
 

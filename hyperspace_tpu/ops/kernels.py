@@ -1,6 +1,7 @@
 """Pallas TPU kernels for the framework's hot device ops.
 
-Two kernels back the build paths (guide: /opt/skills/guides/pallas_guide.md):
+Two kernels back the build paths and one the keyed grouped aggregate (guide:
+/opt/skills/guides/pallas_guide.md):
 
 - ``segmented_min_max`` — one-pass fused min+max over a (segments, width)
   matrix, the device program behind MinMaxSketch builds: one row per source
@@ -10,6 +11,10 @@ Two kernels back the build paths (guide: /opt/skills/guides/pallas_guide.md):
 - ``bucket_histogram`` — rows-per-bucket counts for write planning and skew
   detection in the bucketed index build (the device analogue of counting
   Spark's shuffle partition sizes; ref: HS/index/covering/CoveringIndex.scala:54-69).
+- ``copy_blocks`` — the numbered blocks of several one-dimensional arrays,
+  copied out by DMA from where the arrays lie: the skip step of
+  ``grouped-agg-keyed`` (exec/device.py), which reads only the blocks that
+  hold a selected row.
 
 On the ``cpu`` platform (tests, virtual meshes) the kernels run in interpreter
 mode with identical numerics; every other platform compiles them through
@@ -298,3 +303,77 @@ def bucket_histogram(bucket_ids, num_buckets: int):
     nb_p = -(-num_buckets // _LANES) * _LANES
     out = _hist_call(jnp.asarray(padded), nb_p, _use_interpret())
     return np.asarray(out)[:num_buckets, 0]
+
+
+# ---------------------------------------------------------------------------
+# numbered blocks out of one-dimensional arrays
+# ---------------------------------------------------------------------------
+#
+# The arrays stay in HBM as they are, whole and one-dimensional: no slice, no
+# reshape, nothing of them is read but the blocks asked for. Block ``i`` of
+# every output is block ``numbers[i]`` of its array, one DMA a block and an
+# array, HBM to HBM, up to ``_COPY_WINDOW`` blocks in flight (a semaphore a
+# copy in flight, and the chip holds about 500 of them). ``block`` is a
+# multiple of the 1,024 elements of a one-dimensional 32-bit tile, so every
+# copy starts and ends on a tile; Mosaic has no 64-bit types, so an 8-byte
+# array goes through the interpreter only (the CPU, where columns stay whole)
+# and every index is pinned to int32 (x64 is on: a Python int would be int64).
+
+_COPY_WINDOW = 16
+_COPY_SEMAPHORES = 384
+
+
+def _copy_blocks_kernel(numbers_ref, *refs, n_arrays: int, block: int):
+    srcs, dsts, sems = refs[:n_arrays], refs[n_arrays : 2 * n_arrays], refs[2 * n_arrays]
+    n = numbers_ref.shape[0]
+    in_flight = sems.shape[0]
+    window = jnp.int32(in_flight)
+
+    def copies(i):
+        start = pl.multiple_of(numbers_ref[i] * jnp.int32(block), block)
+        to = pl.multiple_of(i * jnp.int32(block), block)
+        return [
+            pltpu.make_async_copy(src.at[pl.ds(start, block)], dst.at[pl.ds(to, block)], sems.at[i % window, jnp.int32(a)])
+            for a, (src, dst) in enumerate(zip(srcs, dsts))
+        ]
+
+    def start(i, carry):
+        @pl.when(i >= window)
+        def _():
+            for c in copies(i - window):  # its slot's semaphores are taken next
+                c.wait()
+
+        for c in copies(i):
+            c.start()
+        return carry
+
+    def drain(i, carry):
+        for c in copies(i):
+            c.wait()
+        return carry
+
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(n), start, jnp.int32(0))
+    jax.lax.fori_loop(jnp.int32(max(0, n - in_flight)), jnp.int32(n), drain, jnp.int32(0))
+
+
+def copy_blocks(numbers, arrays, block: int):
+    """``[a.reshape(-1, block)[numbers].reshape(-1) for a in arrays]`` for
+    one-dimensional device arrays of one length, without laying any of them
+    out in blocks: traced, one kernel launch for all of them. ``numbers`` is
+    int32, each below ``len(a) // block``."""
+    arrays = tuple(arrays)
+    in_flight = max(1, min(_COPY_WINDOW, _COPY_SEMAPHORES // len(arrays)))
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        partial(_copy_blocks_kernel, n_arrays=len(arrays), block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[any_space] * len(arrays),
+            out_specs=[any_space] * len(arrays),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((in_flight, len(arrays)))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((numbers.shape[0] * block,), a.dtype) for a in arrays],
+        interpret=_use_interpret(),
+        name="hs_copy_blocks",
+    )(numbers, *arrays)

@@ -25,7 +25,10 @@ for them are taken as they were handed to ``jit``. Then:
 The topology is described inside a module-scoped fixture, never at import
 (one process at a time may load the TPU's library; every xdist worker
 imports this file), and the tests that need it skip where it cannot be
-described. This is the only test file that describes one.
+described. This is the only test file that describes one, so the keyed
+program's copy kernel (``ops/kernels.copy_blocks``) is compiled here too: at
+the benchmark's planes, Mosaic takes it (a block starts and ends on a tile of
+a one-dimensional plane), and the module it makes reads no plane whole.
 """
 
 import re
@@ -285,3 +288,33 @@ def test_the_row_count_chooses_the_width_of_the_bookkeeping(q1, rows, width):
         assert operands.count("i32") == 6 and operands.count("i64") == 1, operands
     else:
         assert operands.count("i64") == 7 and "i32" not in operands, operands
+
+
+@pytest.mark.parametrize("block, n_planes", [(D._KEYED_BLOCK_ROWS, 8), (4096, 8), (D._KEYED_BLOCK_ROWS, 40)])
+def test_the_copy_kernel_compiles_for_the_chip_and_reads_no_plane_whole(one_chip, monkeypatch, block, n_planes):
+    """Eight planes as ``sf10-rollup`` holds them (and forty: fewer copies in
+    flight, for the chip's semaphores), 768 blocks out of each: one Mosaic
+    call, and nothing else of the module has a row-length operand."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from hyperspace_tpu.ops import kernels
+
+    monkeypatch.setattr(kernels, "_use_interpret", lambda: False)
+    dtypes = ([jnp.float32] * 4 + [jnp.uint32, jnp.int32] * 2) * (n_planes // 8)
+    planes = [jax.ShapeDtypeStruct((SF10_PADDED_ROWS,), dt, sharding=one_chip) for dt in dtypes]
+    numbers = jax.ShapeDtypeStruct((768,), jnp.int32, sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda n, p: kernels.copy_blocks(n, p, block)).lower(numbers, planes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    entry = text[text.index("\nENTRY "):]
+    entry = [l.strip() for l in entry[:entry.index("\n}")].splitlines() if " = " in l]
+    calls = [l for l in entry if "custom-call(" in l and "tpu_custom_call" in l]
+    assert len(calls) == 1 and calls[0].count(f"[{768 * block}]") == len(planes), calls
+    others = [l for l in entry if l not in calls and " parameter(" not in l and f"[{SF10_PADDED_ROWS}]" in l]
+    assert not others, others

@@ -23,7 +23,15 @@ keys, counts and integer sums exact, floats to 1e-12. The cases:
   predicate's literals over a scan, and a call that selects every block
   leaves the shape of a narrow one as it was;
 - whole columns and 32-bit planes give the same answer;
-- the same on one device and on a mesh of four virtual devices.
+- the same on one device and on a mesh of four virtual devices;
+- the skip: runs that start and end inside a block, end in the short last
+  block, lie in adjacent blocks, hit one block or every block, with NULL
+  dates and NaN inputs in the blocks taken; one device copies the blocks out
+  of the columns as they lie (``copy``, the Pallas kernel, interpreted here),
+  a mesh lays the columns out in blocks (``layout``), read from the tier
+  span; the blocks' own ladder wastes an eighth at most and leaves the shared
+  ladders alone; no operation of the copy form's program outside the filter
+  takes a whole column and gives more than a block.
 """
 
 import numpy as np
@@ -32,6 +40,7 @@ import pytest
 
 import hyperspace_tpu as hst
 from hyperspace_tpu.exec import device as D
+from hyperspace_tpu.obs import spans
 from hyperspace_tpu.obs.metrics import REGISTRY
 from hyperspace_tpu.parallel.mesh import make_mesh
 
@@ -96,6 +105,13 @@ def _run(sess, batch, condition, computes, keys, aggs, max_groups=1 << 20, cap_f
     got = D.device_scan_aggregate(sess, cols, condition, computes, list(keys), list(aggs),
                                   max_groups=max_groups, cap_floor=cap_floor)
     return got, _groups_counted() - before
+
+
+def _traced_run(sess, batch, condition, computes, keys, aggs):
+    """``(answer, what the program said of itself on the tier's span)``."""
+    with spans.trace("keyed") as root:
+        got, _ = _run(sess, batch, condition, computes, keys, aggs)
+    return got, dict(root.attrs)
 
 
 def _same(got: dict, want: pd.DataFrame, keys, aggs, int_inputs=("i", "big")) -> None:
@@ -228,7 +244,7 @@ def test_a_call_over_every_block_leaves_a_narrow_calls_shape_as_it_was(sess):
     the probe counts them once for a predicate's literals over a scan's files,
     and a query over the whole scan in between changes nothing for a narrow
     one (same program, no compile, no second probe)."""
-    batch = _batch(60000, 300)
+    batch = _batch(D.bucket_rows(60000), 300)  # no padded block without a row: the fine ladder would leave it out
     aggs = [("total", "sum", "rev"), ("best", "max", "rev")]  # a shape no other test compiles
     key = (("blocks", int(sess.mesh.devices.size)),)
     narrow = (hst.col("d") >= 40) & (hst.col("d") < 44)
@@ -288,11 +304,155 @@ def test_planes_and_whole_columns_give_the_same_answer(sess, keys, monkeypatch):
     monkeypatch.setattr(D, "computes_in_pairs", lambda mesh: True)
     counted = REGISTRY.counter("hs_device_program_columns_total", "", form="planes")
     before = counted.value
-    planes, _ = _run(sess, batch, hst.col("d") <= 70, REVENUE, keys, REVENUE_AGGS + [("mx", "max", "i")])
+    planes, said = _traced_run(sess, batch, hst.col("d") <= 70, REVENUE, keys, REVENUE_AGGS + [("mx", "max", "i")])
     assert counted.value > before, "the program was handed planes"
+    assert said["skip"] == ("copy" if sess.mesh.devices.size == 1 else "layout"), "some blocks were skipped"
     assert list(planes) == list(whole)
     for c in whole:
         if np.asarray(whole[c]).dtype.kind == "f":
             np.testing.assert_allclose(planes[c], whole[c], rtol=1e-13, atol=0, err_msg=c)
         else:
             assert np.array_equal(planes[c], whole[c]), c
+
+
+# ---------------------------------------------------------------------------
+# the skip: which blocks go on, and how they are taken
+# ---------------------------------------------------------------------------
+
+BLOCK = D._KEYED_BLOCK_ROWS
+SKIP_ROWS = D.bucket_rows(10 * BLOCK)  # fills its padded length: ten whole blocks and more, and a short last one
+
+
+def _row_numbers(b):
+    """``d`` is the row's number: a range on it selects exactly those rows."""
+    return dict(b, d=np.arange(len(b["d"]), dtype=np.int64))
+
+
+def _between(lo, hi):
+    return (hst.col("d") >= lo) & (hst.col("d") < hi)
+
+
+# no stddev: a group of a handful of rows cancels to 1e-12 of its sum of squares, which is the tolerance
+SKIP_AGGS = [a for a in SLOT_AGGS if a[1] != "stddev_samp"]
+SKIP_CASES = {
+    # name: (condition, keys, aggs, patch, blocks that hold a selected row; None: every one)
+    "a-run-that-starts-and-ends-inside-a-block": (_between(BLOCK + 100, 3 * BLOCK + 900), ["k"], SKIP_AGGS, None, 3),
+    "a-run-that-ends-in-the-short-last-block": (hst.col("d") >= SKIP_ROWS // BLOCK * BLOCK - 500, ["k"], SKIP_AGGS, None, 1),
+    "two-runs-in-adjacent-blocks": (_between(2 * BLOCK - 100, 2 * BLOCK) | _between(2 * BLOCK + 10, 2 * BLOCK + 500),
+                                    ["k"], SKIP_AGGS, None, 2),
+    "a-single-block": (_between(3 * BLOCK + 5, 3 * BLOCK + 50), ["k"], SKIP_AGGS, None, 1),
+    "the-first-and-the-last-whole-block": (_between(0, 10) | _between(SKIP_ROWS // BLOCK * BLOCK - 10, SKIP_ROWS // BLOCK * BLOCK),
+                                           ["k"], SKIP_AGGS, None, 2),
+    "every-block": (hst.col("d") >= 0, ["k"], SKIP_AGGS, None, None),
+    "null-dates-and-nan-inputs-in-the-blocks-taken": (_between(2 * BLOCK - 700, 5 * BLOCK + 3), ["day"], SKIP_AGGS[:6], _nat_days, 5),
+}
+
+
+@pytest.mark.parametrize("case", list(SKIP_CASES))
+def test_the_skip_takes_the_blocks_that_hold_a_selected_row(sess, case):
+    condition, keys, aggs, patch, n_hit = SKIP_CASES[case]
+    batch = _row_numbers(_batch(SKIP_ROWS, 300))
+    if patch is not None:
+        batch = patch(batch)
+    assert SKIP_ROWS % BLOCK and D.bucket_rows(SKIP_ROWS) == SKIP_ROWS, "a short last block, all of it rows"
+    selected = REGISTRY.counter("hs_keyed_rows_total", "", kind="selected")
+    sorted_ = REGISTRY.counter("hs_keyed_rows_total", "", kind="sorted")
+    runs = REGISTRY.counter("hs_device_dispatches_total", "", program="grouped-agg-keyed")
+    before = (selected.value, sorted_.value, runs.value)
+    got, said = _traced_run(sess, batch, condition, [], keys, aggs)
+    matched = _matched(pd.DataFrame(batch), condition)
+    _same(got, _reference(matched, keys, aggs), keys, aggs)
+    launches = runs.value - before[2]
+    one_device = sess.mesh.devices.size == 1
+    if n_hit is None:
+        padded = SKIP_ROWS + (-SKIP_ROWS) % sess.mesh.devices.size
+        assert said["skip"] == "whole" and said["rows_on"] == padded and said["blocks"] == padded // BLOCK
+    else:
+        assert said["skip"] == ("copy" if one_device else "layout")
+        assert said["blocks"] == n_hit, "under sixteen blocks the ladder is exact"
+        assert said["rows_on"] == (n_hit + 1) * BLOCK, "and the short last block"
+    assert said["selected_rows"] == len(matched)
+    assert selected.value - before[0] == launches * len(matched)
+    assert sorted_.value - before[1] == launches * said["rows_on"]
+
+
+def test_the_blocks_ladder_wastes_an_eighth_at_most_and_leaves_the_shared_ladders_alone():
+    counts = np.arange(1, 20001)
+    caps = np.asarray([D._keyed_block_capacity(int(n)) for n in counts])
+    assert (caps >= counts).all() and (caps <= counts * 9 / 8 + 1).all()
+    assert (caps[:16] == counts[:16]).all() and D._keyed_block_capacity(705) == 768 and D._keyed_block_capacity(0) == 1
+    assert (np.diff(caps) >= 0).all()
+    # two counts within 3 % of each other share an executable more often than not
+    near = [(int(n), int(m)) for n in range(100, 20001, 7) for m in (int(n * 1.03),)]
+    shared = sum(D._keyed_block_capacity(n) == D._keyed_block_capacity(m) for n, m in near)
+    assert shared > len(near) / 2, (shared, len(near))
+    # the ladders every other shape shares are what they were
+    assert [D.group_capacity(n, 4) for n in (1, 4, 5, 705, 100000)] == [4, 4, 6, 925, 118642]
+    assert [D.group_capacity(n, 64) for n in (1, 100, 3000, 100000)] == [64, 129, 4170, 133494]
+    assert [D.bucket_rows(n) for n in (1, 4096, 4097, 20000, 60_000_000)] == [4096, 4096, 5793, 23175, 67126100]
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs it calls, but a kernel's
+    own body (its operands are references, not values)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.fixture
+def one_device(tmp_path):
+    s = hst.Session(conf={hst.keys.SYSTEM_PATH: str(tmp_path / "indexes")})
+    s.set_mesh(make_mesh(1))
+    hst.set_session(s)
+    yield s
+    hst.set_session(None)
+
+
+def test_the_copy_form_lays_no_whole_column_out(one_device, monkeypatch):
+    """The whole-column copies cannot come back unnoticed: in the narrow form
+    on one device, outside the filter (the mask and the blocks it hits), no
+    ``slice``, ``reshape``, ``gather`` or ``dynamic_slice`` takes an operand
+    of the scan's padded length and gives more than a block of it (the short
+    last block's rows are sliced out: less than a block)."""
+    import jax
+
+    sess = one_device
+    batch = _row_numbers(_batch(SKIP_ROWS, 300))
+    traced = {}
+    inner = D._cached_predicate_jit
+
+    def keep(key, program, family):
+        jitted = inner(key, program, family)
+        if family == "grouped-agg-keyed":
+            traced["program"] = program
+        return jitted
+
+    monkeypatch.setattr(D, "_cached_predicate_jit", keep)
+    monkeypatch.setattr(D, "computes_in_pairs", lambda mesh: True)  # planes, as the chip holds them
+    cols = D.ScanColumns(sess, None, sorted(batch), lambda: batch)
+    dev_cols, _ = cols.on_device()
+    _, said = _traced_run(sess, batch, _between(BLOCK + 100, 3 * BLOCK + 900), REVENUE, ["k"], REVENUE_AGGS)
+    assert said["skip"] == "copy"
+    taken = {c: dev_cols[c] for c in ("a", "b", "d", "k")}
+    assert all(isinstance(v, D.ColumnPlanes) for v in taken.values())
+    lits = [np.int64(0)] * 8
+    closed = jax.make_jaxpr(traced["program"])(taken, lits, np.int64(SKIP_ROWS))
+    kinds, whole = set(), []
+    for eqn in _equations(closed.jaxpr):
+        kinds.add(eqn.primitive.name)
+        if eqn.primitive.name not in ("slice", "reshape", "gather", "dynamic_slice"):
+            continue
+        if "filter" in str(eqn.source_info.name_stack):
+            continue
+        if any(getattr(v.aval, "shape", ()) == (SKIP_ROWS,) for v in eqn.invars) and \
+                max(int(np.prod(v.aval.shape)) for v in eqn.outvars) > BLOCK:
+            whole.append(str(eqn)[:200])
+    assert "pallas_call" in kinds and "sort" in kinds
+    assert not whole, whole

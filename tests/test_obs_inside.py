@@ -495,6 +495,26 @@ class TestLaunchAndWait:
         assert "device-launch" in kids and kids.index("device-launch") < kids.index("device-wait")
         assert not [ev for sp in root.walk() for ev in sp.events if ev[0] == "device-program"]
 
+    def test_a_keyed_launch_counts_its_selected_and_sorted_rows_and_the_tier_says_how_it_skipped(self, indexed):
+        sess, df = indexed
+        q = df.filter(col("c1") > 20).group_by("c2").agg(s=("c3", "sum"))
+        kept = int((df.select("c1").collect()["c1"] > 20).sum())
+        before = {k: counter("hs_keyed_rows_total", kind=k) for k in ("selected", "sorted")}
+        launched = counter("hs_device_dispatches_total", program="grouped-agg-keyed")
+        with spans.trace("keyed") as root:
+            q.collect()
+        launched = counter("hs_device_dispatches_total", program="grouped-agg-keyed") - launched
+        tier = root.find("agg-device-grouped-scan")[0]
+        assert tier.attrs["program"] == "grouped-agg-keyed" and tier.attrs["selected_rows"] == kept
+        # 1,000 rows lie in the first block of the padded scan: it alone goes on
+        from hyperspace_tpu.exec.device import _KEYED_BLOCK_ROWS
+
+        assert tier.attrs["skip"] == ("copy" if sess.mesh.devices.size == 1 else "layout")
+        assert tier.attrs["blocks"] == 1 and tier.attrs["rows_on"] == _KEYED_BLOCK_ROWS >= 1000
+        assert launched >= 1
+        assert counter("hs_keyed_rows_total", kind="selected") - before["selected"] == launched * kept
+        assert counter("hs_keyed_rows_total", kind="sorted") - before["sorted"] == launched * tier.attrs["rows_on"]
+
     def test_a_join_s_programs_launch_on_the_joining_request_s_tree(self, tmp_path):
         rng = np.random.default_rng(3)
         left, right = tmp_path / "l", tmp_path / "r"

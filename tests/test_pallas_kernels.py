@@ -2,13 +2,14 @@
 
 The kernels are the device programs behind MinMaxSketch builds and bucketed
 write planning (ops/kernels.py); off-TPU they run in the pallas interpreter
-with identical numerics.
+with identical numerics. ``copy_blocks`` is the skip of the keyed grouped
+aggregate (exec/device.py): numbered blocks out of one-dimensional arrays.
 """
 
 import numpy as np
 import pytest
 
-from hyperspace_tpu.ops.kernels import bucket_histogram, segmented_min_max
+from hyperspace_tpu.ops.kernels import _COPY_WINDOW, bucket_histogram, copy_blocks, segmented_min_max
 
 
 def test_segmented_min_max_matches_numpy():
@@ -79,3 +80,26 @@ def test_minmax_sketch_build_uses_exact_int_bounds(tmp_path):
         assert sorted(zip(mins, maxs)) == sorted(expected)
     finally:
         hst.set_session(None)
+
+
+@pytest.mark.parametrize("n_numbers", [1, _COPY_WINDOW - 1, _COPY_WINDOW, _COPY_WINDOW + 1, 3 * _COPY_WINDOW + 5])
+def test_copy_blocks_is_the_numbered_blocks_of_every_array(n_numbers):
+    """Fewer blocks than copies in flight, exactly as many, and several
+    windows; a block asked for twice; a short last block never read; 4-byte
+    planes and the 8-byte columns a CPU keeps whole."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.config.update("jax_enable_x64", True)
+    block, n_blocks = 1024, 70
+    rng = np.random.default_rng(n_numbers)
+    total = n_blocks * block + 300
+    arrays = [rng.integers(0, 2**31, total).astype(dt) for dt in (np.uint32, np.int32, np.float32, np.int64, np.float64)]
+    numbers = np.sort(rng.integers(0, n_blocks, n_numbers)).astype(np.int32)
+    if n_numbers > 2:
+        numbers[1] = numbers[0]
+    got = jax.jit(lambda n, a: copy_blocks(n, a, block))(jnp.asarray(numbers), [jnp.asarray(a) for a in arrays])
+    assert len(got) == len(arrays)
+    for g, a in zip(got, arrays):
+        assert g.dtype == a.dtype
+        assert np.array_equal(np.asarray(g), a[: n_blocks * block].reshape(n_blocks, block)[numbers].reshape(-1))
