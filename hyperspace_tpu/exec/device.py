@@ -43,6 +43,7 @@ from hyperspace_tpu.exec.file_identity import committed_keys, plan_identity
 from hyperspace_tpu.plan import logical as L
 from hyperspace_tpu.plan.expr import (
     BinaryOp,
+    Case,
     Col,
     Expr,
     In,
@@ -304,20 +305,44 @@ def _fold_const(e: Expr) -> Expr:
     return Lit(arr.reshape(-1)[0] if arr.ndim else arr[()])
 
 
-def _build_num(e: Expr, codecs: Dict[str, ColumnCodec], slots: "_LitSlots"):
+def in_device_language(e: Expr) -> bool:
+    """Whether every node of ``e`` is one the compilers below know: columns,
+    literals, arithmetic, compares, AND/OR/NOT, IS NULL, IN and CASE. What
+    their types allow is the compilers' to say (DeviceUnsupported); this asks
+    nothing of the data, so a tier can turn a ``LIKE``, a cast or a function
+    away before it reads a file."""
+    if not isinstance(e, (Col, Lit, BinaryOp, Not, IsNull, In, Case)):
+        return False
+    return all(in_device_language(c) for c in e.children())
+
+
+def _build_num(e: Expr, codecs: Dict[str, ColumnCodec], slots: "_LitSlots", datetimes: bool = True):
     """Numeric-valued subexpression -> device fn ``f(cols, lits)``; its
     literals take slots of ``slots``. The one numeric expression compiler:
     predicates (``compile_predicate``) and computed aggregate inputs
-    (``compile_computes``) build through it."""
+    (``compile_computes``) build through it. ``datetimes``: whether a datetime
+    column may stand as a value (its int64 epoch view): a compare's side may,
+    a computed input may not (a per-column unit has no arithmetic).
+
+    ``CASE WHEN c THEN v ... [ELSE d] END`` is a chain of selects, the first
+    condition that is definitely true winning as on the host (``np.select``
+    over ``as_bool_mask``): the conditions are boolean expressions of the
+    predicate compiler, string equality over dictionary codes included, and
+    may read datetimes whatever ``datetimes`` says of the values. Without an
+    ELSE the default is NULL, a float NaN, which the sums and counts skip."""
     e = _fold_const(e)
     if isinstance(e, Col):
         codec = codecs[e.name]
         if codec.kind == "string":
             raise DeviceUnsupported("string column used in numeric context")
+        if codec.kind == "datetime" and not datetimes:
+            raise DeviceUnsupported(f"datetime column {e.name!r} used as a number")
         name = e.name
         return lambda cols, lits: cols[name]
     if isinstance(e, Lit):
         v = e.value
+        if v is None:
+            raise DeviceUnsupported("NULL literal in numeric context")
         if isinstance(v, str):
             raise DeviceUnsupported("string literal in numeric context")
         if isinstance(v, np.datetime64):
@@ -325,7 +350,7 @@ def _build_num(e: Expr, codecs: Dict[str, ColumnCodec], slots: "_LitSlots"):
         i = slots.add(_as_lit_scalar(v))
         return lambda cols, lits: lits[i]
     if isinstance(e, BinaryOp) and e.op in ("+", "-", "*", "/", "%"):
-        lf, rf = _build_num(e.left, codecs, slots), _build_num(e.right, codecs, slots)
+        lf, rf = _build_num(e.left, codecs, slots, datetimes), _build_num(e.right, codecs, slots, datetimes)
         op = e.op
         def f(cols, lits):
             l, r = lf(cols, lits), rf(cols, lits)
@@ -339,6 +364,23 @@ def _build_num(e: Expr, codecs: Dict[str, ColumnCodec], slots: "_LitSlots"):
                 return l / r
             return l % r
         return f
+    if isinstance(e, Case):
+        import jax.numpy as jnp
+
+        build_bool = _bool_builder(codecs, slots)
+        branches = [(build_bool(c), _build_num(v, codecs, slots, datetimes)) for c, v in e.branches]
+        otherwise = e.otherwise
+        if isinstance(otherwise, Lit) and otherwise.value is None:
+            otherwise = None  # ELSE NULL is no ELSE
+        default = _build_num(otherwise, codecs, slots, datetimes) if otherwise is not None else None
+
+        def case(cols, lits):
+            out = default(cols, lits) if default is not None else jnp.float64(np.nan)
+            for (value, unknown), then in reversed(branches):  # the first true condition wins
+                out = jnp.where(value(cols, lits) & ~unknown(cols, lits), then(cols, lits), out)
+            return out
+
+        return case
     raise DeviceUnsupported(f"unsupported numeric expr {type(e).__name__}")
 
 
@@ -348,16 +390,17 @@ def compile_computes(computes, codecs: Dict[str, ColumnCodec], lit_base: int = 0
     lit_values, skeleton)``: ``f(cols, lits)`` returns ``cols`` with every
     computed column added, to run inside an aggregate program. Literal slots
     start at ``lit_base``, behind the predicate's, so one ``lits`` tuple
-    serves both. Numeric operands only: a datetime operand has a per-column
-    epoch unit and a string none (DeviceUnsupported)."""
+    serves both. Numeric values only: a datetime value has a per-column
+    epoch unit and a string none (DeviceUnsupported); the conditions of a
+    ``CASE`` may read both."""
     slots = _LitSlots(lit_base)
     codecs = dict(codecs)
     fns, parts = [], []
     for name, expr in computes:
         for r in expr.references():
-            if r not in codecs or codecs[r].kind != "numeric":
-                raise DeviceUnsupported(f"computed input {name!r} over non-numeric column {r!r}")
-        fns.append((name, _build_num(expr, codecs, slots)))
+            if r not in codecs:
+                raise DeviceUnsupported(f"computed input {name!r} over unknown column {r!r}")
+        fns.append((name, _build_num(expr, codecs, slots, datetimes=False)))
         parts.append(f"{name}={predicate_skeleton(expr, codecs)}")
         codecs[name] = ColumnCodec("numeric")
 
@@ -370,17 +413,13 @@ def compile_computes(computes, codecs: Dict[str, ColumnCodec], lit_base: int = 0
     return fn, tuple(slots.values), ";".join(parts)
 
 
-def compile_predicate(expr: Expr, codecs: Dict[str, ColumnCodec]):
-    """Compile ``expr`` into ``(f, lit_values)`` where
-    ``f(cols: dict[str, jnp.ndarray], lits: tuple) -> bool mask`` and
-    ``lit_values`` is the concrete argument tuple for this query.
-
-    Raises DeviceUnsupported for shapes outside the device language (string
-    arithmetic, input_file_name(), col-vs-col string compares, ...).
-    """
+def _bool_builder(codecs: Dict[str, ColumnCodec], slots: "_LitSlots"):
+    """``build_bool`` of the predicate compiler over ``codecs``, its literals
+    taking slots of ``slots``: ``build_bool(expr)`` gives the ``(value,
+    unknown)`` Kleene pair of a boolean expression. ``compile_predicate``
+    keeps the definitely-true rows of one; a ``CASE`` of ``_build_num`` asks
+    it for its conditions."""
     import jax.numpy as jnp
-
-    slots = _LitSlots()
 
     def is_string_col(e: Expr) -> bool:
         return isinstance(e, Col) and codecs[e.name].kind == "string"
@@ -564,11 +603,18 @@ def compile_predicate(expr: Expr, codecs: Dict[str, ColumnCodec]):
                 val = _literal_numeric(codec, right.value)
                 i = slots.add(_as_lit_scalar(val))
                 return _compare(lf, lambda cols, lits: lits[i], op, lu=num_unknown_expr(left))
-            # general numeric compare (col-vs-col, arithmetic): datetime
-            # operands have per-column epoch units the generic path cannot
-            # reconcile — reject rather than compare mismatched units
+            # general numeric compare (col-vs-col, arithmetic): two datetime
+            # columns of one unit compare as their epoch views (NaT on either
+            # side is unknown); any other datetime operand has a per-column
+            # epoch unit the generic path cannot reconcile — reject rather
+            # than compare mismatched units
+            same_unit = (
+                isinstance(left, Col) and isinstance(right, Col)
+                and codecs[left.name].kind == codecs[right.name].kind == "datetime"
+                and codecs[left.name].unit == codecs[right.name].unit
+            )
             for side in (left, right):
-                if _has_datetime(side):
+                if _has_datetime(side) and not same_unit:
                     raise DeviceUnsupported("datetime arithmetic compare on device")
             return _compare(
                 build_num(left), build_num(right), op,
@@ -578,7 +624,21 @@ def compile_predicate(expr: Expr, codecs: Dict[str, ColumnCodec]):
             raise DeviceUnsupported("input_file_name() is host-only")
         raise DeviceUnsupported(f"unsupported boolean expr {type(e).__name__}")
 
-    vf, uf = build_bool(expr)
+    return build_bool
+
+
+def compile_predicate(expr: Expr, codecs: Dict[str, ColumnCodec], lit_base: int = 0):
+    """Compile ``expr`` into ``(f, lit_values)`` where
+    ``f(cols: dict[str, jnp.ndarray], lits: tuple) -> bool mask`` and
+    ``lit_values`` is the concrete argument tuple for this query; its slots
+    start at ``lit_base`` of the ``lits`` it is called with (a program with
+    two predicates hands both one tuple).
+
+    Raises DeviceUnsupported for shapes outside the device language (string
+    arithmetic, input_file_name(), col-vs-col string compares, ...).
+    """
+    slots = _LitSlots(lit_base)
+    vf, uf = _bool_builder(codecs, slots)(expr)
 
     def fn(cols, lits):
         return vf(cols, lits) & ~uf(cols, lits)
@@ -1345,6 +1405,8 @@ class ScanColumns:
         #: every column was resident when the query asked
         self.resident = bool(self.names) and len(self._found) == len(self.names)
         self._asked = (len(self._found), len(self.names) - len(self._found))
+        #: how many columns were not, and would be uploaded
+        self.missing = self._asked[1]
 
     def _ckey(self, c):
         return (self.scan_key, c, self._fp) if self.scan_key is not None else None
@@ -1510,6 +1572,69 @@ def device_scan_aggregate(
     return _dense_grouped_aggregate(*args)
 
 
+def _fused_reduce(cols, mask, agg_spec):
+    """The traced reductions of ungrouped ``agg_spec`` (``(fn, column)``
+    pairs) over the rows of ``cols`` that ``mask`` keeps: ``(values, counts of
+    non-null matches)``, scalars all. The body ``fused-agg`` and
+    ``join-agg-resident`` share."""
+    import jax.numpy as jnp
+
+    cnt = mask.sum()
+    outs = []
+    valids = []  # per-aggregate non-null match count (NaN-skipping)
+    for fn, c in agg_spec:
+        if fn == "count":
+            if c is None or not jnp.issubdtype(cols[c].dtype, jnp.floating):
+                outs.append(cnt.astype(jnp.int64))
+            else:
+                # count(col) skips nulls (NaN), like the host path
+                outs.append((mask & ~jnp.isnan(cols[c])).sum().astype(jnp.int64))
+            valids.append(cnt)
+            continue
+        x = cols[c]
+        is_int = jnp.issubdtype(x.dtype, jnp.integer) or x.dtype == jnp.bool_
+        # pandas semantics: NaNs are skipped, not propagated
+        m = mask if is_int else (mask & ~jnp.isnan(x))
+        valids.append(m.sum())
+        if fn == "sum":
+            # integer sums stay int64 (host-path parity; exact)
+            z = x.astype(jnp.int64) if is_int else x.astype(jnp.float64)
+            outs.append(jnp.where(m, z, z.dtype.type(0)).sum())
+        elif fn == "avg":
+            xf = x.astype(jnp.float64)
+            outs.append(jnp.where(m, xf, 0.0).sum() / jnp.maximum(m.sum(), 1))
+        elif fn == "min":
+            if is_int:
+                outs.append(jnp.where(m, x.astype(jnp.int64), jnp.iinfo(jnp.int64).max).min())
+            else:
+                outs.append(jnp.where(m, x.astype(jnp.float64), jnp.inf).min())
+        else:  # max
+            if is_int:
+                outs.append(jnp.where(m, x.astype(jnp.int64), jnp.iinfo(jnp.int64).min).max())
+            else:
+                outs.append(jnp.where(m, x.astype(jnp.float64), -jnp.inf).max())
+    return tuple(outs), tuple(valids)
+
+
+def _fused_result(aggs, outs, valids) -> B.Batch:
+    """The one-row answer of ungrouped ``aggs`` from what :func:`_fused_reduce`
+    gave, with the host path's NULLs and dtypes."""
+    result: Dict[str, np.ndarray] = {}
+    for (name, fn, c), val, n_valid in zip(aggs, outs, (int(v) for v in valids)):
+        if fn == "count":
+            result[name] = np.asarray([int(val)])
+        elif n_valid == 0:
+            # no non-null matches: SQL yields NULL (sum included — SUM over
+            # zero rows is NULL, not 0)
+            result[name] = np.asarray([np.nan])
+        elif fn != "avg" and np.asarray(val).dtype.kind in ("i", "u"):
+            # an integer input (or integer-valued computed one) keeps int64
+            result[name] = np.asarray([int(val)])
+        else:
+            result[name] = np.asarray([float(val)])
+    return result
+
+
 def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skeleton, aggs):
     import jax.numpy as jnp
 
@@ -1525,41 +1650,7 @@ def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skel
         mask = valid if pred_fn is None else (pred_fn(cols, lits) & valid)
         if comp_fn is not None:
             cols = comp_fn(cols, lits)
-        cnt = mask.sum()
-        outs = []
-        valids = []  # per-aggregate non-null match count (NaN-skipping)
-        for fn, c in agg_spec:
-            if fn == "count":
-                if c is None or not jnp.issubdtype(cols[c].dtype, jnp.floating):
-                    outs.append(cnt.astype(jnp.int64))
-                else:
-                    # count(col) skips nulls (NaN), like the host path
-                    outs.append((mask & ~jnp.isnan(cols[c])).sum().astype(jnp.int64))
-                valids.append(cnt)
-                continue
-            x = cols[c]
-            is_int = jnp.issubdtype(x.dtype, jnp.integer) or x.dtype == jnp.bool_
-            # pandas semantics: NaNs are skipped, not propagated
-            m = mask if is_int else (mask & ~jnp.isnan(x))
-            valids.append(m.sum())
-            if fn == "sum":
-                # integer sums stay int64 (host-path parity; exact)
-                z = x.astype(jnp.int64) if is_int else x.astype(jnp.float64)
-                outs.append(jnp.where(m, z, z.dtype.type(0)).sum())
-            elif fn == "avg":
-                xf = x.astype(jnp.float64)
-                outs.append(jnp.where(m, xf, 0.0).sum() / jnp.maximum(m.sum(), 1))
-            elif fn == "min":
-                if is_int:
-                    outs.append(jnp.where(m, x.astype(jnp.int64), jnp.iinfo(jnp.int64).max).min())
-                else:
-                    outs.append(jnp.where(m, x.astype(jnp.float64), jnp.inf).min())
-            else:  # max
-                if is_int:
-                    outs.append(jnp.where(m, x.astype(jnp.int64), jnp.iinfo(jnp.int64).min).max())
-                else:
-                    outs.append(jnp.where(m, x.astype(jnp.float64), -jnp.inf).max())
-        return tuple(outs), tuple(valids)
+        return _fused_reduce(cols, mask, agg_spec)
 
     key = _program_key(skeleton, mesh)
     jitted = _cached_predicate_jit(key, program, "fused-agg")
@@ -1570,25 +1661,10 @@ def _fused_aggregate(session, cols, dev_cols, pred_fn, comp_fn, lit_values, skel
     with launch("fused-agg"):
         outs, valids = jitted(dev_cols, lit_values, np.int64(n))
     outs, valids = fetch((outs, valids), "agg-table", "fused-agg")
-    valids = [int(v) for v in valids]
     _observe_program("fused-agg", first, t0)
     trace.agg_rows("device", n)
     _annotate_tier(program="fused-agg")
-
-    result: Dict[str, np.ndarray] = {}
-    for (name, fn, c), val, n_valid in zip(aggs, outs, valids):
-        if fn == "count":
-            result[name] = np.asarray([int(val)])
-        elif n_valid == 0:
-            # no non-null matches: SQL yields NULL (sum included — SUM over
-            # zero rows is NULL, not 0)
-            result[name] = np.asarray([np.nan])
-        elif fn != "avg" and np.asarray(val).dtype.kind in ("i", "u"):
-            # an integer input (or integer-valued computed one) keeps int64
-            result[name] = np.asarray([int(val)])
-        else:
-            result[name] = np.asarray([float(val)])
-    return result
+    return _fused_result(aggs, outs, valids)
 
 
 # --------------------------------------------------------------------------
@@ -2412,6 +2488,103 @@ def _dense_key_plan(group_keys, codecs, max_groups: int):
     return plan, groups
 
 
+def _dense_slots(aggs, comp_fn, dev_cols, codecs, lit_values):
+    """``(input dtypes, slots, refs, index of the matched-row count)`` of a
+    dense grouped aggregate: :func:`_resident_slots`, and a ``cntm`` slot
+    (which groups hold a row at all) where no aggregate asked for one."""
+    input_dtypes, slots, refs = _resident_slots(aggs, comp_fn, dev_cols, codecs, lit_values)
+    if not any(kind == "cntm" for kind, _, _ in slots):
+        slots = slots + [("cntm", None, True)]
+    cntm_at = next(i for i, (kind, _, _) in enumerate(slots) if kind == "cntm")
+    return input_dtypes, slots, refs, cntm_at
+
+
+def _dense_reduce(cols, mask, rows, plan, groups: int, slots):
+    """The traced group table of the direct-addressed program: every state of
+    ``slots`` for each of ``groups`` mixed-radix groups of ``plan``'s
+    dictionary codes, over the rows of ``cols`` that ``mask`` keeps, in ONE
+    variadic reduction. ``rows`` numbers the rows (its dtype is the
+    bookkeeping's: int32 below 2^31 rows); the first of a group orders the
+    answer. ``(first rows, the slots' tables)``, int64 and float64. The body
+    ``grouped-agg-dense`` and ``join-agg-resident`` share."""
+    import jax
+    import jax.numpy as jnp
+
+    idx = rows.dtype
+    total = rows.shape[0]
+    with jax.named_scope("key-encode"):
+        gid = jnp.zeros((total,), jnp.int32)
+        for name, size, off in plan:
+            gid = gid * size + (cols[name].astype(jnp.int32) + off)
+        # (groups, rows): a row reduction over the minor axis per group
+        member = (jnp.arange(groups, dtype=jnp.int32)[:, None] == gid[None, :]) & mask[None, :]
+
+    def operand(kind, col, isint):
+        """``(values[groups, rows], identity, combiner)`` of one state."""
+        if col is None:
+            return member.astype(idx), 0, jnp.add
+        x = cols[col]
+        nn = member if isint else (member & ~jnp.isnan(x)[None, :])
+        if kind == "cnt":
+            return nn.astype(idx), 0, jnp.add
+        # integer sums stay int64 (exact), every float state is float64
+        z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
+        if kind in ("sum", "sumsq"):
+            fill, fold = 0, jnp.add
+            z = z * z if kind == "sumsq" else z
+        elif kind == "min":
+            fill, fold = (jnp.iinfo(jnp.int64).max if isint else jnp.inf), jnp.minimum
+        else:  # max
+            fill, fold = (jnp.iinfo(jnp.int64).min if isint else -jnp.inf), jnp.maximum
+        return jnp.where(nn, z[None, :], z.dtype.type(fill)), fill, fold
+
+    with jax.named_scope("group-reduce"):
+        last = jnp.iinfo(idx).max
+        operands = {"fs": (jnp.where(member, rows[None, :], last), last, jnp.minimum)}
+        at = []
+        for kind, col, isint in slots:
+            if kind == "cntm" or (kind == "cnt" and isint):
+                # every member row of an int column counts: one operand
+                kind, col = "cnt", None
+            key = (kind, col, isint)
+            if key not in operands:
+                operands[key] = operand(kind, col, isint)
+            at.append(key)
+        values, fills, folds = zip(*operands.values())
+        reduced = jax.lax.reduce(
+            values,
+            tuple(v.dtype.type(f) for v, f in zip(values, fills)),
+            lambda acc, x: tuple(fold(a, b) for fold, a, b in zip(folds, acc, x)),
+            (1,),
+        )
+        table = {
+            key: r.astype(jnp.int64) if jnp.issubdtype(r.dtype, jnp.integer) else r
+            for key, r in zip(operands, reduced)
+        }
+    return table["fs"], tuple(table[key] for key in at)
+
+
+def _dense_result(plan, codecs, group_keys, aggs, refs, input_dtypes, fs, slot_out, cntm_at) -> B.Batch:
+    """The answer of a dense grouped aggregate from its group table: the
+    groups that hold a row, in first-appearance order (pandas sort=False),
+    their keys decoded from the mixed-radix group number."""
+    live = np.flatnonzero(slot_out[cntm_at] > 0)
+    live = live[np.argsort(fs[live], kind="stable")]
+    result: B.Batch = {}
+    radix = live.copy()
+    for name, size, off in reversed(plan):
+        codes = radix % size - off
+        radix = radix // size
+        vals = np.full(len(live), np.nan, dtype=object)
+        pos = codes >= 0
+        if pos.any():
+            vals[pos] = np.asarray(codecs[name].uniques, dtype=object)[codes[pos]]
+        result[name] = vals
+    result = {k: result[k] for k in group_keys}
+    result.update(_final_columns(aggs, refs, input_dtypes, [s[live] for s in slot_out]))
+    return result
+
+
 def _dense_grouped_aggregate(
     session, cols, dev_cols, codecs, pred_fn, comp_fn, lit_values, skeleton,
     group_keys, aggs, max_groups,
@@ -2422,10 +2595,7 @@ def _dense_grouped_aggregate(
     mesh = cols.mesh
     n = cols.rows
     plan, groups = _dense_key_plan(group_keys, codecs, max_groups)
-    input_dtypes, slots, refs = _resident_slots(aggs, comp_fn, dev_cols, codecs, lit_values)
-    if not any(kind == "cntm" for kind, _, _ in slots):
-        slots = slots + [("cntm", None, True)]  # which groups have a row at all
-    cntm_at = next(i for i, (kind, _, _) in enumerate(slots) if kind == "cntm")
+    input_dtypes, slots, refs, cntm_at = _dense_slots(aggs, comp_fn, dev_cols, codecs, lit_values)
 
     def program(cols, lits, n_valid):
         cols = join_columns(cols)
@@ -2439,56 +2609,7 @@ def _dense_grouped_aggregate(
                 mask = pred_fn(cols, lits) & mask
         if comp_fn is not None:
             cols = comp_fn(cols, lits)
-        with jax.named_scope("key-encode"):
-            gid = jnp.zeros((total,), jnp.int32)
-            for name, size, off in plan:
-                gid = gid * size + (cols[name].astype(jnp.int32) + off)
-            # (groups, rows): a row reduction over the minor axis per group
-            member = (jnp.arange(groups, dtype=jnp.int32)[:, None] == gid[None, :]) & mask[None, :]
-
-        def operand(kind, col, isint):
-            """``(values[groups, rows], identity, combiner)`` of one state."""
-            if col is None:
-                return member.astype(idx), 0, jnp.add
-            x = cols[col]
-            nn = member if isint else (member & ~jnp.isnan(x)[None, :])
-            if kind == "cnt":
-                return nn.astype(idx), 0, jnp.add
-            # integer sums stay int64 (exact), every float state is float64
-            z = x.astype(jnp.int64) if isint else x.astype(jnp.float64)
-            if kind in ("sum", "sumsq"):
-                fill, fold = 0, jnp.add
-                z = z * z if kind == "sumsq" else z
-            elif kind == "min":
-                fill, fold = (jnp.iinfo(jnp.int64).max if isint else jnp.inf), jnp.minimum
-            else:  # max
-                fill, fold = (jnp.iinfo(jnp.int64).min if isint else -jnp.inf), jnp.maximum
-            return jnp.where(nn, z[None, :], z.dtype.type(fill)), fill, fold
-
-        with jax.named_scope("group-reduce"):
-            last = jnp.iinfo(idx).max
-            operands = {"fs": (jnp.where(member, rows[None, :], last), last, jnp.minimum)}
-            at = []
-            for kind, col, isint in slots:
-                if kind == "cntm" or (kind == "cnt" and isint):
-                    # every member row of an int column counts: one operand
-                    kind, col = "cnt", None
-                key = (kind, col, isint)
-                if key not in operands:
-                    operands[key] = operand(kind, col, isint)
-                at.append(key)
-            values, fills, folds = zip(*operands.values())
-            reduced = jax.lax.reduce(
-                values,
-                tuple(v.dtype.type(f) for v, f in zip(values, fills)),
-                lambda acc, x: tuple(fold(a, b) for fold, a, b in zip(folds, acc, x)),
-                (1,),
-            )
-            table = {
-                key: r.astype(jnp.int64) if jnp.issubdtype(r.dtype, jnp.integer) else r
-                for key, r in zip(operands, reduced)
-            }
-        return table["fs"], tuple(table[key] for key in at)
+        return _dense_reduce(cols, mask, rows, plan, groups, slots)
 
     skeleton = (
         f"gdense[{groups}]:{skeleton}|k:{','.join(f'{k}:{size}:{off}' for k, size, off in plan)}"
@@ -2507,24 +2628,10 @@ def _dense_grouped_aggregate(
     fs, slot_out = fetch(out, "agg-table", "grouped-agg-dense")
     _observe_program("grouped-agg-dense", first, t0)
     trace.agg_rows("device", n)
-
-    # groups that hold a row, in first-appearance order (pandas sort=False)
-    live = np.flatnonzero(slot_out[cntm_at] > 0)
-    live = live[np.argsort(fs[live], kind="stable")]
-    result: B.Batch = {}
-    radix = live.copy()
-    for name, size, off in reversed(plan):
-        codes = radix % size - off
-        radix = radix // size
-        vals = np.full(len(live), np.nan, dtype=object)
-        pos = codes >= 0
-        if pos.any():
-            vals[pos] = np.asarray(codecs[name].uniques, dtype=object)[codes[pos]]
-        result[name] = vals
-    result = {k: result[k] for k in group_keys}
-    result.update(_final_columns(aggs, refs, input_dtypes, [s[live] for s in slot_out]))
-    _count_groups("grouped-agg-dense", len(live))
-    _annotate_tier(program="grouped-agg-dense", groups=len(live))
+    result = _dense_result(plan, codecs, group_keys, aggs, refs, input_dtypes, fs, slot_out, cntm_at)
+    n_groups = len(next(iter(result.values())))
+    _count_groups("grouped-agg-dense", n_groups)
+    _annotate_tier(program="grouped-agg-dense", groups=n_groups)
     return result
 
 

@@ -1443,6 +1443,14 @@ class Executor:
         # program over HBM-resident columns; only scalars transfer back
         child = None
         if not with_file_names and self.session.conf.device_execution_enabled:
+            # an aggregate over a join of two index scans, from the columns of
+            # both resident on the device: tried before the host bucket walk,
+            # decided before any file is opened (and so before the join's own
+            # stream gate is reached)
+            got = self._try_resident_join_aggregate(plan)
+            if got is not None:
+                trace.record("agg", "device-join-scan")
+                return got
             # fused aggregate over a bucketed join: spans give each pair's
             # multiplicity, so no join output is ever materialized (global
             # aggregates, or grouped by the join keys)
@@ -2172,6 +2180,73 @@ class Executor:
                 tier.set(fallback="unsupported")
             # not answered here: the caller reuses the scan if this tier read it
             return got, cols.loaded, filter_node, _kept_groups(node)
+
+    def _try_resident_join_aggregate(self, plan: L.Aggregate) -> Optional[B.Batch]:
+        """The resident join-aggregate tier (``exec/join_agg.py``): an
+        ``Aggregate`` over an inner equi-``Join`` of two index-scan chains,
+        answered by one device program over the columns of both scans and the
+        build side's table, all resident. None means the caller runs the
+        tiers behind it (the host bucket walk, the join tiers, ``agg-host``).
+
+        Chosen from what the code observes, before any file is read: the
+        scans' identities (their log entries': a refresh or optimize is
+        another identity), their rows (the resident columns' own, else the
+        files' footers), ``deviceMinRows`` for both together, and whether
+        what is not resident yet of the two scans' needed columns and the
+        build side's table fits the device cache's budget
+        (``deviceCacheBytes``; fallback ``over-cap``). The probe side is the
+        scan with more rows. Another shape is not this tier's and is not
+        counted; every refusal past that is a
+        ``hs_device_fallback_total{op=agg}``."""
+        from hyperspace_tpu.exec import device as D
+        from hyperspace_tpu.exec import join_agg as JA
+
+        conf = self.session.conf
+        shape = JA.join_aggregate_shape(plan)
+        if shape is None or (plan.keys and not conf.agg_device_grouped_enabled):
+            return None
+        sides = []
+        for side in (shape.left, shape.right):
+            identity = scan_identity(side.scan)
+            if identity is None:
+                return None
+            cols = D.ScanColumns(
+                self.session, identity, JA.side_columns(side, shape.reads),
+                functools.partial(self._exec, side.scan, with_file_names=False),
+            )
+            rows = cols.rows if cols.resident else _footer_rows(side.scan, identity)
+            if rows is None:
+                return None
+            sides.append((rows, side, cols))
+        sides.sort(key=lambda s: s[0], reverse=True)  # stable: the left side probes a tie
+        (rows_p, probe, cols_p), (rows_b, build, cols_b) = sides
+        if rows_p + rows_b < conf.device_exec_min_rows:
+            trace.fallback("agg", "min-rows")
+            return None
+        table_resident = JA.resident_table(cols_b, build.key) is not None
+        resident = cols_p.resident and cols_b.resident and table_resident
+        with spans.span("agg-device-join-scan", cat="exec", resident="hit" if resident else "miss") as tier:
+            try:
+                if conf.parallel_enabled:
+                    raise D.DeviceUnsupported("no sharded form of the resident join-aggregate")
+                if not resident:
+                    JA.check_fits([
+                        (rows_p, cols_p.missing, 0),
+                        (rows_b, cols_b.missing, 0 if table_resident else JA.table_budget_bytes(rows_b)),
+                    ])
+                got, found = JA.device_join_aggregate(
+                    self.session, probe, build, cols_p, cols_b, shape.computes,
+                    list(plan.keys), list(plan.aggs), max_groups=conf.agg_max_groups,
+                )
+                tier.set(**found)
+                return got
+            except D.ResidentOverCap:
+                trace.fallback("agg", "over-cap")
+                tier.set(fallback="over-cap")
+            except D.DeviceUnsupported:
+                trace.fallback("agg", "unsupported")
+                tier.set(fallback="unsupported")
+        return None
 
     def _device_aggregate(self, plan: L.Aggregate, cols, condition, computes) -> B.Batch:
         """One program over the scan's device columns: ``fused-agg``,

@@ -104,6 +104,17 @@ class _Request:
         return (self.token, self.fp.structure)
 
 
+def _program_families() -> Dict[str, Any]:
+    """Every device program family the build registers a contract for."""
+    import importlib
+
+    for module in ("exec.device", "exec.join_agg", "exec.join_stream", "exec.lineage", "ops.bucketize", "ops.sort"):
+        importlib.import_module(f"hyperspace_tpu.{module}")
+    from hyperspace_tpu.check import hlo_lint
+
+    return hlo_lint.registered_contracts()
+
+
 class QueryServer:
     """Concurrent query-serving runtime over a :class:`Session`.
 
@@ -119,7 +130,11 @@ class QueryServer:
     ``sched_burn_factor``, ``result_cache_enabled``, ``result_cache_bytes``,
     ``result_cache_max_entry_bytes``, ``result_cache_subsumption``; plus
     ``name`` (explicit metrics ``server=`` label, defaulting to the
-    process-sequential ``qsN``).
+    process-sequential ``qsN``) and ``requires`` (no conf key: names of device
+    program families, as ``check.hlo_lint`` registers them, that the
+    deployment was sized for; a build that has no family of that name raises
+    ``ValueError`` here, before a request is taken, where it would answer
+    each of them on the host. It asserts, and chooses no tier).
     """
 
     def __init__(self, session, **overrides):
@@ -279,8 +294,13 @@ class QueryServer:
         self.telemetry = None
         self._telemetry_port = conf.obs_http_port
         self._telemetry_host = conf.obs_http_host
+        requires = tuple(opt("requires", ()))
         if overrides:
             raise TypeError(f"Unknown QueryServer options: {sorted(overrides)}")
+        if requires:
+            missing = sorted(set(requires) - set(_program_families()))
+            if missing:
+                raise ValueError(f"this build has no device program family {missing}; the deployment requires {sorted(requires)}")
 
         self._sql_memo_lock = named_lock("serving.sqlMemo")
         self._sql_memo: Dict[str, tuple] = {}

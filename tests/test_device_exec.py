@@ -182,6 +182,144 @@ class TestDeviceFilter:
         run_both(session, q)
 
 
+class TestCompilerDateCompareAndCase:
+    """The predicate compiler's two extensions of PR 43: a compare of two
+    datetime columns of one unit, and ``CASE`` with boolean conditions as a
+    numeric expression (a computed aggregate input)."""
+
+    @staticmethod
+    def _encoded(batch):
+        import jax.numpy as jnp
+
+        D.ensure_x64()
+        cols, codecs = {}, {}
+        for name, arr in batch.items():
+            enc, codecs[name] = D.encode_column(arr)
+            cols[name] = jnp.asarray(enc)
+        return cols, codecs
+
+    @pytest.fixture()
+    def dates(self):
+        a = np.array(["2024-01-05", "2024-02-01", "NaT", "2024-03-09", "2024-03-09"], dtype="datetime64[D]")
+        b = np.array(["2024-01-06", "2024-01-31", "2024-01-01", "NaT", "2024-03-09"], dtype="datetime64[D]")
+        return {"a": a, "b": b, "s": a.astype("datetime64[s]")}
+
+    @pytest.mark.parametrize("op", ["<", "<=", "=", "!=", ">", ">="])
+    def test_two_date_columns_of_one_unit_compare_on_the_device(self, dates, op):
+        from hyperspace_tpu.plan.expr import BinaryOp, as_bool_mask
+
+        cond = BinaryOp(op, col("a"), col("b"))
+        cols, codecs = self._encoded(dates)
+        fn, lits = D.compile_predicate(cond, codecs)
+        got = np.asarray(fn(cols, lits))
+        a, b = dates["a"], dates["b"]
+        known = ~(np.isnat(a) | np.isnat(b))  # a NaT on either side is unknown: never kept
+        want = {"<": a < b, "<=": a <= b, "=": a == b, "!=": a != b, ">": a > b, ">=": a >= b}[op] & known
+        assert got.tolist() == want.tolist()
+        assert got[known].tolist() == as_bool_mask(cond.eval(dates))[known].tolist(), "the host's answer where both are dates"
+
+    def test_not_of_a_date_column_compare_keeps_no_nat_row(self, dates):
+        cols, codecs = self._encoded(dates)
+        fn, lits = D.compile_predicate(~(col("a") < col("b")), codecs)
+        assert np.asarray(fn(cols, lits)).tolist() == [False, True, False, False, True]
+
+    @pytest.mark.parametrize("cond", [lambda: col("a") < col("s"), lambda: col("a") < col("b") + lit(1), lambda: col("a") < col("n")])
+    def test_two_units_or_date_arithmetic_are_refused_as_before(self, dates, cond):
+        batch = dict(dates, n=np.arange(5, dtype=np.int64))
+        _cols, codecs = self._encoded(batch)
+        with pytest.raises(D.DeviceUnsupported):
+            D.compile_predicate(cond(), codecs)
+
+    @pytest.fixture()
+    def rows(self):
+        return {
+            "p": np.array(["1-URGENT", "3-MEDIUM", None, "2-HIGH", "5-LOW", "1-URGENT"], dtype=object),
+            "q": np.array([5, 7, 11, 13, 17, 19], dtype=np.int64),
+            "x": np.array([0.5, np.nan, 1.5, 2.5, 3.5, 4.5]),
+            "d": np.array(["2024-01-05", "2024-02-01", "NaT", "2024-03-09", "2024-03-09", "2024-04-01"], dtype="datetime64[D]"),
+        }
+
+    def _computed(self, rows, expr):
+        cols, codecs = self._encoded(rows)
+        fn, lits, skeleton = D.compile_computes([("c", expr)], codecs)
+        assert "Case(" in skeleton
+        return np.asarray(fn(cols, lits)["c"])
+
+    def test_case_over_string_codes_with_or_and_ne(self, rows):
+        """Q12's two inputs: a NULL priority is in neither count."""
+        from hyperspace_tpu.plan.expr import Case
+
+        high = Case([((col("p") == lit("1-URGENT")) | (col("p") == lit("2-HIGH")), lit(1))], lit(0))
+        low = Case([((col("p") != lit("1-URGENT")) & (col("p") != lit("2-HIGH")), lit(1))], lit(0))
+        for expr in (high, low):
+            got = self._computed(rows, expr)
+            assert got.dtype == np.int64 and got.tolist() == np.asarray(expr.eval(rows)).tolist()
+        assert self._computed(rows, high).tolist() == [1, 0, 0, 1, 0, 1] and self._computed(rows, low).tolist() == [0, 1, 0, 0, 1, 0]
+
+    def test_the_first_true_branch_wins_and_values_may_be_expressions(self, rows):
+        from hyperspace_tpu.plan.expr import Case
+
+        expr = Case([(col("q") > lit(12), col("q") * lit(2)), (col("q") > lit(6), col("x") + lit(100.0)),
+                     (col("d") < lit(np.datetime64("2024-01-10")), lit(-1))], col("q"))
+        got = self._computed(rows, expr)
+        np.testing.assert_array_equal(got, np.asarray(expr.eval(rows), dtype=np.float64))
+        assert got.tolist()[0] == -1.0 and np.isnan(got[1]) and got.tolist()[2:] == [101.5, 26.0, 34.0, 38.0]
+
+    def test_a_case_without_else_is_null(self, rows):
+        from hyperspace_tpu.plan.expr import Case
+
+        for otherwise in (None, lit(None)):
+            expr = Case([(col("p") == lit("1-URGENT"), col("q"))], otherwise)
+            got = self._computed(rows, expr)
+            assert got.dtype == np.float64
+            np.testing.assert_array_equal(got, np.asarray(expr.eval(rows), dtype=np.float64))
+            assert got.tolist()[0] == 5.0 and np.isnan(got[1:5]).all() and got[5] == 19.0
+
+    @pytest.mark.parametrize("expr", [
+        lambda C: C([(col("q") > lit(6), lit("big"))], lit("small")),  # a string's value
+        lambda C: C([(col("q") > lit(6), col("d"))], col("d")),  # a date's value has a unit, no arithmetic
+        lambda C: C([(col("p") == col("p"), lit(1))], lit(0)),  # a string column against a column
+    ])
+    def test_a_case_outside_the_language_is_refused(self, rows, expr):
+        from hyperspace_tpu.plan.expr import Case
+
+        _cols, codecs = self._encoded(rows)
+        with pytest.raises(D.DeviceUnsupported):
+            D.compile_computes([("c", expr(Case))], codecs)
+
+    def test_like_and_functions_are_outside_the_language_before_any_type_is_known(self):
+        from hyperspace_tpu.plan.expr import Case, Like
+
+        assert D.in_device_language(Case([((col("a") == lit("x")) | col("b").is_null(), col("c") * lit(2))], lit(0)))
+        assert not D.in_device_language(Case([(Like(col("a"), "PROMO%"), col("c"))], lit(0)))
+
+    def test_case_as_a_computed_input_of_the_scan_tiers_program(self, session):
+        """``sf10-report``'s tier takes it too: a grouped count by CASE over
+        one scan's resident columns, through ``device_scan_aggregate``."""
+        from hyperspace_tpu.plan.expr import Case
+
+        rng = np.random.default_rng(43)
+        n = 5000
+        batch = {
+            "mode": rng.choice(np.array(["AIR", "MAIL", "SHIP"], dtype=object), n),
+            "pri": rng.choice(np.array(["1-URGENT", "2-HIGH", "3-MEDIUM", None], dtype=object), n),
+            "qty": rng.integers(1, 51, n).astype(np.int64),
+        }
+        computes = [("hi", Case([((col("pri") == lit("1-URGENT")) | (col("pri") == lit("2-HIGH")), col("qty"))], lit(0)))]
+        aggs = [("high_qty", "sum", "hi"), ("n", "count", None)]
+        D.clear_device_cache()
+        cols = D.ScanColumns(session, (("mem://case", 1, n),), ["mode", "pri", "qty"], lambda: batch)
+        got = D.device_scan_aggregate(session, cols, col("qty") > lit(10), computes, ["mode"], aggs)
+        keep = batch["qty"] > 10
+        hi = np.where(np.isin(batch["pri"].astype(str), ["1-URGENT", "2-HIGH"]), batch["qty"], 0)
+        for mode, high_qty, count in zip(got["mode"], got["high_qty"], got["n"]):
+            rows = keep & (batch["mode"] == mode)
+            assert (int(high_qty), int(count)) == (int(hi[rows].sum()), int(rows.sum())), mode
+        assert sorted(got["mode"]) == ["AIR", "MAIL", "SHIP"] and got["high_qty"].dtype == np.int64
+        glob = D.device_scan_aggregate(session, cols, col("qty") > lit(10), computes, [], aggs)
+        assert int(glob["high_qty"][0]) == int(hi[keep].sum()) and int(glob["n"][0]) == int(keep.sum())
+
+
 def _link_counters():
     """(h2d bytes of scan columns, resident-column hits, misses) so far."""
     from hyperspace_tpu.obs.metrics import REGISTRY
@@ -1833,3 +1971,27 @@ def test_grouped_fused_name_collision_with_key(session, tmp_path):
     plain = q.collect()
     session.conf.set(hst.keys.TPU_QUERY_DEVICE_EXECUTION, True)
     assert sorted(fused["a#r"].tolist()) == sorted(plain["a#r"].tolist()) == [100, 200]
+
+
+_CELLS = np.array(["MAIL", "AIR", "SHIP", "REG AIR", "TRUCK", "FOB", "RAIL", "", "\u00e9", "a\0", "a", None], dtype=object)
+
+
+@pytest.mark.parametrize("cells", [
+    _CELLS[np.random.default_rng(1).integers(0, 7, 5000)],        # strings alone
+    _CELLS[np.random.default_rng(2).integers(0, 12, 5000)],       # NULLs, "", a trailing NUL, non-ASCII
+    _CELLS[np.random.default_rng(3).integers(0, 12, 1)],
+    _CELLS[:0],
+    np.array([None, None], dtype=object),
+    np.array(["b", "a", "b"]),                                    # a fixed-width unicode column
+    np.array([b"b", b"a"]),                                       # bytes: every cell's str()
+    np.array(["x", 1, 1.5, None, float("nan"), "1"], dtype=object),  # cells that are neither str nor None
+], ids=["strings", "nulls", "one", "empty", "all-null", "unicode", "bytes", "mixed"])
+def test_factorize_strings_equals_the_sort_of_all_cells(cells):
+    from hyperspace_tpu.ops.encode import _factorize_by_sort, factorize_strings
+
+    got, want = factorize_strings(cells), _factorize_by_sort(cells.astype(object))
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+    codes, uniques, null_mask = got
+    assert codes.dtype == np.int64 and np.array_equal(codes < 0, null_mask)
+    assert np.array_equal(uniques, np.sort(uniques)) and len(set(uniques.tolist())) == len(uniques)

@@ -127,6 +127,10 @@ Q12 = (
     "from orders, lineitem where o_orderkey = l_orderkey and l_shipmode in ('MAIL', 'SHIP') "
     "group by l_shipmode order by l_shipmode"
 )
+JOIN_ROWS = (  # the join's rows are wanted, not an aggregate over them: the bucketed join tiers
+    "select l_orderkey, l_price, o_flag from orders, lineitem "
+    "where o_orderkey = l_orderkey and l_shipmode in ('MAIL', 'SHIP')"
+)
 Q3 = (
     "select l_orderkey, sum(l_price) as revenue from customer, orders, lineitem "
     "where c_segment = 'BUILDING' and c_custkey = o_custkey and l_orderkey = o_orderkey "
@@ -150,6 +154,9 @@ def _oracle(sql, t):
         j = li[li.l_shipmode.isin(["MAIL", "SHIP"])].merge(o, left_on="l_orderkey", right_on="o_orderkey")
         g = j.assign(high=(j.o_flag == "1-URGENT").astype(int), low=(j.o_flag != "1-URGENT").astype(int))
         return g.groupby("l_shipmode", as_index=False)[["high", "low"]].sum().sort_values("l_shipmode")
+    if sql == JOIN_ROWS:
+        j = li[li.l_shipmode.isin(["MAIL", "SHIP"])].merge(o, left_on="l_orderkey", right_on="o_orderkey")
+        return j[["l_orderkey", "l_price", "o_flag"]]
     if sql == Q3:
         j = c[c.c_segment == "BUILDING"].merge(o, left_on="c_custkey", right_on="o_custkey")
         j = j.merge(li[li.l_shipdate > day("1994-01-20")], left_on="o_orderkey", right_on="l_orderkey")
@@ -222,7 +229,8 @@ SHAPES = {
     "point-lookup-cold": (LOOKUP, True, True, False, None),
     "point-lookup-hot": (LOOKUP, True, False, False, None),
     "range-aggregate": (RANGE_AGG, True, True, True, None),
-    "bucketed-join-q12": (Q12, False, True, True, ("join", ("device-smj", "host-span-smj"))),
+    "bucketed-join-q12": (Q12, False, True, True, ("agg", ("device-join-scan",))),  # since PR 43 the resident join-aggregate
+    "bucketed-join-rows": (JOIN_ROWS, False, True, False, ("join", ("device-smj", "host-span-smj"))),
     "generic-merge-join-q3": (Q3, False, True, True, ("join", ("generic-merge",))),
     "scan-aggregate-q6": (Q6, False, True, True, None),
 }

@@ -392,6 +392,23 @@ def test_server_rejects_unknown_option(simple):
         QueryServer(simple, wrokers=2)
 
 
+@pytest.mark.parametrize("requires, error", [
+    (["join-agg-resident"], None),
+    (("fused-agg", "grouped-agg-dense", "join-agg-resident"), None),
+    ([], None),
+    (["join-agg-resident", "no-such-program"], "no-such-program"),
+])
+def test_server_requires_program_families(simple, requires, error):
+    """``requires`` asserts that the build registers the device program
+    families a deployment was sized for; it refuses at construction."""
+    if error is None:
+        with QueryServer(simple, requires=requires, workers=1):
+            pass
+        return
+    with pytest.raises(ValueError, match=error):
+        QueryServer(simple, requires=requires)
+
+
 def test_serving_conf_defaults(simple):
     conf = simple.conf
     assert conf.serving_queue_depth == 64
