@@ -35,8 +35,14 @@ program family ``join-agg-resident`` (executables ``jit_hs_join_agg*``):
    query goes on to the tiers there are. A probe row without a match adds
    nothing (inner join); a NULL key matches nothing.
 4. *gather and fold*: the columns the aggregate reads are gathered for the
-   selected rows only, the probe side's by position, the build side's by the
-   match; computed inputs (``CASE`` included) run over them, and the fold is
+   selected rows only, the probe side's by position, one word a row: what a
+   selected probe row hands on (its key as the offset into the table's range,
+   a dictionary code in the bits its dictionary needs) is packed into 32-bit
+   **row words** beside the mask, where the planes are streamed anyway, and
+   one gather a word fetches it (:func:`_row_layout`; a column whose codec
+   does not bound its values keeps its planes and is gathered from them). The
+   build side's are gathered by the match; computed inputs (``CASE``
+   included) run over them, and the fold is
    the scan tiers' own: ``fused-agg``'s reductions for a global aggregate,
    ``grouped-agg-dense``'s one variadic reduction for group keys that are
    dictionary codes of either side (at most 64 combinations). Counts and
@@ -79,7 +85,7 @@ _COLLECTIVES = {"all-gather": _ANY, "all-reduce": _ANY, "all-to-all": _ANY, "col
 _hlo_lint.register_contract(
     "join-agg-resident",
     collectives=_COLLECTIVES,
-    description="aggregate over a join of two resident scans: mask, compaction by two one-operand sorts, probe of the build side's table, gathers of the selected rows, fold; only the group table leaves",
+    description="aggregate over a join of two resident scans: mask and the probe side's row words, compaction by two one-operand sorts, the selected probe rows fetched one word a row, probe of the build side's table, gather of the matched build rows, fold; only the group table leaves",
 )
 _hlo_lint.register_contract(
     "join-agg-probe",
@@ -464,27 +470,106 @@ def _gather(columns: dict, at):
     return D.join_columns(jax.tree_util.tree_unflatten(tree, taken))
 
 
-def _lookup(table_form: str, arrays, lo, key, on):
-    """``(build row, matched)`` of each selected probe key: traced."""
+class _Field(NamedTuple):
+    """``bits`` bits of row word ``word``, from bit ``shift`` up."""
+
+    word: int
+    shift: int
+    bits: int
+
+
+class RowLayout(NamedTuple):
+    """Where each value a selected probe row hands on rides: the key's code
+    of the build table and the dictionary columns in ``words`` row words, and
+    the columns whose planes are gathered as they are."""
+
+    key: _Field
+    columns: tuple  # (name, _Field) of every dictionary column the fold reads of the probe side
+    planes: tuple  # names of the columns the codec does not bound
+    words: int
+
+    def skeleton(self) -> str:
+        return ",".join(f"{name}@{f.word}.{f.shift}+{f.bits}" for name, f in (("", self.key), *self.columns))
+
+    def fields(self) -> dict:
+        """How many fields ride in each form (``hs_join_row_fields_total``)."""
+        return {"word": 1 + len(self.columns), "planes": len(self.planes)}
+
+
+def _row_layout(table_form: str, size: int, take, codecs) -> RowLayout:
+    """The layout of the probe side's row words, from what is observed and
+    nothing configured: the key as its code of the build table (``direct``:
+    the offset into ``size`` slots, ``size`` itself for "no build row";
+    ``sorted``: the 32-bit code, ``_NO_CODE`` for none), each dictionary
+    column of ``take`` as code + 1 (NULL is 0) in the bits its dictionary
+    needs. Fields go, in that order, into the first 32-bit word with room. A
+    column the codec does not bound stays planes."""
+    used: List[int] = []
+
+    def place(bits: int) -> _Field:
+        for word, taken in enumerate(used):
+            if taken + bits <= 32:
+                used[word] += bits
+                return _Field(word, taken, bits)
+        used.append(bits)
+        return _Field(len(used) - 1, 0, bits)
+
+    key = place(size.bit_length() if table_form == "direct" else 32)
+    packed = [c for c in take if codecs[c].kind == "string"]
+    columns = tuple((c, place(len(codecs[c].uniques).bit_length())) for c in packed)
+    return RowLayout(key, columns, tuple(c for c in take if c not in packed), len(used))
+
+
+def _row_words(pcols, layout: RowLayout, key_codec, key: str, none: int, lo, n_valid):
+    """The probe side's row words (``layout.words`` uint32 arrays of its
+    padded length): traced, element-wise over the planes the mask streams.
+    The key's code is its offset from ``lo`` where that is under ``none``,
+    and ``none`` where it has no build row: under ``lo``, past the table's
+    range, NULL, or a row outside the scan."""
+    import jax
     import jax.numpy as jnp
 
-    off = key - lo
+    with jax.named_scope("row-words"):
+        k = D.join_planes(pcols[key])
+        off = k - lo
+        inside = _key_valid(k, key_codec, n_valid) & (off >= 0) & (off < none)
+        values = [(layout.key, jnp.where(inside, off, none))] + [(f, pcols[c] + 1) for c, f in layout.columns]
+        words = [jnp.uint32(0)] * layout.words
+        for f, v in values:
+            words[f.word] = words[f.word] | (v.astype(jnp.uint32) << f.shift)
+        return tuple(words)
+
+
+def _field(words, f: _Field):
+    """Field ``f`` of the gathered row words, as uint32."""
+    return (words[f.word] >> f.shift) & np.uint32((1 << f.bits) - 1)
+
+
+def _no_code(table_form: str, arrays) -> int:
+    """The key code that stands for "no build row", one past the codes a
+    table of ``arrays`` can hold."""
+    return arrays[0].shape[0] if table_form == "direct" else int(_NO_CODE)
+
+
+def _lookup(table_form: str, arrays, code, on):
+    """``(build row, matched)`` of each selected probe row's key code (its
+    row word's: :func:`_row_words`): traced."""
+    import jax.numpy as jnp
+
+    on = on & (code != _no_code(table_form, arrays))
     if table_form == "direct":
         (table,) = arrays
-        size = table.shape[0]
-        row = table.at[jnp.clip(off, 0, size - 1).astype(jnp.int32)].get(mode="promise_in_bounds")
-        return jnp.maximum(row, 0), on & (off >= 0) & (off < size) & (row >= 0)
+        row = table.at[jnp.minimum(code, table.shape[0] - 1).astype(jnp.int32)].get(mode="promise_in_bounds")
+        return jnp.maximum(row, 0), on & (row >= 0)
     codes, rows = arrays
     n = codes.shape[0]
-    inside = on & (off >= 0) & (off < int(_NO_CODE))
-    code = jnp.where(inside, off, 0).astype(jnp.uint32)
     below = jnp.zeros(code.shape, jnp.int32)  # how many codes lie under the probe's: a lower bound, bit by bit
     for bit in reversed(range(n.bit_length())):
         step = below + jnp.int32(1 << bit)
         under = (step <= n) & (codes.at[jnp.minimum(step, n) - 1].get(mode="promise_in_bounds") < code)
         below = jnp.where(under, step, below)
     at = jnp.minimum(below, n - 1)
-    found = inside & (below < n) & (codes.at[at].get(mode="promise_in_bounds") == code)
+    found = on & (below < n) & (codes.at[at].get(mode="promise_in_bounds") == code)
     return rows.at[at].get(mode="promise_in_bounds"), found
 
 
@@ -551,6 +636,19 @@ def _count_probe_rows(selected: int, matched: int) -> None:
         c.inc(rows)
 
 
+def _count_row_fields(layout: RowLayout) -> None:
+    """One launch of ``join-agg-resident`` in ``hs_join_row_fields_total{form}``:
+    the fields a selected probe row hands on (its key and each column the
+    fold reads of it), ``word`` where one rides in a row word, ``planes``
+    where its planes are gathered."""
+    for form, fields in layout.fields().items():
+        _REGISTRY.counter(
+            "hs_join_row_fields_total",
+            "Fields a selected probe row of the resident join-aggregate hands on past the compaction, a launch: packed into a 32-bit row word, or gathered from the column's planes",
+            form=form,
+        ).inc(fields)
+
+
 def _selected_rows(session, cols: D.ScanColumns, dev_cols, codec_key, key: str, pred_fn, pred_cols, lits, skeleton: str, total: int) -> int:
     """How many probe rows the predicate keeps (a NULL key is not kept): the
     capacity the program is built for. One launch of ``join-agg-probe`` the
@@ -599,36 +697,39 @@ def _probe_mask(cols, codec_key, key: str, pred_fn, pred_cols, lits, n_valid):
     return mask
 
 
-def _join_program(probe_key: str, key_codec, compiled: _Compiled, preds, takes, totals, cap: int, form: str,
-                  table_form: str, fold):
+def _join_program(probe_key: str, key_codec, compiled: _Compiled, preds, take_b, totals, cap: int, form: str,
+                  table_form: str, layout: RowLayout, fold):
     """The traced body of ``join-agg-resident`` for ``totals`` padded rows
     (probe, build), ``cap`` selected probe rows going on (no fewer than the
     predicate keeps: the probe counted them) compacted by ``form``, a build
     table of ``table_form``. ``preds``: each side's predicate columns;
-    ``takes``: the columns gathered of each side; ``fold``: ``("dense", plan,
-    groups, slots)`` of a grouped fold, or ``("fused", (fn, column) pairs)``
-    of a global one."""
+    ``take_b``: the build side's columns gathered by the match; ``layout``:
+    how a selected probe row is fetched, one word a row; ``fold``: ``("dense",
+    plan, groups, slots)`` of a grouped fold, or ``("fused", (fn, column)
+    pairs)`` of a global one."""
     import jax
     import jax.numpy as jnp
 
-    (pred_p, pred_b), (take_p, take_b), (total_p, total_b) = preds, takes, totals
+    (pred_p, pred_b), (total_p, total_b) = preds, totals
 
     def program(pcols, bcols, arrays, lo, lits, n_probe, n_build):
         mask = _probe_mask(pcols, key_codec, probe_key, compiled.probe_pred, pred_p, lits, n_probe)
+        words = _row_words(pcols, layout, key_codec, probe_key, _no_code(table_form, arrays), lo, n_probe)
         with jax.named_scope("compact"):
             at = _selected_positions(mask, total_p, cap, form)
             on = at < total_p
             at = jnp.minimum(at, total_p - 1)
         with jax.named_scope("probe"):
-            key = _gather({probe_key: pcols[probe_key]}, at)[probe_key]
-            row, matched = _lookup(table_form, arrays, lo, key, on)
+            taken = [w.at[at].get(mode="promise_in_bounds") for w in words]
+            row, matched = _lookup(table_form, arrays, _field(taken, layout.key), on)
             counts = (on.sum(dtype=jnp.int32), matched.sum(dtype=jnp.int32))
             if compiled.build_pred is not None:
                 keep = compiled.build_pred(D.join_columns({c: bcols[c] for c in pred_b}), lits)
                 keep = keep & (jnp.arange(total_b, dtype=jnp.int32) < n_build.astype(jnp.int32))
                 matched = matched & keep.at[row].get(mode="promise_in_bounds")
         with jax.named_scope("gather"):
-            cols = {**_gather({c: bcols[c] for c in take_b}, row), **_gather({c: pcols[c] for c in take_p}, at)}
+            cols = {**_gather({c: bcols[c] for c in take_b}, row), **_gather({c: pcols[c] for c in layout.planes}, at),
+                    **{c: _field(taken, f).astype(jnp.int32) - 1 for c, f in layout.columns}}
         if compiled.comp_fn is not None:
             cols = compiled.comp_fn(cols, lits)
         if fold[0] == "fused":
@@ -693,12 +794,13 @@ def device_join_aggregate(
                               compiled.lits[: compiled.probe_lits], compiled.skeletons[0], total_p)
     cap = min(total_p, D._keyed_block_capacity(selected))
     form = _compaction(total_p, cap)
+    layout = _row_layout(table.form, int(table.arrays[0].shape[0]), take_p, codecs_p)
 
     fold_args = ("dense", plan, groups, slots) if group_keys else ("fused", agg_spec)
-    program = _join_program(probe.key, key_codec, compiled, (pred_p, pred_b), (take_p, take_b),
-                            (total_p, total_b), cap, form, table.form, fold_args)
+    program = _join_program(probe.key, key_codec, compiled, (pred_p, pred_b), take_b,
+                            (total_p, total_b), cap, form, table.form, layout, fold_args)
     skeleton = (f"jagg[{total_p},{total_b},{cap},{form},{table.form}]:{probe.key}={build.key}|{'|'.join(compiled.skeletons)}"
-                f"|{fold}|p:{','.join(take_p)}|b:{','.join(take_b)}")
+                f"|{fold}|p:{','.join(take_p)}|b:{','.join(take_b)}|w:{layout.skeleton()}")
     key = D._program_key(skeleton, mesh)
     jitted = D._cached_predicate_jit(key, program, "join-agg-resident")
     args = (dev_p, dev_b, table.arrays, np.int64(table.lo), compiled.lits, np.int64(n_p), np.int64(n_b))
@@ -712,6 +814,7 @@ def device_join_aggregate(
     D._observe_program("join-agg-resident", first, t0)
     n_selected, n_matched = int(n_selected), int(n_matched)
     _count_probe_rows(n_selected, n_matched)
+    _count_row_fields(layout)
     trace.agg_rows("device", n_p)
     if group_keys:
         result = D._dense_result(plan, codecs, group_keys, aggs, refs, input_dtypes, *folded, cntm_at)
@@ -720,5 +823,6 @@ def device_join_aggregate(
     else:
         result, n_groups = D._fused_result(aggs, *folded), 1
     found = dict(program="join-agg-resident", probe_rows=n_p, build_rows=n_b, selected=n_selected,
-                 matched=n_matched, groups=n_groups, table=table.form, capacity=cap, compaction=form)
+                 matched=n_matched, groups=n_groups, table=table.form, capacity=cap, compaction=form,
+                 row_words=layout.words, **{f"fields_{form}": n for form, n in layout.fields().items()})
     return result, found

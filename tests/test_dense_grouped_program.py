@@ -28,7 +28,11 @@ imports this file), and the tests that need it skip where it cannot be
 described. This is the only test file that describes one, so the keyed
 program's copy kernel (``ops/kernels.copy_blocks``) is compiled here too: at
 the benchmark's planes, Mosaic takes it (a block starts and ends on a tile of
-a one-dimensional plane), and the module it makes reads no plane whole.
+a one-dimensional plane), and the module it makes reads no plane whole. And
+so is the resident join-aggregate (``exec/join_agg.py``) at ``sf10-join``'s
+shapes: one operation gathers out of a probe-length operand, the row word,
+which a fusion of its own writes (fused into the gather as its operand's
+producer it would be three random reads a row again).
 """
 
 import re
@@ -318,3 +322,74 @@ def test_the_copy_kernel_compiles_for_the_chip_and_reads_no_plane_whole(one_chip
     assert len(calls) == 1 and calls[0].count(f"[{768 * block}]") == len(planes), calls
     others = [l for l in entry if l not in calls and " parameter(" not in l and f"[{SF10_PADDED_ROWS}]" in l]
     assert not others, others
+
+
+def test_the_join_program_fetches_a_selected_probe_row_once(one_chip):
+    """TPC-H Q12 as ``sf10-join`` asks it: 67,126,100 padded ``lineitem`` rows
+    (nine planes), 16,781,524 ``orders`` rows and as many slots of the direct
+    table, 557,056 selected rows going on. The key's offset (25 bits) and the
+    mode's code (3) ride in one row word."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from hyperspace_tpu.exec import join_agg as JA
+    from hyperspace_tpu.plan.expr import Case, col, lit
+
+    D.ensure_x64()
+    build_rows, cap = 16_781_524, 557_056
+    dates = ("l_shipdate", "l_commitdate", "l_receiptdate")
+    strings = lambda values: D.ColumnCodec("string", uniques=np.array(values, dtype=object), dtype=np.dtype(object), nulls=False)
+    codecs_p = {"l_orderkey": D.ColumnCodec("numeric", dtype=np.dtype("int64")),
+                "l_shipmode": strings(["AIR", "FOB", "MAIL", "RAIL", "REG AIR", "SHIP", "TRUCK"]),
+                **{c: D.ColumnCodec("datetime", unit="D", dtype=np.dtype("M8[D]")) for c in dates}}
+    codecs_b = {"o_orderkey": D.ColumnCodec("numeric", dtype=np.dtype("int64")),
+                "o_orderpriority": strings(["1-URGENT", "2-HIGH", "3-MEDIUM", "4-NOT SPECIFIED", "5-LOW"])}
+    day = lambda text: lit(np.datetime64(text))
+    cond = ((col("l_shipmode").isin("MAIL", "SHIP")) & (col("l_commitdate") < col("l_receiptdate")) & (col("l_shipdate") < col("l_commitdate"))
+            & (col("l_receiptdate") >= day("1994-01-01")) & (col("l_receiptdate") < day("1995-01-01")))
+    high = (col("o_orderpriority") == lit("1-URGENT")) | (col("o_orderpriority") == lit("2-HIGH"))
+    low = (col("o_orderpriority") != lit("1-URGENT")) & (col("o_orderpriority") != lit("2-HIGH"))
+    computes = [("hi", Case([(high, lit(1))], lit(0))), ("lo", Case([(low, lit(1))], lit(0)))]
+    aggs, keys = [("high_line_count", "sum", "hi"), ("low_line_count", "sum", "lo")], ["l_shipmode"]
+    sides = (JA.JoinSide(None, cond, "l_orderkey", frozenset(codecs_p)), JA.JoinSide(None, None, "o_orderkey", frozenset(codecs_b)))
+    made = JA._compile(sides, codecs_p, codecs_b, computes, aggs, keys)
+    codecs = {**codecs_b, **codecs_p}
+    plan, groups = D._dense_key_plan(keys, codecs, 0)
+    dtypes = {c: jax.ShapeDtypeStruct((8,), np.dtype("int32" if codecs[c].kind == "string" else "int64")) for c in codecs}
+    _, slots, _, _ = D._dense_slots(aggs, made.comp_fn, dtypes, codecs, made.lits)
+    layout = JA._row_layout("direct", build_rows, ["l_shipmode"], codecs_p)
+    assert layout.skeleton() == "@0.0+25,l_shipmode@0.25+3" and (layout.words, layout.planes) == (1, ())
+    program = JA._join_program("l_orderkey", codecs_p["l_orderkey"], made, (sorted(cond.references()), []), ["o_orderpriority"],
+                               (SF10_PADDED_ROWS, build_rows), cap, JA._compaction(SF10_PADDED_ROWS, cap), "direct", layout, ("dense", plan, groups, slots))
+
+    plane = lambda n, dt: jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+    planes = lambda n: D.ColumnPlanes(plane(n, jnp.uint32), plane(n, jnp.int32))
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
+    pcols = {"l_orderkey": planes(SF10_PADDED_ROWS), "l_shipmode": plane(SF10_PADDED_ROWS, jnp.int32), **{c: planes(SF10_PADDED_ROWS) for c in dates}}
+    bcols = {"o_orderkey": planes(build_rows), "o_orderpriority": plane(build_rows, jnp.int32)}
+    args = (pcols, bcols, (plane(build_rows, jnp.int32),), scalar(np.int64), tuple(scalar(np.asarray(v).dtype) for v in made.lits), scalar(np.int64), scalar(np.int64))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(hlo_lint.named("join-agg-resident", program)).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    entry = text[text.index("\nENTRY "):]
+    entry = [l.strip() for l in entry[:entry.index("\n}")].splitlines() if " = " in l and not l.startswith("ENTRY")]
+    split = {name: (result, tail) for name, result, tail in map(_split, entry)}
+    long = lambda name: f"[{SF10_PADDED_ROWS}]" in split.get(name, ("",))[0]
+    # what takes a probe-length operand and hands on the selected rows' length gathers out of it
+    gathers = [(name, [n for n in _operands(tail) if long(n)]) for name, (result, tail) in split.items()
+               if f"[{cap}]" in result and any(long(n) for n in _operands(tail))]
+    assert len(gathers) == 1 and len(gathers[0][1]) == 1, gathers
+    word, (result, tail) = gathers[0][1][0], split[gathers[0][1][0]]
+    assert result.startswith("u32[") and tail.startswith("fusion("), (word, result, tail[:80])
+    # written by a fusion of its own or by the mask's, whichever the compiler makes: either way it streams what the word is made of
+    assert {n for n in _operands(tail) if long(n)} >= {n for n in split if re.match(r"%pcols__l_(orderkey|shipmode)", n)}, "the word's pass reads the key's planes and the mode's codes"
+    parameters = [n for n, (result, tail) in split.items() if tail.startswith("parameter(") and long(n)]
+    assert len(parameters) == 9, parameters
+    readers = [tail for _, tail in split.values() if tail.startswith("fusion(")]
+    assert all(any(n in _operands(tail) for tail in readers) for n in parameters), "every plane is streamed by a fusion"
+    assert not [n for n, (result, tail) in split.items() if tail.startswith(("sort(", "while(")) and long(n)]

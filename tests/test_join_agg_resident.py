@@ -15,7 +15,11 @@ build side's table made by the tier itself. Held here:
   compare of two date columns, inputs and group keys from either side;
 - a build key that repeats is refused before any other column of the side is
   uploaded; keys no form takes are refused;
-- the table is made once an index version and again for another.
+- the table is made once an index version and again for another;
+- the probe side's row words: a layout of one word and of two, a column that
+  stays planes beside a packed key, NULL keys and codes, keys outside the
+  table's range, the widths' edges, both compactions, columns resident as
+  planes; what ``hs_join_row_fields_total`` and the tier's span say of each.
 """
 
 import numpy as np
@@ -299,3 +303,104 @@ def test_the_table_is_made_once_an_index_version_and_again_for_another(sess):
     assert list(refreshed["high"]) != list(first["high"])
     tables = [k for k in D._device_cache.keys() if k[1] == ("join-table", "o_key")]
     assert len(tables) == 2 and D._device_cache.total_bytes >= sum(D._device_cache_get(k).nbytes for k in tables), "the budget counts them"
+
+
+# -- the probe side's row words -------------------------------------------------
+
+
+def _row_fields(form: str) -> float:
+    return REGISTRY.counter("hs_join_row_fields_total", "", form=form).value
+
+
+def _modes(probe: pd.DataFrame, values, seed: int) -> pd.DataFrame:
+    return probe.assign(l_mode=np.random.default_rng(seed).choice(np.array(values, dtype=object), len(probe)))
+
+
+COUNTS = [("n", "count", None), ("q", "sum", "l_qty")]
+# case -> (frames' arguments, what is asked, (row_words, fields_word, fields_planes), table, compaction)
+ROW_WORD_CASES = {
+    # the key's offset into a direct table and a dictionary code: 13 + 3 bits
+    "one-word": (dict(seed=21, orphans=0.1), dict(pcond=Q12_FILTER, computes=Q12_COMPUTES, keys=["l_mode"], aggs=Q12_AGGS), (1, 2, 0), "direct", "whole"),
+    # a sorted table's 32-bit code fills a word: the mode's code goes into a second
+    "two-words": (dict(seed=22, key_step=1000, orphans=0.1), dict(pcond=Q12_FILTER, computes=Q12_COMPUTES, keys=["l_mode"], aggs=Q12_AGGS), (2, 2, 0), "sorted", "whole"),
+    # a float64 and an int64 the codec does not bound: gathered from their own columns, the key packed alone
+    "unbounded-columns-stay-planes": (dict(seed=23), dict(pcond=col("l_qty") == lit(7), keys=["o_priority"], aggs=COUNTS + [("p", "sum", "l_price")]), (1, 1, 2), "direct", "words"),
+    "null-code": (dict(seed=24), dict(pcond=col("l_qty") == lit(7), keys=["l_mode"], aggs=COUNTS), (1, 2, 1), "direct", "words"),
+    "keys-outside-the-range": (dict(seed=25), dict(pcond=col("l_qty") == lit(7), keys=["l_mode"], aggs=COUNTS), (1, 2, 1), "direct", "words"),
+    "dictionary-of-one": (dict(seed=26), dict(pcond=col("l_qty") == lit(7), keys=["l_mode", "o_priority"], aggs=COUNTS), (1, 2, 1), "direct", "words"),
+    "dictionary-of-eight": (dict(seed=27), dict(pcond=col("l_qty") == lit(7), keys=["l_mode"], aggs=COUNTS), (1, 2, 1), "direct", "words"),
+    "whole": (dict(seed=28, probe_rows=3000, build_rows=300), dict(pcond=col("l_qty") == lit(7), keys=["l_mode"], aggs=COUNTS), (1, 2, 1), "direct", "whole"),
+    "two-words-sparse": (dict(seed=29, key_step=1000, orphans=0.2), dict(pcond=col("l_qty") == lit(7), keys=["l_mode"], aggs=COUNTS), (2, 2, 1), "sorted", "words"),
+    "columns-as-planes": (dict(seed=30, orphans=0.1), dict(pcond=Q12_FILTER, computes=Q12_COMPUTES, keys=["l_mode"], aggs=Q12_AGGS), (1, 2, 0), "direct", "whole"),
+}
+
+
+@pytest.mark.parametrize("case", list(ROW_WORD_CASES))
+def test_a_selected_probe_row_is_fetched_as_row_words(sess, monkeypatch, case):
+    """Every value a selected probe row hands on rides in a 32-bit row word
+    where the code knows its bounds, and the answer is the reference's."""
+    sess.set_mesh(make_mesh(1))
+    frames, asked, (row_words, fields_word, fields_planes), table, compaction = ROW_WORD_CASES[case]
+    probe, build = _frames(**frames)
+    if case == "keys-outside-the-range":  # under ``lo`` (17), past the table's slots, past 32 bits of offset, negative
+        probe.loc[probe.index[::7], "l_key"] = np.resize(np.array([3, 16, 17 + 10**6, 2**40, -5, 2**62], dtype=np.int64), len(probe.index[::7]))
+    elif case == "dictionary-of-one":  # one bit: NULL or the value
+        probe = _modes(probe, ["AIR", None], 1)
+    elif case == "dictionary-of-eight":  # 2^3 values and NULL: code + 1 needs a fourth bit
+        probe = _modes(probe, [f"M{i}" for i in range(8)] + [None], 2)
+    elif case == "columns-as-planes":  # the chip's resident form: every 8-byte column as two 32-bit planes
+        monkeypatch.setattr(D, "computes_in_pairs", lambda mesh: True)
+    before = {form: _row_fields(form) for form in ("word", "planes")}
+    got, found = _ask(sess, probe, build, **asked)
+    if case == "columns-as-planes":
+        assert all(isinstance(D._device_cache_get(k)[0], D.ColumnPlanes) for k in D._device_cache.keys() if k[1] in ("l_key", "l_qty", "l_ship", "o_key"))
+    keys = asked["keys"]
+    if "computes" in asked:
+        want = _q12_reference(probe, build, keys)
+    else:
+        cond = asked.get("pcond")
+        want = R.join_aggregate(probe, build, ("l_key", "o_key"), left_filter=(lambda f: (f.l_qty == 7).to_numpy()) if cond is not None else None, keys=keys, aggs=asked["aggs"])
+    _same(got, want, keys)
+    assert (found["row_words"], found["fields_word"], found["fields_planes"]) == (row_words, fields_word, fields_planes)
+    assert {form: _row_fields(form) - before[form] for form in before} == {"word": fields_word, "planes": fields_planes}
+    assert (found["table"], found["compaction"]) == (table, compaction)
+    if case in ("one-word", "two-words", "keys-outside-the-range", "two-words-sparse", "columns-as-planes"):
+        assert 0 < found["matched"] < found["selected"], "a probe key without a build row adds nothing"
+    else:
+        assert found["matched"] == found["selected"] > 0
+    if case == "null-code":
+        assert found["groups"] == 6 and sum(v is None or v != v for v in got["l_mode"]) == 1, "the NULL mode is a group of its own"
+
+
+def test_a_null_key_in_a_row_word_matches_nothing(sess):
+    """Timestamp keys a second apart (a direct table) with NaT on both sides:
+    the row word of a NULL key holds no offset."""
+    rng = np.random.default_rng(31)
+    days = DAY0.astype("datetime64[s]") + np.arange(500).astype("timedelta64[s]")
+    build = pd.DataFrame({"o_day": np.concatenate([rng.permutation(days), [np.datetime64("NaT")] * 3]),
+                          "o_priority": rng.choice(np.array(PRIORITIES, dtype=object), 503)})
+    pk = rng.choice(days, 9000)
+    pk[rng.random(9000) < 0.05] = np.datetime64("NaT")
+    probe = pd.DataFrame({"l_day": pk, "l_mode": rng.choice(np.array(MODES + [None], dtype=object), 9000)})
+    aggs = [("n", "count", None)]
+    got, found = _ask(sess, probe, build, keys=["l_mode", "o_priority"], aggs=aggs, on=("l_day", "o_day"))
+    _same(got, R.join_aggregate(probe, build, ("l_day", "o_day"), keys=["l_mode", "o_priority"], aggs=aggs), ["l_mode", "o_priority"])
+    assert found["table"] == "direct" and found["selected"] == int((~pd.isna(probe.l_day)).sum()) == found["matched"]
+    assert (found["row_words"], found["fields_word"], found["fields_planes"]) == (1, 2, 0)
+
+
+@pytest.mark.parametrize("table, size, uniques, want", [
+    ("direct", 16_781_524, [7], "@0.0+25,a@0.25+3"),  # tpch-sf10-join: one word
+    ("direct", 16_781_524, [1, 8, 63], "@0.0+25,a@0.25+1,b@0.26+4,c@1.0+6"),  # 2^k values need k + 1 bits; over 32: the next word
+    ("direct", 2**24, [127, 3], "@0.0+25,a@0.25+7,b@1.0+2"),  # a table of 2^24 slots: ``size`` itself is a code
+    ("direct", 2**24 - 1, [127, 3, 1], "@0.0+24,a@0.24+7,b@1.0+2,c@0.31+1"),  # a later field takes the room an earlier word has left
+    ("sorted", 600, [5], "@0.0+32,a@1.0+3"),
+    ("sorted", 600, [], "@0.0+32"),
+])
+def test_the_row_words_layout_follows_what_is_observed(table, size, uniques, want):
+    names = "abc"[: len(uniques)]
+    codecs = {c: D.ColumnCodec("string", uniques=np.array([f"v{i}" for i in range(n)], dtype=object)) for c, n in zip(names, uniques)}
+    codecs["x"] = D.ColumnCodec("numeric", dtype=np.dtype("float64"))
+    layout = JA._row_layout(table, size, sorted([*names, "x"]), codecs)
+    assert layout.skeleton() == want and layout.planes == ("x",)
+    assert layout.words == 1 + max(f.word for f in [layout.key, *(f for _, f in layout.columns)])
